@@ -2,8 +2,7 @@
 
 Run: ``python -m benchmarks.run [config1|config2|config3|config4|config5|all]``
 
-Each config prints one JSON line with the same schema as the driver's
-bench.py ({"metric", "value", "unit", "vs_baseline", ...}) plus
-config-specific detail fields.  The repo-root bench.py remains the
-driver's single headline number (config 2's shape).
+Each config prints one JSON line ({"metric", "value", "unit",
+"vs_baseline", ...}) plus config-specific detail fields.  Configs 2 and 5
+report device rates and fail without a TPU.
 """

@@ -1,14 +1,10 @@
-"""Workload helpers shared by the driver headline bench (repo-root
-bench.py) and the full config harness (benchmarks/run.py) — one generator,
-so the two can't drift apart."""
+"""Workload helpers shared by chip_smoke.py, the scenario workers
+(repo-root bench.py) and the config harness (benchmarks/run.py) — one
+generator, so they can't drift apart."""
 
 from __future__ import annotations
 
-import json
-import os
 import random
-import signal
-import subprocess
 import time
 
 __all__ = [
@@ -18,94 +14,7 @@ __all__ = [
     "cpu_single_core_bench",
     "cpu_single_core_stats",
     "cpu_single_core_rate",
-    "run_json_subprocess",
 ]
-
-
-def run_json_subprocess(
-    argv: list, timeout: float, env_extra: dict | None = None,
-    cwd: str | None = None,
-) -> dict:
-    """Run a subprocess in its own process group; parse its last JSON line.
-
-    Shared by bench.py's watchdog ladder and benchmarks/watcher.py (the
-    round-long sampler) so the trickiest subprocess logic exists once:
-    the whole process GROUP is killed on timeout, because the TPU shim
-    spawns helpers that inherit the stdout pipe and killing only the
-    direct child leaves communicate() blocked on them forever.  On
-    timeout, the worker's last ``[bench-worker]`` stderr progress line is
-    surfaced so the error says what the worker was doing.
-    """
-    env = dict(os.environ)
-    env.update(env_extra or {})
-    proc = subprocess.Popen(
-        argv, cwd=cwd, env=env, stdout=subprocess.PIPE,
-        stderr=subprocess.PIPE, text=True, start_new_session=True,
-    )
-    try:
-        stdout, stderr = proc.communicate(timeout=timeout)
-    except subprocess.TimeoutExpired:
-        try:
-            os.killpg(proc.pid, signal.SIGKILL)
-        except (ProcessLookupError, PermissionError):
-            proc.kill()
-        try:
-            _, stderr = proc.communicate(timeout=10)
-        except subprocess.TimeoutExpired:
-            stderr = ""
-        last = ""
-        for line in (stderr or "").splitlines():
-            if line.startswith("[bench-worker]"):
-                last = line
-        return {
-            "ok": False,
-            "error": f"timed out after {timeout:.0f}s"
-            + (f" (last: {last})" if last else ""),
-        }
-    for line in reversed((stdout or "").splitlines()):
-        line = line.strip()
-        if line.startswith("{"):
-            try:
-                return json.loads(line)
-            except json.JSONDecodeError:
-                continue
-    return {
-        "ok": False,
-        "error": f"worker rc={proc.returncode}, no JSON "
-        f"(stderr tail: {(stderr or '')[-300:]!r})",
-    }
-
-
-def worker_rung_env(batch: int, kernel: str | None = None,
-                    point_form: str | None = None,
-                    field_reduce: str | None = None,
-                    window_bits: int | None = None):
-    """Env + display label for one device-ladder rung.
-
-    Shared by bench.py's round-end ladder and benchmarks/watcher.py (the
-    round-long sampler) so the TPUNODE_BENCH_* worker contract lives in
-    one place: ``kernel`` None means auto-select (pallas on TPU), "xla"
-    forces the portable XLA program (the Mosaic-outage fallback);
-    ``point_form`` selects the MSM point form (ISSUE 8 — the watcher's
-    affine rungs ride this); ``field_reduce``/``window_bits`` select the
-    ISSUE 12 lazy-reduction / window-width formulation (the watcher's
-    ``kind="lazy"`` rungs).  None keeps the worker's process default.
-    """
-    env = {"TPUNODE_BENCH_BATCH": str(batch),
-           "TPUNODE_BENCH_REQUIRE_TPU": "1"}
-    label = f"tpu{'-' + kernel if kernel else ''}@{batch}"
-    if kernel:
-        env["TPUNODE_BENCH_KERNEL"] = kernel
-    if point_form:
-        env["TPUNODE_POINT_FORM"] = point_form
-        label += f"/{point_form}"
-    if field_reduce:
-        env["TPUNODE_FIELD_REDUCE"] = field_reduce
-        label += f"/{field_reduce}"
-    if window_bits:
-        env["TPUNODE_WINDOW_BITS"] = str(window_bits)
-        label += f"/w{window_bits}"
-    return env, label
 
 
 def make_triples(n: int, seed: int = 0xBE5C, invalid_every: int = 16):

@@ -55,6 +55,7 @@ __all__ = [
     "gen_signed_txs",
     "gen_mixed_txs",
     "gen_chain",
+    "assemble_chain",
     "synth_amount",
     "synth_prevout",
     "cache_path",
@@ -484,6 +485,37 @@ def _coinbase(height: int) -> Tx:
     )
 
 
+def assemble_chain(
+    net: Network, all_txs: list[Tx], txs_per_block: int
+) -> list[Block]:
+    """Pack ``all_txs`` into consecutive regtest blocks on top of the
+    genesis (a coinbase + ``txs_per_block`` txs each; correct prev-links,
+    merkle roots, PoW by nonce grinding against the trivial target)."""
+    target = bits_to_target(net.genesis.bits)
+    prev = genesis_node(net).header.hash
+    t0 = net.genesis.timestamp
+    blocks = []
+    for h in range(len(all_txs) // txs_per_block):
+        txs = [_coinbase(h + 1)] + all_txs[h * txs_per_block : (h + 1) * txs_per_block]
+        merkle = build_merkle_root([t.txid for t in txs])
+        nonce = 0
+        while True:
+            hdr = BlockHeader(
+                version=0x20000000,
+                prev=prev,
+                merkle=merkle,
+                timestamp=t0 + 600 * (h + 1),
+                bits=net.genesis.bits,
+                nonce=nonce,
+            )
+            if int.from_bytes(hdr.hash, "little") <= target:
+                break
+            nonce += 1
+        blocks.append(Block(hdr, tuple(txs)))
+        prev = hdr.hash
+    return blocks
+
+
 def gen_chain(
     net: Network,
     n_blocks: int,
@@ -536,10 +568,6 @@ def gen_chain(
             except Exception:
                 pass  # short/corrupt cache — regenerate below
 
-    gen = genesis_node(net)
-    target = bits_to_target(net.genesis.bits)
-    prev = gen.header.hash
-    t0 = net.genesis.timestamp
     if mix:
         all_txs = gen_mixed_txs(
             n_blocks * txs_per_block,
@@ -558,25 +586,7 @@ def gen_chain(
             seed=seed,
             segwit_every=segwit_every,
         )
-    blocks = []
-    for h in range(n_blocks):
-        txs = [_coinbase(h + 1)] + all_txs[h * txs_per_block : (h + 1) * txs_per_block]
-        merkle = build_merkle_root([t.txid for t in txs])
-        nonce = 0
-        while True:
-            hdr = BlockHeader(
-                version=0x20000000,
-                prev=prev,
-                merkle=merkle,
-                timestamp=t0 + 600 * (h + 1),
-                bits=net.genesis.bits,
-                nonce=nonce,
-            )
-            if int.from_bytes(hdr.hash, "little") <= target:
-                break
-            nonce += 1
-        blocks.append(Block(hdr, tuple(txs)))
-        prev = hdr.hash
+    blocks = assemble_chain(net, all_txs, txs_per_block)
     if cache is not None:
         # atomic: a killed run must not leave a truncated cache behind
         path = cache_path(cache)

@@ -10,7 +10,6 @@ import os
 import subprocess
 import sys
 
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -97,38 +96,6 @@ def test_churn_soak_short():
     )
     assert out.returncode == 0, out.stdout[-1500:] + out.stderr[-1500:]
     assert "PASS" in out.stdout
-
-
-def test_mosaic_diag_interpret_cases():
-    """The Mosaic-outage diagnostic's cheap pallas cases run (interpret
-    mode) and the script emits its one JSON verdict line; the flagship
-    case is exercised by the heavy kernel tier's interpret tests."""
-    env = dict(os.environ)
-    env.update(TPUNODE_DIAG_INTERPRET="1", JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [
-            sys.executable,
-            "-c",
-            "import jax; jax.config.update('jax_platforms', 'cpu');"
-            "from benchmarks import mosaic_diag as d;"
-            "import json;"
-            "print(json.dumps([d._case('trivial', d._trivial),"
-            "                  d._case('field_mul', d._field_mul),"
-            "                  d._case('field_mul_dot', d._field_mul_dot),"
-            "                  d._case('table_build', d._table_build),"
-            "                  d._case('pow_window', d._pow_window),"
-            "                  d._case('pow_window_smem',"
-            "                          d._pow_window_smem)]))",
-        ],
-        cwd=REPO,
-        env=env,
-        capture_output=True,
-        text=True,
-        timeout=150,
-    )
-    assert out.returncode == 0, out.stdout[-1500:] + out.stderr[-1500:]
-    cases = json.loads(out.stdout.strip().splitlines()[-1])
-    assert [c["ok"] for c in cases] == [True] * 6, cases
 
 
 # ---------- roofline model (ISSUE 4 tentpole) ------------------------------
@@ -306,88 +273,6 @@ def test_roofline_lazy_reduce_model_pins():
                 "total_mul_like"])
 
 
-@pytest.mark.slow  # ~35 s of interpret-mode numpy in a subprocess
-def test_mosaic_diag_affine_primitive_cases():
-    """The ISSUE-8 mosaic_diag repro cases (mixed add, batch inversion,
-    select tree) pass in interpret mode; the de-scanned pow case — whose
-    interpret run is ~3 min of numpy — has its own slow test below."""
-    env = dict(os.environ)
-    env.update(TPUNODE_DIAG_INTERPRET="1", JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [
-            sys.executable,
-            "-c",
-            "import jax; jax.config.update('jax_platforms', 'cpu');"
-            "from benchmarks import mosaic_diag as d;"
-            "import json;"
-            "print(json.dumps([d._case('mixed_add', d._mixed_add),"
-            "                  d._case('batch_inv', d._batch_inv),"
-            "                  d._case('select_tree', d._select_tree)]))",
-        ],
-        cwd=REPO,
-        env=env,
-        capture_output=True,
-        text=True,
-        timeout=240,
-    )
-    assert out.returncode == 0, out.stdout[-1500:] + out.stderr[-1500:]
-    cases = json.loads(out.stdout.strip().splitlines()[-1])
-    assert [c["ok"] for c in cases] == [True] * 3, cases
-
-
-@pytest.mark.slow  # ~10 s of interpret-mode numpy in a subprocess
-def test_mosaic_diag_lazy_reduce_and_window5_cases():
-    """The ISSUE-12 mosaic_diag repro cases: the lazy wide accumulator
-    (47-sublane intermediates + one loose reduction) and the 5-bit
-    window constructs (32-entry VMEM table, 5-level select tree, shared
-    constant table) pass in interpret mode."""
-    env = dict(os.environ)
-    env.update(TPUNODE_DIAG_INTERPRET="1", JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [
-            sys.executable,
-            "-c",
-            "import jax; jax.config.update('jax_platforms', 'cpu');"
-            "from benchmarks import mosaic_diag as d;"
-            "import json;"
-            "print(json.dumps([d._case('lazy_reduce', d._lazy_reduce),"
-            "                  d._case('window5', d._window5)]))",
-        ],
-        cwd=REPO,
-        env=env,
-        capture_output=True,
-        text=True,
-        timeout=240,
-    )
-    assert out.returncode == 0, out.stdout[-1500:] + out.stderr[-1500:]
-    cases = json.loads(out.stdout.strip().splitlines()[-1])
-    assert [c["ok"] for c in cases] == [True] * 2, cases
-
-
-@pytest.mark.slow  # ~3 min of interpret-mode numpy for 64 unrolled windows
-def test_mosaic_diag_pow_descan_case():
-    env = dict(os.environ)
-    env.update(TPUNODE_DIAG_INTERPRET="1", JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [
-            sys.executable,
-            "-c",
-            "import jax; jax.config.update('jax_platforms', 'cpu');"
-            "from benchmarks import mosaic_diag as d;"
-            "import json;"
-            "print(json.dumps([d._case('pow_descan', d._pow_descan)]))",
-        ],
-        cwd=REPO,
-        env=env,
-        capture_output=True,
-        text=True,
-        timeout=420,
-    )
-    assert out.returncode == 0, out.stdout[-1500:] + out.stderr[-1500:]
-    cases = json.loads(out.stdout.strip().splitlines()[-1])
-    assert [c["ok"] for c in cases] == [True], cases
-
-
 def test_roofline_jaxpr_walk_counts_scans():
     """The jaxpr walker multiplies scan bodies by their trip count (a
     wrong multiplier would silently corrupt every derived bound)."""
@@ -410,423 +295,6 @@ def test_roofline_jaxpr_walk_counts_scans():
     assert c["add"] == 7.0
 
 
-# ---------- watcher: pidfile claim + pallas upgrade gating -----------------
-
-
-def _load_watcher():
-    import importlib
-
-    import benchmarks.watcher as watcher
-
-    return importlib.reload(watcher)
-
-
-def test_claim_pidfile_atomic(tmp_path, monkeypatch):
-    watcher = _load_watcher()
-    pid_path = str(tmp_path / ".watcher_pid")
-    monkeypatch.setattr(watcher, "PID_PATH", pid_path)
-    # clean claim: registers us under the flock
-    assert watcher._claim_pidfile() is True
-    assert int(open(pid_path).read().split()[0]) == os.getpid()
-    # the flock sidecar exists and must NEVER be deleted (deleting it
-    # would let a late claimer lock a fresh inode while an earlier one
-    # still holds the old file's lock — double watcher)
-    assert os.path.exists(pid_path + ".lock")
-    watcher._release_pidfile()
-    assert not os.path.exists(pid_path)
-    assert os.path.exists(pid_path + ".lock")
-    # stale claim (dead pid): overwritten under the lock
-    with open(pid_path, "w") as f:
-        f.write("999999999\n")
-    assert watcher._claim_pidfile() is True
-    assert int(open(pid_path).read().split()[0]) == os.getpid()
-    # live foreign watcher: the claim must be refused (no overwrite)
-    with open(pid_path, "w") as f:
-        f.write("424242\n")
-    monkeypatch.setattr(watcher, "_another_watcher_alive", lambda: True)
-    assert watcher._claim_pidfile(retries=2, wait_s=0.01) is False
-    assert open(pid_path).read().split()[0] == "424242"  # untouched
-
-
-def test_run_headline_reports_pallas_failed(monkeypatch, tmp_path):
-    watcher = _load_watcher()
-    monkeypatch.setattr(watcher, "RUNS_PATH", str(tmp_path / "runs.jsonl"))
-    monkeypatch.setattr(watcher, "_bench_running", lambda: False)
-    watcher._headline_banked = True  # post-bank LADDER sweep
-
-    calls = []
-
-    def fake_run_json(argv, timeout, env=None):
-        calls.append(env or {})
-        kernel = (env or {}).get("TPUNODE_BENCH_KERNEL")
-        if kernel == "xla":
-            return {"ok": True, "rate": 30000.0, "device": "tpu:v5e",
-                    "kernel": "xla", "batch": 8192}
-        # pallas rungs crash with a NON-Mosaic error (e.g. OOM)
-        return {"ok": False, "error": "worker rc=137, no JSON"}
-
-    monkeypatch.setattr(watcher, "_run_json", fake_run_json)
-    head, why, pallas_failed = watcher.run_headline()
-    assert head is not None and why == "banked"
-    assert head["kernel"] == "xla"
-    assert pallas_failed is True  # pallas rungs ran and failed
-    assert not watcher._mosaic_broken  # non-Mosaic error: flag untouched
-
-
-def test_handle_window_skips_upgrade_after_pallas_failure(monkeypatch):
-    """ADVICE r5 #1: when the banking sweep itself just attempted-and-
-    failed the pallas rungs (non-Mosaic error), the same-window
-    pallas-only upgrade must NOT re-run them."""
-    watcher = _load_watcher()
-    monkeypatch.setattr(watcher, "run_config", lambda name: None)
-    monkeypatch.setattr(watcher, "run_affine", lambda: False)
-    monkeypatch.setattr(watcher, "run_lazy", lambda: False)
-    monkeypatch.setattr(watcher, "run_mesh", lambda: False)
-    monkeypatch.setattr(watcher, "run_observability", lambda: False)
-    upgrade_calls = []
-
-    def fake_run_headline(pallas_only=False):
-        if pallas_only:
-            upgrade_calls.append(1)
-            return None, "exhausted", True
-        return ({"kernel": "xla", "rate": 30000.0}, "banked", True)
-
-    monkeypatch.setattr(watcher, "run_headline", fake_run_headline)
-    watcher.handle_window(set())
-    assert upgrade_calls == []  # upgrade skipped
-
-    def fake_run_headline2(pallas_only=False):
-        if pallas_only:
-            upgrade_calls.append(1)
-            return None, "yielded", True
-        return ({"kernel": "xla", "rate": 30000.0}, "banked", False)
-
-    monkeypatch.setattr(watcher, "run_headline", fake_run_headline2)
-    watcher.handle_window(set())
-    assert upgrade_calls == [1]  # pallas untried this sweep: upgrade runs
-
-
-def test_run_affine_banks_kind_affine(monkeypatch, tmp_path):
-    """ISSUE 8: the watcher's affine rungs bank a ``kind="affine"`` row
-    (NOT "headline" — bench.py's fallback must never report an affine
-    sample as the projective headline), pass TPUNODE_POINT_FORM to the
-    worker, and keep only the XLA rung during a Mosaic outage."""
-    watcher = _load_watcher()
-    runs = tmp_path / "runs.jsonl"
-    monkeypatch.setattr(watcher, "RUNS_PATH", str(runs))
-    monkeypatch.setattr(watcher, "_bench_running", lambda: False)
-
-    calls = []
-
-    def fake_run_json(argv, timeout, env=None):
-        calls.append(env or {})
-        return {"ok": True, "rate": 123456.0, "device": "tpu:v5e",
-                "kernel": "pallas", "point_form": "affine", "batch": 32768}
-
-    monkeypatch.setattr(watcher, "_run_json", fake_run_json)
-    assert watcher.run_affine() is True
-    assert calls[0].get("TPUNODE_POINT_FORM") == "affine"
-    rows = [json.loads(line) for line in open(runs)]
-    assert [r["kind"] for r in rows] == ["affine"]
-    assert rows[0]["point_form"] == "affine"
-    # bench.py's headline fallback ignores the affine row
-    import bench
-
-    assert bench._freshest_device_run(str(runs)) is None
-
-    # Mosaic outage: only the XLA rung is attempted
-    calls.clear()
-    watcher._mosaic_broken = True
-    assert watcher.run_affine() is True
-    assert len(calls) == 1
-    assert calls[0].get("TPUNODE_BENCH_KERNEL") == "xla"
-
-
-def test_run_observability_banks_passthrough_row(monkeypatch, tmp_path):
-    """ISSUE 17 satellite: the once-per-round observability slot passes
-    the worker's JSON through as a ``kind="observability"`` row (slo
-    keys included), pins the worker to the CPU platform, and keeps the
-    slot for a later window on failure."""
-    watcher = _load_watcher()
-    runs = tmp_path / "runs.jsonl"
-    monkeypatch.setattr(watcher, "RUNS_PATH", str(runs))
-    calls = []
-    ok = {
-        "ok": True,
-        "sampler": {"tick_us_p50": 88.0, "disabled_tick_us_p50": 0.2,
-                    "series": 128},
-        "blackbox": {"build_ms": 5.1, "bundle_keys": ["reason"]},
-        "slo": {"tick_us_p50": 52.0, "disabled_tick_us_p50": 0.3,
-                "burn_detection": {"ticks": 7, "seconds": 7.0}},
-    }
-
-    def fake_run_json(argv, timeout, env=None):
-        calls.append((argv, env or {}))
-        return dict(ok)
-
-    monkeypatch.setattr(watcher, "_run_json", fake_run_json)
-    assert watcher.run_observability() is True
-    ((argv, env),) = calls
-    assert argv[-1] == "--observability"
-    assert env.get("JAX_PLATFORMS") == "cpu"
-    rows = [json.loads(line) for line in open(runs)]
-    assert [r["kind"] for r in rows] == ["observability"]
-    assert rows[0]["slo"]["burn_detection"]["ticks"] == 7
-
-    # a failed worker banks nothing: the once-per-round slot survives
-    monkeypatch.setattr(
-        watcher, "_run_json", lambda *a, **k: {"ok": False, "error": "boom"}
-    )
-    assert watcher.run_observability() is False
-    assert sum(1 for _ in open(runs)) == 1
-
-
-def test_run_affine_pallas_failure_does_not_degrade_headline(
-    monkeypatch, tmp_path
-):
-    """Review r8: a MosaicError on the AFFINE pallas rung sets only the
-    affine-local broken flag — the projective headline ladder's
-    _mosaic_broken must stay untouched (the affine program carries
-    primitives Mosaic may reject while the flagship lowers fine)."""
-    watcher = _load_watcher()
-    monkeypatch.setattr(watcher, "RUNS_PATH", str(tmp_path / "runs.jsonl"))
-    monkeypatch.setattr(watcher, "_bench_running", lambda: False)
-
-    calls = []
-
-    def fake_run_json(argv, timeout, env=None):
-        calls.append(env or {})
-        if env and env.get("TPUNODE_BENCH_KERNEL") == "xla":
-            return {"ok": True, "rate": 50000.0, "device": "tpu:v5e",
-                    "kernel": "xla", "point_form": "affine", "batch": 8192}
-        return {"ok": False,
-                "error": "MosaicError: cannot lower mixed_add"}
-
-    monkeypatch.setattr(watcher, "_run_json", fake_run_json)
-    assert watcher.run_affine() is True  # banked via the XLA affine rung
-    assert watcher._affine_pallas_broken is True
-    assert watcher._mosaic_broken is False  # headline ladder unaffected
-    # later affine attempts skip straight to the XLA rung
-    calls.clear()
-    watcher.run_affine()
-    assert len(calls) == 1
-    assert calls[0].get("TPUNODE_BENCH_KERNEL") == "xla"
-
-
-def test_run_lazy_banks_kind_lazy(monkeypatch, tmp_path):
-    """ISSUE 12: the watcher's lazy rungs bank a ``kind="lazy"`` row
-    (never the headline), pass TPUNODE_FIELD_REDUCE/TPUNODE_WINDOW_BITS
-    to the worker (the leading rung is lazy@w5), keep only the lazy XLA
-    rung during a Mosaic outage, and a failing LAZY pallas program sets
-    only the lazy-local broken flag."""
-    watcher = _load_watcher()
-    runs = tmp_path / "runs.jsonl"
-    monkeypatch.setattr(watcher, "RUNS_PATH", str(runs))
-    monkeypatch.setattr(watcher, "_bench_running", lambda: False)
-
-    calls = []
-
-    def fake_run_json(argv, timeout, env=None):
-        calls.append(env or {})
-        return {"ok": True, "rate": 234567.0, "device": "tpu:v5e",
-                "kernel": "pallas", "field_reduce": "lazy",
-                "window_bits": 5, "batch": 32768}
-
-    monkeypatch.setattr(watcher, "_run_json", fake_run_json)
-    assert watcher.run_lazy() is True
-    assert calls[0].get("TPUNODE_FIELD_REDUCE") == "lazy"
-    assert calls[0].get("TPUNODE_WINDOW_BITS") == "5"
-    rows = [json.loads(line) for line in open(runs)]
-    assert [r["kind"] for r in rows] == ["lazy"]
-    assert rows[0]["field_reduce"] == "lazy"
-    assert rows[0]["window_bits"] == 5
-    # bench.py's headline fallback ignores the lazy row
-    import bench
-
-    assert bench._freshest_device_run(str(runs)) is None
-
-    # Mosaic outage: only the lazy XLA rung is attempted
-    calls.clear()
-    watcher._mosaic_broken = True
-    assert watcher.run_lazy() is True
-    assert len(calls) == 1
-    assert calls[0].get("TPUNODE_BENCH_KERNEL") == "xla"
-    watcher._mosaic_broken = False
-
-    # a MosaicError on a lazy pallas rung: lazy-local flag only
-    def fail_pallas(argv, timeout, env=None):
-        calls.append(env or {})
-        if env and env.get("TPUNODE_BENCH_KERNEL") == "xla":
-            return {"ok": True, "rate": 50000.0, "device": "tpu:v5e",
-                    "kernel": "xla", "field_reduce": "lazy",
-                    "window_bits": 4, "batch": 8192}
-        return {"ok": False,
-                "error": "MosaicError: cannot lower wide accumulator"}
-
-    monkeypatch.setattr(watcher, "_run_json", fail_pallas)
-    calls.clear()
-    assert watcher.run_lazy() is True  # banked via the lazy XLA rung
-    assert watcher._lazy_pallas_broken is True
-    assert watcher._mosaic_broken is False  # headline ladder unaffected
-    calls.clear()
-    watcher.run_lazy()
-    assert len(calls) == 1
-    assert calls[0].get("TPUNODE_BENCH_KERNEL") == "xla"
-
-
-def test_run_mesh_banks_kind_mesh(monkeypatch, tmp_path):
-    """ISSUE 13: the watcher's pod-mesh rungs bank ``kind="mesh"`` rows
-    (one per 8/4/2-way success, never the headline), drive bench.py
-    --mesh-device with the way count in env, keep only XLA programs
-    during a Mosaic outage, and a MosaicError sets only the mesh-local
-    broken flag."""
-    watcher = _load_watcher()
-    runs = tmp_path / "runs.jsonl"
-    monkeypatch.setattr(watcher, "RUNS_PATH", str(runs))
-    monkeypatch.setattr(watcher, "_bench_running", lambda: False)
-
-    calls = []
-
-    def fake_run_json(argv, timeout, env=None):
-        assert argv[-1] == "--mesh-device"
-        calls.append(env or {})
-        ways = int((env or {}).get("TPUNODE_BENCH_MESH_WAYS", 0))
-        return {"ok": True, "rate": 100000.0 * ways, "device": "tpu:v5e",
-                "kernel": env.get("TPUNODE_BENCH_KERNEL") or "auto",
-                "mesh_ways": ways, "batch": 4096}
-
-    monkeypatch.setattr(watcher, "_run_json", fake_run_json)
-    assert watcher.run_mesh() is True
-    assert [c.get("TPUNODE_BENCH_MESH_WAYS") for c in calls] == [
-        "8", "4", "2"
-    ]
-    assert all(c.get("TPUNODE_BENCH_REQUIRE_TPU") == "1" for c in calls)
-    rows = [json.loads(line) for line in open(runs)]
-    assert [r["kind"] for r in rows] == ["mesh"] * 3
-    assert [r["mesh_ways"] for r in rows] == [8, 4, 2]
-    # bench.py's headline fallback ignores mesh rows
-    import bench
-
-    assert bench._freshest_device_run(str(runs)) is None
-
-    # Mosaic outage: every way runs the XLA program inside shard_map
-    calls.clear()
-    watcher._mosaic_broken = True
-    assert watcher.run_mesh() is True
-    assert all(c.get("TPUNODE_BENCH_KERNEL") == "xla" for c in calls)
-    watcher._mosaic_broken = False
-
-    # a MosaicError on the mesh pallas program: mesh-local flag only
-    def fail_pallas(argv, timeout, env=None):
-        calls.append(env or {})
-        if env and env.get("TPUNODE_BENCH_KERNEL") == "xla":
-            return {"ok": True, "rate": 50000.0, "device": "tpu:v5e",
-                    "kernel": "xla",
-                    "mesh_ways": int(env["TPUNODE_BENCH_MESH_WAYS"]),
-                    "batch": 4096}
-        return {"ok": False,
-                "error": "MosaicError: cannot lower inside shard_map"}
-
-    monkeypatch.setattr(watcher, "_run_json", fail_pallas)
-    calls.clear()
-    assert watcher.run_mesh() is True
-    assert watcher._mesh_pallas_broken is True
-    assert watcher._mosaic_broken is False  # headline ladder unaffected
-    # review r13: the FAILED way itself retries on XLA in-round (the
-    # 8-way headline sample must not be dropped), then later ways go
-    # straight to XLA
-    assert [
-        (c.get("TPUNODE_BENCH_MESH_WAYS"), c.get("TPUNODE_BENCH_KERNEL"))
-        for c in calls
-    ] == [("8", None), ("8", "xla"), ("4", "xla"), ("2", "xla")]
-
-    # a fatal mesh/oracle mismatch poisons the round like the headline's
-    monkeypatch.setattr(
-        watcher, "_run_json",
-        lambda argv, timeout, env=None: {
-            "ok": False, "fatal": True,
-            "error": "mesh/oracle verdict mismatch",
-        },
-    )
-    watcher._mesh_pallas_broken = False
-    with pytest.raises(watcher.FatalMismatch):
-        watcher.run_mesh()
-    rows = [json.loads(line) for line in open(runs)]
-    assert rows[-1]["kind"] == "fatal"
-
-
-def test_run_affine_fatal_poisons_round(monkeypatch, tmp_path):
-    """An affine/oracle verdict mismatch is a correctness failure like
-    any other: recorded as a fatal row (poisoning bench.py's watcher
-    fallback) and raised."""
-    watcher = _load_watcher()
-    runs = tmp_path / "runs.jsonl"
-    monkeypatch.setattr(watcher, "RUNS_PATH", str(runs))
-    monkeypatch.setattr(watcher, "_bench_running", lambda: False)
-    monkeypatch.setattr(
-        watcher, "_run_json",
-        lambda argv, timeout, env=None: {
-            "ok": False, "fatal": True, "error": "verdict mismatch"},
-    )
-    with pytest.raises(watcher.FatalMismatch):
-        watcher.run_affine()
-    rows = [json.loads(line) for line in open(runs)]
-    assert rows[0]["kind"] == "fatal"
-    import bench
-
-    # a fatal row disables the headline fallback for the round
-    with open(runs, "a") as f:
-        f.write(json.dumps({"kind": "headline", "unix": 10**10,
-                            "ts": "t", "value": 1.0,
-                            "device": "tpu:v5e"}) + "\n")
-    assert bench._freshest_device_run(str(runs)) is None
-
-
-# ---------- bench kernel point-form A/B section (ISSUE 8) -------------------
-
-
-def test_kernel_section_shape_and_labels(monkeypatch):
-    """The BENCH ``kernel`` section: per-batch workers, failure-labeled
-    cells, and the 32768 cell disabled by default with a reasoned
-    label."""
-    import bench
-
-    calls = []
-
-    def fake_run_worker(mode, timeout, env=None):
-        calls.append((mode, timeout, env))
-        if env and env.get("TPUNODE_BENCH_KERNELAB_BATCH") == "1024":
-            return {"ok": True, "batch": 1024, "proxy": "cpu-jax",
-                    "iters": 5,
-                    "forms": {"projective": {"step_ms": 2000.0},
-                              "affine": {"step_ms": 2060.0}},
-                    "affine_vs_projective": 0.03}
-        return {"ok": False, "error": "timed out after 1s"}
-
-    monkeypatch.setattr(bench, "_run_worker", fake_run_worker)
-    out = bench._kernel_section()
-    assert out["batch_1024"]["ok"] is True
-    assert out["batch_1024"]["affine_vs_projective"] == 0.03
-    # 32768 disabled by default: labeled, no worker launched for it
-    assert out["batch_32768"]["ok"] is False
-    assert "disabled by default" in out["batch_32768"]["error"]
-    # the ISSUE 12 reduce x window grid rides its own worker call
-    assert [c[0] for c in calls] == ["--kernel-ab", "--kernel-ab"]
-    assert calls[0][2]["TPUNODE_BENCH_KERNELAB_BATCH"] == "1024"
-    assert "TPUNODE_BENCH_KERNELAB_MODE" not in calls[0][2]
-    assert calls[1][2]["TPUNODE_BENCH_KERNELAB_MODE"] == "reduce"
-    assert out["reduce_window_batch_1024"]["ok"] is True
-
-    # env-enabled big batch: attempted and failure-labeled on timeout
-    monkeypatch.setattr(bench, "T_KERNEL_AB_BIG", 60.0)
-    calls.clear()
-    out = bench._kernel_section()
-    assert [c[2]["TPUNODE_BENCH_KERNELAB_BATCH"] for c in calls] == [
-        "1024", "32768", "1024"]
-    assert out["batch_32768"] == {"ok": False,
-                                  "error": "timed out after 1s"}
-
-
 # ---------- cpu baseline median-of-N ---------------------------------------
 
 
@@ -846,134 +314,3 @@ def test_cpu_single_core_stats_median_and_spread():
     rate, engine, out = cpu_single_core_bench(sample, runs=3)
     assert rate > 0 and engine in ("native-cpp", "python-oracle")
     assert len(out) == len(sample)
-
-
-# ---------- watcher: cross-round history + regression detector -------------
-
-
-def test_detect_regression_needs_three_rounds_and_flags_drops():
-    watcher = _load_watcher()
-    hist = [{"medians": {"headline": m}} for m in (1000.0, 1010.0, 990.0)]
-    # fewer than 3 rounds of history for the key: never flags
-    assert watcher.detect_regression("headline", 1.0, hist[:2]) is None
-    assert watcher.detect_regression("other_key", 1.0, hist) is None
-    # in-band sample (floor = 1000 - max(20, 50) = 950): clean
-    assert watcher.detect_regression("headline", 955.0, hist) is None
-    # the synthetic -20% regression (ISSUE 16 acceptance)
-    reg = watcher.detect_regression("headline", 800.0, hist)
-    assert reg is not None
-    assert reg["key"] == "headline" and reg["value"] == 800.0
-    assert reg["baseline"] == 1000.0 and reg["rounds"] == 3
-    assert reg["floor"] == 950.0
-    assert reg["drop_pct"] == 20.0
-
-
-def test_history_key_separates_mesh_way_counts():
-    watcher = _load_watcher()
-    assert watcher._history_key("headline", {"value": 1.0}) == "headline"
-    assert watcher._history_key(
-        "mesh", {"value": 1.0, "mesh_ways": 8}
-    ) == "mesh@8w"
-
-
-def test_record_folds_history_and_banks_regression_row(
-    tmp_path, monkeypatch
-):
-    """ISSUE 16 acceptance end-to-end: three rounds folded into the
-    history file, then a -20% banked sample produces a
-    ``kind="regression"`` row in the runs file AND a bench.regression
-    event; an in-band sample stays clean."""
-    watcher = _load_watcher()
-    runs = tmp_path / "device_runs.jsonl"
-    monkeypatch.setattr(watcher, "RUNS_PATH", str(runs))
-    monkeypatch.setattr(
-        watcher, "HISTORY_PATH", str(tmp_path / "hist.jsonl")
-    )
-    for rate in (1000.0, 1010.0, 990.0):
-        watcher._fold_history([
-            {"kind": "headline", "value": rate},
-            {"kind": "headline", "value": rate + 2.0},
-            {"kind": "mesh", "value": rate * 8, "mesh_ways": 8},
-            {"kind": "regression", "value": 1.0},  # never folded
-            {"kind": "fatal", "error": "x"},  # no value: ignored
-        ])
-    hist = watcher._load_history()
-    assert len(hist) == 3
-    assert set(hist[0]["medians"]) == {"headline", "mesh@8w"}
-
-    from tpunode.events import events
-
-    seq0 = events.seq()
-    # in-band sample: only the headline row itself lands
-    watcher._record("headline", {"value": 1005.0, "device": "tpu:v5e"})
-    rows = [json.loads(x) for x in runs.read_text().splitlines()]
-    assert [r["kind"] for r in rows] == ["headline"]
-
-    # the -20% sample: headline row + regression row + event
-    watcher._record("headline", {"value": 800.0, "device": "tpu:v5e"})
-    rows = [json.loads(x) for x in runs.read_text().splitlines()]
-    assert [r["kind"] for r in rows] == [
-        "headline", "headline", "regression",
-    ]
-    reg = rows[-1]
-    assert reg["key"] == "headline" and reg["value"] == 800.0
-    assert reg["floor"] > 800.0 and reg["rounds"] == 3
-    assert reg["drop_pct"] == pytest.approx(20.1, abs=0.2)
-    evs = [
-        e for e in events.tail_since(seq0)
-        if e["type"] == "bench.regression"
-    ]
-    assert len(evs) == 1 and evs[0]["key"] == "headline"
-
-    # a mesh sample regresses against its own way-count series
-    watcher._record(
-        "mesh", {"value": 6000.0, "mesh_ways": 8, "device": "tpu:v5e"}
-    )
-    rows = [json.loads(x) for x in runs.read_text().splitlines()]
-    assert rows[-1]["kind"] == "regression"
-    assert rows[-1]["key"] == "mesh@8w"
-    # a way count with no history never flags
-    watcher._record(
-        "mesh", {"value": 10.0, "mesh_ways": 2, "device": "tpu:v5e"}
-    )
-    rows = [json.loads(x) for x in runs.read_text().splitlines()]
-    assert rows[-1]["kind"] == "mesh"
-
-
-def test_load_history_caps_rounds_and_skips_garbage(tmp_path, monkeypatch):
-    watcher = _load_watcher()
-    hist = tmp_path / "hist.jsonl"
-    monkeypatch.setattr(watcher, "HISTORY_PATH", str(hist))
-    assert watcher._load_history() == []  # absent file
-    lines = ["not json", json.dumps({"medians": "nope"})]
-    lines += [
-        json.dumps({"unix": i, "medians": {"headline": 1000.0 + i}})
-        for i in range(8)
-    ]
-    hist.write_text("\n".join(lines) + "\n")
-    rows = watcher._load_history()
-    assert len(rows) == watcher.HISTORY_ROUNDS  # capped at the last N
-    assert rows[-1]["unix"] == 7  # newest retained
-
-
-def test_banked_headline_carries_profile_path(tmp_path, monkeypatch):
-    """ISSUE 16: the watcher banks the worker's device-profile path
-    alongside the verdict row, linking each sample in the runs file to
-    its captured profile directory."""
-    watcher = _load_watcher()
-    runs = tmp_path / "device_runs.jsonl"
-    monkeypatch.setattr(watcher, "RUNS_PATH", str(runs))
-    monkeypatch.setattr(
-        watcher, "HISTORY_PATH", str(tmp_path / "hist.jsonl")
-    )
-    monkeypatch.setattr(watcher, "_bench_running", lambda: False)
-    watcher._headline_banked = True
-    monkeypatch.setattr(watcher, "_run_json", lambda *a, **k: {
-        "ok": True, "rate": 30000.0, "device": "tpu:v5e", "kernel": "xla",
-        "batch": 8192, "profile_path": "/p/bench-xla-b8192-7",
-    })
-    head, why, _ = watcher.run_headline()
-    assert why == "banked"
-    rows = [json.loads(x) for x in runs.read_text().splitlines()]
-    assert rows[0]["kind"] == "headline"
-    assert rows[0]["profile_path"] == "/p/bench-xla-b8192-7"

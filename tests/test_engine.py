@@ -1,4 +1,5 @@
 import asyncio
+import os
 import random
 
 import pytest
@@ -228,144 +229,157 @@ def test_engine_raw_sync_from_native_extract():
     assert False in got and True in got
 
 
-@pytest.mark.asyncio
-async def test_engine_big_shape_failure_degrades_not_fails(monkeypatch):
-    """A Mosaic-outage shape: the small device shape compiles and
-    cross-checks but device_batch does not (engine.BigShapeFailed) —
-    the engine must stay on the device path chunked at batch_size
-    instead of pinning itself to the CPU engine."""
-    from tpunode.verify.engine import BigShapeFailed
-
-    def big_shape_boom(bs, db=0):
-        raise BigShapeFailed("tpu:fake", "MosaicError: HTTP 500")
-
-    monkeypatch.setattr(VerifyEngine, "_warmup_fn", staticmethod(big_shape_boom))
-    cfg = VerifyConfig(backend="auto", max_wait=0.0, batch_size=64,
-                       device_batch=4096, min_tpu_batch=10**9)
-    async with VerifyEngine(cfg) as eng:
-        eng._warmup_done.wait(5)
-        assert eng.device_state == "ready"
-        assert eng._device_kind == "tpu:fake"
-        assert eng._device_batch == 64  # degraded to the small shape
-        assert cfg.device_batch == 4096  # caller's config untouched
-        # min_tpu_batch forces CPU for the actual verify (no real device)
-        items, expected = make_items(4, tamper_every=2)
-        assert await eng.verify(items) == expected
-
-
-def test_run_tpu_recovers_from_collect_time_mosaic_error(monkeypatch):
+def test_run_tpu_collect_time_mosaic_error_propagates(monkeypatch):
     """JAX async dispatch surfaces Mosaic RUNTIME failures at collect
-    time, not at the dispatch call: _run_tpu must mark pallas broken and
-    re-run the chunk through the (now XLA) dispatch instead of failing
-    the batch and staying pinned to the broken path."""
+    time, not at the dispatch call.  On a chip that is present a Mosaic
+    error means the kernel does not run: nothing re-dispatches the chunk
+    through another program, the error leaves _run_tpu as it is."""
     import tpunode.verify.kernel as K
     from tpunode.verify.raw import pack_items
 
-    items, expected = make_items(6, tamper_every=2)
+    items, _expected = make_items(6, tamper_every=2)
     raw = pack_items([it if len(it) > 4 else tuple(it) for it in items])
-
     calls = {"dispatch": 0, "collect": 0}
 
     def fake_dispatch(chunk, pad_to=None):
         calls["dispatch"] += 1
         return ("fake-array", len(chunk))
 
-    def fake_collect(arr, count):
+    def bad_collect(arr, count):
         calls["collect"] += 1
-        if calls["collect"] == 1:
-            raise RuntimeError(
-                "MosaicError: INTERNAL: remote_compile: HTTP 500"
-            )
-        return expected
+        raise RuntimeError("MosaicError: INTERNAL: Mosaic failed to compile")
 
-    monkeypatch.setattr(K, "_PALLAS_BROKEN", False)
     monkeypatch.setattr(K, "dispatch_batch_tpu_raw", fake_dispatch)
-    monkeypatch.setattr(K, "collect_verdicts", fake_collect)
+    monkeypatch.setattr(K, "collect_verdicts", bad_collect)
     eng = VerifyEngine(
         VerifyConfig(backend="cpu", warmup=False, min_tpu_batch=1)
     )
-    assert eng._run_tpu([raw]) == expected
-    assert calls == {"dispatch": 2, "collect": 2}  # one retry, then good
-    assert K.pallas_broken()
-
-    # non-Mosaic collect failures still propagate
-    monkeypatch.setattr(K, "_PALLAS_BROKEN", False)
-    calls["collect"] = 10  # force the non-raising branch off
-    def bad_collect(arr, count):
-        raise ValueError("device OOM")
-    monkeypatch.setattr(K, "collect_verdicts", bad_collect)
-    with pytest.raises(ValueError, match="device OOM"):
+    with pytest.raises(RuntimeError, match="MosaicError"):
         eng._run_tpu([raw])
-    assert not K.pallas_broken()
+    assert calls == {"dispatch": 1, "collect": 1}  # no second program
 
 
-def test_warmup_recovers_from_collect_time_mosaic_error(monkeypatch):
-    """A Mosaic failure surfacing INSIDE warmup's small-shape cross-check
-    (collect time, past _dispatch_prep's compile-stage catch) must mark
-    pallas broken and retry via the XLA program — not fail warmup and pin
-    the engine to CPU."""
+def test_warmup_compiles_every_program_the_dispatcher_selects(monkeypatch):
+    """The warmup builds both program variants (full, and the ECDSA-only
+    schnorr_free one) at both shapes, cross-checks each and records its
+    first-call seconds — so the first ECDSA-only lane of an IBD compiles
+    nothing on the hot path.  A failure of any of them (here: a
+    Mosaic-named one at the big shape) fails the warmup; the engine never
+    stays "ready" at a smaller shape."""
     import types
 
     import jax as _jax
 
     import tpunode.verify.kernel as K
+    from tpunode.events import events
     from tpunode.verify.ecdsa_cpu import verify_batch_cpu
     from tpunode.verify.engine import _device_warmup
 
-    calls = {"n": 0}
+    seen = []
 
     def fake_vbt(items, pad_to=None):
-        calls["n"] += 1
-        if calls["n"] == 1:
-            raise RuntimeError("MosaicError: INTERNAL: remote_compile 500")
+        seen.append((pad_to, all(len(it) == 4 for it in items)))
         return verify_batch_cpu(items)
 
-    monkeypatch.setattr(K, "_PALLAS_BROKEN", False)
     monkeypatch.setattr(K, "verify_batch_tpu", fake_vbt)
     monkeypatch.setattr(
         _jax, "devices",
         lambda *a: [types.SimpleNamespace(platform="tpu",
                                           device_kind="fake")],
     )
-    kind = _device_warmup(16, 32)
-    assert kind == "tpu:fake"
-    assert K.pallas_broken()
-    assert calls["n"] == 3  # failed small, retried small, big shape
+    seq0 = events.seq()
+    assert _device_warmup(16, 32) == "tpu:fake"
+    # (shape, ECDSA-only?) — every program the dispatcher can select
+    assert seen == [(16, False), (16, True), (32, False), (32, True)]
+    rows = [e for e in events.tail(16, type="verify.compile")
+            if e["seq"] > seq0]
+    assert [(e["batch"], e["schnorr_free"]) for e in rows] == seen
+    assert all(e["seconds"] >= 0 for e in rows)
+
+    def big_shape_boom(items, pad_to=None):
+        if pad_to == 32:
+            raise RuntimeError("MosaicError: INTERNAL: scoped vmem exceeded")
+        return verify_batch_cpu(items)
+
+    monkeypatch.setattr(K, "verify_batch_tpu", big_shape_boom)
+    with pytest.raises(RuntimeError, match="MosaicError"):
+        _device_warmup(16, 32)
 
 
-def test_with_mosaic_fallback_contract(monkeypatch):
-    """Direct unit for the shared retry helper: one retry after a Mosaic
-    failure (flag set), non-Mosaic errors propagate untouched, and a
-    second Mosaic failure (the retry itself) propagates too."""
-    import tpunode.verify.kernel as K
+@pytest.mark.asyncio
+async def test_forced_tpu_does_not_ladder_down(monkeypatch):
+    """backend="tpu" means tpu: a batch that fails on the device is not
+    served by the cpu/oracle rungs with only a counter to show it — the
+    error reaches the waiters.  backend="auto" keeps its ladder."""
+    from tpunode.metrics import metrics
 
-    monkeypatch.setattr(K, "_PALLAS_BROKEN", False)
-    calls = []
+    def boom(self, payloads, host=None):
+        raise RuntimeError("device fell over")
 
-    def flaky():
-        calls.append(1)
-        if len(calls) == 1:
-            raise RuntimeError("MosaicError: INTERNAL: HTTP 500")
-        return "ok"
+    monkeypatch.setattr(VerifyEngine, "_warmup_fn",
+                        staticmethod(lambda bs, db=0: "tpu:fake"))
+    monkeypatch.setattr(VerifyEngine, "_run_tpu", boom)
+    items, expected = make_items(4, tamper_every=2)
 
-    assert K.with_mosaic_fallback(flaky, "in test") == "ok"
-    assert len(calls) == 2 and K.pallas_broken()
+    before = {k: metrics.get(k) for k in (
+        "verify.failovers", "verify.cpu_items", "verify.oracle_items")}
+    async with VerifyEngine(
+        VerifyConfig(backend="tpu", max_wait=0.0, warmup_timeout=5)
+    ) as eng:
+        with pytest.raises(RuntimeError, match="device fell over"):
+            await eng.verify(items)
+    assert {k: metrics.get(k) for k in before} == before
 
-    monkeypatch.setattr(K, "_PALLAS_BROKEN", False)
-    with pytest.raises(ValueError, match="not mosaic"):
-        K.with_mosaic_fallback(
-            lambda: (_ for _ in ()).throw(ValueError("not mosaic")),
-            "in test",
-        )
-    assert not K.pallas_broken()
+    async with VerifyEngine(
+        VerifyConfig(backend="auto", max_wait=0.0, min_tpu_batch=1)
+    ) as eng:
+        eng._warmup_done.wait(5)
+        assert eng.device_state == "ready"
+        assert await eng.verify(items) == expected  # laddered down
+    assert metrics.get("verify.failovers") == before["verify.failovers"] + 1
 
-    def always_mosaic():
-        raise RuntimeError("MosaicError: still broken")
 
-    monkeypatch.setattr(K, "_PALLAS_BROKEN", False)
-    with pytest.raises(RuntimeError, match="still broken"):
-        K.with_mosaic_fallback(always_mosaic, "in test")
-    assert K.pallas_broken()
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
+    """Unset, the cache lives at the fixed <checkout>/.jax_cache; where
+    JAX_COMPILATION_CACHE_DIR is set it wins and no directory is set in
+    code (a fresh process shows jax itself picked it up)."""
+    import subprocess
+    import sys
+
+    import jax
+
+    from tpunode.verify import engine as E
+
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append((k, v)), real_update(k, v))[1],
+    )
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert E.enable_compile_cache() == E._DEFAULT_CACHE
+    assert ("jax_compilation_cache_dir", E._DEFAULT_CACHE) in updates
+    assert E._DEFAULT_CACHE == os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache",
+    )
+
+    updates.clear()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    E.enable_compile_cache()
+    assert not [k for k, _ in updates if k == "jax_compilation_cache_dir"]
+
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from tpunode.verify.engine import enable_compile_cache as e; "
+         "print(e())"],
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "JAX_COMPILATION_CACHE_DIR": str(tmp_path)},
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == str(tmp_path)
 
 
 @pytest.mark.asyncio

@@ -184,102 +184,51 @@ def test_np_conversions_match_scalar():
         assert row.tolist() == _digits_base16(v)
 
 
-@pytest.mark.heavy  # compiles the XLA program (pytest.ini tiers)
-def test_dispatch_falls_back_to_xla_on_mosaic_error(monkeypatch):
-    """r5 Mosaic outage: a pallas compile failing with a Mosaic/remote-
-    compile error must mark pallas broken for the process and fall
-    through to the XLA program with correct verdicts — this is what
-    keeps the engine's device path alive when the compile helper 500s."""
+def test_program_choice_is_what_the_code_observes(monkeypatch):
+    """Pallas on a TPU platform when the padded batch tiles into BLOCK,
+    the XLA program otherwise — selected from jax.devices()[0] and the
+    batch size, nothing else (no env knob, no sticky process flag)."""
+    import types
+
+    import jax as _jax
+
+    import tpunode.verify.kernel as K
+    import tpunode.verify.pallas_kernel as PK
+
+    assert K._pallas_usable(PK.BLOCK) is False  # this box is cpu
+    monkeypatch.setattr(
+        _jax, "devices",
+        lambda *a: [types.SimpleNamespace(platform="tpu")],
+    )
+    assert K._pallas_usable(PK.BLOCK) is True
+    assert K._pallas_usable(4 * PK.BLOCK) is True
+    assert K._pallas_usable(PK.BLOCK + 8) is False  # does not tile
+
+
+def test_dispatch_propagates_mosaic_errors(monkeypatch):
+    """On a chip that is present a Mosaic error means the kernel does not
+    compile: _dispatch_prep raises it — no fall-through to the XLA
+    program, which would silently run several times slower."""
     import tpunode.verify.kernel as K
     import tpunode.verify.pallas_kernel as PK
 
     def mosaic_boom(*a, **k):
         raise RuntimeError(
-            "MosaicError: INTERNAL: http://127.0.0.1:8083/remote_compile: "
-            "HTTP 500: tpu_compile_helper subprocess exit code 1"
+            "MosaicError: INTERNAL: Mosaic failed to compile TPU kernel: "
+            "Unsupported target bitwidth for truncation"
         )
 
-    import types
+    def xla_must_not_run(*a, **k):
+        raise AssertionError("the XLA program stood in for the chip")
 
-    import jax as _jax
-
-    orig_usable = K._pallas_usable
-    monkeypatch.setattr(K, "_PALLAS_BROKEN", False)
     monkeypatch.setattr(K, "_pallas_usable", lambda batch: True)
     monkeypatch.setattr(PK, "verify_blocked", mosaic_boom)
-    items, expected = _random_batch(8, tamper_every=3)
-    assert K.verify_batch_tpu(items, pad_to=16) == expected
-    assert K.pallas_broken()
-    # sticky: the REAL _pallas_usable must gate on _PALLAS_BROKEN even
-    # when the platform looks like a TPU (faked here — this box is cpu),
-    # so dispatch stays off pallas (mosaic_boom would raise again).
-    monkeypatch.setattr(
-        _jax, "devices",
-        lambda *a: [types.SimpleNamespace(platform="tpu")],
-    )
-    monkeypatch.setattr(K, "_pallas_usable", orig_usable)
-    assert orig_usable(PK.BLOCK) is False  # the gate, not the platform
-    monkeypatch.setattr(K, "_PALLAS_BROKEN", False)
-    assert orig_usable(PK.BLOCK) is True   # fake-tpu sanity check
-    monkeypatch.setattr(K, "_PALLAS_BROKEN", True)
-    assert K.verify_batch_tpu(items, pad_to=16) == expected
-
-
-def test_dispatch_reraises_non_mosaic_errors(monkeypatch):
-    """Only Mosaic/remote-compile failures are swallowed; anything else
-    (OOM, verdict-affecting bugs) must propagate."""
-    import tpunode.verify.kernel as K
-    import tpunode.verify.pallas_kernel as PK
-
-    monkeypatch.setattr(K, "_PALLAS_BROKEN", False)
-    monkeypatch.setattr(K, "_pallas_usable", lambda batch: True)
-    monkeypatch.setattr(
-        PK, "verify_blocked",
-        lambda *a, **k: (_ for _ in ()).throw(ValueError("boom")),
-    )
+    monkeypatch.setattr(K, "verify_device", xla_must_not_run)
     items, _ = _random_batch(4)
-    with pytest.raises(ValueError, match="boom"):
+    with pytest.raises(RuntimeError, match="MosaicError"):
         K.verify_batch_tpu(items, pad_to=16)
-    assert not K.pallas_broken()
-
-
-def test_env_knob_seeds_pallas_broken():
-    """TPUNODE_VERIFY_KERNEL=xla seeds the sticky pallas-broken flag at
-    import: the watcher forces fresh config subprocesses straight to the
-    XLA program during a Mosaic outage whose hang mode (observed r5,
-    03:48Z window) cannot be caught in-process.
-
-    Probed in a SUBPROCESS (ADVICE r5 #2): the former in-process
-    ``importlib.reload(kernel)`` created a second module object while
-    engine/multichip/pallas dispatch kept references to the first, so
-    sticky state (_PALLAS_BROKEN, the jit caches) could diverge across
-    copies — an order-dependent flake in the heavy tier.  The env knob is
-    an IMPORT-time contract anyway, which only a fresh interpreter tests
-    honestly."""
-    import os
-    import sys
-
-    from benchmarks.common import run_json_subprocess
-
-    script = (
-        "import json\n"
-        "from tpunode.verify import kernel as K\n"
-        "print(json.dumps({'broken': K.pallas_broken(),"
-        " 'usable': K._pallas_usable(32768)}))\n"
-    )
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    seeded = run_json_subprocess(
-        [sys.executable, "-c", script], 120.0,
-        {"TPUNODE_VERIFY_KERNEL": "xla", "JAX_PLATFORMS": "cpu"},
-        cwd=repo,
-    )
-    assert seeded == {"broken": True, "usable": False}
-    unseeded = run_json_subprocess(
-        [sys.executable, "-c", script], 120.0,
-        {"TPUNODE_VERIFY_KERNEL": "", "JAX_PLATFORMS": "cpu"},
-        cwd=repo,
-    )
-    assert unseeded["broken"] is False
+    with pytest.raises(RuntimeError, match="MosaicError"):
+        K._dispatch_prep(K.prepare_batch(items, pad_to=16))
 
 
 def test_acceptance_pows_gated_per_batch():

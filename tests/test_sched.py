@@ -550,8 +550,9 @@ async def test_campaign_pool_clean_through_packed_path():
 
 def test_engine_mesh_gating(monkeypatch):
     """VerifyConfig.mesh_devices: off by default; a usable mesh is built
-    lazily (and only once); an unusable topology fails soft — the
-    single-chip rung keeps serving (the compile-parity pin for the
+    lazily (and only once); a mesh that was asked for and cannot be built
+    RAISES — it never degrades to one chip, and fleet hosts never
+    silently share the default device (the compile-parity pin for the
     sharded program itself lives in test_multichip's heavy tier)."""
     jax = pytest.importorskip("jax")
 
@@ -568,10 +569,29 @@ def test_engine_mesh_gating(monkeypatch):
     eng3 = VerifyEngine(
         VerifyConfig(backend="cpu", warmup=False, mesh_devices=4)
     )
+    fleet = VerifyEngine(
+        VerifyConfig(backend="cpu", warmup=False, mesh_hosts=4,
+                     mesh_devices=4)
+    )
     devs = jax.devices()
     monkeypatch.setattr(jax, "devices", lambda *a: devs[:1])
-    assert eng3._mesh() is None  # 1 visible device: soft-off
-    assert eng3._mesh_state == "failed"  # tried once, never again
+    with pytest.raises(RuntimeError, match="only 1 device"):
+        eng3._mesh()
+    with pytest.raises(RuntimeError, match="only 1 device"):
+        eng3._mesh()  # asked again, raises again: never "off for good"
+    with pytest.raises(RuntimeError, match="only 1 device"):
+        fleet._host_mesh(fleet._hosts["h0"])
+    # without mesh_devices the grid is carved from what is visible: four
+    # hosts cannot be carved out of one device either
+    fleet2 = VerifyEngine(
+        VerifyConfig(backend="cpu", warmup=False, mesh_hosts=4)
+    )
+    with pytest.raises(ValueError, match="needs 4 devices"):
+        fleet2._host_mesh(fleet2._hosts["h0"])
+    monkeypatch.setattr(jax, "devices", lambda *a: devs)
+    assert eng3._mesh().devices.size == 4  # the chips came back
+    subs = [fleet._host_mesh(hs) for hs in fleet._hosts.values()]
+    assert len({m.devices.flat[0].id for m in subs}) == 4
 
 
 # --- fleet engine integration (ISSUE 13) -------------------------------------

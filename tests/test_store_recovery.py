@@ -78,9 +78,6 @@ def _restart_child_main(dirpath: str) -> None:
     """Subprocess body: sync the fakenet chain, connect every block into
     the UTXO store, then signal readiness and idle until SIGKILLed."""
     sys.path.insert(0, REPO)
-    from tpunode.compat import install_asyncio_timeout
-
-    install_asyncio_timeout()
     from tests.fakenet import dummy_peer_connect, poll_until
     from tests.fixtures import all_blocks
     from tpunode import BCH_REGTEST, ChainSynced, Node, NodeConfig, Publisher

@@ -41,7 +41,6 @@ import weakref
 from collections import deque
 
 from .chaos import chaos
-from .compat import timeout as _timeout
 from .events import events
 from .metrics import metrics
 from .tracectx import _ACTIVE as _active_trace
@@ -337,7 +336,7 @@ async def receive_match(
     """``receive_match`` with an optional timeout (NQE ``receiveMatchS``)."""
     if timeout is None:
         return await mailbox.receive_match(select)
-    async with _timeout(timeout):
+    async with asyncio.timeout(timeout):
         return await mailbox.receive_match(select)
 
 

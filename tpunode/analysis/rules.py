@@ -471,8 +471,7 @@ _METRIC_ATTRS = {"inc", "observe", "set_gauge"}
 # adds `mempool` (the mempool subsystem's metric/event/span names).
 KNOWN_LAYERS = frozenset({
     "asyncsan",   # runtime sanitizers (tpunode/asyncsan.py)
-    "bench",      # driver bench traces (bench.py; incl. the watcher's
-                  # cross-round regression detector, ISSUE 16)
+    "bench",      # bench worker traces (bench.py)
     "blackbox",   # flight recorder (tpunode/blackbox.py, ISSUE 16)
     "bus",        # Publisher/user bus (tpunode/actors.py)
     "chain",      # header-chain actor (tpunode/chain.py)

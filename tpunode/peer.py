@@ -23,7 +23,6 @@ from typing import Callable, Optional, Protocol, Union
 
 from .actors import Mailbox, Publisher, spawn_supervised
 from .chaos import chaos
-from .compat import timeout as _timeout
 from .metrics import metrics
 from .params import Network
 from .trace import span
@@ -430,7 +429,7 @@ async def get_data(
         acc: list[Union[Tx, Block]] = []
         remaining = list(invs)
         try:
-            async with _timeout(seconds):
+            async with asyncio.timeout(seconds):
                 while remaining:
                     msg = await inbox.receive_match(select)
                     iv = remaining[0]
@@ -517,7 +516,7 @@ async def ping_peer(seconds: float, p: Peer) -> bool:
             return None
 
         try:
-            async with _timeout(seconds):
+            async with asyncio.timeout(seconds):
                 return await inbox.receive_match(select)
         except TimeoutError:
             return False

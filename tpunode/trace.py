@@ -38,12 +38,10 @@ from .tracectx import _ACTIVE as _active_trace
 
 __all__ = ["span", "profile_to"]
 
-# Resolve the profiler ONCE at import (a failed import is not cached by
-# Python, so retrying per span would pay a sys.path scan on the hot path).
-try:
-    import jax.profiler as _jax_profiler
-except Exception:  # jax absent: spans still time into metrics
-    _jax_profiler = None
+# Bound by profile_to() for the length of a capture: importing this module
+# (and so the whole tpunode package) stays jax-free, which lets worker
+# processes that only sign or parse start without jax.
+_jax_profiler = None
 
 # True only inside a profile_to() capture: spans skip the per-entry
 # TraceAnnotation construction otherwise (it costs ~2µs — measurable
@@ -118,7 +116,7 @@ def profile_to(directory: Optional[str]) -> Iterator[None]:
     """Capture a JAX device profile into ``directory`` (no-op when None or
     the profiler is unavailable).  Spans entered during the capture are
     annotated onto the device timeline."""
-    global _profiling
+    global _profiling, _jax_profiler
     if not directory:
         yield
         return
@@ -129,6 +127,7 @@ def profile_to(directory: Optional[str]) -> Iterator[None]:
     except Exception:
         yield
         return
+    _jax_profiler = jax.profiler
     _profiling = True
     try:
         with cm:

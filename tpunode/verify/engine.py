@@ -39,17 +39,19 @@ dark, lanes fall through the local ladder so waiters still resolve.
 Device survival discipline (VERDICT r2 item 4 + ISSUE 7): the TPU path is
 only used after an off-queue **warmup** (backend init + XLA compile at the
 fixed batch shape + a verdict cross-check against the oracle) completes in
-a background thread.  Until then batches flow to the CPU engine, so a box
-with a broken or slow TPU backend still produces verdicts with nothing
-blocked and the decision logged; a failed warmup is re-probed on a timer
-(``warmup_retry``), never terminal.  Compiles go through a persistent
-compilation cache so a restart reuses earlier work.
+a background thread.  Until then ``backend="auto"`` batches flow to the
+CPU engine, so a box with a broken or slow TPU backend still produces
+verdicts with nothing blocked and the decision logged; a failed warmup is
+re-probed on a timer (``warmup_retry``), never terminal.  Compiles go
+through a persistent compilation cache so a restart reuses earlier work.
 
-Self-healing dispatch (ISSUE 7): a batch that fails on one backend
-re-dispatches down the ladder (tpu -> cpu-native -> python oracle), so
-waiters get verdicts — not exceptions — for transient faults; only a
-batch that fails on EVERY rung fails its waiters (and only its own: the
-queue loop survives to serve the next batch).  Device-rung failures feed
+Self-healing dispatch (ISSUE 7): under ``backend="auto"`` a batch that
+fails on one backend re-dispatches down the ladder (tpu -> cpu-native ->
+python oracle), so waiters get verdicts — not exceptions — for transient
+faults; only a batch that fails on EVERY rung fails its waiters (and only
+its own: the queue loop survives to serve the next batch).  A forced
+``backend="tpu"`` never ladders down: nothing stands in for the chip, the
+device error reaches the waiters.  Device-rung failures feed
 a :class:`CircuitBreaker` (``ready -> degraded -> open -> probing ->
 ready``): repeated failures inside a window open the breaker and route
 all traffic to the CPU, then a periodic half-open canary batch re-probes
@@ -120,43 +122,32 @@ _DEFAULT_CACHE = os.path.join(
 )
 
 
-def enable_compile_cache(path: Optional[str] = None) -> None:
-    """Point JAX's persistent compilation cache at ``path`` (idempotent).
-
-    The kernel's XLA program is large; a cold compile can take minutes on
-    some backends.  With the cache enabled, any process on this machine
-    (engine warmup, bench.py, tests) reuses the first successful compile.
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache (idempotent) and return
+    the directory in force.  The directory is placed from outside: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and none is
+    set in code; otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache`` (the path is part of the cache key, so a
+    directory that moves never hits).  Any process on this machine (engine
+    warmup, chip_smoke.py, tests) then reuses the first successful compile.
     """
     import jax
 
-    target = path or os.environ.get("TPUNODE_JAX_CACHE") or _DEFAULT_CACHE
-    try:
-        if not jax.config.jax_compilation_cache_dir:
-            jax.config.update("jax_compilation_cache_dir", target)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception as e:  # cache is an optimization, never a hard failure
-        log.debug("compilation cache unavailable: %s", e)
-
-
-class BigShapeFailed(RuntimeError):
-    """Warmup outcome: the small device shape compiled and cross-checked
-    but the steady-state ``device_batch`` shape did not compile.  Carries
-    the device kind so the engine can stay on the device path with
-    ``device_batch`` degraded to ``batch_size``."""
-
-    def __init__(self, kind: str, error: str):
-        super().__init__(error)
-        self.kind = kind
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", _DEFAULT_CACHE)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    return jax.config.jax_compilation_cache_dir
 
 
 def _device_warmup(batch_size: int, device_batch: int = 0) -> str:
     """Default warmup body (runs in a daemon thread): init the backend,
-    compile the kernel at the engine's fixed batch shapes (the small
-    ``batch_size`` shape first so readiness comes early, then the big
-    ``device_batch`` steady-state shape), and cross-check a small batch
-    against the oracle.  Returns the device kind string.  Raises on any
-    failure — including a verdict mismatch, which must disqualify the
-    device path permanently."""
+    compile every program the dispatcher can select — the full and the
+    ECDSA-only (``schnorr_free``) variant, each at the engine's two fixed
+    batch shapes (the small ``batch_size`` shape first, then the big
+    ``device_batch`` steady-state shape) — and cross-check each against
+    the oracle.  Returns the device kind string.  Raises on any failure —
+    a compile error, or a verdict mismatch, which must disqualify the
+    device path."""
     import jax
 
     enable_compile_cache()
@@ -204,37 +195,31 @@ def _device_warmup(batch_size: int, device_batch: int = 0) -> str:
             z ^= 1
         items.append((pub, z, r, s))
         expect.append(i % 3 != 2)
-    from .kernel import with_mosaic_fallback
-
-    kind = f"{devs[0].platform}:{getattr(devs[0], 'device_kind', '?')}"
-    # A Mosaic RUNTIME failure surfaces at collect time inside
-    # verify_batch_tpu, past _dispatch_prep's compile-stage catch: mark
-    # pallas broken and retry once through the XLA program instead of
-    # pinning the engine to CPU for the whole process.
-    got = with_mosaic_fallback(
-        lambda: verify_batch_tpu(items, pad_to=batch_size),
-        "during warmup",
-    )
-    if got != expect:
-        raise RuntimeError("device/oracle verdict mismatch during warmup")
+    # the ECDSA-only subset selects the schnorr_free program variant
+    plain = [it for it in items if len(it) == 4]
+    plain_expect = [ok for it, ok in zip(items, expect) if len(it) == 4]
+    shapes = [batch_size]
     if device_batch and device_batch != batch_size:
-        try:
-            got = verify_batch_tpu(items, pad_to=device_batch)
-        except Exception as e:  # noqa: BLE001 — verdict errors re-raised below
-            # The small shape works but the steady-state shape doesn't
-            # compile (e.g. the XLA fallback at 32768 during a Mosaic
-            # outage): keep the device path, chunk at the small shape.
-            # (A Mosaic error here is unreachable in practice — the
-            # small-shape pass above already forced the XLA program —
-            # and degrading to the known-good small shape handles it.)
-            raise BigShapeFailed(
-                kind, f"{type(e).__name__}: {e}"[:300]
-            ) from e
-        if got != expect:
-            raise RuntimeError(
-                "device/oracle verdict mismatch at device_batch"
+        shapes.append(device_batch)
+    for shape in shapes:
+        for schnorr_free, its, want in (
+            (False, items, expect),
+            (True, plain, plain_expect),
+        ):
+            t0 = time.perf_counter()
+            got = verify_batch_tpu(its, pad_to=shape)
+            # first call per program: trace + compile (or persistent-cache
+            # load) + one step — set-up time, never a rate
+            events.emit(
+                "verify.compile", batch=shape, schnorr_free=schnorr_free,
+                seconds=round(time.perf_counter() - t0, 3),
             )
-    return kind
+            if got != want:
+                raise RuntimeError(
+                    f"device/oracle verdict mismatch during warmup at "
+                    f"batch {shape}"
+                )
+    return f"{devs[0].platform}:{devs[0].device_kind}"
 
 
 class CircuitBreaker:
@@ -428,10 +413,10 @@ class VerifyConfig:
     # dispatch); 1 restores the serial pre-pipeline dispatch for A/B.
     pipeline_depth: int = 2
     # Mesh-aware device rung (ISSUE 10): >1 shards each packed lane over
-    # a mesh of that many local devices (multichip.dispatch_raw_sharded)
-    # when they are visible; 0/1 keeps single-chip dispatch.  The mesh
-    # program compiles on first dispatch (warmup compiles the single-chip
-    # shapes only).
+    # a mesh of that many local devices (multichip.dispatch_raw_sharded);
+    # fewer visible devices than asked for is an error, not a smaller
+    # mesh.  0/1 keeps single-chip dispatch.  The mesh program compiles on
+    # first dispatch (warmup compiles the single-chip shapes only).
     mesh_devices: int = 0
     # Pod-scale fleet dispatch (ISSUE 13): >= 2 carves the device set
     # into this many host groups (a (host, chip) hybrid mesh —
@@ -458,12 +443,14 @@ class VerifyConfig:
     # threads (secp_verify_batch_mt; each MSM row is independent).
     cpu_threads: int = 1
     # device warmup discipline
-    warmup_timeout: float = 600.0  # backend=tpu: max wait for warmup
+    # backend=tpu: max wait for warmup.  A cold warmup compiles four
+    # programs (two variants x two shapes); see PERF.md "On the chip" for
+    # the measured compile seconds this has to cover.
+    warmup_timeout: float = 600.0
     warmup: bool = True  # start warmup thread on engine start
-    # A failed warmup is re-probed after this many seconds (ISSUE 7:
-    # the old terminal `failed` state outlived many a transient outage
-    # — the r5 Mosaic remote-compile 500s cleared within the round).
-    # 0 disables re-probing (the pre-ISSUE-7 terminal behavior).
+    # A failed warmup is re-probed after this many seconds (ISSUE 7: a
+    # terminal `failed` state would outlive a transient device fault).
+    # 0 disables re-probing.
     warmup_retry: float = 60.0
     # Circuit breaker on the device dispatch path (ISSUE 7):
     # `breaker_threshold` failures inside `breaker_window` seconds open
@@ -546,7 +533,7 @@ class _HostState:
 
     __slots__ = (
         "name", "index", "breaker", "lost", "lost_at",
-        "mesh", "mesh_state", "chips", "full_chips", "shrunk_at", "event",
+        "mesh", "chips", "full_chips", "shrunk_at", "event",
     )
 
     def __init__(self, name: str, index: int, cfg: "VerifyConfig"):
@@ -561,7 +548,6 @@ class _HostState:
         self.lost = False
         self.lost_at = 0.0
         self.mesh = None  # lazily-built 1-D sub-mesh over this host's row
-        self.mesh_state = "cold"  # cold -> ready | failed (soft: single-chip)
         self.chips = 0  # current healthy sub-mesh width (0 = not built yet)
         self.full_chips = 0  # the full row width (re-grow target)
         self.shrunk_at = 0.0  # last shrink time (paces the re-grow probe)
@@ -735,14 +721,11 @@ class VerifyEngine:
         self._slots: Optional[asyncio.Semaphore] = None
         self._kick: Optional[asyncio.Event] = None
         self._task: Optional[asyncio.Task] = None
-        # sharded device rung (cfg.mesh_devices): lazily-built mesh;
-        # "failed" means mesh construction was tried and is off for
-        # good.  Init races between concurrent dispatch worker threads
+        # sharded device rung (cfg.mesh_devices): lazily-built mesh.
+        # Init races between concurrent dispatch worker threads
         # (pipeline_depth > 1) are serialized by _mesh_lock — without
-        # it two lanes would double-build (and double-compile), and a
-        # transient loser could pin "failed" over a winner's mesh.
+        # it two lanes would double-build (and double-compile).
         self._mesh_obj = None
-        self._mesh_state = "cold"
         self._mesh_lock = threadsan.lock("verify.mesh")
         # Pod-scale fleet (ISSUE 13, cfg.mesh_hosts >= 2): per-host
         # states + the work-stealing dispatcher, built in __aenter__;
@@ -751,7 +734,6 @@ class VerifyEngine:
         self._fleet: Optional[FleetDispatcher] = None
         self._hosts: dict[str, _HostState] = {}
         self._fleet_hybrid = None  # the (host, chip) Mesh, carved lazily
-        self._fleet_hybrid_state = "cold"
         self._room: Optional[asyncio.Event] = None
         if self.cfg.mesh_hosts >= 2:
             # canonical names from sched.py (ISSUE 19): the affinity
@@ -775,10 +757,6 @@ class VerifyEngine:
             from .cpu_native import load_native_verifier
 
             self._cpu = load_native_verifier()
-        # Steady-state device shape actually in use: starts at the config
-        # value, degraded to batch_size if the big shape fails to compile
-        # (never written back into the caller's cfg).
-        self._device_batch = self.cfg.device_batch
         # device readiness state machine: cold -> warming -> ready | failed
         # (failed re-probes on the warmup_retry timer — never terminal)
         self._device_state = "cold"
@@ -818,30 +796,12 @@ class VerifyEngine:
                 kind = type(self)._warmup_fn(
                     self.cfg.batch_size, self.cfg.device_batch
                 )
-            except BigShapeFailed as e:
-                # Small shape is good; stay on the device path chunked at
-                # the small shape instead of losing the device entirely.
-                self._device_batch = self.cfg.batch_size
-                self._device_kind = e.kind
-                self._device_state = "ready"
-                log.warning(
-                    "[Engine] device ready (%s) but device_batch shape "
-                    "failed to compile (%s) — chunking at batch_size=%d",
-                    e.kind,
-                    e,
-                    self.cfg.batch_size,
-                )
-                events.emit(
-                    "verify.device", state="ready", kind=e.kind,
-                    degraded_batch=self.cfg.batch_size, error=str(e),
-                )
             except Exception as e:  # noqa: BLE001 — any failure disables tpu
                 self._device_error = f"{type(e).__name__}: {e}"
                 self._warmup_failed_at = time.monotonic()
                 self._device_state = "failed"
                 log.warning(
-                    "[Engine] device warmup failed, using cpu engine"
-                    " (re-probe in %.0fs): %s",
+                    "[Engine] device warmup failed (re-probe in %.0fs): %s",
                     self.cfg.warmup_retry,
                     self._device_error,
                 )
@@ -952,7 +912,7 @@ class VerifyEngine:
             "device_state": self._device_state,
             "device_kind": self._device_kind or None,
             "device_error": self._device_error,
-            "device_batch": self._device_batch,
+            "device_batch": self.cfg.device_batch,
             "backlog": self.queue_depth(),
             "dispatch_inflight_seconds": round(
                 self.dispatch_inflight_seconds(), 3
@@ -1190,7 +1150,7 @@ class VerifyEngine:
         """Pack/fill goal: the steady-state device shape once the device
         is up, the small shape before."""
         return (
-            self._device_batch
+            self.cfg.device_batch
             if self._device_state == "ready"
             else self.cfg.batch_size
         )
@@ -1572,7 +1532,8 @@ class VerifyEngine:
         feed the circuit breaker (the HOST's in fleet mode).  Returns
         (results, rung that served).  Only a batch that fails on every
         rung raises — and then fails just this batch's waiters; the
-        queue loop survives (pinned by tests/test_engine.py).
+        queue loop survives (pinned by tests/test_engine.py).  A forced
+        ``backend="tpu"`` has one rung.
 
         Fleet specifics (ISSUE 13): a host partition
         (:class:`HostLost` / injected ``mesh.dispatch:partition``)
@@ -1589,6 +1550,10 @@ class VerifyEngine:
             for r in self._LADDER[start:]
             if r != "cpu" or self._cpu is not None
         ]
+        if backend == "tpu" and self.cfg.backend == "tpu":
+            # forced tpu means tpu: no rung stands in for the chip, the
+            # device error reaches the waiters
+            rungs = ["tpu"]
         for i, rung in enumerate(rungs):
             try:
                 if chaos.on:  # injected batch/device failure (ISSUE 7/13)
@@ -1692,41 +1657,33 @@ class VerifyEngine:
         metrics.inc("verify.oracle_items", total)
         return out
 
+    def _mesh_device_count(self) -> int:
+        """How many devices the configured mesh spans: ``mesh_devices``
+        when set — fewer visible is an error, never a smaller mesh — else
+        every visible device."""
+        import jax
+
+        want, seen = self.cfg.mesh_devices, len(jax.devices())
+        if seen < want:
+            raise RuntimeError(
+                f"mesh_devices={want} but only {seen} device(s) visible"
+            )
+        return want or seen
+
     def _mesh(self):
         """Lazily-built device mesh for the sharded tpu rung (ISSUE 10):
-        None when ``mesh_devices`` is off, fewer than 2 devices are
-        visible, or mesh construction already failed (tried once).
+        None when ``mesh_devices`` is off.  A mesh that was asked for and
+        cannot be built raises — it never degrades to one chip.
         Thread-safe: concurrent lanes race to be the first dispatch."""
-        if self.cfg.mesh_devices < 2 or self._mesh_state == "failed":
+        if self.cfg.mesh_devices < 2:
             return None
         with self._mesh_lock:
-            if self._mesh_state == "failed":
-                return None
             if self._mesh_obj is None:
-                try:
-                    import jax
+                from .multichip import make_mesh
 
-                    from .multichip import make_mesh
-
-                    n = min(self.cfg.mesh_devices, len(jax.devices()))
-                    if n < 2:
-                        raise RuntimeError(
-                            f"mesh_devices={self.cfg.mesh_devices} but "
-                            f"only {n} device(s) visible"
-                        )
-                    self._mesh_obj = make_mesh(n)
-                    self._mesh_state = "ready"
-                    events.emit("verify.mesh", state="ready", devices=n)
-                except Exception as e:  # mesh is an upgrade, never a gate
-                    self._mesh_state = "failed"
-                    log.warning(
-                        "[Engine] sharded dispatch unavailable, "
-                        "single-chip rung: %s", e,
-                    )
-                    events.emit(
-                        "verify.mesh", state="failed", error=str(e)[:300]
-                    )
-                    return None
+                n = self._mesh_device_count()
+                self._mesh_obj = make_mesh(n)
+                events.emit("verify.mesh", state="ready", devices=n)
             return self._mesh_obj
 
     # -- fleet host health / sub-meshes (ISSUE 13) ---------------------------
@@ -1786,15 +1743,13 @@ class VerifyEngine:
                 # still 0): resolve this host's row width so there is a
                 # known-good whole to halve
                 hybrid = self._fleet_hybrid_mesh()
-                if hybrid is not None:
-                    hs.full_chips = int(hybrid.devices.shape[-1])
-                    hs.chips = hs.full_chips
+                hs.full_chips = int(hybrid.devices.shape[-1])
+                hs.chips = hs.full_chips
             if hs.chips <= 1:
                 return
             hs.chips //= 2
             hs.shrunk_at = time.monotonic()
             hs.mesh = None  # rebuilt lazily at the new width
-            hs.mesh_state = "cold"
             chips = hs.chips
         metrics.inc("mesh.shrinks")
         self._chips_gauge(hs.name, chips)
@@ -1816,7 +1771,6 @@ class VerifyEngine:
                 return
             hs.chips = hs.full_chips
             hs.mesh = None
-            hs.mesh_state = "cold"
             chips = hs.chips
         metrics.inc("mesh.regrows")
         self._chips_gauge(hs.name, chips)
@@ -1836,74 +1790,37 @@ class VerifyEngine:
 
     def _fleet_hybrid_mesh(self):
         """The fleet's (host, chip) hybrid mesh, carved lazily on first
-        device dispatch.  Caller holds ``_mesh_lock``.  None = hybrid
-        construction failed (hosts fall back to single-chip
-        default-device dispatch — the mesh is an upgrade, never a
-        gate)."""
-        if self._fleet_hybrid_state == "failed":
-            return None
+        device dispatch.  Caller holds ``_mesh_lock``.  Raises when the
+        requested grid cannot be built from the visible devices — fleet
+        hosts never silently share one chip."""
         if self._fleet_hybrid is None:
-            try:
-                import jax
+            from .multichip import make_hybrid_mesh
 
-                from .multichip import make_hybrid_mesh
-
-                n = len(jax.devices())
-                if self.cfg.mesh_devices:
-                    n = min(n, self.cfg.mesh_devices)
-                hosts = self.cfg.mesh_hosts
-                chips = max(1, n // hosts)
-                self._fleet_hybrid = make_hybrid_mesh(hosts, chips)
-                self._fleet_hybrid_state = "ready"
-                events.emit(
-                    "verify.mesh", state="ready", hosts=hosts,
-                    chips_per_host=chips,
-                )
-            except Exception as e:  # mesh is an upgrade, never a gate
-                self._fleet_hybrid_state = "failed"
-                log.warning(
-                    "[Engine] hybrid fleet mesh unavailable, per-host "
-                    "single-chip dispatch: %s", e,
-                )
-                events.emit(
-                    "verify.mesh", state="failed", error=str(e)[:300]
-                )
-                return None
+            hosts = self.cfg.mesh_hosts
+            chips = max(1, self._mesh_device_count() // hosts)
+            self._fleet_hybrid = make_hybrid_mesh(hosts, chips)
+            events.emit(
+                "verify.mesh", state="ready", hosts=hosts,
+                chips_per_host=chips,
+            )
         return self._fleet_hybrid
 
     def _host_mesh(self, hs: _HostState):
         """This host's 1-D device sub-mesh at its current healthy width
-        (its hybrid-mesh row via :func:`multichip.host_submesh`; None =
-        single-chip dispatch).  Thread-safe: dispatch worker threads
-        race on first build and after shrink/re-grow."""
-        if hs.mesh_state == "ready":
-            return hs.mesh
-        if hs.mesh_state == "failed":
-            return None
+        (its hybrid-mesh row via :func:`multichip.host_submesh`).
+        Thread-safe: dispatch worker threads race on first build and
+        after shrink/re-grow."""
         with self._mesh_lock:
-            if hs.mesh_state != "cold":
-                return hs.mesh if hs.mesh_state == "ready" else None
-            hybrid = self._fleet_hybrid_mesh()
-            if hybrid is None:
-                hs.mesh_state = "failed"
-                return None
-            try:
+            if hs.mesh is None:
                 from .multichip import host_submesh
 
+                hybrid = self._fleet_hybrid_mesh()
                 if not hs.full_chips:
                     hs.full_chips = int(hybrid.devices.shape[-1])
                     hs.chips = hs.full_chips
                 hs.mesh = host_submesh(hybrid, hs.index, chips=hs.chips)
-                hs.mesh_state = "ready"
                 self._chips_gauge(hs.name, hs.chips)
-                return hs.mesh
-            except Exception as e:
-                hs.mesh_state = "failed"
-                events.emit(
-                    "verify.mesh", state="failed", host=hs.name,
-                    error=str(e)[:300],
-                )
-                return None
+            return hs.mesh
 
     def _dispatch_chunk(self, chunk, pad_to: int,
                         host: Optional[_HostState] = None):
@@ -1933,11 +1850,11 @@ class VerifyEngine:
         submissions; ``min_tpu_batch`` is the shed-only floor applied
         when a lingered partial lane finally lands here (forced-tpu
         backend excepted)."""
-        from .kernel import collect_verdicts, mark_pallas_broken_if_mosaic
+        from .kernel import collect_verdicts
 
         raw = concat_raw([as_raw_batch(p) for p in payloads])
-        B = self._device_batch
-        # (chunk | None, pad, (device array, count) | list[bool])
+        B = self.cfg.device_batch
+        # (device array, count) handles | list[bool] (cpu-shed tails)
         pending: list = []
         for i in range(0, len(raw), B):
             chunk = raw.slice(i, i + B)
@@ -1946,33 +1863,17 @@ class VerifyEngine:
                 and self.cfg.backend != "tpu"
                 and self._cpu is not None
             ):
-                pending.append((None, 0, self._cpu.verify_raw(chunk)))
+                pending.append(self._cpu.verify_raw(chunk))
                 metrics.inc("verify.cpu_items", len(chunk))
             else:
                 # small tails take the small compiled shape, not a mostly
                 # empty device_batch step
                 pad = B if len(chunk) > self.cfg.batch_size else self.cfg.batch_size
                 pending.append(
-                    (chunk, pad, self._dispatch_chunk(chunk, pad_to=pad,
-                                                      host=host))
+                    self._dispatch_chunk(chunk, pad_to=pad, host=host)
                 )
                 metrics.inc("verify.tpu_items", len(chunk))
         out: list[bool] = []
-        for chunk, pad, p in pending:
-            if isinstance(p, list):
-                out.extend(p)
-                continue
-            try:
-                out.extend(collect_verdicts(*p))
-            except Exception as e:  # noqa: BLE001 — only Mosaic recovered
-                # JAX async dispatch: a Mosaic RUNTIME failure surfaces
-                # here, not at the dispatch call.  Mark pallas broken and
-                # re-run this chunk once through the (now XLA) program.
-                if not mark_pallas_broken_if_mosaic(e):
-                    raise
-                out.extend(
-                    collect_verdicts(
-                        *self._dispatch_chunk(chunk, pad_to=pad, host=host)
-                    )
-                )
+        for p in pending:
+            out.extend(p if isinstance(p, list) else collect_verdicts(*p))
         return out

@@ -161,8 +161,8 @@ SQR_MODES = ("half", "mul")
 # time by tpunode.verify.bounds (not argued in comments).  "lazy" is
 # the default since round 12: −27% carry/fold vector ops in the op
 # model and a −9.5% measured step on the cpu-jax proxy @1024 (PERF.md;
-# campaign-clean on XLA and pallas-interpret, device verdict pending
-# the watcher's kind="lazy" rungs).
+# campaign-clean on XLA and pallas-interpret; no device verdict yet —
+# ROADMAP S5).
 REDUCE_MODES = ("eager", "lazy")
 
 

@@ -40,13 +40,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from ..trace import span
 from .ecdsa_cpu import Point
@@ -57,11 +52,9 @@ from .sched import host_names
 from .kernel import (
     ARG_IS_2D,
     kernel_modes,
-    pallas_broken,
     prepare_batch,
     prepare_batch_raw,
     verify_core,
-    with_mosaic_fallback,
 )
 
 __all__ = [
@@ -110,7 +103,7 @@ def make_hybrid_mesh(
     (the engine's fleet layer handles shrinking explicitly).
     """
     devs = jax.devices()
-    nproc = getattr(jax, "process_count", lambda: 1)()
+    nproc = jax.process_count()
     if nproc > 1:  # pragma: no cover - real pod only (no CI multi-host)
         hosts = nproc if hosts is None else hosts
         if chips_per_host is None:
@@ -136,8 +129,6 @@ def make_hybrid_mesh(
         )
     grid = np.array(devs[:need]).reshape(hosts, chips_per_host)
     return Mesh(grid, HYBRID_AXES)
-
-
 
 
 def host_submesh(
@@ -208,7 +199,7 @@ def sharded_verify_fn(
     if kernel not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown kernel {kernel!r}: auto|pallas|xla")
     use_pallas = kernel == "pallas" or (
-        kernel == "auto" and _mesh_is_tpu(mesh) and not pallas_broken()
+        kernel == "auto" and _mesh_is_tpu(mesh)
     )
     schnorr_free = bool(schnorr_free) and use_pallas
     # kernel_modes() carries the field formulation AND the point-form/
@@ -253,22 +244,13 @@ def sharded_verify_fn(
     # check_vma off: verify_core's scan carry starts from a broadcast
     # constant (INFINITY), which the varying-manual-axes analysis rejects
     # even though the program is shard-correct (pure DP + one psum).
-    try:
-        sharded = _shard_map(
-            step,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=(spec_1d, P()),
-            check_vma=False,
-        )
-    except TypeError:  # pragma: no cover - older jax spells it check_rep
-        sharded = _shard_map(
-            step,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=(spec_1d, P()),
-            check_rep=False,
-        )
+    sharded = shard_map(
+        step,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=(spec_1d, P()),
+        check_vma=False,
+    )
     fn = jax.jit(sharded)
     _FN_CACHE[key] = fn
     return fn
@@ -278,7 +260,7 @@ def _mesh_quantum(mesh: Mesh) -> int:
     """Per-batch size quantum: Pallas shards need BLOCK-aligned per-shard
     batches; the XLA program just needs a multiple of the mesh size."""
     n = mesh.devices.size
-    if _mesh_is_tpu(mesh) and not pallas_broken():
+    if _mesh_is_tpu(mesh):
         from .pallas_kernel import BLOCK
 
         return n * BLOCK
@@ -299,8 +281,7 @@ def dispatch_raw_sharded(
     This is the engine's mesh rung (``VerifyConfig.mesh_devices``): a
     packed full lane shards across chips with zero inter-chip traffic in
     the hot loop.  The CPU-mesh dryrun path (conftest's 8 virtual host
-    devices) pins it without TPU hardware; the device verdict is banked
-    by the watcher when a TPU window opens.
+    devices) pins it without TPU hardware.
     """
     from .raw import as_raw_batch
 
@@ -351,16 +332,8 @@ def verify_batch_sharded(
         for a, is2d in zip(prep.device_args, ARG_IS_2D)
     ]
 
-    def run():
-        # resolved inside the retry: after a Mosaic failure marks pallas
-        # broken, the auto selection yields the XLA variant (cached
-        # separately per use_pallas).  schnorr_free comes from the host
-        # prep flags (the ONE safe derivation — kernel.PreparedBatch):
-        # an ECDSA-only sharded batch sheds the acceptance pows exactly
-        # like the single-chip dispatcher.
-        ok, _total = sharded_verify_fn(
-            mesh, schnorr_free=prep.schnorr_free
-        )(*args)
-        return [bool(b) for b in np.asarray(ok)[: prep.count]]
-
-    return with_mosaic_fallback(run, "in shard_map")
+    # schnorr_free comes from the host prep flags (the ONE safe derivation
+    # — kernel.PreparedBatch): an ECDSA-only sharded batch sheds the
+    # acceptance pows exactly like the single-chip dispatcher.
+    ok, _total = sharded_verify_fn(mesh, schnorr_free=prep.schnorr_free)(*args)
+    return [bool(b) for b in np.asarray(ok)[: prep.count]]

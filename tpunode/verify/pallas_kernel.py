@@ -54,7 +54,11 @@ from .kernel import (
 
 __all__ = ["verify_blocked", "verify_blocked_impl", "BLOCK"]
 
-BLOCK = 256  # lanes per grid step: 2 tables x 1.2 MB VMEM + headroom
+# Lanes per grid step: 2 scratch tables x 1.2 MB VMEM + the two constant
+# tables + headroom.  All four default programs compile on a v5e under the
+# default 16 MiB scoped-VMEM limit, so no vmem_limit_bytes is set (PERF.md
+# "On the chip").
+BLOCK = 256
 
 _BETA_LIMBS = [int(x) for x in F.to_limbs(BETA)]
 _SEVEN_LIMBS = [7] + [0] * (F.NLIMBS - 1)
@@ -184,8 +188,7 @@ def _kernel(
     # ``t`` in powtab, then 64 4-bit windows with digits from SMEM row
     # ``row`` of euler_ref.  fori_loop bodies (one mul each) instead of
     # unrolled chains: the straight-line form dominated Mosaic compile
-    # time (the r3 finding; benchmarks/mosaic_diag.py's ``pow_descan``
-    # case probes whether a de-scanned static-digit ladder lowers too).
+    # time (the r3 finding).
     def pow_build_table(t):
         powtab_ref[0] = one
         powtab_ref[1] = t
@@ -334,22 +337,22 @@ def _kernel(
     seven = PF.const_col(_SEVEN_LIMBS, b)
     on_curve = PF.eq(PF.sqr(qy), PF.mul(PF.sqr(qx), qx) + seven)
 
-    # ---- jacobi(y(R)) for Schnorr lanes -----------------------------------
-    # y = Y/Z so jacobi(y) = jacobi(Y·Z); Euler pow t^((p-1)/2) == 1 as a
-    # windowed 4-bit exponentiation: the digit sequence is a compile-time
-    # constant (_EULER_DIGITS), the 16-entry power table lives in VMEM.
-    #
+    # ---- per-lane acceptance ----------------------------------------------
+    # Mask algebra, not jnp.where: a select whose OPERANDS are bool vectors
+    # is the one construct here Mosaic refuses (jax 0.9.0 / libtpu 0.0.34
+    # on v5e: "Unsupported target bitwidth for truncation", i8 -> i1).
+    is_ecdsa = (flags_ref[2:3] == 0) & (flags_ref[3:4] == 0)
+    algo_ok = is_ecdsa & (m1 | m2)
     # ``schnorr_free`` (STATIC, set by the dispatcher when no lane in the
     # batch carries a Schnorr/BIP340 flag — the common real shape: BTC
-    # mainnet has no BCH Schnorr, IBD-era blocks no taproot, and the
-    # ECDSA-only headline bench workload) prunes BOTH acceptance pows at
-    # trace time; the placeholders below are never selected by algo_ok.
-    if schnorr_free:
-        jac_ok = jnp.ones((1, b), dtype=jnp.bool_)
-        even_ok = jnp.ones((1, b), dtype=jnp.bool_)
-    else:
-        # jacobi(Y·Z) via the Euler pow (digit row 0), rebuilding the
-        # power table (the affine variant used it for the inversion)
+    # mainnet has no BCH Schnorr, IBD-era blocks no taproot) prunes BOTH
+    # acceptance pows at trace time; a flagged lane that reached this
+    # variant anyway fails closed.
+    if not schnorr_free:
+        # jacobi(y(R)) for the BCH Schnorr lanes: y = Y/Z so jacobi(y) =
+        # jacobi(Y·Z); Euler pow t^((p-1)/2) == 1 as a windowed 4-bit
+        # exponentiation (digit row 0), rebuilding the power table (the
+        # affine variant used it for the inversion)
         pow_build_table(PF.mul(Y, Z))
         pacc = lax.fori_loop(0, 64, pow_window_for(0), one)
         jac_ok = PF.eq(pacc, one)
@@ -361,11 +364,10 @@ def _kernel(
         y_aff = PF.mul(Y, zinv)
         even_ok = (PF.canonical(y_aff)[0:1] & 1) == 0
 
-    is_sch = flags_ref[2:3] != 0
-    is_b340 = flags_ref[3:4] != 0
-    algo_ok = jnp.where(
-        is_b340, m1 & even_ok, jnp.where(is_sch, m1 & jac_ok, m1 | m2)
-    )
+        # bip340 wins over schnorr, as in kernel.verify_core
+        is_b340 = flags_ref[3:4] != 0
+        is_sch = (flags_ref[2:3] != 0) & (flags_ref[3:4] == 0)
+        algo_ok = algo_ok | (m1 & ((is_b340 & even_ok) | (is_sch & jac_ok)))
     valid = (flags_ref[1:2] != 0) & on_curve & not_inf & algo_ok
     out_ref[:] = valid.astype(jnp.int32)
 
@@ -497,9 +499,7 @@ def verify_blocked_impl(
     if affine or not schnorr_free:
         # Exponent digits live in SMEM: the kernel reads them with
         # dynamic scalar indices inside the window fori_loop, which is
-        # scalar memory's canonical job — a VMEM block read that way
-        # is the r5 Mosaic-outage suspect (benchmarks/mosaic_diag.py
-        # probes both placements).  The projective schnorr_free variant
+        # scalar memory's canonical job.  The projective schnorr_free variant
         # omits the digits AND the (16, L, blk) pow-table scratch
         # entirely; the affine variants always need both (the batch
         # inversion's Fermat ladder reads the _PM2 digit row).

@@ -1,8 +1,8 @@
 """Stall watchdog: localize hangs instead of discovering them post-mortem.
 
-The BENCH_r05 outage mode — ``jax.devices`` blocking for an entire
-watchdog budget with nothing in the logs but a timeout — is exactly the
-failure this actor exists for.  It watches three stall surfaces:
+A backend call that blocks with nothing in the logs but a timeout is
+exactly the failure this actor exists for.  It watches three stall
+surfaces:
 
 * **event-loop lag** — the gap between when a timer should have fired and
   when it did.  A blocked loop (sync I/O, a long pure-Python section)
