@@ -1,0 +1,148 @@
+"""The per-layer metrics that read the waits (ISSUE 24): each new reader on
+a hand-made ``Reading`` — with ``None`` where its input is missing, as at a
+parent commit that has no such span — and every ``per_layer`` entry of
+``BENCHMARK.json`` against its metric file and its reader."""
+
+import importlib
+
+import pytest
+
+from chipbench import harness
+from chipbench.readers import (
+    idle_gap_share,
+    span_self_ms_per_count,
+    span_share_of_window,
+)
+
+BENCH = harness.load_json(harness.ROOT, "BENCHMARK.json")
+CELLS = {wl["name"] for wl in BENCH["workloads"]}
+WAITS = (
+    "sched.starved_share", "sched.slot_wait_share",
+    "sched.linger_ms_per_lane", "engine.hop_ms_per_lane",
+    "engine.deliver_ms_per_lane", "transfer.on_cpu_share",
+    "prep.on_cpu_share", "device.idle_share.starved",
+    "device.idle_share.unnamed",
+)
+COUNTER_BASED = WAITS[:7]
+
+# one window of a program that has the spans: 10 lanes in 4 s
+COUNTERS = {
+    "sched.lanes": 10.0,
+    "span.sched.starved.seconds": 3.0, "span.sched.starved.count": 12.0,
+    "span.sched.slot_wait.seconds": 0.0, "span.sched.slot_wait.count": 10.0,
+    "span.sched.linger.seconds": 0.25, "span.sched.linger.count": 10.0,
+    "span.verify.lane.seconds": 1.0, "span.verify.lane.count": 10.0,
+    "span.verify.dispatch.seconds": 0.6, "span.verify.dispatch.count": 10.0,
+    "span.verify.deliver.seconds": 0.1, "span.verify.deliver.count": 10.0,
+    "span.verify.transfer.seconds": 0.2,
+    "span.verify.transfer.cpu_seconds": 0.05,
+    "span.verify.prepare.seconds": 0.1,
+    "span.verify.prepare.cpu_seconds": 0.09,
+}
+TRACE = {"window_s": 2.0, "busy_s_by_chip": [0.45], "kernels": {},
+         "breakdown": {"idle_gaps": [
+             ["sched.starved", 1.2], ["verify.transfer", 0.3],
+             ["none", 0.05]]}}
+# the same window at a commit without them
+PARENT = {k: v for k, v in COUNTERS.items()
+          if not k.startswith(("span.sched.", "span.verify.lane",
+                               "span.verify.deliver"))
+          and not k.endswith(".cpu_seconds")}
+
+
+def reading(counters=COUNTERS, trace=TRACE, window_s=4.0):
+    return harness.Reading(dict(counters), window_s, trace, {}, {})
+
+
+def test_span_share_of_window():
+    assert span_share_of_window.read(
+        reading(), span="sched.starved") == pytest.approx(75.0)
+    # a wait that never had to be waited reads 0, not nothing
+    assert span_share_of_window.read(reading(), span="sched.slot_wait") == 0.0
+    assert span_share_of_window.read(
+        reading(PARENT), span="sched.starved") is None
+    assert span_share_of_window.read(
+        reading(window_s=0.0), span="sched.starved") is None
+
+
+def test_span_self_ms_per_count():
+    args = dict(span="verify.lane", children=["verify.dispatch",
+                                              "verify.deliver"],
+                per=["sched.lanes"])
+    assert span_self_ms_per_count.read(
+        reading(), **args) == pytest.approx(30.0)  # (1.0 - 0.6 - 0.1) s / 10
+    assert span_self_ms_per_count.read(reading(PARENT), **args) is None
+    no_lanes = dict(COUNTERS, **{"sched.lanes": 0.0})
+    assert span_self_ms_per_count.read(reading(no_lanes), **args) is None
+    # a child the window never saw takes nothing away
+    assert span_self_ms_per_count.read(
+        reading(), span="verify.lane", children=["verify.absent"],
+        per=["sched.lanes"]) == pytest.approx(100.0)
+
+
+def test_idle_gap_share():
+    assert idle_gap_share.read(
+        reading(), span="sched.starved") == pytest.approx(60.0)
+    assert idle_gap_share.read(reading(), span="none") == pytest.approx(2.5)
+    # the program has the span, the breakdown's ten largest do not: 0
+    assert idle_gap_share.read(reading(), span="sched.linger") == 0.0
+    # no trace (an untraced run); no such span (the parent commit)
+    assert idle_gap_share.read(
+        reading(trace=None), span="sched.starved") is None
+    assert idle_gap_share.read(reading(PARENT), span="sched.starved") is None
+    # what no span covers can be read at any commit
+    assert idle_gap_share.read(
+        reading(PARENT), span="none") == pytest.approx(2.5)
+    all_named = dict(TRACE, breakdown={"idle_gaps": [["sched.starved", 1.0]]})
+    assert idle_gap_share.read(reading(trace=all_named), span="none") == 0.0
+
+
+@pytest.mark.parametrize("entry", BENCH["per_layer"],
+                         ids=[m["name"] for m in BENCH["per_layer"]])
+def test_per_layer_entry_has_its_metric_file_and_its_reader(entry):
+    spec = harness.load_json(harness.ROOT, "chipbench", "metrics",
+                             entry["name"] + ".json")
+    for key in ("layer", "unit", "better", "source", "moves"):
+        assert spec[key] == entry[key], key
+    assert set(entry["workloads"]) <= CELLS
+    reader = importlib.import_module("chipbench.readers." + spec["reader"])
+    # the reader takes the file's arguments; on an empty window it returns
+    # a number or nothing, and does not raise
+    empty = harness.Reading({}, 1.0, None, {}, {})
+    out = reader.read(empty, **spec.get("args", {}))
+    assert out is None or isinstance(out, float)
+
+
+def test_the_nine_wait_metrics_are_listed_in_every_cell():
+    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    for name in WAITS:
+        assert set(by_name[name]["workloads"]) == CELLS, name
+        assert by_name[name]["moves"] == "sigs_per_s"
+    # appended: what was there before them is where it was
+    assert [m["name"] for m in BENCH["per_layer"]][-len(WAITS):] == list(WAITS)
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_wait_metrics_read_through_the_harness(traced):
+    """``harness.read_per_layer`` over the hand-made window: all nine in a
+    traced run, the seven counter-based ones in an untraced run; at the
+    parent commit the new readers leave their metrics out."""
+    ctx = harness.Ctx(
+        workload={"name": "bch-node.mempool"}, bench=BENCH, config={},
+        traffic={}, seed=0, seconds=4.0, trace=traced, rehearsal=None,
+        t_start=0.0)
+    got = harness.read_per_layer(ctx, reading(trace=TRACE if traced else None))
+    want = set(WAITS if traced else COUNTER_BASED)
+    assert want <= set(got)
+    assert not (set(WAITS) - want) & set(got)
+    assert got["sched.starved_share"]["value"] == pytest.approx(75.0)
+    assert got["sched.linger_ms_per_lane"]["value"] == pytest.approx(25.0)
+    assert got["engine.deliver_ms_per_lane"]["value"] == pytest.approx(10.0)
+    assert got["transfer.on_cpu_share"]["value"] == pytest.approx(25.0)
+    assert got["prep.on_cpu_share"]["value"] == pytest.approx(90.0)
+    old = harness.read_per_layer(
+        ctx, reading(PARENT, trace=TRACE if traced else None))
+    for name in ("sched.starved_share", "sched.slot_wait_share",
+                 "engine.hop_ms_per_lane", "device.idle_share.starved"):
+        assert name not in old
+

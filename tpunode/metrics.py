@@ -292,9 +292,11 @@ class Metrics:
             h.observe(value)
 
     def time_span(self, hist_name: str, seconds_name: str, count_name: str,
-                  dt: float) -> None:
-        """One-lock fast path for trace.span: histogram observe + the two
-        legacy counters (``span.<name>.seconds`` / ``.count``)."""
+                  dt: float, cpu_name: Optional[str] = None,
+                  cpu: Optional[float] = None) -> None:
+        """One-lock fast path for trace.span: histogram observe + the
+        ``span.<name>.seconds`` / ``.count`` counters and, where the span
+        took it, its thread's CPU time (``span.<name>.cpu_seconds``)."""
         if self.disabled:
             return
         now = time.monotonic()
@@ -305,6 +307,8 @@ class Metrics:
             h.observe(dt)
             self._inc_locked((seconds_name, ()), dt, now)
             self._inc_locked((count_name, ()), 1.0, now)
+            if cpu is not None:
+                self._inc_locked((cpu_name, ()), cpu, now)
 
     def on_drop(self, hook: Callable[[str, str], None]) -> None:
         """Register a ``(key, value)`` callback fired after every

@@ -2150,9 +2150,11 @@ class Node:
                         )
                     per_tx.append((tx, stats, items, task))
             # Awaiting the engine happens OUTSIDE any commit span — the
-            # wait is already attributed by the verify.queue spans, and
-            # folding it into node.commit would make that histogram mean
-            # something different on this path than on the native one.
+            # wait is carried by the engine's own spans (sched.linger /
+            # sched.slot_wait, then verify.lane with its dispatch and
+            # delivery), and folding it into node.commit would make that
+            # histogram mean something different on this path than on
+            # the native one.
             for tx, stats, items, task in per_tx:
                 if task is None:
                     self._publish_verdict(

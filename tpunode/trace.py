@@ -6,13 +6,27 @@ BASELINE metrics honestly:
 
 * :class:`span` — a context manager that times a region into the metrics
   registry: durations land in the ``span.<name>`` histogram (p50/p90/p99
-  via ``metrics.histogram("span.<name>").quantile``) plus the legacy
-  ``span.<name>.seconds`` / ``.count`` counters.  While a
-  :func:`profile_to` capture is active it also emits a
+  via ``metrics.histogram("span.<name>").quantile``) plus the
+  ``span.<name>.seconds`` / ``.count`` counters.  ``span(name, cpu=True)``
+  also records the CPU time the entering thread spent inside it, in
+  ``span.<name>.cpu_seconds`` (``time.thread_time``).  Wall less CPU is
+  time the thread did not run — GIL, lock, blocking call — and means
+  that only for a span with no ``await`` inside: across an ``await`` the
+  thread runs other tasks.  It is asked for span by span because the
+  clock is a system call: two reads cost 11 µs a span on the v5e's host
+  (1.2 µs here), which at the per-message spans' 13k entries a second
+  took a fifth off the mempool cell's rate (PERF.md, PR 24); the spans
+  that take it are entered a few times a lane.
+  While a :func:`profile_to` capture is active a span also emits a
   ``jax.profiler.TraceAnnotation`` so the region shows up named on the
-  TensorBoard/perfetto timeline of the device trace.
-* :func:`profile_to` — wraps ``jax.profiler.trace``: capture a full device
-  profile into a directory (``TPUNODE_PROFILE=<dir>`` in bench.py).
+  TensorBoard/perfetto timeline of the device trace.  The annotation is a
+  complete event written at exit, so a span held across an ``await``
+  keeps its own start and length however many others open and close on
+  that thread meanwhile; one open when the capture starts or stops is
+  in it from the capture's start or up to its stop.
+* :func:`profile_to` — capture a device profile into a directory
+  (``jax.profiler.start_trace`` / ``stop_trace``) with the Python tracer
+  off; the callers are ``chipbench/harness.py`` and ``bench.py``.
 
 When a request-scoped trace is active (tpunode/tracectx.py — one
 per-block/tx pipeline trace), every span additionally lands as a child
@@ -30,6 +44,7 @@ trace, pinned by tests/test_bench.py).  ``TPUNODE_NO_METRICS=1``
 from __future__ import annotations
 
 import contextlib
+import logging
 import time
 from typing import Iterator, Optional
 
@@ -37,6 +52,8 @@ from .metrics import metrics
 from .tracectx import _ACTIVE as _active_trace
 
 __all__ = ["span", "profile_to"]
+
+log = logging.getLogger("tpunode.trace")
 
 # Bound by profile_to() for the length of a capture: importing this module
 # (and so the whole tpunode package) stays jax-free, which lets worker
@@ -48,19 +65,29 @@ _jax_profiler = None
 # against the <5µs span budget, and useless without an active trace).
 _profiling = False
 
+# The spans open right now.  A capture annotates those entered before it
+# started (from its start on) and ends the annotations of those still open
+# when it stops (up to its stop): the waits are held for hundreds of
+# milliseconds, and without this the idle time at both edges of a few
+# seconds' capture has no name (PERF.md, PR 24).  set.add / discard and
+# list(set) are single operations under the GIL.
+_open: set["span"] = set()
 
-# name -> ("span.<name>", "span.<name>.seconds", "span.<name>.count"):
-# precomputed so the hot path allocates no strings per span entry.
-_span_names: dict[str, tuple[str, str, str]] = {}
+
+# name -> ("span.<name>", "span.<name>.seconds", "span.<name>.count",
+# "span.<name>.cpu_seconds"): precomputed so the hot path allocates no
+# strings per span entry.
+_span_names: dict[str, tuple[str, str, str, str]] = {}
 
 
-def _names(name: str) -> tuple[str, str, str]:
+def _names(name: str) -> tuple[str, str, str, str]:
     keys = _span_names.get(name)
     if keys is None:
         keys = _span_names[name] = (
             f"span.{name}",
             f"span.{name}.seconds",
             f"span.{name}.count",
+            f"span.{name}.cpu_seconds",
         )
     return keys
 
@@ -68,10 +95,11 @@ def _names(name: str) -> tuple[str, str, str]:
 class span:
     """``with span("verify.dispatch"): ...`` — see module docstring."""
 
-    __slots__ = ("_name", "_ann", "_t0", "_rec", "_tok")
+    __slots__ = ("_name", "_cpu", "_ann", "_t0", "_c0", "_rec", "_tok")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, cpu: bool = False):
         self._name = name
+        self._cpu = cpu
         self._ann = None
 
     def __enter__(self) -> "span":
@@ -85,37 +113,58 @@ class span:
             tr, parent = act
             self._rec = tr.begin(self._name, parent)
             self._tok = _active_trace.set((tr, self._rec.id))
-        if _profiling and _jax_profiler is not None:
-            try:
-                ann = _jax_profiler.TraceAnnotation(self._name)
-                ann.__enter__()
-                self._ann = ann
-            except Exception:  # profiler unavailable on this backend
-                self._ann = None
+        if _profiling:
+            self._annotate()
+        _open.add(self)
         self._t0 = time.perf_counter()
+        if self._cpu:  # inside the wall interval: CPU never reads above wall
+            self._c0 = time.thread_time()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        cpu = time.thread_time() - self._c0 if self._cpu else None
         dt = time.perf_counter() - self._t0
+        _open.discard(self)
         if not metrics.disabled:
             keys = _names(self._name)
-            metrics.time_span(keys[0], keys[1], keys[2], dt)
+            metrics.time_span(keys[0], keys[1], keys[2], dt, keys[3], cpu)
         rec = self._rec
         if rec is not None:
             rec.dur = dt
             _active_trace.reset(self._tok)
             self._rec = None
-        if self._ann is not None:
-            self._ann.__exit__(exc_type, exc, tb)
-            self._ann = None
+        self._end_annotation()
         return False
+
+    # The annotation is a complete event, written by whichever thread ends
+    # it: the capture's thread may begin or end one for a span of another
+    # thread.  Ending it twice writes it once.
+
+    def _annotate(self) -> None:
+        if self._ann is None and _jax_profiler is not None:
+            try:
+                ann = _jax_profiler.TraceAnnotation(self._name)
+                ann.__enter__()
+                self._ann = ann
+            except Exception:  # profiler unavailable on this backend
+                pass
+
+    def _end_annotation(self) -> None:
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(None, None, None)
 
 
 @contextlib.contextmanager
 def profile_to(directory: Optional[str]) -> Iterator[None]:
-    """Capture a JAX device profile into ``directory`` (no-op when None or
-    the profiler is unavailable).  Spans entered during the capture are
-    annotated onto the device timeline."""
+    """Capture a JAX device profile into ``directory`` (no-op when None).
+    Spans entered during the capture are annotated onto the device
+    timeline.  The Python tracer is off: JAX's default traces every
+    Python call, which cut a host-bound node to a third of its rate and
+    stalled it for seconds at stop (PERF.md, PR 23).  A capture that
+    cannot start is logged and counted (``trace.capture_failed``) and the
+    body runs without one."""
     global _profiling, _jax_profiler
     if not directory:
         yield
@@ -123,14 +172,26 @@ def profile_to(directory: Optional[str]) -> Iterator[None]:
     try:
         import jax.profiler
 
-        cm = jax.profiler.trace(directory)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        # start_trace/stop_trace, not the jax.profiler.trace context
+        # manager: a caller may have wrapped that one to pass options of
+        # its own (chipbench/harness.py does)
+        jax.profiler.start_trace(directory, profiler_options=opts)
     except Exception:
+        log.exception("[Trace] profiler capture into %s did not start",
+                      directory)
+        metrics.inc("trace.capture_failed")
         yield
         return
     _jax_profiler = jax.profiler
     _profiling = True
+    for sp in list(_open):
+        sp._annotate()
     try:
-        with cm:
-            yield
+        yield
     finally:
         _profiling = False
+        for sp in list(_open):
+            sp._end_annotation()
+        jax.profiler.stop_trace()

@@ -1177,10 +1177,14 @@ class VerifyEngine:
         workers (not this loop) own dispatch."""
         assert self._kick is not None and self._slots is not None
         while True:
-            # wait for work
-            while not self._uncut_pending():
-                await self._kick.wait()
-                self._kick.clear()
+            # The loop's three waits are spans (sched.starved / linger /
+            # slot_wait): one of them is open whenever it is not cutting
+            # a lane, so the device's idle time has a name on the
+            # profiler's clock.  Nothing queued: upstream has not fed us.
+            with span("sched.starved"):
+                while not self._uncut_pending():
+                    await self._kick.wait()
+                    self._kick.clear()
             target = self._lane_target()
             # Event-driven fill (VERDICT r4 weak #6 — the former 2 ms poll
             # burned ≤500 wakes/s per linger window): sleep until either a
@@ -1189,18 +1193,21 @@ class VerifyEngine:
             # remainder lingers for later submissions to pack with only
             # while its submitter is younger than max_wait (ISSUE 10:
             # max-linger — a lone small batch still dispatches promptly).
-            while self._uncut_pending() < target:
-                oldest = self._uncut_oldest()
-                if oldest is None:
-                    break
-                remain = oldest + self.cfg.max_wait - time.monotonic()
-                if remain <= 0:
-                    break
-                try:
-                    await asyncio.wait_for(self._kick.wait(), timeout=remain)
-                except asyncio.TimeoutError:
-                    break
-                self._kick.clear()
+            with span("sched.linger"):
+                while self._uncut_pending() < target:
+                    oldest = self._uncut_oldest()
+                    if oldest is None:
+                        break
+                    remain = oldest + self.cfg.max_wait - time.monotonic()
+                    if remain <= 0:
+                        break
+                    try:
+                        await asyncio.wait_for(
+                            self._kick.wait(), timeout=remain
+                        )
+                    except asyncio.TimeoutError:
+                        break
+                    self._kick.clear()
             if not self._uncut_pending():
                 continue
             if self._fleet is not None:
@@ -1208,12 +1215,19 @@ class VerifyEngine:
                 continue
             # admission: a free pipeline slot (more work keeps queueing —
             # and packing fuller lanes — while every slot is busy)
-            await self._slots.acquire()
+            await self._acquire_slot()
             lane = self._packer.pop_lane(self._lane_target())
             if lane is None:
                 self._slots.release()
                 continue
             self._spawn_lane_task(lane)
+
+    async def _acquire_slot(self) -> None:
+        """Wait for a free pipeline slot: a lane could be cut, but
+        ``pipeline_depth`` lanes are in flight."""
+        assert self._slots is not None
+        with span("sched.slot_wait"):
+            await self._slots.acquire()
 
     def _spawn_lane_task(self, lane: PackedLane) -> None:
         """Spawn one locally-dispatched lane task (the caller holds a
@@ -1237,15 +1251,16 @@ class VerifyEngine:
         still produces verdicts."""
         assert self._fleet is not None and self._room is not None
         assert self._slots is not None
-        while not self._fleet.feedable() and self._fleet.active_hosts():
-            self._room.clear()
-            await self._room.wait()
+        with span("sched.slot_wait"):  # every active host's queue is full
+            while not self._fleet.feedable() and self._fleet.active_hosts():
+                self._room.clear()
+                await self._room.wait()
         if not self._fleet.active_hosts():
             # no active host at all: local fallback, traffic never stops
             lane = self._fleet.pop_any(self._lane_target())
             if lane is None:
                 return
-            await self._slots.acquire()
+            await self._acquire_slot()
             self._spawn_lane_task(lane)
             return
         lane, host = self._fleet.cut_next(self._lane_target())
@@ -1255,7 +1270,7 @@ class VerifyEngine:
             # cut from the central packer but no queue had room (raced
             # with other cuts): serve locally rather than re-queueing —
             # the lane exists now and must resolve exactly once
-            await self._slots.acquire()
+            await self._acquire_slot()
             self._spawn_lane_task(lane)
             return
         self._wake_fleet()
@@ -1320,63 +1335,73 @@ class VerifyEngine:
         finds no healthy peer) falls through the LOCAL cpu ladder so its
         waiters still resolve."""
         assert self._kick is not None and self._slots is not None
-        payloads = lane.payloads()
-        total = lane.total
-        metrics.inc("verify.batches")
-        metrics.inc("verify.items", total)
-        metrics.set_gauge("verify.batch_occupancy", lane.occupancy)
-        with self._inflight_lock:
-            self._inflight_seq += 1
-            token = self._inflight_seq
-            self._inflight[token] = time.monotonic()
-        try:
-            classes = lane.class_counts()
-            tenants = lane.tenant_counts()
-            try:
-                results = await asyncio.to_thread(
-                    self._dispatch_traced, payloads, lane.target, lane.act0,
-                    host, None, classes, tenants,
-                )
-            except HostLost as e:
-                assert host is not None and self._fleet is not None
-                self._host_down(host, str(e))
-                if (
-                    lane.requeues < len(self._hosts)
-                    and self._fleet.requeue(host.name, lane) is not None
-                ):
-                    self._wake_fleet()
-                    return
-                # no healthy peer (or the lane is orbiting dying hosts):
-                # serve it locally, skipping the device rungs entirely
-                results = await asyncio.to_thread(
-                    self._dispatch_traced, payloads, lane.target, lane.act0,
-                    None, "cpu" if self._cpu is not None else "oracle",
-                    classes, tenants,
-                )
-        except asyncio.CancelledError:
-            # engine teardown mid-dispatch: waiters must not hang on a
-            # future nobody will resolve
-            for sub, _, _ in lane.slices:
-                if not sub.fut.done():
-                    sub.fut.cancel()
-            raise
-        except Exception as e:  # all rungs failed: the waiters learn it
-            log.error("[Engine] lane of %d failed: %s", total, e)
-            for sub, _, _ in lane.slices:
-                sub.fail(e)
-            return
-        finally:
+        # verify.lane is the lane's life on the loop side, entry to last
+        # delivery; less its children verify.dispatch (worker thread) and
+        # verify.deliver, what is left is the two thread hops — where a
+        # busy loop or a held GIL shows first.  Every end of a lane but a
+        # re-queue is one verify.deliver: verdicts, failure or cancel.
+        with span("verify.lane"):
+            payloads = lane.payloads()
+            total = lane.total
+            metrics.inc("verify.batches")
+            metrics.inc("verify.items", total)
             with self._inflight_lock:
-                self._inflight.pop(token, None)
-            if slot:
-                self._slots.release()
-            if self._room is not None:
-                self._room.set()
-            self._kick.set()  # a freed slot may unblock the scheduler
-        pos = 0
-        for sub, lo, hi in lane.slices:
-            sub.deliver(lo, results[pos : pos + (hi - lo)])
-            pos += hi - lo
+                self._inflight_seq += 1
+                token = self._inflight_seq
+                self._inflight[token] = time.monotonic()
+            try:
+                classes = lane.class_counts()
+                tenants = lane.tenant_counts()
+                try:
+                    results = await asyncio.to_thread(
+                        self._dispatch_traced, payloads, lane.target,
+                        lane.act0, host, None, classes, tenants,
+                    )
+                except HostLost as e:
+                    assert host is not None and self._fleet is not None
+                    self._host_down(host, str(e))
+                    if (
+                        lane.requeues < len(self._hosts)
+                        and self._fleet.requeue(host.name, lane) is not None
+                    ):
+                        self._wake_fleet()
+                        return
+                    # no healthy peer (or the lane is orbiting dying
+                    # hosts): serve it locally, skipping the device rungs
+                    # entirely
+                    results = await asyncio.to_thread(
+                        self._dispatch_traced, payloads, lane.target,
+                        lane.act0, None,
+                        "cpu" if self._cpu is not None else "oracle",
+                        classes, tenants,
+                    )
+            except asyncio.CancelledError:
+                # engine teardown mid-dispatch: waiters must not hang on
+                # a future nobody will resolve
+                with span("verify.deliver", cpu=True):
+                    for sub, _, _ in lane.slices:
+                        if not sub.fut.done():
+                            sub.fut.cancel()
+                raise
+            except Exception as e:  # all rungs failed: the waiters learn it
+                log.error("[Engine] lane of %d failed: %s", total, e)
+                with span("verify.deliver", cpu=True):
+                    for sub, _, _ in lane.slices:
+                        sub.fail(e)
+                return
+            finally:
+                with self._inflight_lock:
+                    self._inflight.pop(token, None)
+                if slot:
+                    self._slots.release()
+                if self._room is not None:
+                    self._room.set()
+                self._kick.set()  # a freed slot may unblock the scheduler
+            with span("verify.deliver", cpu=True):
+                pos = 0
+                for sub, lo, hi in lane.slices:
+                    sub.deliver(lo, results[pos : pos + (hi - lo)])
+                    pos += hi - lo
 
     def _dispatch(self, payload) -> list[bool]:
         """Pick an execution engine and run one payload (worker thread)."""
@@ -1478,7 +1503,7 @@ class VerifyEngine:
         batch through that fleet host's breaker and sub-mesh (ISSUE 13);
         ``backend`` forces the starting rung (the fleet's local-fallback
         path pins "cpu" so a dark fleet never re-enters device picks)."""
-        with span("verify.dispatch"):
+        with span("verify.dispatch", cpu=True):
             total = sum(len(p) for p in payloads)
             occupancy = total / target if target else None
             if occupancy is not None:
@@ -1491,7 +1516,6 @@ class VerifyEngine:
             t0 = time.perf_counter()
             out, served = self._run_ladder(picked, payloads, total, host)
             dt = time.perf_counter() - t0
-            metrics.inc("verify.seconds", dt)
             # Ledger charge (ISSUE 17): the ONE measured rung time is cut
             # across the lane's carried classes; the sync/no-lane paths
             # (verify_sync, warmup canaries) have no class counts and

@@ -499,7 +499,7 @@ def prepare_batch(
             hv[i] = True
             s_vals.append(s)
             s_idx.append(i)
-    with span("verify.batch_inv"):
+    with span("verify.batch_inv", cpu=True):
         s_inv = _batch_inverse_mod_n(s_vals) if s_vals else []
     inv_by_idx = dict(zip(s_idx, s_inv))
 
@@ -1104,7 +1104,7 @@ def _dispatch_prep(prep: PreparedBatch) -> tuple[jnp.ndarray, int]:
     # host->device transfer and kernel enqueue are separate spans (both
     # are async under JAX dispatch: these time the enqueue, the blocking
     # tail shows up in verify.readback)
-    with span("verify.transfer"):
+    with span("verify.transfer", cpu=True):
         args = tuple(jnp.asarray(a) for a in prep.device_args)
     if _pallas_usable(args[8].shape[-1]):
         from .pallas_kernel import verify_blocked
@@ -1113,12 +1113,12 @@ def _dispatch_prep(prep: PreparedBatch) -> tuple[jnp.ndarray, int]:
         # batch (the common real shape) selects the variant with the
         # jacobi/parity acceptance pows pruned at trace time.  The XLA
         # program below gets the same effect at runtime via lax.cond.
-        with span("verify.kernel"):
+        with span("verify.kernel", cpu=True):
             return (
                 verify_blocked(*args, schnorr_free=prep.schnorr_free),
                 prep.count,
             )
-    with span("verify.kernel"):
+    with span("verify.kernel", cpu=True):
         return verify_device(*args), prep.count
 
 
@@ -1131,7 +1131,7 @@ def dispatch_batch_tpu(
     asynchronous, so the caller can prep the next chunk while this one
     computes — the overlap that keeps the device saturated during IBD
     (SURVEY.md §7 hard part 5).  Collect with :func:`collect_verdicts`."""
-    with span("verify.prepare"):
+    with span("verify.prepare", cpu=True):
         prep = prepare_batch(items, pad_to=pad_to)
     return _dispatch_prep(prep)
 
@@ -1139,14 +1139,14 @@ def dispatch_batch_tpu(
 def dispatch_batch_tpu_raw(raw, pad_to: Optional[int] = None) -> tuple[jnp.ndarray, int]:
     """:func:`dispatch_batch_tpu` over a packed RawBatch (native-extract
     fast path): same async dispatch, no Python-int round trip."""
-    with span("verify.prepare"):
+    with span("verify.prepare", cpu=True):
         prep = prepare_batch_raw(raw, pad_to=pad_to)
     return _dispatch_prep(prep)
 
 
 def collect_verdicts(out: jnp.ndarray, count: int) -> list[bool]:
     """Block on a :func:`dispatch_batch_tpu` result and return verdicts."""
-    with span("verify.readback"):
+    with span("verify.readback", cpu=True):
         return [bool(b) for b in np.asarray(out)[:count]]
 
 

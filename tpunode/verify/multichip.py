@@ -289,18 +289,18 @@ def dispatch_raw_sharded(
     quantum = _mesh_quantum(mesh)
     size = max(pad_to or 0, len(raw), 1)
     size = (size + quantum - 1) // quantum * quantum
-    with span("verify.prepare"):
+    with span("verify.prepare", cpu=True):
         prep = prepare_batch_raw(raw, pad_to=size)
     axes = _batch_axes(mesh)
     shard_2d = NamedSharding(mesh, P(None, axes))
     shard_1d = NamedSharding(mesh, P(axes))
-    with span("verify.transfer"):
+    with span("verify.transfer", cpu=True):
         args = [
             jax.device_put(np.asarray(a), shard_2d if is2d else shard_1d)
             for a, is2d in zip(prep.device_args, ARG_IS_2D)
         ]
     fn = sharded_verify_fn(mesh, kernel, schnorr_free=prep.schnorr_free)
-    with span("verify.kernel"):
+    with span("verify.kernel", cpu=True):
         ok, _total = fn(*args)
     return ok, prep.count
 

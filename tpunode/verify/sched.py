@@ -62,7 +62,10 @@ surface — ``sched.host_depth{host=}`` gauges, ``sched.steals`` /
 ``sched.requeued`` counters, ``sched.steal`` events, plus the affine
 feed surface: ``sched.affinity_routed{host=}`` / ``sched.affinity_spilled``
 counters and ``sched.feed_idle{host=}`` gauges (queue-idle fraction —
-the per-host feed-starvation metric; OBSERVABILITY.md).
+the per-host feed-starvation metric; OBSERVABILITY.md).  The scheduler
+loop that drives a packer lives in ``engine.VerifyEngine._run``; its
+three waits are the ``sched.starved`` / ``sched.linger`` /
+``sched.slot_wait`` spans, entered there.
 """
 
 from __future__ import annotations
