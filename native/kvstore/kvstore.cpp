@@ -61,23 +61,38 @@ constexpr uint16_t KIND_SNAPSHOT = 1;
 const char MAGIC[4] = {'T', 'P', 'K', '2'};
 constexpr uint64_t SEG_LIMIT = 64ull << 20;  // rotation size, LogKV default
 
-// zlib-compatible CRC-32 (polynomial 0xEDB88320), table-driven.
+// zlib-compatible CRC-32 (polynomial 0xEDB88320), slicing-by-8: a block's
+// UTXO delta is 12 MB of records a block (kv_frame_v2), where a byte a
+// step cost as much as the write itself.
 struct Crc32Table {
-  uint32_t t[256];
+  uint32_t t[8][256];
   Crc32Table() {
     for (uint32_t i = 0; i < 256; ++i) {
       uint32_t c = i;
       for (int k = 0; k < 8; ++k)
         c = (c & 1) ? 0xEDB88320u ^ (c >> 1) : c >> 1;
-      t[i] = c;
+      t[0][i] = c;
     }
+    for (uint32_t i = 0; i < 256; ++i)
+      for (int s = 1; s < 8; ++s)
+        t[s][i] = t[0][t[s - 1][i] & 0xFF] ^ (t[s - 1][i] >> 8);
   }
 };
 
 uint32_t crc32(const uint8_t *p, size_t n) {
   static const Crc32Table tab;
   uint32_t c = 0xFFFFFFFFu;
-  for (size_t i = 0; i < n; ++i) c = tab.t[(c ^ p[i]) & 0xFF] ^ (c >> 8);
+  for (; n >= 8; p += 8, n -= 8) {
+    uint32_t lo, hi;  // little-endian targets only, as put_u32 below
+    memcpy(&lo, p, 4);
+    memcpy(&hi, p + 4, 4);
+    lo ^= c;
+    c = tab.t[7][lo & 0xFF] ^ tab.t[6][(lo >> 8) & 0xFF] ^
+        tab.t[5][(lo >> 16) & 0xFF] ^ tab.t[4][lo >> 24] ^
+        tab.t[3][hi & 0xFF] ^ tab.t[2][(hi >> 8) & 0xFF] ^
+        tab.t[1][(hi >> 16) & 0xFF] ^ tab.t[0][hi >> 24];
+  }
+  for (; n; ++p, --n) c = tab.t[0][(c ^ *p) & 0xFF] ^ (c >> 8);
   return c ^ 0xFFFFFFFFu;
 }
 
@@ -590,6 +605,81 @@ int kv_write_batch(void* h, const char* blob, uint64_t len, int do_fsync) {
   }
   if (pos != len) return -1;
   return s->commit(ops, do_fsync != 0) ? 0 : -2;
+}
+
+// Frame a v1 batch blob as the v2 records LogKV appends, for a caller that
+// holds no store handle (tpunode/store.py LogKV.write_delta; ctypes drops
+// the GIL for the call).  `blob` is a DELTA: v1 records (op u8, klen u32le,
+// vlen u32le, key, value), every put before every delete, deletes without
+// a value.  `out` receives, per record,
+//   crc32 | seq, op, ns_len + klen, vlen | ns ++ key | value
+// with seq counting up from seq0 and the CRC over everything after itself.
+// Beside it the same keys and values in columns, for the index: `keys` =
+// every ns ++ key end to end with `klens` their lengths, `vals` = the puts'
+// values with `vlens`.  counts = {records, puts, bytes of out, of keys, of
+// vals}.  With out == NULL the call only validates and counts.  Returns 0;
+// -1 = malformed blob (bad opcode, a length past the end, a put after a
+// delete, a delete with a value); -2 = the blob is not what was counted.
+int kv_frame_v2(const uint8_t *blob, uint64_t len, const uint8_t *ns,
+                uint32_t ns_len, uint32_t seq0, uint8_t *out, uint8_t *keys,
+                int32_t *klens, uint8_t *vals, int32_t *vlens,
+                uint64_t *counts) {
+  uint64_t pos = 0, w = 0, kw = 0, vw = 0, n = 0, puts = 0;
+  bool deleting = false;
+  while (pos < len) {
+    if (len - pos < REC_HDR) return -1;
+    uint8_t op = blob[pos];
+    uint32_t klen, vlen;
+    memcpy(&klen, blob + pos + 1, 4);
+    memcpy(&vlen, blob + pos + 5, 4);
+    uint64_t body = uint64_t(klen) + vlen;
+    if (body > len - pos - REC_HDR) return -1;
+    if (op == OP_PUT) {
+      if (deleting) return -1;
+    } else if (op == OP_DEL) {
+      if (vlen) return -1;
+      deleting = true;
+    } else {
+      return -1;
+    }
+    uint64_t total = REC_V2_HDR + ns_len + body;
+    const uint8_t *key = blob + pos + REC_HDR;
+    if (out) {
+      if (n >= counts[0] || w + total > counts[2]) return -2;
+      uint8_t *r = out + w;
+      put_u32(r + 4, seq0 + uint32_t(n));
+      r[8] = op;
+      put_u32(r + 9, ns_len + klen);
+      put_u32(r + 13, vlen);
+      if (ns_len) memcpy(r + REC_V2_HDR, ns, ns_len);
+      memcpy(r + REC_V2_HDR + ns_len, key, body);
+      put_u32(r, crc32(r + 4, total - 4));
+      memcpy(keys + kw, r + REC_V2_HDR, ns_len + klen);
+      klens[n] = int32_t(ns_len + klen);
+      if (op == OP_PUT) {
+        memcpy(vals + vw, key + klen, vlen);
+        vlens[puts] = int32_t(vlen);
+      }
+    }
+    w += total;
+    kw += ns_len + klen;
+    if (op == OP_PUT) {
+      vw += vlen;
+      ++puts;
+    }
+    pos += REC_HDR + body;
+    ++n;
+  }
+  if (!out) {
+    counts[0] = n;
+    counts[1] = puts;
+    counts[2] = w;
+    counts[3] = kw;
+    counts[4] = vw;
+  } else if (n != counts[0] || w != counts[2]) {
+    return -2;
+  }
+  return 0;
 }
 
 // Serialize every (key, value) with key starting with prefix, in key order,

@@ -24,6 +24,7 @@ WAITS = (
     "device.idle_share.unnamed",
 )
 COUNTER_BASED = WAITS[:7]
+CONNECT = ("utxo.connect_ms_per_block", "utxo.connect_cpu_ms_per_block")
 
 # one window of a program that has the spans: 10 lanes in 4 s
 COUNTERS = {
@@ -118,8 +119,49 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
     for name in WAITS:
         assert set(by_name[name]["workloads"]) == CELLS, name
         assert by_name[name]["moves"] == "sigs_per_s"
-    # appended: what was there before them is where it was
-    assert [m["name"] for m in BENCH["per_layer"]][-len(WAITS):] == list(WAITS)
+    # appended: what was there before them is where it was, and what
+    # later PRs added comes after them
+    names = [m["name"] for m in BENCH["per_layer"]]
+    first = names.index(WAITS[0])
+    assert names[first:first + len(WAITS)] == list(WAITS)
+    assert names[first + len(WAITS):] == list(CONNECT)
+
+
+def test_the_two_connect_metrics_read_the_utxo_connect_span():
+    """ISSUE 26: ``utxo.connect`` over the blocks connected, wall and CPU,
+    in the two cells that connect blocks; 0 at a commit without the span
+    (``span_ms_per_count`` / ``counter_ratio`` cannot tell), nothing where
+    no block was connected."""
+    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    for name in CONNECT:
+        entry = by_name[name]
+        assert entry["workloads"] == ["bch-node.ibd", "bch-32mb.blocks"]
+        assert entry["layer"] == "UTXO connect / store"
+        assert entry["moves"] == "host_cpu_ms_per_ksig"
+        assert (entry["unit"], entry["better"]) == ("ms/block", "lower")
+    ctx = harness.Ctx(
+        workload={"name": "bch-32mb.blocks"}, bench=BENCH, config={},
+        traffic={}, seed=0, seconds=4.0, trace=False, rehearsal=None,
+        t_start=0.0)
+    window = dict(COUNTERS, **{
+        "utxo.applied": 4.0, "span.utxo.connect.count": 4.0,
+        "span.utxo.connect.seconds": 1.2,
+        "span.utxo.connect.cpu_seconds": 0.5})
+    got = harness.read_per_layer(ctx, reading(window, trace=None))
+    assert got["utxo.connect_ms_per_block"]["value"] == pytest.approx(300.0)
+    assert got["utxo.connect_cpu_ms_per_block"]["value"] == pytest.approx(125.0)
+    parent = harness.read_per_layer(
+        ctx, reading(dict(PARENT, **{"utxo.applied": 4.0}), trace=None))
+    assert parent["utxo.connect_ms_per_block"]["value"] == 0.0
+    assert parent["utxo.connect_cpu_ms_per_block"]["value"] == 0.0
+    idle = harness.read_per_layer(ctx, reading(trace=None))
+    assert not set(CONNECT) & set(idle)
+    mempool = harness.read_per_layer(
+        harness.Ctx(workload={"name": "bch-node.mempool"}, bench=BENCH,
+                    config={}, traffic={}, seed=0, seconds=4.0, trace=False,
+                    rehearsal=None, t_start=0.0),
+        reading(window, trace=None))
+    assert not set(CONNECT) & set(mempool)
 
 
 @pytest.mark.parametrize("traced", [False, True])

@@ -138,6 +138,19 @@ def load_kvstore_lib() -> ctypes.CDLL:
         lib.kv_count.restype = ctypes.c_uint64
         lib.kv_count.argtypes = [ctypes.c_void_p]
         lib.kv_buf_free.argtypes = [ctypes.c_void_p]
+        # handle-free: frames a v1 delta blob as LogKV's v2 records
+        # (store.LogKV.write_delta); pointers as integers, the caller's
+        # numpy buffers stay alive across the call
+        lib.kv_frame_v2.restype = ctypes.c_int
+        lib.kv_frame_v2.argtypes = [
+            ctypes.c_char_p, ctypes.c_uint64,  # blob
+            ctypes.c_char_p, ctypes.c_uint32,  # ns
+            ctypes.c_uint32,  # seq0
+            ctypes.c_void_p,  # out
+            ctypes.c_void_p, ctypes.c_void_p,  # keys, klens
+            ctypes.c_void_p, ctypes.c_void_p,  # vals, vlens
+            ctypes.c_void_p,  # counts
+        ]
         _lib = lib
         return lib
 

@@ -105,6 +105,23 @@ log = logging.getLogger("tpunode.node")
 _native_extract_state: Optional[bool] = None
 
 
+def _parse_region(raw: bytes, n_txs: int, want_delta: bool):
+    """Worker-thread job: the ONE native parse of a message's tx region,
+    and with it a block's UTXO delta (``utxo_ops``: 2-3 ms per 8,000 txs
+    while the region is open, against a second parse of the block at
+    connect time).  -> (region, delta or None)."""
+    from .txextract import ParsedTxRegion
+
+    region = ParsedTxRegion(raw, n_txs)
+    if not want_delta:
+        return region, None
+    try:
+        return region, region.utxo_ops()
+    except BaseException:
+        region.close()
+        raise
+
+
 def _native_extract_available() -> bool:
     """Does the native extractor load on this box?  Cached; the first call
     may run `make` (one attempt per process, like the other native libs)."""
@@ -1029,16 +1046,18 @@ class Node:
                 return None  # different branch: not covered
         return bn.height
 
-    def _connect_block_utxo(self, block) -> None:
+    def _connect_block_utxo(self, block, delta=None) -> None:
         """Schedule the persistent UTXO connect for an ingested block
-        (supervised; ordering enforced by ``_utxo_lock``)."""
+        (supervised; ordering enforced by ``_utxo_lock``).  ``delta``:
+        the block's ``ParsedTxRegion.utxo_ops()`` where its verification
+        made one (``_verify_txs_native``), so the connect parses nothing."""
         if self.utxo is None:
             return
         self._verify_tasks.add_child(
-            self._apply_block_utxo(block), name="utxo-connect"
+            self._apply_block_utxo(block, delta), name="utxo-connect"
         )
 
-    async def _apply_block_utxo(self, block) -> None:
+    async def _apply_block_utxo(self, block, delta=None) -> None:
         """Apply one block's spends/creates + watermark atomically.  The
         tx parse and the store write both run off-loop; failures are loud
         (``utxo.error``) but never kill ingest — the UTXO set degrades to
@@ -1069,7 +1088,7 @@ class Node:
                 return
             if bn.height > expected:
                 if len(self._utxo_pending) < self.MAX_UTXO_PENDING:
-                    self._utxo_pending[bn.height] = block
+                    self._utxo_pending[bn.height] = (block, delta)
                     metrics.inc("utxo.deferred")
                 else:
                     metrics.inc("utxo.out_of_order")
@@ -1078,13 +1097,13 @@ class Node:
                         watermark=self.utxo.height,
                     )
                 return
-            await self._utxo_apply_one(bn.height, block)
+            await self._utxo_apply_one(bn.height, block, delta)
             # drain parked successors now contiguous with the watermark
             while True:
                 nxt = self._utxo_pending.pop(self.utxo.height + 1, None)
                 if nxt is None:
                     break
-                await self._utxo_apply_one(self.utxo.height + 1, nxt)
+                await self._utxo_apply_one(self.utxo.height + 1, *nxt)
         if self.ibd is not None:
             # the watermark may have moved: the planner retires finished
             # batches and schedules further ahead
@@ -1095,7 +1114,7 @@ class Node:
     # flight at once, this is belt-and-braces above it).
     MAX_UTXO_PENDING = 128
 
-    async def _utxo_apply_one(self, height: int, block) -> None:
+    async def _utxo_apply_one(self, height: int, block, delta=None) -> None:
         """One atomic connect (caller holds ``_utxo_lock`` and guarantees
         ``height`` is the first applicable one, ``max(watermark+1, 1)``);
         parse + write both off-loop.
@@ -1137,7 +1156,7 @@ class Node:
                     # above the rolled-back watermark: park — its
                     # predecessors on the new branch are being fetched
                     if len(self._utxo_pending) < self.MAX_UTXO_PENDING:
-                        self._utxo_pending[bn.height] = block
+                        self._utxo_pending[bn.height] = (block, delta)
                         metrics.inc("utxo.deferred")
                     return
                 height = bn.height
@@ -1161,7 +1180,7 @@ class Node:
                 )
                 return
         try:
-            await self._utxo_connect_off_loop(height, block)
+            await self._utxo_connect_off_loop(height, block, delta)
         except asyncio.CancelledError:
             raise
         except Exception as e:
@@ -1173,41 +1192,54 @@ class Node:
                 "[Node] utxo connect failed at height %d: %r", height, e
             )
 
-    async def _utxo_connect_off_loop(self, height: int, block) -> None:
-        """The physical connect, off-loop.  Native fast path (ISSUE 11):
-        the C++ extractor computes the whole spend/create delta + undo
-        rows in ONE pass over the wire bytes (``ParsedTxRegion.utxo_ops``
-        -> ``UtxoStore.apply_ops_blob``), so no Python per-tx parse ever
-        runs during block connect.  The Python ``apply_block`` path stays
-        the reference and the fallback (``TPUNODE_UTXO_NATIVE=0``, eager
-        blocks without raw bytes, no native toolchain); both produce
-        bit-identical stores (tests/test_utxo.py)."""
+    async def _utxo_connect_off_loop(
+        self, height: int, block, delta=None
+    ) -> None:
+        """The physical connect, in a worker thread under the
+        ``utxo.connect`` span (one a block, ``cpu=True``).  Native fast
+        path: the block's delta blob — made by the one parse its
+        verification ran (``delta``), else by a parse here (a block that
+        came another way: no engine, Python verify path) — goes through
+        ``UtxoStore.apply_ops_blob`` into the log and the index with no
+        Python object per operation (ISSUE 11, ISSUE 26).  The Python
+        ``apply_block`` path stays the reference and the fallback
+        (``TPUNODE_UTXO_NATIVE=0``, eager blocks without raw bytes, no
+        native toolchain); both produce bit-identical stores
+        (tests/test_utxo.py, tests/test_utxo_delta.py)."""
         assert self.utxo is not None
+        utxo = self.utxo
+        block_hash = block.header.hash
         raw = getattr(block, "raw_txs", None)
-        if (
-            raw is not None
-            and _native_extract_available()
-            and os.environ.get("TPUNODE_UTXO_NATIVE", "1") != "0"
-        ):
-            utxo = self.utxo
-            block_hash = block.header.hash
-            n_txs = block.tx_count
+        native = delta is not None or (
+            raw is not None and self._utxo_native()
+        )
 
-            def connect_native():
-                from .txextract import ParsedTxRegion
-
-                with ParsedTxRegion(raw, n_txs) as region:
-                    blob, created, spent = region.utxo_ops()
-                    return utxo.apply_ops_blob(
-                        height, block_hash, blob, created, spent
+        def connect():
+            with span("utxo.connect", cpu=True):
+                if not native:
+                    return utxo.apply_block(
+                        height, block_hash, list(block.txs)
                     )
+                ops = delta
+                if ops is None:
+                    from .txextract import ParsedTxRegion
 
-            await self._run_extract(connect_native)
+                    with ParsedTxRegion(raw, block.tx_count) as region:
+                        ops = region.utxo_ops()
+                return utxo.apply_ops_blob(height, block_hash, *ops)
+
+        if native:
+            await self._run_extract(connect)
         else:
-            txs = await asyncio.to_thread(lambda: list(block.txs))
-            await asyncio.to_thread(
-                self.utxo.apply_block, height, block.header.hash, txs
-            )
+            await asyncio.to_thread(connect)
+
+    @staticmethod
+    def _utxo_native() -> bool:
+        """Does block connect take the extractor's delta blob?"""
+        return (
+            _native_extract_available()
+            and os.environ.get("TPUNODE_UTXO_NATIVE", "1") != "0"
+        )
 
     async def _utxo_unwind_reorg(self, block) -> bool:
         """Disconnect tip blocks (per-block UNDO records, ISSUE 11) until
@@ -1862,6 +1894,7 @@ class Node:
                 )
 
         region: Optional[ParsedTxRegion] = None
+        delta = None  # the block's (ops blob, created, spent), if wanted
         submitted = False  # once the extract job is in a worker thread,
         # that thread owns region.close (see _extract_and_close)
         try:
@@ -1871,9 +1904,14 @@ class Node:
             with span("node.extract"):
                 try:
                     # shared worker pool (ISSUE 10): several blocks'
-                    # regions parse/extract in parallel
-                    region = await self._run_extract(
-                        ParsedTxRegion, raw, n_txs
+                    # regions parse/extract in parallel.  A block's UTXO
+                    # delta comes out of the same parse (ISSUE 26): it
+                    # travels to the connect once the verdicts are out,
+                    # and goes with this frame if they never are.
+                    region, delta = await self._run_extract(
+                        _parse_region, raw, n_txs,
+                        block is not None and self.utxo is not None
+                        and self._utxo_native(),
                     )
                 except asyncio.CancelledError:
                     raise
@@ -1961,7 +1999,7 @@ class Node:
                 # applied", so a crash mid-verify must leave the block
                 # unpersisted for its re-delivery to re-verify (extract/
                 # engine failure paths return before reaching here)
-                self._connect_block_utxo(block)
+                self._connect_block_utxo(block, delta)
         finally:
             if region is not None and not submitted:
                 region.close()

@@ -56,7 +56,10 @@ import struct
 import threading
 import time
 import zlib
-from typing import Iterator, Optional, Protocol, Sequence
+from itertools import pairwise, repeat
+from typing import Callable, Iterator, Optional, Protocol, Sequence
+
+import numpy as np
 
 from . import threadsan
 from .chaos import ChaosFault, chaos
@@ -71,6 +74,8 @@ __all__ = [
     "MemoryKV",
     "LogKV",
     "Namespaced",
+    "DeltaTail",
+    "write_delta",
     "StoreCorruption",
     "StoreVersionError",
     "open_store",
@@ -124,6 +129,114 @@ def _validate_ops(ops: Sequence[BatchOp]) -> None:
             raise ValueError(f"unknown batch op {op!r}")
 
 
+# ---------------------------------------------------------------------------
+# delta blobs: a batch that arrives already serialised (ISSUE 26)
+
+#: ``tail(put_keys, del_keys, del_olds, strip)`` -> the ops that close a
+#: delta's batch (UtxoStore's undo record, expiry and watermark).  Called
+#: once, before anything is written: the keys are the delta's own as the
+#: store holds them (``strip`` bytes of namespace in front), ``del_olds``
+#: the values the deletes will remove, read before the batch.  The ops it
+#: returns are in the caller's key space.
+DeltaTail = Callable[
+    [list[bytes], list[bytes], list[Optional[bytes]], int],
+    Sequence[BatchOp],
+]
+
+# v1 record (the native ABI's batch format, and the legacy log's): op,
+# klen, vlen, key, value
+_REC_V1 = struct.Struct("<BII")
+_OP_PUT = 1
+_OP_DEL = 2
+
+
+def _decode_delta(blob: bytes, ns: bytes = b"") -> tuple[list[BatchOp], int]:
+    """A delta blob (v1 records, every put before every delete, deletes
+    without a value) as ``(ops, n_puts)`` with ``ns`` in front of every
+    key — the plain reading, for stores that take no blob.  Refuses what
+    ``kv_frame_v2`` refuses (native/kvstore)."""
+    ops: list[BatchOp] = []
+    n_puts = 0
+    pos, n = 0, len(blob)
+    while pos < n:
+        if n - pos < _REC_V1.size:
+            raise ValueError("malformed delta blob: short record header")
+        op, klen, vlen = _REC_V1.unpack_from(blob, pos)
+        pos += _REC_V1.size
+        if klen + vlen > n - pos:
+            raise ValueError("malformed delta blob: length past the end")
+        key = ns + blob[pos : pos + klen]
+        pos += klen
+        if op == _OP_PUT and n_puts == len(ops):
+            ops.append(("put", key, blob[pos : pos + vlen]))
+            n_puts += 1
+        elif op == _OP_DEL and not vlen:
+            ops.append(("del", key, b""))
+        else:
+            raise ValueError(f"malformed delta blob: op {op} out of place")
+        pos += vlen
+    return ops, n_puts
+
+
+def write_delta(
+    kv: "KVStore", blob: bytes, tail: DeltaTail, ns: bytes = b""
+) -> None:
+    """Apply a delta blob and its ``tail`` to ``kv`` as ONE atomic batch:
+    through the store's own ``write_delta`` where it has one (LogKV frames
+    the blob natively), else decoded into a plain ``write_batch``."""
+    own = getattr(kv, "write_delta", None)
+    if own is not None:
+        own(blob, tail, ns)
+    else:
+        _write_delta_decoded(kv, blob, tail, ns)
+
+
+def _write_delta_decoded(
+    kv: "KVStore", blob: bytes, tail: DeltaTail, ns: bytes
+) -> None:
+    ops, n_puts = _decode_delta(blob, ns)
+    del_keys = [k for _, k, _ in ops[n_puts:]]
+    ops.extend(tail(
+        [k for _, k, _ in ops[:n_puts]], del_keys,
+        [kv.get(k) for k in del_keys], len(ns),
+    ))
+    kv.write_batch(ops)
+
+
+def _split(buf: np.ndarray, lens: np.ndarray) -> list[bytes]:
+    """``buf`` (uint8 array) cut into ``bytes`` of ``lens`` each.  Pieces
+    of one length — a UTXO delta's keys always, its values nearly — come
+    out of one numpy call; mixed lengths take a slice each."""
+    if not len(lens):
+        return []
+    size = int(lens[0])
+    if size and size * len(lens) == len(buf) and (lens == size).all():
+        return buf.view(f"V{size}").tolist()
+    raw = buf.tobytes()
+    ends = [0] + np.cumsum(lens, dtype=np.int64).tolist()
+    return [raw[a:b] for a, b in pairwise(ends)]
+
+
+_delta_framer_state: Optional[tuple] = None
+
+
+def _delta_framer():
+    """The kvstore library, for its framing call — or None where it does
+    not build (one attempt a process)."""
+    global _delta_framer_state
+    if _delta_framer_state is None:
+        try:
+            from .native import load_kvstore_lib
+
+            _delta_framer_state = (load_kvstore_lib(),)
+        except Exception:
+            log.info(
+                "[LogKV] native framing unavailable; deltas decode in python"
+            )
+            _delta_framer_state = (None,)
+    return _delta_framer_state[0]
+
+
 class MemoryKV:
     """Ephemeral dict-backed store."""
 
@@ -165,14 +278,11 @@ class MemoryKV:
 # ---------------------------------------------------------------------------
 # on-disk formats
 
-# v1 record (legacy, still written by native/kvstore): op, klen, vlen
-_REC_V1 = struct.Struct("<BII")
+# v1 record (legacy, still written by native/kvstore): _REC_V1, above
 # v2 record: crc32, seq, op, klen, vlen — crc covers everything after
 # itself (seq..value), so a flipped bit anywhere in the record is caught.
 _REC_V2 = struct.Struct("<IIBII")
 _REC_V2_BODY = struct.Struct("<IBII")  # seq, op, klen, vlen
-_OP_PUT = 1
-_OP_DEL = 2
 
 # v2 segment/snapshot file header: magic, version, kind, segment sequence.
 _MAGIC = b"TPK2"
@@ -690,40 +800,49 @@ class LogKV:
 
     def _append_physical(self, ops: Sequence[BatchOp]) -> None:
         """Append ``ops`` to the active segment (rotating first when full)
-        and make them as durable as ``self.fsync`` promises.  Raises
+        and make them as durable as ``self.fsync`` promises."""
+        with self._lock:
+            self._rotate_if_full()
+            self._write_records(
+                self._pack_records(ops, self._rec_seq), len(ops)
+            )
+
+    def _rotate_if_full(self) -> None:
+        if self._active_bytes >= self.segment_bytes:
+            self._new_segment(self._next_seg_seq())
+
+    def _write_records(self, blob: bytes, count: int) -> None:
+        """ONE write + flush + fsync of ``count`` packed records (caller
+        holds the lock and numbered them from ``_rec_seq``).  Raises
         without side effects on an injected ``error``; ``torn_write``/
         ``bit_flip``/``crash`` faults damage the disk exactly the way the
         recovery path must survive."""
-        with self._lock:
-            if self._active_bytes >= self.segment_bytes:
-                self._new_segment(self._next_seg_seq())
-            blob = self._pack_records(ops, self._rec_seq)
-            exit_after_write = False
-            if chaos.on:
-                spec = chaos.decide("store.append", self.path)
-                if spec is not None:
-                    if spec.action == "error":
-                        raise ChaosFault(
-                            f"chaos[{spec.describe()}] at {self.path}"
-                        )
-                    if spec.action == "crash":
-                        chaos.hard_exit()
-                    blob = chaos.mutate_blob(spec, blob)
-                    exit_after_write = spec.action == "torn_write"
-            try:
-                self._file.write(blob)
-                self._file.flush()
-                if self.fsync:
-                    os.fsync(self._file.fileno())
-            except ChaosFault:
-                raise
-            except BaseException as e:  # disk state now ambiguous
-                self._poison(e)
-                raise
-            if exit_after_write:
-                chaos.hard_exit()
-            self._rec_seq += len(ops)
-            self._active_bytes += len(blob)
+        exit_after_write = False
+        if chaos.on:
+            spec = chaos.decide("store.append", self.path)
+            if spec is not None:
+                if spec.action == "error":
+                    raise ChaosFault(
+                        f"chaos[{spec.describe()}] at {self.path}"
+                    )
+                if spec.action == "crash":
+                    chaos.hard_exit()
+                blob = chaos.mutate_blob(spec, blob)
+                exit_after_write = spec.action == "torn_write"
+        try:
+            self._file.write(blob)
+            self._file.flush()
+            if self.fsync:
+                os.fsync(self._file.fileno())
+        except ChaosFault:
+            raise
+        except BaseException as e:  # disk state now ambiguous
+            self._poison(e)
+            raise
+        if exit_after_write:
+            chaos.hard_exit()
+        self._rec_seq += count
+        self._active_bytes += len(blob)
 
     def _next_seg_seq(self) -> int:
         used = [s for s, _ in self._segments] + [self._active_seq]
@@ -815,6 +934,85 @@ class LogKV:
         if not metrics.disabled:
             metrics.observe("store.write_seconds", time.perf_counter() - t0)
             metrics.inc("store.writes", len(ops))
+
+    def write_delta(
+        self, blob: bytes, tail: DeltaTail, ns: bytes = b""
+    ) -> None:
+        """:meth:`write_batch` for a batch that arrives serialised: a v1
+        *delta* blob (:func:`_decode_delta`'s format — a block's UTXO
+        creates and spends from the extractor) with ``ns`` in front of
+        every key, closed by the few ops ``tail`` returns.  The same bytes
+        reach the log as ``write_batch`` of the decoded ops would write,
+        in one append, disk first and index second, behind the same chaos
+        points — without a Python object per operation on the way: one
+        native call (GIL-free, under the lock because the record numbers
+        are the segment's) frames the records, and what is left per key is
+        the slices the index keeps and its dict.  A malformed blob is
+        refused before a byte is written.
+
+        With the group-commit writer running this still appends directly:
+        the lock orders the two appends, and the writer's caveat about one
+        key written through both APIs holds here as there."""
+        lib = _delta_framer()
+        if lib is None:
+            _write_delta_decoded(self, blob, tail, ns)
+            return
+        self._check_failed()
+        if chaos.on:  # injected write failure (tpunode/chaos.py)
+            chaos.maybe_raise("store.write", self.path)
+        t0 = time.perf_counter()
+        counts = np.zeros(5, np.uint64)
+        args = (blob, len(blob), ns, len(ns))
+        if lib.kv_frame_v2(*args, 0, None, None, None, None, None,
+                           counts.ctypes.data):
+            raise ValueError("malformed delta blob")
+        n, n_puts, size, key_bytes, val_bytes = counts.tolist()
+        out = np.empty(size, np.uint8)
+        keys = np.empty(key_bytes, np.uint8)
+        vals = np.empty(val_bytes, np.uint8)
+        lens = np.empty(n + n_puts, np.int32)  # keys', then values'
+        with self._lock:
+            self._rotate_if_full()
+            if lib.kv_frame_v2(*args, self._rec_seq, out.ctypes.data,
+                               keys.ctypes.data, lens.ctypes.data,
+                               vals.ctypes.data, lens[n:].ctypes.data,
+                               counts.ctypes.data):
+                raise ValueError("malformed delta blob")
+            keys = _split(keys, lens[:n])
+            put_keys, del_keys = keys[:n_puts], keys[n_puts:]
+            put_vals = _split(vals, lens[n:])
+            data = self._data
+            tail_ops = tail(
+                put_keys, del_keys, list(map(data.get, del_keys)), len(ns)
+            )
+            _validate_ops(tail_ops)
+            self._write_records(
+                out.tobytes()
+                + self._pack_records(tail_ops, self._rec_seq + n),
+                n + len(tail_ops),
+            )
+            # the index, as _stage would leave it: a record a put or a
+            # delete supersedes moves from live to dead
+            head = _REC_V2.size
+            moved = 0
+            for k, v in zip(put_keys, put_vals):
+                old = data.get(k)
+                if old is not None:
+                    moved += head + len(k) + len(old)
+                data[k] = v
+            for k, old in zip(del_keys, map(data.pop, del_keys, repeat(None))):
+                if old is not None:
+                    moved += head + len(k) + len(old)
+            put_bytes = (
+                head * n_puts + int(lens[:n_puts].sum()) + val_bytes
+            )
+            self._live_bytes += put_bytes - moved
+            self._dead_bytes += size - put_bytes + moved
+            self._stage(tail_ops)
+        self._maybe_compact()
+        if not metrics.disabled:
+            metrics.observe("store.write_seconds", time.perf_counter() - t0)
+            metrics.inc("store.writes", n + len(tail_ops))
 
     def write_batch_async(
         self, ops: Sequence[BatchOp]
@@ -972,6 +1170,20 @@ class Namespaced:
 
     def write_batch(self, ops: Sequence[BatchOp]) -> None:
         self._inner.write_batch([(op, self._k(k), v) for op, k, v in ops])
+
+    def write_delta(
+        self, blob: bytes, tail: DeltaTail, ns: bytes = b""
+    ) -> None:
+        """The blob goes down as it is, this view's namespace with it;
+        the tail's ops come back in the caller's key space and get the
+        namespace here."""
+        def outer(put_keys, del_keys, del_olds, strip):
+            return [
+                (op, self._k(k), v)
+                for op, k, v in tail(put_keys, del_keys, del_olds, strip)
+            ]
+
+        write_delta(self._inner, blob, outer, self._ns + ns)
 
     def scan_prefix(self, prefix: bytes) -> Iterator[tuple[bytes, bytes]]:
         n = len(self._ns)

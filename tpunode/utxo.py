@@ -35,7 +35,11 @@ Block connect has two producers: :meth:`apply_block` parses wire ``Tx``
 objects in Python (the reference path), and :meth:`apply_ops_blob`
 consumes the C++ extractor's one-pass delta blob
 (``ParsedTxRegion.utxo_ops``) so the Python per-tx parse leaves block
-ingest entirely (node._apply_block_utxo, ISSUE 11).
+ingest entirely (node._apply_block_utxo, ISSUE 11).  The blob is never
+unpacked into per-operation tuples: ``store.write_delta`` hands it to the
+store, which frames it natively into the records it appends and gives
+back only the keys and pre-spend values the undo record is made of
+(ISSUE 26) — the same bytes in the same one append as the reference path.
 
 Schema (within the namespaced view): ``b"o" + txid + vout_le32`` ->
 ``amount_le64 + scriptPubKey``; ``b"!wm"`` -> ``height_le64 + block_hash``;
@@ -49,7 +53,7 @@ from typing import Iterable, Optional, Sequence
 
 from .events import events
 from .metrics import metrics
-from .store import BatchOp, KVStore, delete_op, put_op
+from .store import BatchOp, KVStore, delete_op, put_op, write_delta
 
 __all__ = ["UtxoStore", "UTXO_NAMESPACE", "UNDO_DEPTH_DEFAULT"]
 
@@ -67,12 +71,6 @@ _AMOUNT = struct.Struct("<q")
 _WM = struct.Struct("<q")
 _U32 = struct.Struct("<I")
 _ZERO_TXID = b"\x00" * 32
-
-# ops-blob record header (shared with native/txextract txx_utxo_ops_h and
-# the native kvstore's v1 batch ABI): op(u8) klen(u32le) vlen(u32le)
-_REC = struct.Struct("<BII")
-_OP_PUT = 1
-_OP_DEL = 2
 
 
 def _okey(txid: bytes, vout: int) -> bytes:
@@ -199,44 +197,34 @@ class UtxoStore:
     ) -> bool:
         """Connect a block from the C++ extractor's one-pass delta blob
         (``ParsedTxRegion.utxo_ops`` — creates then spends in v1 record
-        format, ISSUE 11): the hot-path twin of :meth:`apply_block` with
-        zero Python per-tx work.  Bit-identical final state (pinned by
-        tests/test_utxo.py)."""
+        format, ISSUE 11): the hot-path twin of :meth:`apply_block`.  The
+        blob goes to the store as it is (``store.write_delta``: LogKV
+        frames it natively into the records it appends) and comes back
+        only as the keys the undo record needs — no Python object per
+        operation (ISSUE 26).  Bit-identical log, index and undo record
+        (pinned by tests/test_utxo.py, tests/test_utxo_delta.py)."""
         if height <= self._height:
             metrics.inc("utxo.skipped")
             return False
-        ops: list[BatchOp] = []
-        created_keys: list[bytes] = []
-        spent_pairs: list[tuple[bytes, bytes]] = []
-        want_undo = self.undo_depth > 0  # pre-spend reads are undo-only
-        pos = 0
-        n = len(blob)
-        while pos < n:
-            op, klen, vlen = _REC.unpack_from(blob, pos)
-            pos += _REC.size
-            key = blob[pos : pos + klen]
-            pos += klen
-            if op == _OP_PUT:
-                ops.append(("put", key, blob[pos : pos + vlen]))
-                pos += vlen
-                created_keys.append(key)
-            elif op == _OP_DEL:
-                if want_undo:
-                    old = self._kv.get(key)
-                    if old is not None:
-                        spent_pairs.append((key, old))
-                ops.append(("del", key, b""))
-            else:
-                raise ValueError(f"bad op {op} in utxo ops blob")
-        applied = self._commit(
-            height, block_hash, ops, spent_pairs, created_keys,
-            created, spent,
-        )
-        if applied:
-            events.emit(
-                "utxo.block", height=height, created=created, spent=spent,
+
+        def tail(put_keys, del_keys, del_olds, strip):
+            spent_pairs, created_keys = [], []
+            if self.undo_depth > 0:  # both are the undo record's only
+                spent_pairs = [
+                    (k[strip:], old) for k, old in zip(del_keys, del_olds)
+                    if old is not None
+                ]
+                created_keys = [k[strip:] for k in put_keys]
+            return self._tail_ops(
+                height, block_hash, spent_pairs, created_keys
             )
-        return applied
+
+        write_delta(self._kv, blob, tail)
+        self._advance(height, block_hash, created, spent)
+        events.emit(
+            "utxo.block", height=height, created=created, spent=spent,
+        )
+        return True
 
     def _commit(
         self,
@@ -249,6 +237,24 @@ class UtxoStore:
         spent: int,
     ) -> bool:
         """One atomic connect: delta + undo record + watermark."""
+        ops.extend(
+            self._tail_ops(height, block_hash, spent_pairs, created_keys)
+        )
+        self._kv.write_batch(ops)
+        self._advance(height, block_hash, created, spent)
+        return True
+
+    def _tail_ops(
+        self,
+        height: int,
+        block_hash: bytes,
+        spent_pairs: list[tuple[bytes, bytes]],
+        created_keys: list[bytes],
+    ) -> list[BatchOp]:
+        """What closes a connect's batch after its delta: the undo record,
+        the expiry of the one that leaves the retained depth, the
+        watermark."""
+        ops: list[BatchOp] = []
         if self.undo_depth > 0:
             ops.append(put_op(
                 _ukey(height),
@@ -261,13 +267,16 @@ class UtxoStore:
             if expired >= 0:
                 ops.append(delete_op(_ukey(expired)))
         ops.append(put_op(_WM_KEY, _WM.pack(height) + block_hash))
-        self._kv.write_batch(ops)
+        return ops
+
+    def _advance(
+        self, height: int, block_hash: bytes, created: int, spent: int
+    ) -> None:
         self._height, self._block_hash = height, block_hash
         metrics.set_gauge("utxo.height", float(height))
         metrics.inc("utxo.applied")
         metrics.inc("utxo.created", created)
         metrics.inc("utxo.spent", spent)
-        return True
 
     # -- per-block UNDO (ISSUE 11) -------------------------------------------
 
