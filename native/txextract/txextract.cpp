@@ -1630,10 +1630,13 @@ long txx_extract_h(void *hp, int flags, const int64_t *ext_amounts,
 // built, else — one-shot back-compat — a local map over the whole region.
 // Range callers MUST build the shared map first: ranges are extracted on
 // concurrent worker threads and only the pre-built map is read-only.
+//
+// With `subset` (ISSUE 27) the txs are subset[tx_lo..tx_hi) instead of the
+// contiguous range, and every "range-relative" above reads subset-relative.
 static long extract_body(TxxHandle *h, int flags, const int64_t *ext_amounts,
                          long n_ext, const uint8_t *ext_scripts,
                          const int64_t *ext_script_off, long tx_lo, long tx_hi,
-                         long capacity, uint8_t *z,
+                         const int32_t *subset, long capacity, uint8_t *z,
                          uint8_t *px, uint8_t *py, uint8_t *r, uint8_t *s,
                          uint8_t *present, int32_t *item_tx, int32_t *item_input,
                          int32_t *item_sig, int32_t *item_key, int32_t *item_nsigs,
@@ -1642,7 +1645,14 @@ static long extract_body(TxxHandle *h, int flags, const int64_t *ext_amounts,
                          int32_t *tx_items, int32_t *tx_sigs, int32_t *tx_coinbase,
                          int32_t *tx_unsupported) {
   std::vector<TxSpan> &txs = h->txs;
-  if (tx_lo < 0 || tx_hi > long(txs.size()) || tx_lo > tx_hi) return -1;
+  if (subset != nullptr) {
+    // [tx_lo, tx_hi) are then positions in `subset`, each a tx index
+    if (tx_lo < 0 || tx_lo > tx_hi) return -1;
+    for (long k = tx_lo; k < tx_hi; ++k)
+      if (subset[k] < 0 || subset[k] >= long(txs.size())) return -1;
+  } else if (tx_lo < 0 || tx_hi > long(txs.size()) || tx_lo > tx_hi) {
+    return -1;
+  }
   bool bch = (flags & 1) != 0;
   bool intra = (flags & 2) != 0;
   PrevoutMap local_map;
@@ -1698,8 +1708,9 @@ static long extract_body(TxxHandle *h, int flags, const int64_t *ext_amounts,
                           // object, so no cross-lane key collisions exist
   long item = 0;
   long flat_input = 0;  // RANGE-RELATIVE index into ext_amounts/ext_script_off
-  for (size_t ti = size_t(tx_lo); ti < size_t(tx_hi); ++ti) {
-    size_t oti = ti - size_t(tx_lo);  // range-relative output row
+  for (long pos = tx_lo; pos < tx_hi; ++pos) {
+    size_t ti = subset != nullptr ? size_t(subset[pos]) : size_t(pos);
+    size_t oti = size_t(pos - tx_lo);  // range-relative output row
     TxSpan &tx = txs[ti];
     memcpy(txids + oti * 32, tx.txid, 32);
     int32_t n_inputs = 0, extracted = 0, coinbase = 0, unsupported = 0;
@@ -2094,7 +2105,8 @@ long txx_extract_h2(void *hp, int flags, const int64_t *ext_amounts,
                     int32_t *tx_unsupported) {
   TxxHandle *h = static_cast<TxxHandle *>(hp);
   return extract_body(h, flags, ext_amounts, n_ext, ext_scripts,
-                      ext_script_off, 0, long(h->txs.size()), capacity, z, px,
+                      ext_script_off, 0, long(h->txs.size()), nullptr, capacity,
+                      z, px,
                       py, r, s, present, item_tx, item_input, item_sig,
                       item_key, item_nsigs, item_nkeys, txids, tx_n_inputs,
                       tx_extracted, tx_items, tx_sigs, tx_coinbase,
@@ -2152,10 +2164,37 @@ long txx_extract_range_h(void *hp, int flags, const int64_t *ext_amounts,
                          int32_t *tx_sigs, int32_t *tx_coinbase,
                          int32_t *tx_unsupported) {
   return extract_body(static_cast<TxxHandle *>(hp), flags, ext_amounts, n_ext,
-                      ext_scripts, ext_script_off, tx_lo, tx_hi, capacity, z,
-                      px, py, r, s, present, item_tx, item_input, item_sig,
-                      item_key, item_nsigs, item_nkeys, txids, tx_n_inputs,
-                      tx_extracted, tx_items, tx_sigs, tx_coinbase,
+                      ext_scripts, ext_script_off, tx_lo, tx_hi, nullptr,
+                      capacity, z, px, py, r, s, present, item_tx, item_input,
+                      item_sig, item_key, item_nsigs, item_nkeys, txids,
+                      tx_n_inputs, tx_extracted, tx_items, tx_sigs, tx_coinbase,
+                      tx_unsupported);
+}
+
+// Subset extraction (ISSUE 27): the txs `subset[0..n_subset)` (indices into
+// the region, any order, each at most once) through the same body, so a
+// block whose other txs are answered elsewhere extracts only these — still
+// against the block's ONE intra-block prevout map.  Oracle rows and output
+// rows are SUBSET-relative: row 0 is the first input of subset[0], output
+// tx row k is subset[k].  Thread-safety as for ranges.
+long txx_extract_subset_h(void *hp, int flags, const int64_t *ext_amounts,
+                          long n_ext, const uint8_t *ext_scripts,
+                          const int64_t *ext_script_off, const int32_t *subset,
+                          long n_subset, long capacity, uint8_t *z,
+                          uint8_t *px, uint8_t *py, uint8_t *r, uint8_t *s,
+                          uint8_t *present, int32_t *item_tx,
+                          int32_t *item_input, int32_t *item_sig,
+                          int32_t *item_key, int32_t *item_nsigs,
+                          int32_t *item_nkeys, uint8_t *txids,
+                          int32_t *tx_n_inputs, int32_t *tx_extracted,
+                          int32_t *tx_items, int32_t *tx_sigs,
+                          int32_t *tx_coinbase, int32_t *tx_unsupported) {
+  if (subset == nullptr) return -1;
+  return extract_body(static_cast<TxxHandle *>(hp), flags, ext_amounts, n_ext,
+                      ext_scripts, ext_script_off, 0, n_subset, subset,
+                      capacity, z, px, py, r, s, present, item_tx, item_input,
+                      item_sig, item_key, item_nsigs, item_nkeys, txids,
+                      tx_n_inputs, tx_extracted, tx_items, tx_sigs, tx_coinbase,
                       tx_unsupported);
 }
 
@@ -2251,6 +2290,23 @@ long txx_txids_h(void *hp, uint8_t *out) {
   TxxHandle *h = static_cast<TxxHandle *>(hp);
   for (size_t ti = 0; ti < h->txs.size(); ++ti)
     memcpy(out + ti * 32, h->txs[ti].txid, 32);
+  return long(h->txs.size());
+}
+
+// Every parsed tx's double-SHA over its FULL wire bytes as they stand in the
+// region, row-major (n_txs x 32): the wtxid of a witness serialization, the
+// txid (copied, not rehashed) of any other.  The key under which a relay
+// verdict may answer for a block transaction (ISSUE 27): the same txid under
+// another witness is another key.
+long txx_wire_hashes_h(void *hp, uint8_t *out) {
+  TxxHandle *h = static_cast<TxxHandle *>(hp);
+  for (size_t ti = 0; ti < h->txs.size(); ++ti) {
+    const TxSpan &tx = h->txs[ti];
+    if (tx.inout_start == tx.version + 4)
+      memcpy(out + ti * 32, tx.txid, 32);
+    else  // marker + flag after the version: hash what was sent
+      dsha256(tx.version, size_t(tx.locktime + 4 - tx.version), out + ti * 32);
+  }
   return long(h->txs.size());
 }
 

@@ -449,6 +449,115 @@ def test_extract_range_validates_bounds():
         assert empty.count == 0 and empty.n_txs == 0
 
 
+# ---------------------------------------------------------------------------
+# subset extraction (ISSUE 27): the txs of a block that no relay verdict
+# answered, scattered through it, are extracted bit-identically to
+# extract_range over the same txs, against the block's one intra map
+
+_SHARD_ROWS = (
+    "z", "px", "py", "r", "s", "present", "item_input", "item_sig",
+    "item_key", "item_nsigs", "item_nkeys", "txids", "tx_n_inputs",
+    "tx_extracted", "tx_items", "tx_sigs", "tx_coinbase", "tx_unsupported",
+)
+
+
+@pytest.mark.parametrize("subset", [
+    (3,), (0, 39), (1, 2, 3), (5, 11, 17, 23, 38), tuple(range(0, 40, 2)),
+    tuple(range(40)), (39, 7, 20),
+], ids=["one", "ends", "run", "scattered", "every-other", "all", "unordered"])
+def test_extract_subset_matches_extract_range(subset):
+    """Each tx of the subset, row for row, equals the one-tx
+    ``extract_range`` of the same tx (oracle rows and result rows are the
+    subset's, in its order)."""
+    import numpy as np
+
+    from benchmarks.txgen import gen_mixed_txs, synth_prevout
+    from tpunode.txextract import ParsedTxRegion
+
+    txs = gen_mixed_txs(40, seed=0x5A5A)
+    with ParsedTxRegion(_serialize_all(txs), len(txs)) as region:
+        pv_txids, pv_vouts, pv_wants = region.scan_prevouts(False)
+        ext = [-1] * len(pv_wants)
+        scr = [None] * len(pv_wants)
+        for i in pv_wants.nonzero()[0]:
+            res = synth_prevout(pv_txids[i].tobytes(), int(pv_vouts[i]))
+            if res is not None:
+                ext[int(i)], scr[int(i)] = res
+        region.build_intra()
+        off = region.input_offsets()
+        rows = [j for t in subset for j in range(int(off[t]), int(off[t + 1]))]
+        got = region.extract_subset(
+            subset, intra_amounts=True,
+            ext_amounts=[ext[j] for j in rows],
+            ext_scripts=[scr[j] for j in rows],
+        )
+        singles = [
+            region.extract_range(
+                t, t + 1, intra_amounts=True,
+                ext_amounts=ext[int(off[t]):int(off[t + 1])],
+                ext_scripts=scr[int(off[t]):int(off[t + 1])],
+            )
+            for t in subset
+        ]
+        want = _merge_shards(singles)
+        assert got.n_txs == len(subset) and got.count == want.count
+        for name in _SHARD_ROWS:
+            assert np.array_equal(getattr(got, name), getattr(want, name)), name
+        # item_tx is subset-relative: position k stands for subset[k]
+        assert np.array_equal(got.item_tx, np.concatenate(
+            [s.item_tx + k for k, s in enumerate(singles)]))
+        assert [got.txid(k) for k in range(len(subset))] == [
+            txs[t].txid for t in subset]
+
+
+def test_extract_subset_resolves_in_block_spends_of_left_out_txs():
+    """A subset tx spending a tx that is NOT in the subset still finds its
+    amount: the intra map is the whole block's."""
+    from benchmarks.txgen import gen_signed_txs
+    from tpunode.txextract import ParsedTxRegion
+
+    txs = gen_signed_txs(8, inputs_per_tx=1, seed=0x17, segwit_every=2)
+    with ParsedTxRegion(_serialize_all(txs), len(txs)) as region:
+        serial = region.extract(intra_amounts=True)
+    for build in (True, False):  # the shared map, or the one-shot local one
+        with ParsedTxRegion(_serialize_all(txs), len(txs)) as region:
+            if build:
+                region.build_intra()
+            sub = region.extract_subset([1, 5], intra_amounts=True)
+        assert [int(x) for x in sub.tx_extracted] == [1, 1]
+        assert [int(x) for x in sub.tx_unsupported] == [
+            int(serial.tx_unsupported[1]), int(serial.tx_unsupported[5])] == [0, 0]
+
+
+def test_extract_subset_validates_and_takes_an_empty_subset():
+    from benchmarks.txgen import gen_signed_txs
+    from tpunode.txextract import ParsedTxRegion
+
+    txs = gen_signed_txs(3, inputs_per_tx=1, seed=0x18)
+    with ParsedTxRegion(_serialize_all(txs), 3) as region:
+        for bad in ([3], [-1], [[0, 1]]):
+            with pytest.raises(ValueError):
+                region.extract_subset(bad)
+        empty = region.extract_subset([])
+        assert empty.count == 0 and empty.n_txs == 0
+
+
+def test_wire_hashes_are_wtxids_for_witness_txs_and_txids_otherwise():
+    from benchmarks.txgen import gen_signed_txs
+    from tpunode.txextract import ParsedTxRegion
+    from tpunode.util import double_sha256
+
+    txs = gen_signed_txs(6, inputs_per_tx=1, seed=0x27, segwit_every=2)
+    assert any(t.has_witness for t in txs) and not all(t.has_witness for t in txs)
+    with ParsedTxRegion(_serialize_all(txs), len(txs)) as region:
+        wire = region.wire_hashes()
+        txids = region.txids()
+    for i, t in enumerate(txs):
+        assert wire[i].tobytes() == double_sha256(t.serialize())
+        assert txids[i].tobytes() == t.txid
+        assert (wire[i].tobytes() == t.txid) == (not t.has_witness)
+
+
 def test_utxo_ops_blob_layout():
     """The one-pass UTXO delta blob: creates (key -> amount+script) before
     spends, coinbase inputs skipped, v1 record framing."""

@@ -124,7 +124,13 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
     names = [m["name"] for m in BENCH["per_layer"]]
     first = names.index(WAITS[0])
     assert names[first:first + len(WAITS)] == list(WAITS)
-    assert names[first + len(WAITS):] == list(CONNECT)
+    after = names[first + len(WAITS):]
+    assert after[:len(CONNECT)] == list(CONNECT)
+    # PR 27's, appended in their turn
+    assert after[len(CONNECT):] == [
+        "reuse.hit_share", "reuse.ms_per_block", "reuse.cpu_ms_per_block",
+        "tip.relay_verdict_p50_ms", "tip.block_verdict_p50_ms",
+        "open.late_p99_ms", "open.verdict_p99_ms"]
 
 
 def test_the_two_connect_metrics_read_the_utxo_connect_span():
@@ -135,7 +141,9 @@ def test_the_two_connect_metrics_read_the_utxo_connect_span():
     by_name = {m["name"]: m for m in BENCH["per_layer"]}
     for name in CONNECT:
         entry = by_name[name]
-        assert entry["workloads"] == ["bch-node.ibd", "bch-32mb.blocks"]
+        # the cells that connect blocks: PR 27 appended the tip cell
+        assert entry["workloads"] == ["bch-node.ibd", "bch-32mb.blocks",
+                                      "bch-tip.tip"]
         assert entry["layer"] == "UTXO connect / store"
         assert entry["moves"] == "host_cpu_ms_per_ksig"
         assert (entry["unit"], entry["better"]) == ("ms/block", "lower")
@@ -188,3 +196,39 @@ def test_wait_metrics_read_through_the_harness(traced):
                  "engine.hop_ms_per_lane", "device.idle_share.starved"):
         assert name not in old
 
+
+
+@pytest.mark.parametrize("cell,samples,want", [
+    ("bch-tip.tip", {"verdict_ms": [float(i) for i in range(2001)],
+                     "block_ms": [100.0, 140.0, 180.0],
+                     "late_ms": [float(i % 100) for i in range(2000)]},
+     {"reuse.hit_share": 95.0, "reuse.ms_per_block": 17.0,
+      "reuse.cpu_ms_per_block": 15.0, "tip.relay_verdict_p50_ms": 1000.0,
+      "tip.block_verdict_p50_ms": 140.0, "open.verdict_p99_ms": 1980.0}),
+    ("bch-node.relay-open", {"verdict_ms": [float(i) for i in range(2001)],
+                             "late_ms": [1.0] * 1500},
+     {"open.verdict_p99_ms": 1980.0, "open.late_p99_ms": 1.0}),
+    # a program without the reuse (the parent commit), or a node that
+    # looked nothing up: the three reuse metrics are left out, not 0
+    ("bch-tip.tip", {}, {}),
+])
+def test_the_reuse_and_open_loop_metrics_read_through_the_harness(
+        cell, samples, want):
+    """ISSUE 27: ``node.reuse`` per looked-up block, hits over look-ups,
+    and the open driver's samples, each in the cells that list it."""
+    window = dict(COUNTERS)
+    if samples:
+        window.update({
+            "node.reuse_blocks": 20.0, "node.reuse_lookups": 62000.0,
+            "node.reuse_hits": 58900.0, "span.node.reuse.seconds": 0.34,
+            "span.node.reuse.cpu_seconds": 0.30, "span.node.reuse.count": 20.0})
+    ctx = harness.Ctx(workload={"name": cell}, bench=BENCH, config={},
+                      traffic={}, seed=0, seconds=4.0, trace=False,
+                      rehearsal=None, t_start=0.0)
+    got = harness.read_per_layer(
+        ctx, harness.Reading(window, 4.0, None, samples, {}))
+    new = {k: v["value"] for k, v in got.items()
+           if k.startswith(("reuse.", "tip.", "open."))}
+    assert set(new) == set(want) | ({"open.late_p99_ms"} if samples else set())
+    for name, value in want.items():
+        assert new[name] == pytest.approx(value), name

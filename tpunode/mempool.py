@@ -33,6 +33,14 @@ txs are stolen straight from the roofline (PERF.md).  This actor owns:
 * **Confirmation eviction** — block connect (txids from the block
   ingest path, C++-computed on the native path) flips entries to
   CONFIRMED, drops their payloads, and re-checks waiting orphans.
+* **Relay verdicts for the block path** (ISSUE 27) — a finished entry
+  keeps the ``ExtractStats`` of its relay-time extraction beside its
+  per-signature verdicts, and :meth:`Mempool.relay_verdicts` reads them
+  synchronously on the loop, keyed by the double-SHA of a block
+  transaction's full wire bytes: the analogue of Bitcoin Core's
+  signature cache, which ``ConnectBlock`` reads and never writes.  Only
+  ``VALID`` / ``INVALID`` entries whose extraction left no input out
+  answer; everything else is verified afresh by the block path.
 * **Backpressure** — fetch scheduling defers while the node's ingest
   accumulator is saturated (``VerifyShed``/``MAX_TX_ACCUM`` machinery in
   node.py), so a flooding peer degrades into a stale want-list instead
@@ -111,7 +119,7 @@ class _Entry:
     """One seen txid: state + (while useful) the tx and its outputs."""
 
     __slots__ = ("txid", "wtxid", "state", "tx", "outputs", "origin",
-                 "missing", "added", "verdicts")
+                 "missing", "added", "verdicts", "stats")
 
     def __init__(self, txid: bytes, wtxid: bytes, state: str, tx=None,
                  outputs=None, origin: str = "?"):
@@ -126,6 +134,10 @@ class _Entry:
         self.missing: Optional[set[bytes]] = None  # ORPHAN: parent txids
         self.added = time.monotonic()
         self.verdicts: tuple[bool, ...] = ()
+        # ExtractStats of the relay-time extraction, while the entry is
+        # VALID or INVALID by a relay verdict: what makes it readable by
+        # the block path (relay_verdicts)
+        self.stats = None
 
 
 class _Want:
@@ -162,6 +174,7 @@ class _Verdict:
     valid: bool
     verdicts: tuple
     error: Optional[str]
+    stats: object = None  # the TxVerdict's ExtractStats
 
 
 @dataclass(frozen=True)
@@ -257,6 +270,7 @@ class Mempool:
         self._inflight: dict[Peer, int] = {}
         self._sched_queued = False  # a _Sched marker is in the mailbox
         self._size = 0  # PENDING + VALID entries
+        self._finished = 0  # entries with a relay verdict's stats
         self._announcers: dict[str, int] = {}  # label -> announcements
         self._misbehavior: dict[str, int] = {}  # label -> incidents
         # stats() counters: instance-owned (the metrics registry is
@@ -322,9 +336,13 @@ class Mempool:
             self.mailbox.send(_Invs(peer, tuple(txids)))
 
     def verdict(self, txid: bytes, valid: bool, verdicts: tuple = (),
-                error: Optional[str] = None) -> None:
-        """The verify pipeline published a TxVerdict for ``txid``."""
-        self.mailbox.send(_Verdict(txid, valid, tuple(verdicts), error))
+                error: Optional[str] = None, stats=None) -> None:
+        """The RELAY path published a TxVerdict for ``txid`` (the block
+        path does not report here: it reads this store, never writes
+        it).  ``stats``: the verdict's ``ExtractStats``."""
+        self.mailbox.send(
+            _Verdict(txid, valid, tuple(verdicts), error, stats)
+        )
 
     def confirmed(self, txids: "list[bytes]") -> None:
         """Block connect: these txids are now in a block."""
@@ -378,6 +396,40 @@ class Mempool:
         if e is not None and e.outputs is not None and 0 <= vout < len(e.outputs):
             return e.outputs[vout]
         return None
+
+    def finished(self) -> int:
+        """Entries holding a relay verdict with its extraction stats:
+        with none, the block path does not look anything up."""
+        return self._finished
+
+    def relay_verdicts(self, keys: "list[bytes]") -> tuple:
+        """The block path's synchronous read (ISSUE 27).  ``keys``: per
+        block transaction, the double-SHA of its full wire bytes as they
+        stand in the block (wtxid of a witness serialization, else txid).
+        -> ``(hits, pending, unfit)``: ``hits`` maps a position in
+        ``keys`` to ``(valid, verdicts, stats)`` of an entry that is
+        VALID or INVALID by a relay verdict, was relayed under exactly
+        these bytes, and whose relay-time extraction left no input out
+        (``stats.unsupported == 0``: an input that lacked its prevout on
+        relay may have it in the block).  ``pending`` counts keys seen
+        with no verdict yet (PENDING, ORPHAN), ``unfit`` keys seen and
+        not reusable (degraded, confirmed, the txid under another
+        witness).  Reads only: no LRU touch, no state change."""
+        hits: dict = {}
+        pending = unfit = 0
+        lookup = self._seen.lookup
+        for i, key in enumerate(keys):
+            e = lookup(key)
+            if e is None:
+                continue
+            if e.state in (TxState.PENDING, TxState.ORPHAN):
+                pending += 1
+            elif (e.stats is None or e.stats.unsupported
+                  or e.wtxid != key):
+                unfit += 1
+            else:
+                hits[i] = (e.state == TxState.VALID, e.verdicts, e.stats)
+        return hits, pending, unfit
 
     def stats(self) -> dict:
         """Snapshot for Node.stats() / the debug server."""
@@ -529,6 +581,13 @@ class Mempool:
             metrics.set_gauge("mempool.size", self._size)
         if e.state == TxState.ORPHAN:
             self._unpark(txid, e)
+        self._drop_stats(e)
+
+    def _drop_stats(self, e: _Entry) -> None:
+        """The entry stops answering for the block path."""
+        if e.stats is not None:
+            e.stats = None
+            self._finished -= 1
 
     # -- orphan pool --------------------------------------------------------
 
@@ -637,6 +696,9 @@ class Mempool:
             self._forget(v.txid, e)
             return
         e.verdicts = v.verdicts
+        if v.stats is not None:
+            e.stats = v.stats
+            self._finished += 1
         if v.valid:
             e.state = TxState.VALID
             metrics.inc("mempool.accepted")
@@ -676,6 +738,7 @@ class Mempool:
                 e.tx = None
                 e.outputs = None
                 e.missing = None
+                self._drop_stats(e)
                 flipped += 1
             self._drop_want(txid)
         metrics.set_gauge("mempool.size", self._size)

@@ -24,6 +24,8 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
+import numpy as np
+
 from . import asyncsan, threadsan
 from .actors import (
     LinkedTasks,
@@ -105,21 +107,32 @@ log = logging.getLogger("tpunode.node")
 _native_extract_state: Optional[bool] = None
 
 
-def _parse_region(raw: bytes, n_txs: int, want_delta: bool):
+def _parse_region(raw: bytes, n_txs: int, want_delta: bool,
+                  want_keys: bool = False):
     """Worker-thread job: the ONE native parse of a message's tx region,
     and with it a block's UTXO delta (``utxo_ops``: 2-3 ms per 8,000 txs
     while the region is open, against a second parse of the block at
-    connect time).  -> (region, delta or None)."""
+    connect time) and, where relay verdicts may answer for its txs
+    (ISSUE 27), each tx's hash over its full wire bytes.
+    -> (region, delta or None, wire hashes or None)."""
     from .txextract import ParsedTxRegion
 
     region = ParsedTxRegion(raw, n_txs)
-    if not want_delta:
-        return region, None
     try:
-        return region, region.utxo_ops()
+        return (
+            region,
+            region.utxo_ops() if want_delta else None,
+            region.wire_hashes() if want_keys else None,
+        )
     except BaseException:
         region.close()
         raise
+
+
+def _hash_rows(rows) -> "list[bytes]":
+    """An ``(n, 32)`` uint8 array of hashes as a list of ``bytes``."""
+    blob = rows.tobytes()
+    return [blob[i : i + 32] for i in range(0, len(blob), 32)]
 
 
 def _native_extract_available() -> bool:
@@ -895,12 +908,17 @@ class Node:
         metrics.inc("node.verify_errors")
         events.emit("verify.failure", where=where, error=str(error)[:300])
 
-    def _publish_verdict(self, v: TxVerdict) -> None:
+    def _publish_verdict(self, v: TxVerdict, relay: bool = True) -> None:
         """Every TxVerdict flows through here: the mempool's verdict
-        cache learns it (dedup: re-relays of this tx now cost zero
-        verify work) before the user bus does."""
-        if self.mempool is not None:
-            self.mempool.verdict(v.txid, v.valid, v.verdicts, v.error)
+        cache learns a RELAY verdict (dedup: re-relays of this tx now cost
+        zero verify work, and its block is answered from it) before the
+        user bus does.  A block's verdicts (``relay=False``) go to the bus
+        alone: the block path reads that cache and never writes it, as
+        Bitcoin Core's ConnectBlock reads its signature cache."""
+        if relay and self.mempool is not None:
+            self.mempool.verdict(
+                v.txid, v.valid, v.verdicts, v.error, v.stats
+            )
         self.cfg.pub.publish(v)
 
     def _mempool_submit(self, peer, tx) -> None:
@@ -1425,17 +1443,27 @@ class Node:
             self.cfg.pub.publish(VerifyShed(peer, n, pending))
 
     def _resolve_ext_rows(
-        self, region, bch: bool
+        self, region, bch: bool, subset=None
     ) -> "tuple[Optional[list[int]], Optional[list[Optional[bytes]]]]":
         """External-oracle rows for a parsed region: per-input amounts and
         scriptPubKeys from the prevout oracle (mempool outputs first,
         then ``cfg.prevout_lookup``), aligned with the region's flat
         input order (only rows the tx-level wants gate marks are looked
-        up).  Shared by block and mempool ingest."""
+        up).  Shared by block and mempool ingest.  ``subset`` (ascending
+        tx indices): the rows of those txs alone, in that order — what
+        ``extract_subset`` takes."""
         lookup = self._prevout_oracle()
         if lookup is None:
             return None, None
         pv_txids, pv_vouts, pv_wants = region.scan_prevouts(bch)
+        if subset is not None:
+            n_in, _ = region.tx_layout()
+            keep = np.zeros(len(n_in), bool)
+            keep[subset] = True
+            rows = np.flatnonzero(np.repeat(keep, n_in))
+            pv_txids, pv_vouts, pv_wants = (
+                pv_txids[rows], pv_vouts[rows], pv_wants[rows]
+            )
         ext: list[int] = [-1] * len(pv_wants)
         ext_scripts: list[Optional[bytes]] = [None] * len(pv_wants)
         for i in pv_wants.nonzero()[0]:
@@ -1562,14 +1590,17 @@ class Node:
             tr.end(rec)
 
     @staticmethod
-    def _extract_and_close(region, **kw):
+    def _extract_and_close(region, subset=None, **kw):
         """Worker-thread tail of a shard extract: the thread that runs
-        the native extract also frees the handle.  Closing from the loop
+        the native extract (of the whole region, or of ``subset``) also
+        frees the handle.  Closing from the loop
         side would race a cancelled-but-still-running extract (awaiting
         an executor future stops WAITING on cancellation, it does not
         stop the thread) — txx_parse_free under a live txx_extract_h2 is
         a native use-after-free (review finding)."""
         try:
+            if subset is not None:
+                return region.extract_subset(subset, **kw)
             return region.extract(**kw)
         finally:
             region.close()
@@ -1880,8 +1911,12 @@ class Node:
             self._verify_failure("extract", e)
             txids: list[bytes] = []
             try:
-                src = txs if txs is not None else block.txs
-                txids = [tx.txid for tx in src]
+                if subset is not None:
+                    # relay verdicts answered the block's other txs
+                    txids = [block_txids[i] for i in subset]
+                else:
+                    src = txs if txs is not None else block.txs
+                    txids = [tx.txid for tx in src]
             except Exception:
                 # tx region unparseable (lazy tx/block): one aggregate
                 # verdict, and the peer dies as under eager decode
@@ -1890,9 +1925,12 @@ class Node:
             for txid in txids:
                 self._publish_verdict(
                     TxVerdict(peer, txid, False, (), ExtractStats(),
-                              error=f"extract: {e}")
+                              error=f"extract: {e}"),
+                    relay=block is None,
                 )
 
+        block_txids: Optional[list[bytes]] = None
+        subset = None  # a block's tx indices still to verify; None = all
         region: Optional[ParsedTxRegion] = None
         delta = None  # the block's (ops blob, created, spent), if wanted
         submitted = False  # once the extract job is in a worker thread,
@@ -1908,44 +1946,63 @@ class Node:
                     # delta comes out of the same parse (ISSUE 26): it
                     # travels to the connect once the verdicts are out,
                     # and goes with this frame if they never are.
-                    region, delta = await self._run_extract(
+                    region, delta, wire = await self._run_extract(
                         _parse_region, raw, n_txs,
                         block is not None and self.utxo is not None
                         and self._utxo_native(),
+                        # a block's txs may have relay verdicts only where
+                        # a mempool holds one: a node without (or with an
+                        # empty one: IBD, big-block replay) hashes and
+                        # looks up nothing
+                        block is not None and self.mempool is not None
+                        and self.mempool.finished() > 0,
                     )
                 except asyncio.CancelledError:
                     raise
                 except Exception as e:
                     _publish_extract_error(e)
                     return
+                if block is not None and self.mempool is not None:
+                    block_txids = _hash_rows(region.txids())
+                    if wire is not None:
+                        subset = self._reuse_relay_verdicts(
+                            peer, block_txids, _hash_rows(wire)
+                        )
                 # Out-of-block prevout rows via the embedder's oracle,
                 # flattened per input in parse order.  The native side
                 # consults its intra-block map FIRST, so resolving every
                 # wants-marked input here matches the Python path's
                 # block_outs -> prevout_lookup precedence (an in-block hit
                 # shadows whatever the oracle would have said).
-                ext, ext_scripts = self._resolve_ext_rows(region, bch)
+                ext, ext_scripts = self._resolve_ext_rows(
+                    region, bch, subset
+                )
                 # BLOCK regions shard across the worker pool as contiguous
                 # tx ranges (ISSUE 11), exactly like mempool drains: the
                 # intra-block prevout map is built ONCE on the shared
                 # handle (read-only for the range jobs), so sharded
                 # extraction is bit-identical to serial (pinned by
-                # tests/test_txextract.py).
+                # tests/test_txextract.py).  With relay verdicts read, the
+                # jobs are runs of the txs still to verify (ISSUE 27).
+                n_todo = region.n_txs if subset is None else len(subset)
                 shard_block = (
                     block is not None
                     and self._extract_workers > 1
-                    and region.n_txs >= 2 * self.MIN_SHARD_TXS
+                    and n_todo >= 2 * self.MIN_SHARD_TXS
                 )
                 try:
-                    if shard_block:
+                    if n_todo == 0:
+                        shards = []  # every tx answered: nothing to extract
+                    elif shard_block:
                         submitted = True
                         shards = await self._extract_block_sharded(
-                            region, bch, ext, ext_scripts
+                            region, bch, ext, ext_scripts, subset
                         )
                     else:
                         submitted = True
                         shards = [await self._run_extract_owned(
                             region,
+                            subset=subset,
                             bch=bch,
                             intra_amounts=n_txs > 1,
                             ext_amounts=ext,
@@ -1956,14 +2013,13 @@ class Node:
                 except Exception as e:
                     _publish_extract_error(e)
                     return
-            if block is not None and self.mempool is not None:
-                # block connect: evict confirmed txs from the mempool.
-                # The txids come from the native extract — no Python
-                # parse — and arrive before the verdicts do.
-                self.mempool.confirmed(
-                    [it.txid(ti) for it in shards
-                     for ti in range(it.n_txs)]
-                )
+            if block_txids is not None:
+                # block connect: evict confirmed txs from the mempool —
+                # the whole block's, answered from relay or not.  The
+                # txids come from the native parse — no Python parse —
+                # and arrive before the engine's verdicts do.
+                assert self.mempool is not None
+                self.mempool.confirmed(block_txids)
             metrics.inc(
                 "node.verify_txs", sum(it.n_txs for it in shards)
             )
@@ -1990,7 +2046,8 @@ class Node:
                 except Exception:
                     aff = None
             clean = all(await asyncio.gather(*(
-                self._commit_items(peer, it, priority, aff)
+                self._commit_items(peer, it, priority, aff,
+                                   relay=block is None)
                 for it in shards
             )))
             if block is not None and clean:
@@ -2008,12 +2065,45 @@ class Node:
             # the item's pipeline trace (if any) ends with its verdicts
             _finish_active_trace()
 
+    def _reuse_relay_verdicts(
+        self, peer, txids: "list[bytes]", wire: "list[bytes]"
+    ) -> Optional["np.ndarray"]:
+        """A block's transactions that this node's RELAY path already
+        verdicted are answered from those verdicts (ISSUE 27; Bitcoin
+        Core's signature cache, which ConnectBlock reads and does not
+        write): one ``TxVerdict`` each — same ``valid``, ``verdicts``
+        and ``stats`` — published here, on the loop, in one hold.
+        ``wire``: per tx the hash of its full bytes as they stand in the
+        block, the only key a relay verdict answers under
+        (``Mempool.relay_verdicts`` has the rules).  -> the ascending
+        indices of the txs still to verify, None when that is all of
+        them."""
+        assert self.mempool is not None
+        with span("node.reuse", cpu=True):
+            hits, pending, unfit = self.mempool.relay_verdicts(wire)
+            metrics.inc("node.reuse_blocks")
+            metrics.inc("node.reuse_lookups", len(txids))
+            metrics.inc("node.reuse_hits", len(hits))
+            metrics.inc("node.reuse_pending", pending)
+            metrics.inc("node.reuse_unfit", unfit)
+            if not hits:
+                return None
+            todo = np.ones(len(txids), bool)
+            for i, (valid, verdicts, stats) in hits.items():
+                todo[i] = False
+                self._publish_verdict(
+                    TxVerdict(peer, txids[i], valid, verdicts, stats),
+                    relay=False,
+                )
+            return np.flatnonzero(todo).astype(np.int32)
+
     async def _commit_items(
-        self, peer, items, priority: str, affinity: Optional[int] = None
+        self, peer, items, priority: str, affinity: Optional[int] = None,
+        relay: bool = True,
     ) -> bool:
         """Engine round + verdict publication for one RawSigItems batch
-        (a whole message, or one tx-range shard of a block).  Returns
-        False when the engine failed (error verdicts published)."""
+        (a whole message, or one shard of a block: ``relay=False``).
+        Returns False when the engine failed (error verdicts published)."""
         assert self.verify_engine is not None
         verdicts: list[bool] = []
         if items.count:
@@ -2028,7 +2118,8 @@ class Node:
                 for ti in range(items.n_txs):
                     self._publish_verdict(
                         TxVerdict(peer, items.txid(ti), False, (),
-                                  items.stats(ti), error=f"engine: {e}")
+                                  items.stats(ti), error=f"engine: {e}"),
+                        relay=relay,
                     )
                 return False
         # candidate verdicts -> per-signature verdicts (consensus walk)
@@ -2038,33 +2129,45 @@ class Node:
                 vs = tuple(per_sig[sl])
                 self._publish_verdict(
                     TxVerdict(peer, items.txid(ti), all(vs), vs,
-                              items.stats(ti))
+                              items.stats(ti)),
+                    relay=relay,
                 )
         return True
 
     async def _extract_block_sharded(self, region, bch: bool, ext,
-                                     ext_scripts) -> list:
+                                     ext_scripts, subset=None) -> list:
         """Split a parsed BLOCK region into contiguous per-worker
-        tx-range sub-extractions (ISSUE 11).  The shared intra-block
-        prevout map is built once (off-loop) before the range jobs go to
-        the pool; each job's oracle rows are the range's slice of the
-        whole-region rows.  Close ownership is collective: the region is
+        tx-range sub-extractions (ISSUE 11) — or, with ``subset`` (the
+        txs no relay verdict answered, ISSUE 27), into runs of it.  The
+        shared intra-block prevout map is built once (off-loop) before
+        the jobs go to the pool; each job's oracle rows are its slice of
+        the rows ``_resolve_ext_rows`` gave (the whole region's, or the
+        subset's).  Close ownership is collective: the region is
         freed when the LAST submitted job finishes (or every queued job
         is cancelled before running) — never under a live extract."""
-        n = region.n_txs
+        n = region.n_txs if subset is None else len(subset)
         w = min(self._extract_workers, n // self.MIN_SHARD_TXS)
-        if n > 1:
+        if region.n_txs > 1:
             await self._run_extract(region.build_intra)
-        off = region.input_offsets()
+        if subset is None:
+            off = region.input_offsets()
+        else:
+            off = np.zeros(n + 1, np.int64)
+            np.cumsum(region.tx_layout()[0][subset], out=off[1:])
         size = (n + w - 1) // w
         jobs = []
         for lo in range(0, n, size):
             hi = min(lo + size, n)
             fl, fh = int(off[lo]), int(off[hi])
+            job = (
+                functools.partial(region.extract_range, lo, hi)
+                if subset is None
+                else functools.partial(region.extract_subset, subset[lo:hi])
+            )
             jobs.append(functools.partial(
-                region.extract_range, lo, hi,
+                job,
                 bch=bch,
-                intra_amounts=n > 1,
+                intra_amounts=region.n_txs > 1,
                 ext_amounts=ext[fl:fh] if ext is not None else None,
                 ext_scripts=(
                     ext_scripts[fl:fh] if ext_scripts is not None else None
@@ -2167,7 +2270,8 @@ class Node:
                             peer.kill(CannotDecodePayload(f"tx: {e}"))
                         self._publish_verdict(
                             TxVerdict(peer, txid, False, (), ExtractStats(),
-                                      error=f"extract: {e}")
+                                      error=f"extract: {e}"),
+                            relay=block is None,
                         )
                         continue
                     metrics.inc("node.verify_txs")
@@ -2196,7 +2300,8 @@ class Node:
             for tx, stats, items, task in per_tx:
                 if task is None:
                     self._publish_verdict(
-                        TxVerdict(peer, tx.txid, True, (), stats)
+                        TxVerdict(peer, tx.txid, True, (), stats),
+                        relay=block is None,
                     )
                     continue
                 try:
@@ -2208,7 +2313,8 @@ class Node:
                     self._verify_failure("engine", e)
                     self._publish_verdict(
                         TxVerdict(peer, tx.txid, False, (), stats,
-                                  error=f"engine: {e}")
+                                  error=f"engine: {e}"),
+                        relay=block is None,
                     )
                     continue
                 # candidate verdicts -> per-signature (consensus walk)
@@ -2216,7 +2322,8 @@ class Node:
                     per_sig = tuple(combine_verdicts(items, verdicts))
                     self._publish_verdict(
                         TxVerdict(peer, tx.txid, all(per_sig), per_sig,
-                                  stats)
+                                  stats),
+                        relay=block is None,
                     )
             if block is not None and clean:
                 # persistent UTXO connect only AFTER every verdict landed
