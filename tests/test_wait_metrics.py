@@ -126,11 +126,11 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
     assert names[first:first + len(WAITS)] == list(WAITS)
     after = names[first + len(WAITS):]
     assert after[:len(CONNECT)] == list(CONNECT)
-    # PR 27's, appended in their turn
+    # PR 27's, appended in their turn, then PR 28's
     assert after[len(CONNECT):] == [
         "reuse.hit_share", "reuse.ms_per_block", "reuse.cpu_ms_per_block",
         "tip.relay_verdict_p50_ms", "tip.block_verdict_p50_ms",
-        "open.late_p99_ms", "open.verdict_p99_ms"]
+        "open.late_p99_ms", "open.verdict_p99_ms", "commit.ms_per_ktx"]
 
 
 def test_the_two_connect_metrics_read_the_utxo_connect_span():
@@ -232,3 +232,32 @@ def test_the_reuse_and_open_loop_metrics_read_through_the_harness(
     assert set(new) == set(want) | ({"open.late_p99_ms"} if samples else set())
     for name, value in want.items():
         assert new[name] == pytest.approx(value), name
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_commit_ms_per_ktx_reads_the_commit_span_in_every_cell(cell):
+    """ISSUE 28: open time of ``node.commit`` per 1000 transactions handed
+    to the engine (``node.verify_txs``), in every cell, traced or not; span
+    and counter exist at the parent commit, so both sides of a pair read
+    it.  ``commit.ms_per_block`` stays ``ibd``'s alone."""
+    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    entry = by_name["commit.ms_per_ktx"]
+    assert set(entry["workloads"]) == CELLS
+    assert (entry["layer"], entry["moves"]) == ("verdict publication",
+                                                "sigs_per_s")
+    assert (entry["unit"], entry["better"]) == ("ms/ktx", "lower")
+    assert by_name["commit.ms_per_block"]["workloads"] == ["bch-node.ibd"]
+    ctx = harness.Ctx(workload={"name": cell}, bench=BENCH, config={},
+                      traffic={}, seed=0, seconds=4.0, trace=False,
+                      rehearsal=None, t_start=0.0)
+    # two blocks of 66,672 txs, 0.79 s of node.commit each: 11.85 ms/ktx
+    window = dict(COUNTERS, **{
+        "node.verify_txs": 133344.0, "ibd.blocks": 2.0,
+        "span.node.commit.seconds": 1.58, "span.node.commit.count": 8.0})
+    got = harness.read_per_layer(ctx, reading(window, trace=None))
+    assert got["commit.ms_per_ktx"]["unit"] == "ms/ktx"
+    assert got["commit.ms_per_ktx"]["value"] == pytest.approx(1.58e6 / 133344)
+    assert ("commit.ms_per_block" in got) == (cell == "bch-node.ibd")
+    # a window in which no tx reached the engine: left out, not 0
+    idle = harness.read_per_layer(ctx, reading(trace=None))
+    assert "commit.ms_per_ktx" not in idle

@@ -1797,17 +1797,13 @@ class Node:
                                       error=f"engine: {e}")
                         )
                     return
-            per_sig = items.combine(verdicts)
-            sig_slices = items.sig_slices()
-            for ti, (peer, _, _, act) in enumerate(shard):
-                vs = tuple(per_sig[sig_slices[ti]])
+            for (peer, _, _, act), row in zip(
+                shard, items.verdict_rows(verdicts)
+            ):
                 # per-tx commit span in the tx's OWN trace (ISSUE 10)
                 with _activate_trace(act):
                     with span("node.commit"):
-                        self._publish_verdict(
-                            TxVerdict(peer, items.txid(ti), all(vs), vs,
-                                      items.stats(ti))
-                        )
+                        self._publish_verdict(TxVerdict(peer, *row))
         finally:
             # traces end AFTER the spans close, so a finished trace is
             # never mutated (retention/export reads it immediately)
@@ -2122,16 +2118,11 @@ class Node:
                         relay=relay,
                     )
                 return False
-        # candidate verdicts -> per-signature verdicts (consensus walk)
+        # candidate verdicts -> per-signature verdicts (consensus walk),
+        # one TxVerdict a tx: a shard's publication is one hold of the loop
         with span("node.commit"):
-            per_sig = items.combine(verdicts)
-            for ti, sl in enumerate(items.sig_slices()):
-                vs = tuple(per_sig[sl])
-                self._publish_verdict(
-                    TxVerdict(peer, items.txid(ti), all(vs), vs,
-                              items.stats(ti)),
-                    relay=relay,
-                )
+            for row in items.verdict_rows(verdicts):
+                self._publish_verdict(TxVerdict(peer, *row), relay=relay)
         return True
 
     async def _extract_block_sharded(self, region, bch: bool, ext,

@@ -20,12 +20,13 @@ import ctypes
 import os
 import threading
 from dataclasses import dataclass
+from itertools import accumulate, pairwise
 from typing import Optional, Sequence
 
 import numpy as np
 
 from . import threadsan
-from .txverify import ExtractStats
+from .txverify import ExtractStats, msig_match
 
 __all__ = [
     "RawSigItems",
@@ -269,30 +270,70 @@ class RawSigItems:
     def combine(self, verdicts) -> list[bool]:
         """Collapse per-candidate verdicts to per-signature verdicts (one
         entry per extracted signature, in item order) — the array twin of
-        txverify.combine_verdicts, sharing its consensus walk."""
-        from .txverify import msig_match
-
+        txverify.combine_verdicts, sharing its consensus walk.  Single-
+        signature rows pass through; only multisig windows are walked."""
+        v = np.asarray(verdicts, dtype=bool)
+        multi = np.flatnonzero((self.item_nsigs != 1) | (self.item_nkeys != 1))
+        if not len(multi):
+            return v.tolist()
+        # a window is m * (n - m + 1) adjacent candidate rows
+        rows = multi.tolist()
+        ms = self.item_nsigs[multi].tolist()
+        ns = self.item_nkeys[multi].tolist()
+        cand = tuple(zip(self.item_sig[multi].tolist(),
+                         self.item_key[multi].tolist()))
+        ok = tuple(v[multi].tolist())
+        v = v.tolist()
         out: list[bool] = []
-        k = 0
-        N = self.count
-        nsigs = self.item_nsigs
-        nkeys = self.item_nkeys
-        while k < N:
-            m = int(nsigs[k])
-            n = int(nkeys[k])
-            if m == 1 and n == 1:
-                out.append(bool(verdicts[k]))
-                k += 1
-                continue
-            span = m * (n - m + 1)
-            M: dict[tuple[int, int], bool] = {}
-            for idx in range(k, k + span):
-                M[(int(self.item_sig[idx]), int(self.item_key[idx]))] = bool(
-                    verdicts[idx]
+        walked: dict = {}  # a block's windows repeat a few verdict patterns
+        done = p = 0  # rows of ``v`` / of ``multi`` already taken
+        while p < len(rows):
+            m, n = ms[p], ns[p]
+            end = p + m * (n - m + 1)
+            window = (m, n, cand[p:end], ok[p:end])
+            flags = walked.get(window)
+            if flags is None:
+                got = dict(zip(window[2], window[3])).get
+                flags = walked[window] = msig_match(
+                    m, n, lambda i, j: got((i, j), False)
                 )
-            out.extend(msig_match(m, n, lambda i, j: M.get((i, j), False)))
-            k += span
+            out += v[done:rows[p]]  # the single rows before this window
+            out += flags
+            done = rows[p] + end - p
+            p = end
+        out += v[done:]
         return out
+
+    def verdict_rows(self, verdicts):
+        """Per transaction, in tx order, ``(txid, valid, verdicts, stats)``
+        for the engine's per-candidate ``verdicts``: what a ``TxVerdict``
+        carries besides its peer.  Every column is converted once for the
+        whole batch, so the values are plain Python (``bytes``, ``bool``,
+        ``tuple[bool, ...]``, ``ExtractStats`` of ``int``) and the caller's
+        loop touches no numpy.  A tx without a signature is valid, ``()``."""
+        per_sig = tuple(self.combine(verdicts))
+        # bounds in Python, not numpy: a cast inside an array operation
+        # gives the GIL up, and beside busy threads the loop then waits a
+        # switch interval in the middle of its hold to get it back
+        sigs = self.tx_sigs.tolist()
+        per_tx = [
+            per_sig[a:b] for a, b in pairwise(accumulate(sigs, initial=0))
+        ]
+        blob = self.txids.tobytes()
+        return zip(
+            [blob[o:o + 32] for o in range(0, len(blob), 32)],
+            map(all, per_tx),
+            per_tx,
+            map(
+                ExtractStats,  # its fields, in their order
+                self.tx_n_inputs.tolist(),
+                self.tx_extracted.tolist(),
+                self.tx_coinbase.tolist(),
+                self.tx_unsupported.tolist(),
+                sigs,
+                self.tx_items.tolist(),
+            ),
+        )
 
     def to_verify_items(self):
         """Convert to the engine's ``VerifyItem`` tuples (5-tuples tagged
