@@ -4,7 +4,7 @@ scenario harnesses, each run in this process and printing ONE JSON line.
     python bench.py                 # device worker: fails without a TPU
     python bench.py --<scenario>    # mempool | chaos | recovery | pipeline |
                                     # ibd | mesh | mesh-e2e | mesh-device |
-                                    # serve | kernel-ab | observability
+                                    # serve | observability
 
 The device worker (``_worker_bench``) compiles the Pallas verify kernel at
 one batch shape on the chip, cross-checks the C++ verifier and times a few
@@ -88,10 +88,7 @@ def _worker_bench() -> None:
             sys.exit(1)
 
         from benchmarks.common import device_kind, make_triples, tile
-        from tpunode.verify import field as _field
-        from tpunode.verify import kernel as _kernel_mod
         from tpunode.verify.cpu_native import load_native_verifier
-        from tpunode.verify.curve import point_form as _point_form
         from tpunode.verify.kernel import collect_verdicts, prepare_batch
         from tpunode.verify.pallas_kernel import verify_blocked
 
@@ -167,9 +164,6 @@ def _worker_bench() -> None:
                     "devices": len(jax.devices()),
                     "jax": jax.__version__,
                     "kernel": "pallas",
-                    "point_form": _point_form(),
-                    "field_reduce": _field.reduce_mode(),
-                    "window_bits": _kernel_mod.window_bits(),
                     "batch": batch,
                     "step_ms": round(dt * 1e3, 3),
                     "compile_s": round(compile_s, 1),
@@ -1995,132 +1989,6 @@ def _worker_ibd_child() -> None:
     asyncio.run(run())
 
 
-def _worker_kernel_ab() -> None:
-    """Kernel formulation A/B worker: XLA step times on cpu-jax (they say
-    how fast XLA's CPU backend is, nothing about the chip: ROADMAP S5),
-    cells timed ROUND-ROBIN so host-load drift hits
-    every cell equally (the PERF r6 lesson: sequential per-process runs
-    on this box swing ±75%).
-
-    Two grids behind TPUNODE_BENCH_KERNELAB_MODE:
-
-    * ``forms`` (default, ISSUE 8): projective vs affine point form.
-    * ``reduce`` (ISSUE 12): the field_reduce x window_bits grid
-      (eager/lazy x 4/5) at the default point form.
-
-    Every cell compiles first (persistent cache) and cross-checks its
-    verdicts against the C++ engine (a mismatch is FATAL — an A/B must
-    never time a wrong program).  Prints one JSON line with
-    median-of-N + spread per cell, like ``baseline_cpu_single_core``.
-    """
-    batch = int(os.environ.get("TPUNODE_BENCH_KERNELAB_BATCH", 1024))
-    iters = int(os.environ.get("TPUNODE_BENCH_KERNELAB_ITERS", 5))
-    mode = os.environ.get("TPUNODE_BENCH_KERNELAB_MODE", "forms")
-    try:
-        import jax
-        import jax.numpy as jnp
-
-        # this box's TPU shim force-sets jax_platforms in every process
-        jax.config.update("jax_platforms", "cpu")
-        from tpunode.verify.engine import enable_compile_cache
-
-        enable_compile_cache()
-        from benchmarks.common import make_triples, tile
-        from tpunode.verify import curve as C
-        from tpunode.verify import field as F
-        from tpunode.verify import kernel as K
-        from tpunode.verify.cpu_native import load_native_verifier
-        from tpunode.verify.ecdsa_cpu import verify_batch_cpu
-        from tpunode.verify.kernel import (
-            collect_verdicts,
-            prepare_batch,
-            verify_device,
-        )
-
-        base = make_triples(min(UNIQUE, batch))
-        items = tile(base, batch)
-        native = load_native_verifier()
-        expect = (
-            native.verify_batch(base)
-            if native is not None
-            else verify_batch_cpu(base)
-        )
-
-        # (label, setter) per cell.  Args are prepared per cell: the
-        # 5-bit cells carry 27-row digit arrays (and Python host prep).
-        if mode == "reduce":
-            def setter_for(red, wb):
-                def set_modes():
-                    F.set_field_modes(reduce=red)
-                    K.set_kernel_modes(window_bits=wb)
-                return set_modes
-
-            cells = [
-                (f"{red}@w{wb}", setter_for(red, wb))
-                for red in ("eager", "lazy")
-                for wb in (4, 5)
-            ]
-            delta_keys = ("lazy@w4", "eager@w4", "lazy_vs_eager")
-        else:
-            cells = [
-                (form, (lambda f=form: C.set_point_form(f)))
-                for form in ("projective", "affine")
-            ]
-            delta_keys = ("affine", "projective", "affine_vs_projective")
-        stats: dict = {label: {"times": []} for label, _ in cells}
-        cell_args: dict = {}
-        for label, set_modes in cells:
-            set_modes()
-            prep = prepare_batch(items, pad_to=batch)
-            cell_args[label] = tuple(
-                jnp.asarray(a) for a in prep.device_args
-            )
-            _progress(f"compiling {label} XLA program at batch {batch}...")
-            t0 = time.perf_counter()
-            out = verify_device(*cell_args[label])
-            got = collect_verdicts(out, len(base))
-            stats[label]["compile_s"] = round(time.perf_counter() - t0, 1)
-            if got != expect:
-                print(
-                    json.dumps(
-                        {"ok": False, "fatal": True,
-                         "error": f"{label}/oracle verdict mismatch"}
-                    )
-                )
-                return
-        for i in range(iters):
-            _progress(f"timed round {i + 1}/{iters}...")
-            for label, set_modes in cells:
-                set_modes()
-                t0 = time.perf_counter()
-                verify_device(*cell_args[label]).block_until_ready()
-                stats[label]["times"].append(time.perf_counter() - t0)
-        section: dict = {
-            "ok": True,
-            "batch": batch,
-            "proxy": "cpu-jax",
-            "iters": iters,
-            "mode": mode,
-            "forms": {},
-        }
-        for label, _ in cells:
-            ts = stats[label]["times"]
-            section["forms"][label] = {
-                "step_ms": round(statistics.median(ts) * 1e3, 1),
-                "step_ms_min": round(min(ts) * 1e3, 1),
-                "step_ms_max": round(max(ts) * 1e3, 1),
-                "spread_rel": round(max(ts) / min(ts) - 1.0, 3),
-                "compile_s": stats[label]["compile_s"],
-            }
-        a_key, b_key, delta_name = delta_keys
-        a = section["forms"][a_key]["step_ms"]
-        b = section["forms"][b_key]["step_ms"]
-        section[delta_name] = round(a / b - 1.0, 4)
-        print(json.dumps(section))
-    except Exception as e:  # noqa: BLE001 — one JSON line
-        print(json.dumps({"ok": False, "error": f"{type(e).__name__}: {e}"[:500]}))
-
-
 def _worker_observability() -> None:
     """Observability-overhead micro-bench (ISSUE 16).
 
@@ -2243,8 +2111,6 @@ if __name__ == "__main__":
         _worker_chaos()
     elif "--recovery" in sys.argv:
         _worker_recovery()
-    elif "--kernel-ab" in sys.argv:
-        _worker_kernel_ab()
     elif "--pipeline" in sys.argv:
         _worker_pipeline()
     elif "--ibd-child" in sys.argv:

@@ -22,13 +22,9 @@ Run (CPU-only):
 ``--pallas`` sends the same pool through the flagship Pallas program in
 interpret mode (numpy semantics of the exact Mosaic program; block 32)
 instead of the XLA program — both device paths validated by one
-harness.  ``--field-mul=shift_add|dot_general`` and
-``--field-sqr=half|mul`` select the limb-product formulation (ISSUE 4);
-``--point-form projective|affine`` selects the MSM point form (ISSUE 8):
-a new formulation must produce ZERO mismatches on the full adversarial
-pool before it is eligible for dispatch.  Prints one JSON line: items
-compared, mismatches (MUST be 0), the formulation, and the per-shape
-tally.
+harness: a kernel change must produce ZERO mismatches on the full
+adversarial pool before it is eligible for dispatch.  Prints one JSON
+line: items compared, mismatches (MUST be 0) and the per-shape tally.
 Replaces the one-off scripts behind PERF.md's r5 campaign notes with a
 committed, re-runnable harness.
 """
@@ -131,42 +127,20 @@ def build_pool(n_base: int, rng: random.Random):
     return items, shapes, expects
 
 
-def run_campaign(
-    n_base: int,
-    batch: int,
-    pallas: bool = False,
-    field_mul: str | None = None,
-    field_sqr: str | None = None,
-    point_form: str | None = None,
-    field_reduce: str | None = None,
-    window_bits: int | None = None,
-) -> dict:
+def run_campaign(n_base: int, batch: int, pallas: bool = False) -> dict:
     """Build the pool and compare the chosen device program against the
     C++ verifier AND each shape's required verdict.  Returns the result
-    dict (``mismatches`` MUST be 0).  ``field_mul``/``field_sqr`` select
-    the limb-product formulation, ``point_form`` the MSM point form
-    (ISSUE 8), ``field_reduce`` the reduction discipline and
-    ``window_bits`` the MSM window width (ISSUE 12) process-wide (None
-    keeps the active mode); every dispatch path retraces per mode."""
+    dict (``mismatches`` MUST be 0)."""
     import jax
 
     jax.config.update("jax_platforms", "cpu")
 
-    from tpunode.verify import curve as C
-    from tpunode.verify import field as F
-    from tpunode.verify import kernel as K
     from tpunode.verify.cpu_native import load_native_verifier
     from tpunode.verify.ecdsa_cpu import verify_batch_cpu
     from tpunode.verify.engine import enable_compile_cache
     from tpunode.verify.kernel import verify_batch_tpu
 
     enable_compile_cache()
-    if field_mul is not None or field_sqr is not None or field_reduce is not None:
-        F.set_field_modes(mul=field_mul, sqr=field_sqr, reduce=field_reduce)
-    if point_form is not None:
-        C.set_point_form(point_form)
-    if window_bits is not None:
-        K.set_kernel_modes(window_bits=window_bits)
     if pallas:
         import jax.numpy as jnp
 
@@ -217,13 +191,6 @@ def run_campaign(
         "mismatches": len(mismatches),
         "mismatch_detail": mismatches[:10],
         "kernel": "pallas-interpret" if pallas else "xla",
-        "field_modes": {
-            "mul": F.mul_mode(),
-            "sqr": F.sqr_mode(),
-            "reduce": F.reduce_mode(),
-        },
-        "point_form": C.point_form(),
-        "window_bits": K.window_bits(),
         "gen_s": round(gen_s, 1),
         "run_s": round(run_s, 1),
         "oracle": "native-cpp" if native is not None else "python",
@@ -234,47 +201,13 @@ def run_campaign(
 
 def main() -> None:
     pallas = "--pallas" in sys.argv
-    field_mul = field_sqr = point_form = field_reduce = None
-    window_bits = None
-    pos = []
-    args = list(sys.argv[1:])
-    while args:
-        a = args.pop(0)
-        if a == "--pallas":
-            continue
-        if a.startswith("--field-mul="):
-            field_mul = a.split("=", 1)[1]
-        elif a.startswith("--field-sqr="):
-            field_sqr = a.split("=", 1)[1]
-        elif a.startswith("--point-form="):
-            point_form = a.split("=", 1)[1]
-        elif a == "--point-form":  # ISSUE 8 spells it space-separated
-            if not args:
-                sys.exit("--point-form needs a value (projective|affine)")
-            point_form = args.pop(0)
-        elif a.startswith("--field-reduce="):
-            field_reduce = a.split("=", 1)[1]
-        elif a == "--field-reduce":  # ISSUE 12 spells it space-separated
-            if not args:
-                sys.exit("--field-reduce needs a value (eager|lazy)")
-            field_reduce = args.pop(0)
-        elif a.startswith("--window-bits="):
-            window_bits = int(a.split("=", 1)[1])
-        elif a == "--window-bits":
-            if not args:
-                sys.exit("--window-bits needs a value (4|5)")
-            window_bits = int(args.pop(0))
-        else:
-            pos.append(a)
+    pos = [a for a in sys.argv[1:] if a != "--pallas"]
     n_base = int(pos[0]) if pos else (32 if pallas else 256)
     batch = int(pos[1]) if len(pos) > 1 else (256 if pallas else 2048)
     if pallas and batch % 32:
         sys.exit(f"--pallas batch must be a multiple of the 32-lane "
                  f"interpret block (got {batch})")
-    res = run_campaign(n_base, batch, pallas=pallas,
-                       field_mul=field_mul, field_sqr=field_sqr,
-                       point_form=point_form, field_reduce=field_reduce,
-                       window_bits=window_bits)
+    res = run_campaign(n_base, batch, pallas=pallas)
     print(json.dumps(res))
     if res["mismatches"]:
         sys.exit(1)

@@ -757,19 +757,19 @@ inline void glv_halves(const Fe &k, const uint64_t c1[3], const uint64_t c2[3],
   h2.abs[0] = acc2[0]; h2.abs[1] = acc2[1]; h2.abs[2] = acc2[2];
 }
 
-// MSB-first wb-bit window digits of abs into out[w * size + lane].
-// 4-bit digits never straddle 64-bit word edges; 5-bit digits (ISSUE 13:
-// window_bits=5, 27 windows) can, so the straddle path ORs in the next
-// word's low bits — bit-identical to kernel.py's _ints_to_digits_np.
+// kernel.py's WINDOW_BITS / WINDOWS: 33 4-bit windows cover the GLV
+// half-scalars' 132 bits.
+constexpr int PREP_WINDOW_BITS = 4;
+constexpr int PREP_WINDOWS = 33;
+
+// MSB-first 4-bit window digits of abs into out[w * size + lane] (4-bit
+// digits never straddle 64-bit word edges) — bit-identical to kernel.py's
+// _ints_to_digits_np.
 inline void write_digits(const uint64_t abs[3], int32_t *out, int size,
-                         int lane, int wb, int nwin) {
-  const uint64_t mask = (1u << wb) - 1;
-  for (int w = 0; w < nwin; ++w) {
-    int sh = wb * (nwin - 1 - w);
-    int word = sh / 64, off = sh % 64;
-    uint64_t lo = abs[word] >> off;
-    if (off > 64 - wb && word + 1 < 3) lo |= abs[word + 1] << (64 - off);
-    out[w * size + lane] = (int32_t)(lo & mask);
+                         int lane) {
+  for (int w = 0; w < PREP_WINDOWS; ++w) {
+    int sh = PREP_WINDOW_BITS * (PREP_WINDOWS - 1 - w);
+    out[w * size + lane] = (int32_t)((abs[sh / 64] >> (sh % 64)) & 0xF);
   }
 }
 
@@ -828,30 +828,19 @@ int secp_verify_batch_mt(const uint8_t *px, const uint8_t *py,
 // precomputed challenge e, u1 = s and u2 = n - e need no inversion, and
 // ``r`` is an Fp x-coordinate with no r+n candidate).  int32 outputs are
 // (rows, size) C-contiguous, zero-initialized by the caller; lanes >= count
-// stay zero.  ``window_bits`` selects the digit layout: 4 (33 windows,
-// the default) or 5 (27 windows, ISSUE 12/13 — the digit arrays must be
-// allocated 27 rows tall).  Returns the number of GLV bound violations
-// (0 = success; cannot occur for in-range scalars — a nonzero return
-// means a bug and the caller must refuse the batch), or -1 for an
-// unsupported window width.
-int secp_prepare_batch_w(const uint8_t *px, const uint8_t *py,
-                         const uint8_t *z, const uint8_t *r, const uint8_t *s,
-                         const uint8_t *present, int count, int size,
-                         int32_t *d1a, int32_t *d1b, int32_t *d2a,
-                         int32_t *d2b, uint8_t *negs, int32_t *qx,
-                         int32_t *qy, int32_t *r1, int32_t *r2,
-                         uint8_t *r2_valid, uint8_t *host_valid,
-                         uint8_t *schnorr, uint8_t *bip340, int nthreads,
-                         int window_bits) {
-  int nwin;
-  if (window_bits == 4) {
-    nwin = 33;
-  } else if (window_bits == 5) {
-    nwin = 27;
-  } else {
-    return -1;
-  }
-  const int bound_shift = window_bits * nwin - 128;  // 4 (w4) / 7 (w5)
+// stay zero.  The digit arrays are PREP_WINDOWS (33) rows tall.  Returns
+// the number of GLV bound violations (0 = success; cannot occur for
+// in-range scalars — a nonzero return means a bug and the caller must
+// refuse the batch).
+int secp_prepare_batch(const uint8_t *px, const uint8_t *py, const uint8_t *z,
+                       const uint8_t *r, const uint8_t *s,
+                       const uint8_t *present, int count, int size,
+                       int32_t *d1a, int32_t *d1b, int32_t *d2a, int32_t *d2b,
+                       uint8_t *negs, int32_t *qx, int32_t *qy, int32_t *r1,
+                       int32_t *r2, uint8_t *r2_valid, uint8_t *host_valid,
+                       uint8_t *schnorr, uint8_t *bip340, int nthreads) {
+  // half-scalars live in abs[0..2]: bits >= 132 sit at abs[2] >> 4
+  constexpr int bound_shift = PREP_WINDOW_BITS * PREP_WINDOWS - 128;
   // ---- serial: validity + Montgomery batch inversion of s (ECDSA rows) ----
   std::vector<Fe> sv(count), prefix(count), w(count);
   std::vector<uint8_t> ok(count), is_sch(count);
@@ -911,13 +900,12 @@ int secp_prepare_batch_w(const uint8_t *px, const uint8_t *py,
       glv_halves(u2, c1, c2, h[2], h[3]);
       int32_t *dsts[4] = {d1a, d1b, d2a, d2b};
       for (int j = 0; j < 4; ++j) {
-        // |k| >= 2^(wb*nwin): outside the window range (2^132 at w4,
-        // 2^135 at w5)
+        // |k| >= 2^132: outside the window range
         if (h[j].abs[2] >> bound_shift) {
           violations.fetch_add(1, std::memory_order_relaxed);
           continue;
         }
-        write_digits(h[j].abs, dsts[j], size, i, window_bits, nwin);
+        write_digits(h[j].abs, dsts[j], size, i);
         negs[j * size + i] = h[j].neg ? 1 : 0;
       }
       write_limbs(fe_from_be(px + 32 * i), qx, size, i);
@@ -949,19 +937,6 @@ int secp_prepare_batch_w(const uint8_t *px, const uint8_t *py,
     for (auto &th : ts) th.join();
   }
   return violations.load();
-}
-
-// Legacy 4-bit entry point (kept so an older binding keeps working).
-int secp_prepare_batch(const uint8_t *px, const uint8_t *py, const uint8_t *z,
-                       const uint8_t *r, const uint8_t *s,
-                       const uint8_t *present, int count, int size,
-                       int32_t *d1a, int32_t *d1b, int32_t *d2a, int32_t *d2b,
-                       uint8_t *negs, int32_t *qx, int32_t *qy, int32_t *r1,
-                       int32_t *r2, uint8_t *r2_valid, uint8_t *host_valid,
-                       uint8_t *schnorr, uint8_t *bip340, int nthreads) {
-  return secp_prepare_batch_w(px, py, z, r, s, present, count, size, d1a, d1b,
-                              d2a, d2b, negs, qx, qy, r1, r2, r2_valid,
-                              host_valid, schnorr, bip340, nthreads, 4);
 }
 
 }  // extern "C"

@@ -147,15 +147,6 @@ import threading
 def make():
     return threading.Lock()
 """,
-    # unkeyed jit wrapper: no static mode argument and no mode-accessor
-    # call in the enclosing cache scope (ISSUE 18; the rule scopes to
-    # tpunode/verify/ paths and "<...>" in-memory test sources)
-    "jit-cache-key": """\
-import jax
-
-def build(fn):
-    return jax.jit(fn)
-""",
     # env knob read nowhere documented in OBSERVABILITY.md's inventory
     "env-knob-doc": """\
 import os
@@ -653,7 +644,7 @@ def test_cli_inprocess_exit_codes(tmp_path, capsys):
 
     assert cli_main(["--list-rules"]) == 0
     listed = capsys.readouterr().out
-    for rid in ("raw-spawn", "raw-lock", "jit-cache-key", "env-knob-doc"):
+    for rid in ("raw-spawn", "raw-lock", "env-knob-doc"):
         assert rid in listed
     assert cli_main(["--rules", "bogus", str(good)]) == 2
     assert cli_main([str(tmp_path / "missing.py")]) == 2
@@ -674,7 +665,7 @@ def test_cli_subprocess_tree_is_clean():
     assert json.loads(proc.stdout)["findings"] == []
 
 
-# --- raw-lock / jit-cache-key / env-knob-doc (ISSUE 18) ----------------------
+# --- raw-lock / env-knob-doc (ISSUE 18) -------------------------------------
 
 
 def test_raw_lock_flags_aliases_and_dynamic_import():
@@ -713,59 +704,6 @@ def test_raw_lock_exempts_threadsan_itself():
             src, path="tpunode/store.py"
         )
     ] == ["raw-lock"]
-
-
-_JIT = Analyzer(select=["jit-cache-key"])
-
-
-def test_jit_cache_key_accepts_static_mode_argnames():
-    src = (
-        "import jax\n"
-        "from functools import partial\n"
-        "@partial(jax.jit, static_argnames=('interpret', 'field_modes'))\n"
-        "def f(x):\n"
-        "    return x\n"
-    )
-    assert _JIT.check_source(src, path="tpunode/verify/kernel.py") == []
-
-
-def test_jit_cache_key_accepts_static_argnums():
-    src = "import jax\n\ndef build(fn):\n    return jax.jit(fn, static_argnums=(1,))\n"
-    assert _JIT.check_source(src, path="tpunode/verify/kernel.py") == []
-
-
-def test_jit_cache_key_accepts_mode_keyed_cache_scope():
-    src = (
-        "import jax\n"
-        "from tpunode.verify.modes import kernel_modes\n"
-        "_CACHE = {}\n"
-        "def build(fn, mesh):\n"
-        "    key = (mesh, kernel_modes())\n"
-        "    if key not in _CACHE:\n"
-        "        _CACHE[key] = jax.jit(fn)\n"
-        "    return _CACHE[key]\n"
-    )
-    assert _JIT.check_source(src, path="tpunode/verify/multichip.py") == []
-
-
-def test_jit_cache_key_flags_modeless_static_argnames():
-    src = (
-        "import jax\n"
-        "from functools import partial\n"
-        "@partial(jax.jit, static_argnames=('interpret',))\n"
-        "def f(x):\n"
-        "    return x\n"
-    )
-    findings = _JIT.check_source(src, path="tpunode/verify/kernel.py")
-    assert [f.rule for f in findings] == ["jit-cache-key"]
-
-
-def test_jit_cache_key_scoped_to_verify_paths():
-    src = "import jax\n\ndef build(fn):\n    return jax.jit(fn)\n"
-    assert _JIT.check_source(src, path="tpunode/node.py") == []
-    assert [
-        f.rule for f in _JIT.check_source(src, path="tpunode/verify/engine.py")
-    ] == ["jit-cache-key"]
 
 
 def test_env_knob_doc_containment(monkeypatch):

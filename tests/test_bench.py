@@ -222,37 +222,6 @@ def test_pipeline_worker_subprocess():
         assert curve["4"] > curve["1"]
 
 
-@pytest.mark.slow  # two real XLA compiles in a subprocess (~3-4 min)
-def test_kernel_ab_worker_subprocess():
-    """The real ``--kernel-ab`` worker end-to-end at a tiny batch: both
-    point forms compile, cross-check the oracle, and report median-of-N
-    step times with spread."""
-    import subprocess
-    import sys as _sys
-
-    proc = subprocess.run(
-        [_sys.executable, os.path.join(REPO, "bench.py"), "--kernel-ab"],
-        env=dict(
-            os.environ,
-            TPUNODE_BENCH_KERNELAB_BATCH="32",
-            TPUNODE_BENCH_KERNELAB_ITERS="2",
-            JAX_PLATFORMS="cpu",
-        ),
-        cwd=REPO,
-        capture_output=True,
-        text=True,
-        timeout=900,
-    )
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert line["ok"] is True, line
-    assert line["batch"] == 32 and line["iters"] == 2
-    for form in ("projective", "affine"):
-        f = line["forms"][form]
-        assert f["step_ms_min"] <= f["step_ms"] <= f["step_ms_max"]
-        assert f["compile_s"] > 0
-    assert isinstance(line["affine_vs_projective"], float)
-
-
 @pytest.mark.slow
 def test_recovery_worker_subprocess():
     """The real ``--recovery`` worker end-to-end in a subprocess: replay

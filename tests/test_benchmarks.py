@@ -104,27 +104,41 @@ def test_churn_soak_short():
 def test_roofline_op_counts_match_rcb_and_structure():
     """The op model is DERIVED from the live kernel: the per-point-op
     counts must equal the RCB'16 paper's (12M for complete addition,
-    6M + 2S for doubling) and the per-verify totals must equal the
+    6M + 2S for doubling), the fused reductions are pinned (counted by
+    EXECUTING the live formulas, so a formula edit moves these on
+    purpose or fails), and the per-verify totals must equal the
     structural assembly recomputed here from kernel.py's constants."""
-    from benchmarks.roofline import field_op_model
+    from benchmarks.roofline import CountingField, field_op_model
     from tpunode.verify.kernel import WINDOW_BITS, WINDOWS, _EULER_DIGITS
 
-    # the eager body is the one whose op counts ARE the RCB'16 paper's
-    # (the round-12 lazy default counts wide/tail ops instead — pinned
-    # in test_roofline_lazy_reduce_model_pins)
-    m = field_op_model(field_reduce="eager", window_bits=4)
+    m = field_op_model()
     add, dbl = m["pt_add"], m["pt_double"]
     # RCB Algorithm 7: 12 muls (+ 2 reduced small-constant scalings)
-    assert add["mul"] + add.get("mul_t", 0) == 12
+    assert add["mul_wide"] + add["mul_t_wide"] == 12
     assert add["mul_small_red"] == 2
     # RCB Algorithm 9: 6 muls + 2 squarings (+ 1 reduced scaling)
-    assert dbl["mul"] + dbl.get("mul_t", 0) == 6
-    assert dbl["sqr_t"] == 2
+    assert dbl["mul_t_wide"] == 6
+    assert dbl["sqr_t_wide"] == 2
     assert dbl["mul_small_red"] == 1
 
+    # a reduction per product would be 14 and 9: the bodies fuse the
+    # per-coordinate tails (mul_small_red's fold counts as its own)
+    def reds(c):
+        return sum(c.get(k, 0) for k in (
+            "mul", "mul_t", "sqr", "sqr_t", "mul_small_red",
+            "reduce_wide", "reduce_wide_loose"))
+    assert reds(add) == 11
+    assert reds(dbl) == 8
+
+    mul_like = CountingField.OPS + CountingField.WIDE_OPS
     tab = 1 << WINDOW_BITS
-    per_add = sum(add.values())
-    per_dbl = sum(dbl.values())
+    per_add = sum(add.get(op, 0) for op in mul_like)
+    per_dbl = sum(dbl.get(op, 0) for op in mul_like)
+    assert (per_add, per_dbl) == (14, 9)
+    assert m["structure"] == {
+        "windows": 33, "window_bits": 4, "half_scalars": 4,
+        "table_entries": 16, "pow_digits": 64,
+    }
     ecdsa = m["per_verify"]["ecdsa"]
     expect = (
         WINDOWS * 4 * (per_add + per_dbl)  # MSM: 4 dbl + 4 add per window
@@ -134,6 +148,7 @@ def test_roofline_op_counts_match_rcb_and_structure():
         + 3                                # on-curve qy² = qx³ + 7
     )
     assert ecdsa["total_mul_like"] == expect
+    assert ecdsa["reductions"] < ecdsa["total_mul_like"]
     # the Schnorr/BIP340 lanes add one pow ladder + one mul each
     pow_muls = (tab - 2) + len(_EULER_DIGITS) + WINDOW_BITS * len(_EULER_DIGITS)
     for algo in ("schnorr", "bip340"):
@@ -153,124 +168,12 @@ def test_roofline_full_model_runs():
         w = r["per_verify"][algo]
         assert w["int32_macs"] > 0
         assert w["vector_int_ops"] > w["int32_macs"]  # carries/folds exist
-        b = r["ideal_sigs_per_s"][algo]
-        assert b["vpu_bound_sigs_s"] > 0 and b["mxu_bound_sigs_s"] > 0
+        assert r["ideal_sigs_per_s"][algo]["vpu_bound_sigs_s"] > 0
     for label, u in r["utilization"].items():
         assert 0.0 < u["vpu_utilization"] < 1.0, label
-        assert 0.0 < u["of_mxu_bound"] < 1.0, label
+    from tpunode.verify.kernel import kernel_modes
 
-
-def test_roofline_affine_op_model_pins():
-    """ISSUE 8: the affine op model's pins — mixed add = 11M + 2 reduced
-    scalings (one full mul under the projective add), batch inversion =
-    67 prefix/suffix/normalize muls + one shared Fermat ladder, and the
-    per-verify assembly recomputed structurally."""
-    from benchmarks.roofline import field_op_model
-    from tpunode.verify.kernel import WINDOW_BITS, WINDOWS
-
-    m = field_op_model("affine", field_reduce="eager", window_bits=4)
-    assert m["point_form"] == "affine"
-    mixed, add, dbl = m["pt_add_mixed"], m["pt_add"], m["pt_double"]
-    assert mixed["mul"] + mixed.get("mul_t", 0) == 11  # RCB'16 Alg 8
-    assert mixed["mul_small_red"] == 2
-    per_add = sum(add.values())
-    per_mixed = sum(mixed.values())
-    per_dbl = sum(dbl.values())
-    assert per_mixed == per_add - 1  # the lever: 1 full mul per window add
-
-    inv = m["structure"]["batch_inversion"]
-    # prefix 13 + suffix 26 + X/Y normalize 28 = 67 muls, plus the scan-
-    # mode Fermat ladder (14 table muls + 64 window muls + 4*64 sqr)
-    assert inv["mul"] == 67 + 14 + 64
-    assert inv["sqr"] == 4 * 64
-
-    tab = 1 << WINDOW_BITS
-    expect = (
-        WINDOWS * 4 * (per_dbl + per_mixed)  # MSM with mixed adds
-        + (tab - 2) * per_add                # q-table build (scan mode)
-        + inv["total_mul_like"]              # batch inversion
-        + tab                                # λ-table β·X
-        + 2 + 3                              # m1/m2 + on-curve
-    )
-    ecdsa = m["per_verify"]["ecdsa"]["total_mul_like"]
-    assert ecdsa == expect
-    proj = field_op_model(
-        "projective", field_reduce="eager", window_bits=4
-    )["per_verify"]["ecdsa"]["total_mul_like"]
-    # affine = projective - 132 cheaper adds + the inversion's cost
-    assert ecdsa == proj - WINDOWS * 4 + inv["total_mul_like"]
-
-
-def test_roofline_point_form_compare_block():
-    """roofline() states the projective-vs-affine arithmetic floors side
-    by side (the ISSUE 8 acceptance's 'restates utilization')."""
-    from benchmarks.roofline import roofline
-
-    r = roofline()
-    pc = r["point_form_compare"]
-    assert set(pc) == {"projective", "affine"}
-    for w in pc.values():
-        assert w["field_muls"] > 0
-        assert w["vector_int_ops"] > 0
-        assert w["vpu_bound_sigs_s"] > 0
-    assert r["kernel_modes"]["point_form"] in ("projective", "affine")
-    # the ECDSA mul totals really are per-form (not one model twice)
-    assert pc["affine"]["field_muls"] != pc["projective"]["field_muls"]
-
-
-def test_roofline_lazy_reduce_model_pins():
-    """ISSUE 12 acceptance: the lazy formulation removes >= 25% of the
-    per-verify carry/fold vector ops vs eager (the reduce_window_compare
-    block), with the mul-like work unchanged — laziness removes carry
-    rounds and reduction tails, never convolutions — and the reduction
-    count itself pinned structurally (counted by EXECUTING the live
-    formulas, so a formula edit moves these on purpose or fails)."""
-    from benchmarks.roofline import field_op_model, roofline
-
-    r = roofline()
-    rc = r["reduce_window_compare"]
-    assert set(rc) == {"eager@w4", "eager@w5", "lazy@w4", "lazy@w5"}
-
-    for wb in (4, 5):
-        eager, lazy = rc[f"eager@w{wb}"], rc[f"lazy@w{wb}"]
-        # same convolution work: the mul-like count is reduce-invariant
-        assert lazy["field_muls"] == eager["field_muls"]
-        # the tentpole lever: >= 25% of the carry/fold vector ops gone
-        drop = 1 - lazy["carry_fold_vector_ops"] / eager["carry_fold_vector_ops"]
-        assert drop >= 0.25, (wb, drop)
-        # fewer reductions, strictly better arithmetic floor
-        assert lazy["reductions"] < eager["reductions"]
-        assert lazy["vpu_bound_sigs_s"] > eager["vpu_bound_sigs_s"]
-
-    # structural reduction pins (projective form, counted live):
-    # eager pays one reduction per mul-like op; the lazy bodies fuse the
-    # per-formula tails — pt_add 14 -> 11, pt_double 9 -> 8,
-    # pt_add_mixed 13 -> 10 paid reductions (mul_small_red's fold counts
-    # as its own reduction; all loose tails).
-    m = field_op_model(field_reduce="lazy", window_bits=4)
-    assert m["structure"]["field_reduce"] == "lazy"
-    assert m["structure"]["window_bits"] == 4
-    def reds(c):
-        return sum(c.get(k, 0) for k in (
-            "mul", "mul_t", "sqr", "sqr_t", "mul_small_red",
-            "reduce_wide", "reduce_wide_loose"))
-    assert reds(m["pt_add"]) == 11
-    assert reds(m["pt_double"]) == 8
-    assert reds(m["pt_add_mixed"]) == 10
-    ec = m["per_verify"]["ecdsa"]
-    assert ec["reductions"] < ec["total_mul_like"]
-    eager_ec = field_op_model(field_reduce="eager", window_bits=4)[
-        "per_verify"]["ecdsa"]
-    assert eager_ec["reductions"] == eager_ec["total_mul_like"]
-
-    # 5-bit windows: 27 rounds over 32-entry tables
-    m5 = field_op_model(window_bits=5)
-    assert m5["structure"]["windows"] == 27
-    assert m5["structure"]["table_entries"] == 32
-    # fewer window rounds -> fewer MSM muls despite the bigger table
-    assert (m5["per_verify"]["ecdsa"]["total_mul_like"]
-            < field_op_model(window_bits=4)["per_verify"]["ecdsa"][
-                "total_mul_like"])
+    assert r["formulation"] == list(kernel_modes())
 
 
 def test_roofline_jaxpr_walk_counts_scans():

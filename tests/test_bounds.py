@@ -1,7 +1,7 @@
 """The static limb-bound tracker (ISSUE 12): the int32-safety audit of
 the field pipeline is CHECKED code — these tests pin that it passes over
-every live formula in both reduce modes and that it fails loudly on a
-deliberately-overflowing chain."""
+every live formula and that it fails loudly on a deliberately-overflowing
+chain."""
 
 import pytest
 
@@ -11,15 +11,14 @@ from tpunode.verify import bounds as B
 from tpunode.verify import field as F
 
 
-def test_audit_passes_live_formulas_both_modes():
-    """The acceptance gate: every live formula body, both reduce
-    disciplines, from the window loop's input bounds — no overflow, and
-    output coordinates stay inside the 2^13 closure the MSM feeds back."""
-    for mode in F.REDUCE_MODES:
-        out = B.audit_formulas(mode)
-        assert set(out) == {"pt_add", "pt_double", "pt_add_mixed"}
-        for name, peak in out.items():
-            assert 0 < peak <= B.COORD_BOUND, (mode, name, peak)
+def test_audit_passes_live_formulas():
+    """The acceptance gate: every live formula body from the window
+    loop's input bounds — no overflow, and output coordinates stay
+    inside the 2^13 closure the MSM feeds back."""
+    out = B.audit_formulas()
+    assert set(out) == {"pt_add", "pt_double"}
+    for name, peak in out.items():
+        assert 0 < peak <= B.COORD_BOUND, (name, peak)
 
 
 def test_overflow_chain_fails_loudly():
@@ -65,13 +64,16 @@ def test_carry_bound_is_sound_numerically():
         assert (np.abs(got) <= np.array(tracked.b)[:, None]).all()
 
 
-def test_assert_formulas_safe_is_cached():
+def test_assert_formulas_safe_is_cached(monkeypatch):
     B._AUDITED.clear()
-    B.assert_formulas_safe("eager")
-    assert "eager" in B._AUDITED
-    marker = B._AUDITED["eager"]
-    B.assert_formulas_safe("eager")  # second call: cached, same object
-    assert B._AUDITED["eager"] is marker
+    B.assert_formulas_safe()
+    assert set(B._AUDITED) == {"pt_add", "pt_double"}
+
+    def boom():
+        raise AssertionError("audited twice")
+
+    monkeypatch.setattr(B, "audit_formulas", boom)
+    B.assert_formulas_safe()  # second call: cached, no replay
 
 
 def test_bval_ops():

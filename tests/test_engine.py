@@ -454,87 +454,3 @@ async def test_rung_failure_fails_over_within_dispatch(monkeypatch):
     async with eng:
         assert await eng.verify(items) == expected
     assert seen[-1] == "oracle"
-
-
-def test_verify_config_point_form_knob():
-    """VerifyConfig.point_form (ISSUE 8) applies the process-wide MSM
-    point form at engine construction; None leaves it alone; an unknown
-    form fails fast."""
-    from tpunode.verify import curve as C
-
-    prev = C.point_form()
-    try:
-        VerifyConfig(backend="cpu", warmup=False, point_form="affine")
-        assert C.point_form() == "affine"
-        VerifyConfig(backend="cpu", warmup=False)  # None: unchanged
-        assert C.point_form() == "affine"
-        VerifyConfig(backend="cpu", warmup=False, point_form="projective")
-        assert C.point_form() == "projective"
-        with pytest.raises(ValueError):
-            VerifyConfig(backend="cpu", warmup=False, point_form="jacobian")
-    finally:
-        C.set_point_form(prev)
-
-
-@pytest.mark.heavy
-@pytest.mark.slow  # two full XLA compiles (~4 min on this box): the
-# tier-1 870s budget is already saturated by the seed suite, so the
-# coalesced-affine acceptance runs in the slow tier (the campaign's
-# zero-mismatch runs in PERF.md carry the tier-1-external evidence)
-@pytest.mark.asyncio
-async def test_engine_coalesced_waiters_affine_bit_identical():
-    """ISSUE 8 acceptance: the COALESCED-waiter path (several
-    submissions merged into one device batch) under the affine point
-    form produces per-waiter verdicts identical to the projective run
-    and the per-item expectations."""
-    from tpunode.verify import curve as C
-
-    prev = C.point_form()
-    items1, exp1 = make_items(3, tamper_every=2)
-    items2, exp2 = make_items(2, tamper_every=1)
-
-    async def run_once() -> tuple:
-        metrics.reset()
-        cfg = VerifyConfig(
-            backend="auto", batch_size=8, device_batch=8, min_tpu_batch=1,
-            max_wait=0.05, warmup=False,
-        )
-        eng = VerifyEngine(cfg)
-        eng._device_state = "ready"  # skip warmup: cpu-jax IS the device
-        async with eng:
-            f1 = asyncio.ensure_future(eng.verify(items1))
-            f2 = asyncio.ensure_future(eng.verify(items2))
-            got1, got2 = await asyncio.gather(f1, f2)
-        assert metrics.get("verify.batches") == 1  # really coalesced
-        return got1, got2
-
-    try:
-        C.set_point_form("affine")
-        aff1, aff2 = await run_once()
-        C.set_point_form("projective")
-        proj1, proj2 = await run_once()
-    finally:
-        C.set_point_form(prev)
-    assert aff1 == proj1 == exp1
-    assert aff2 == proj2 == exp2
-
-
-def test_verify_config_field_formulation_knob():
-    """VerifyConfig.field_mul/field_sqr (ISSUE 4) apply the process-wide
-    limb-product formulation at engine construction, so the first device
-    trace uses the requested mode; None leaves the mode alone."""
-    from tpunode.verify import field as F
-
-    prev = F.field_modes()
-    try:
-        VerifyConfig(backend="cpu", warmup=False,
-                     field_mul="dot_general", field_sqr="mul")
-        assert F.field_modes() == ("dot_general", "mul", prev[2])
-        VerifyConfig(backend="cpu", warmup=False)  # None: unchanged
-        assert F.field_modes() == ("dot_general", "mul", prev[2])
-        VerifyConfig(backend="cpu", warmup=False, field_sqr="half")
-        assert F.field_modes() == ("dot_general", "half", prev[2])
-        VerifyConfig(backend="cpu", warmup=False, field_reduce="lazy")
-        assert F.field_modes() == ("dot_general", "half", "lazy")
-    finally:
-        F.set_field_modes(mul=prev[0], sqr=prev[1], reduce=prev[2])

@@ -13,6 +13,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 
 from tpunode.verify import field as F
+from tpunode.verify import pallas_field as PF
 
 rng = random.Random(2024)
 
@@ -141,49 +142,12 @@ def test_mul_under_vmap():
         assert ints(out[i])[0] % F.P == a * b % F.P
 
 
-# ---------- limb-product formulations (ISSUE 4) ---------------------------
+# ---------- the one limb-product formulation ------------------------------
 
 
-@pytest.fixture
-def restore_modes():
-    prev = F.field_modes()
-    yield
-    F.set_field_modes(mul=prev[0], sqr=prev[1])
-
-
-def test_formulations_bit_identical(restore_modes):
-    """Every (mul, sqr) mode combination must produce BIT-identical limb
-    vectors (not just equal mod p): downstream verdicts are pinned
-    bit-exact against the oracle, so the formulations must be
-    interchangeable mid-pipeline."""
-    a_vals = [rand_fe() for _ in range(16)]
-    b_vals = [rand_fe() for _ in range(16)]
-    la, lb = limbs(*a_vals), limbs(*b_vals)
-    neg = limbs(5) - limbs(b_vals[0])  # negative loose operand
-    F.set_field_modes(mul="shift_add", sqr="half")
-    ref = {
-        "mul": np.asarray(F.mul(la, lb)),
-        "mul_t": np.asarray(F.mul_t(la, lb)),
-        "sqr": np.asarray(F.sqr(la)),
-        "sqr_neg": np.asarray(F.sqr(neg)),
-    }
-    st = np.asarray(F.sqr_t(jnp.asarray(ref["mul"])))
-    for mm in F.MUL_MODES:
-        for sm in F.SQR_MODES:
-            F.set_field_modes(mul=mm, sqr=sm)
-            assert (np.asarray(F.mul(la, lb)) == ref["mul"]).all(), (mm, sm)
-            assert (np.asarray(F.mul_t(la, lb)) == ref["mul_t"]).all(), (mm, sm)
-            assert (np.asarray(F.sqr(la)) == ref["sqr"]).all(), (mm, sm)
-            assert (np.asarray(F.sqr(neg)) == ref["sqr_neg"]).all(), (mm, sm)
-            assert (
-                np.asarray(F.sqr_t(jnp.asarray(ref["mul"]))) == st
-            ).all(), (mm, sm)
-
-
-def test_sqr_matches_mul_exactly(restore_modes):
+def test_sqr_matches_mul_exactly():
     """The dedicated half-product sqr IS mul(a, a): same value, same limb
     representation, including through long chains (bounds hold)."""
-    F.set_field_modes(mul="shift_add", sqr="half")
     v = rand_fe()
     x = limbs(v)
     expect = v
@@ -196,55 +160,18 @@ def test_sqr_matches_mul_exactly(restore_modes):
     assert ints(x) % F.P == expect
 
 
-def test_sqr_t_contract(restore_modes):
+def test_sqr_t_contract():
     """sqr_t under mul_t's contract: pre-tight operands (every limb
     <= 2^13), including sums of two mul outputs (point coordinates)."""
-    for mm in F.MUL_MODES:
-        F.set_field_modes(mul=mm, sqr="half")
-        a, b = rand_fe(), rand_fe()
-        m1 = F.mul(limbs(a), limbs(b))
-        coord = m1 + m1  # sum of 2 mul outputs: <= 2^13
-        got = F.sqr_t(coord)
-        want = (2 * (a * b % F.P)) ** 2 % F.P
-        assert ints(got) % F.P == want, mm
+    a, b = rand_fe(), rand_fe()
+    m1 = F.mul(limbs(a), limbs(b))
+    coord = m1 + m1  # sum of 2 mul outputs: <= 2^13
+    got = F.sqr_t(coord)
+    want = (2 * (a * b % F.P)) ** 2 % F.P
+    assert ints(got) % F.P == want
 
 
-def test_set_field_modes_validates(restore_modes):
-    with pytest.raises(ValueError):
-        F.set_field_modes(mul="nope")
-    with pytest.raises(ValueError):
-        F.set_field_modes(sqr="nope")
-    # a rejected call mutates NOTHING — not even the valid half (a
-    # half-flipped process would silently mislabel every later trace)
-    before = F.field_modes()
-    with pytest.raises(ValueError):
-        F.set_field_modes(mul="dot_general", sqr="nope")
-    assert F.field_modes() == before
-    prev = F.set_field_modes(mul="dot_general")
-    assert prev[0] in F.MUL_MODES and F.mul_mode() == "dot_general"
-    assert F.field_modes() == (F.mul_mode(), F.sqr_mode(), F.reduce_mode())
-    with pytest.raises(ValueError):
-        F.set_field_modes(reduce="nope")
-
-
-def test_env_mode_rejects_typos(monkeypatch):
-    """A mistyped env knob must fail fast, not silently measure the
-    default formulation and label it with the requested one."""
-    monkeypatch.setenv("TPUNODE_FIELD_MUL", "dot-general")
-    with pytest.raises(ValueError):
-        F._env_mode("TPUNODE_FIELD_MUL", F.MUL_MODES, "shift_add")
-    monkeypatch.setenv("TPUNODE_FIELD_MUL", " Dot_General ")
-    assert (
-        F._env_mode("TPUNODE_FIELD_MUL", F.MUL_MODES, "shift_add")
-        == "dot_general"
-    )
-    monkeypatch.delenv("TPUNODE_FIELD_MUL")
-    assert F._env_mode("TPUNODE_FIELD_MUL", F.MUL_MODES, "shift_add") == (
-        "shift_add"
-    )
-
-
-# ---------- lazy-reduction wide API (ISSUE 12) ----------------------------
+# ---------- lazy-reduction wide API ---------------------------------------
 
 
 def _adversarial_operands():
@@ -304,65 +231,152 @@ def test_acc_add_and_loose_reduce_exact():
     assert ints(F.mul_t(loose, loose)) % F.P == want * want % F.P
 
 
-@pytest.fixture
-def restore_reduce():
-    prev = F.reduce_mode()
-    yield
-    F.set_field_modes(reduce=prev)
+# Each wide primitive against Python ints, in BOTH field stacks (the XLA
+# one and the Mosaic-friendly one the chip's kernel runs), on the three
+# operand classes the formulas feed them.  Until PR 29 the second
+# formulation of each was the reference; Python's ints are now.
+
+STACKS = {"field": F, "pallas_field": PF}
 
 
-def test_lazy_formulas_equal_eager_mod_p(restore_reduce):
-    """curve.pt_add / pt_double / pt_add_mixed: the lazy bodies produce
-    the SAME canonical values as the eager bodies on random and
-    adversarial (negative-limb, loose) coordinates — the ISSUE 12
-    bit-identity pin (canonical representations compared bit-exact)."""
-    from tpunode.verify.curve import pt_add, pt_add_mixed, pt_double
+def _operand_pair(kind: str):
+    """Two (limbs, exact int value) operands of one class, 2 lanes each.
+    ``canonical``: nonnegative limbs < 2^11.  ``negative``: differences
+    of canonical values (limbs in ±2^11, negative VALUES too).
+    ``edge``: mul's input contract edge — a reduced product scaled by 8
+    (every limb, the top one included, up to 2^15) and a 3-term sum of
+    mul_small_red outputs (fat non-top limbs)."""
+    a, b, c, d = (rand_fe() for _ in range(4))
+    if kind == "canonical":
+        x, y = limbs(a, b), limbs(c, d)
+    elif kind == "negative":
+        x, y = limbs(3, a) - limbs(b, c), limbs(d, 7) - limbs(a, b)
+    else:
+        m = F.mul(limbs(a, b), limbs(c, d))
+        x = m * 8
+        r = F.mul_small_red(m, 21)
+        y = r + r + r
+    return (x, ints(x)), (y, ints(y))
 
-    def canon_pt(p):
-        return [np.asarray(F.canonical(p[i])) for i in range(3)]
 
-    rng_l = random.Random(99)
-    for _ in range(3):
-        # loose adversarial coords: differences of canonical values
-        coords = []
-        for _ in range(8):
-            x, y = rng_l.getrandbits(256) % F.P, rng_l.getrandbits(256) % F.P
-            coords.append(limbs(x) - limbs(y) + limbs(small := 5))
-        p = [coords[0], coords[1], coords[2]]
-        q = [coords[3], coords[4], coords[5]]
-        q2 = [coords[6], coords[7]]
-        for fn, args in (
-            (pt_add, (p, q)),
-            (pt_double, (p,)),
-            (pt_add_mixed, (p, q2)),
+@pytest.mark.parametrize("kind", ["canonical", "negative", "edge"])
+@pytest.mark.parametrize("stack", sorted(STACKS))
+@pytest.mark.parametrize(
+    "prim", ["mul_wide", "sqr_wide", "acc_add", "reduce_wide",
+             "reduce_wide_loose"],
+)
+def test_wide_primitive_matches_python_ints(prim, stack, kind):
+    ns = STACKS[stack]
+    (x, xv), (y, yv) = _operand_pair(kind)
+    if prim == "mul_wide":
+        # carry rounds and the convolution are value-exact over the
+        # integers, not merely mod p
+        w = ns.mul_wide(x, y)
+        assert w.shape[0] == 2 * F.NLIMBS - 1
+        assert ints(w) == [p * q for p, q in zip(xv, yv)]
+    elif prim == "sqr_wide":
+        w = ns.sqr_wide(x)
+        assert ints(w) == [p * p for p in xv]
+        # the half-product path is mul_wide(x, x) limb for limb
+        assert (np.asarray(w) == np.asarray(ns.mul_wide(x, x))).all()
+    elif prim == "acc_add":
+        w1, w2, w3 = ns.mul_wide(x, y), ns.sqr_wide(x), ns.sqr_wide(y)
+        acc = ns.acc_add(w1, w2, -w3)
+        assert ints(acc) == [
+            p * q + p * p - q * q for p, q in zip(xv, yv)
+        ]
+    else:
+        tail = getattr(ns, prim)
+        bound = 1 << (12 if prim == "reduce_wide" else 13)
+        for w, want in (
+            (ns.mul_wide(x, y), [p * q for p, q in zip(xv, yv)]),
+            # a two-product accumulation, the shape pt_add reduces
+            (
+                ns.acc_add(ns.mul_wide(x, y), ns.sqr_wide(x)),
+                [p * q + p * p for p, q in zip(xv, yv)],
+            ),
         ):
-            eager = fn(*args, reduce="eager")
-            lazy = fn(*args, reduce="lazy")
-            for ce, cl in zip(canon_pt(eager), canon_pt(lazy)):
-                assert (ce == cl).all(), fn.__name__
+            out = tail(w)
+            assert out.shape[0] == F.NLIMBS
+            assert [v % F.P for v in ints(out)] == [v % F.P for v in want]
+            assert np.abs(np.asarray(out)).max() <= bound
 
 
-def test_reduce_env_knob_rejects_typos(monkeypatch):
-    monkeypatch.setenv("TPUNODE_FIELD_REDUCE", "lazyy")
-    with pytest.raises(ValueError):
-        F._env_mode("TPUNODE_FIELD_REDUCE", F.REDUCE_MODES, "eager")
-    monkeypatch.setenv("TPUNODE_FIELD_REDUCE", " Lazy ")
-    assert (
-        F._env_mode("TPUNODE_FIELD_REDUCE", F.REDUCE_MODES, "eager")
-        == "lazy"
-    )
+def _oracle_point(k):
+    from tpunode.verify.ecdsa_cpu import GENERATOR, point_mul
+
+    return point_mul(k, GENERATOR)
 
 
-def test_dot_general_scatter_structure():
-    """The scatter matrices encode exactly the limb convolution: row k
-    selects pairs i + j == k; sqr's carries weight 2 off-diagonal."""
-    m = np.asarray(F._MUL_SCATTER)
-    assert m.shape == (2 * F.NLIMBS - 1, F.NLIMBS * F.NLIMBS)
-    assert m.sum() == F.NLIMBS * F.NLIMBS  # every pair lands exactly once
-    for col, (i, j) in enumerate(F._MUL_PAIRS):
-        assert m[i + j, col] == 1
-    s = np.asarray(F._SQR_SCATTER)
-    assert s.shape == (2 * F.NLIMBS - 1, len(F._SQR_PAIRS))
-    # total weight == 576: the 300 half-products with doubling cover the
-    # full 24x24 product matrix
-    assert s.sum() == F.NLIMBS * F.NLIMBS
+def _loose_projective(pt, lam, rng_l):
+    """A curve point as projective (λx : λy : λ) with LOOSE, partly
+    negative limbs: each coordinate is limbs(v + k) - limbs(k)."""
+    coords = []
+    for v in (pt.x * lam % F.P, pt.y * lam % F.P, lam % F.P):
+        k = rng_l.getrandbits(255)
+        coords.append(limbs(v + k) - limbs(k))  # v + k < 2^264: 24 limbs
+    return coords
+
+
+def _affine(p):
+    from tpunode.verify.ecdsa_cpu import Point
+
+    x, y, z = (ints(F.canonical(p[i])) for i in range(3))
+    if z == 0:
+        return Point(None, None)
+    zi = pow(z, -1, F.P)
+    return Point(x * zi % F.P, y * zi % F.P)
+
+
+@pytest.mark.parametrize("stack", sorted(STACKS))
+def test_formulas_match_oracle_on_loose_operands(stack):
+    """curve.pt_add / pt_double — the one body each — against the
+    oracle's affine arithmetic, on projective representatives with a
+    random Z and loose, negative-limb coordinates (the operands the
+    window loop really feeds them), in both field stacks.  (Until PR 29
+    the eager twin bodies were the reference here.)"""
+    from tpunode.verify.curve import pt_add, pt_double
+    from tpunode.verify.ecdsa_cpu import point_add, point_double
+
+    ns = STACKS[stack]
+    rng_l = random.Random(99)
+    for _ in range(2):
+        a = _oracle_point(rng_l.getrandbits(200) + 1)
+        b = _oracle_point(rng_l.getrandbits(200) + 1)
+        p = _loose_projective(a, rng_l.getrandbits(256) % F.P or 1, rng_l)
+        q = _loose_projective(b, rng_l.getrandbits(256) % F.P or 1, rng_l)
+        assert min(int(np.asarray(c).min()) for c in p + q) < 0
+        assert _affine(pt_add(p, q, F=ns)) == point_add(a, b)
+        assert _affine(pt_double(p, F=ns)) == point_double(a)
+
+
+def test_no_formulation_switch_is_left():
+    """PR 29: one formulation.  tpunode/verify/ reads no TPUNODE_*
+    formulation variable, VerifyConfig has none of the five fields, and
+    the setters are gone."""
+    import dataclasses
+    import pathlib
+
+    import tpunode.verify as V
+    from tpunode.verify import curve, kernel
+    from tpunode.verify.engine import VerifyConfig
+
+    knobs = ("TPUNODE_FIELD_", "TPUNODE_POINT_FORM", "TPUNODE_SELECT16",
+             "TPUNODE_POW_LADDER", "TPUNODE_WINDOW_BITS")
+    for path in pathlib.Path(V.__file__).parent.glob("*.py"):
+        text = path.read_text()
+        for k in knobs:
+            assert k not in text, (path.name, k)
+    fields = {f.name for f in dataclasses.fields(VerifyConfig)}
+    assert not fields & {"field_mul", "field_sqr", "point_form",
+                         "field_reduce", "window_bits"}
+    for mod, names in (
+        (F, ("set_field_modes", "field_modes", "mul_mode", "sqr_mode",
+             "reduce_mode", "_env_mode")),
+        (curve, ("set_point_form", "point_form", "pt_add_mixed")),
+        (kernel, ("set_kernel_modes", "structure_modes", "window_bits",
+                  "windows", "select_mode", "pow_ladder_mode")),
+    ):
+        for n in names:
+            assert not hasattr(mod, n), (mod.__name__, n)
+    assert (kernel.WINDOW_BITS, kernel.WINDOWS) == (4, 33)

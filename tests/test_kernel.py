@@ -60,18 +60,50 @@ def test_pt_add_matches_oracle():
         assert got == point_add(a, b)
 
 
-def test_pt_add_complete_cases():
-    a = rand_point()
-    neg = Point(a.x, F.P - a.y)
-    # P + (-P) = O
-    assert to_affine(pt_add(to_proj(a), to_proj(neg))).infinity
-    # P + O = P ; O + P = P
-    assert to_affine(pt_add(to_proj(a), INFINITY)) == a
-    assert to_affine(pt_add(INFINITY, to_proj(a))) == a
-    # P + P (degenerate for incomplete formulas) = 2P
-    assert to_affine(pt_add(to_proj(a), to_proj(a))) == point_double(a)
-    # O + O = O
-    assert to_affine(pt_add(INFINITY, INFINITY)).infinity
+def _neg(p: Point) -> Point:
+    return Point(p.x, F.P - p.y)
+
+
+_O = Point(None, None)
+
+# The complete-addition cases, one test each and per field stack: the XLA
+# stack (field) and the Mosaic-friendly one the chip's kernel runs
+# (pallas_field), which whole-kernel interpret runs alone reached before.
+_COMPLETE_CASES = {
+    "P+Q": lambda a, b: (a, b, point_add(a, b)),
+    "P+P": lambda a, b: (a, a, point_double(a)),
+    "P+(-P)": lambda a, b: (a, _neg(a), _O),
+    "P+O": lambda a, b: (a, _O, a),
+    "O+P": lambda a, b: (_O, a, a),
+    "O+O": lambda a, b: (_O, _O, _O),
+}
+
+
+def _stack(name):
+    from tpunode.verify import pallas_field as PF
+
+    return {"field": F, "pallas_field": PF}[name]
+
+
+def _same_point(got: Point, want: Point) -> bool:
+    return got.infinity if want.infinity else got == want
+
+
+@pytest.mark.parametrize("stack", ["field", "pallas_field"])
+@pytest.mark.parametrize("case", sorted(_COMPLETE_CASES))
+def test_pt_add_complete_cases(case, stack):
+    p, q, want = _COMPLETE_CASES[case](rand_point(), rand_point())
+    got = to_affine(pt_add(to_proj(p), to_proj(q), F=_stack(stack)))
+    assert _same_point(got, want)
+
+
+@pytest.mark.parametrize("stack", ["field", "pallas_field"])
+@pytest.mark.parametrize("case", ["2P", "2O"])
+def test_pt_double_complete_cases(case, stack):
+    p = rand_point() if case == "2P" else _O
+    want = point_double(p) if case == "2P" else _O
+    got = to_affine(pt_double(to_proj(p), F=_stack(stack)))
+    assert _same_point(got, want)
 
 
 def test_pt_double_matches_oracle():
@@ -328,101 +360,36 @@ def test_acceptance_pows_gated_per_batch():
         assert True in got and False in got  # non-degenerate both ways
 
 
-# ---------- ISSUE 8: affine MSM — mixed add, batch inversion, de-scan ------
-
-
-def test_pt_add_mixed_matches_oracle():
-    """curve.pt_add_mixed (RCB'16 Algorithm 8) against the affine oracle,
-    including the completeness-in-P1 cases the window loop relies on:
-    P1 = O, P1 = P2 (doubling degeneracy), P1 = -P2 (infinity out)."""
-    from tpunode.verify.curve import pt_add_mixed
-
-    def to_aff2(p: Point):
-        return jnp.stack(
-            [jnp.array(F.to_limbs(p.x))[:, None],
-             jnp.array(F.to_limbs(p.y))[:, None]], axis=0)
-
-    for _ in range(2):
-        a, b = rand_point(), rand_point()
-        assert to_affine(pt_add_mixed(to_proj(a), to_aff2(b))) == point_add(a, b)
-    a = rand_point()
-    q2 = to_aff2(a)
-    assert to_affine(pt_add_mixed(to_proj(a), q2)) == point_double(a)
-    neg = Point(a.x, F.P - a.y)
-    assert to_affine(pt_add_mixed(to_proj(neg), q2)).infinity
-    assert to_affine(pt_add_mixed(INFINITY, q2)) == a
-    # negated-entry path (_signed): -Q as (x, -y) loose limbs
-    negq = jnp.stack([q2[0], -q2[1]], axis=0)
-    assert to_affine(pt_add_mixed(to_proj(a), negq)).infinity
-
-
-def test_normalize_q_table_batch_inversion():
-    """The Montgomery-trick batch normalization (prefix/suffix products
-    + one shared Fermat ladder) recovers EXACTLY the affine multiples
-    k*Q for every table entry and lane — pinned against ecdsa_cpu's
-    affine arithmetic."""
-    from tpunode.verify.kernel import _build_q_table, _normalize_q_table
-
-    pts = [rand_point() for _ in range(2)]
-    qx = jnp.stack([jnp.array(F.to_limbs(p.x)) for p in pts], axis=1)
-    qy = jnp.stack([jnp.array(F.to_limbs(p.y)) for p in pts], axis=1)
-    aff = _normalize_q_table(_build_q_table(qx, qy))
-    assert aff.shape == (16, 2, F.NLIMBS, len(pts))
-    for lane, p in enumerate(pts):
-        for k in range(1, 16):
-            exp = point_mul(k, p)
-            x = F.from_limbs(F.canonical(aff[k, 0, :, lane : lane + 1]))
-            y = F.from_limbs(F.canonical(aff[k, 1, :, lane : lane + 1]))
-            assert (x, y) == (exp.x, exp.y), (lane, k)
-
-
-def test_pow_const_modes_exact():
-    """_pow_const under both ladder shapes (scan / de-scanned unroll)
-    equals pow() for both constant exponents; _pow_table is the exact
-    power table."""
-    import numpy as np
-
+def test_pow_const_exact():
+    """_pow_const (the lax.scan ladder) equals pow() for both constant
+    exponents the acceptance tests use."""
     from tpunode.verify import kernel as K
 
     v = rng.getrandbits(256) % F.P
     t = jnp.array(F.to_limbs(v))[:, None]
-    prev = (K.select_mode(), K.pow_ladder_mode())
-    try:
-        # one exponent per mode (crosswise) keeps this at 2 traced
-        # programs — the tier-1 870s budget is seed-saturated
-        for mode, digits, e in (
-            ("scan", K._EULER_DIGITS, (F.P - 1) // 2),
-            ("unroll", K._PM2_DIGITS, F.P - 2),
-        ):
-            K.set_kernel_modes(pow_ladder=mode)
-            got = F.from_limbs(F.canonical(K._pow_const(t, digits)))
-            assert got == pow(v, e, F.P), (mode, hex(e)[:8])
-        table = K._pow_table(t)
-        for k in range(16):
-            assert F.from_limbs(F.canonical(table[k])) == pow(v, k, F.P)
-    finally:
-        K.set_kernel_modes(select=prev[0], pow_ladder=prev[1])
+    for digits, e in (
+        (K._EULER_DIGITS, (F.P - 1) // 2),
+        (K._PM2_DIGITS, F.P - 2),
+    ):
+        got = F.from_limbs(F.canonical(K._pow_const(t, digits)))
+        assert got == pow(v, e, F.P), hex(e)[:8]
 
 
-def test_select_entry_tree_matches_onehot():
-    """The balanced 4-level select tree is entry-for-entry identical to
-    the one-hot select — per-signature (4-D) and constant (3-D) tables,
-    every digit value."""
-    import numpy as np
-
+def test_select_entry_is_a_plain_index():
+    """The balanced 4-level select tree picks table[digit] for every
+    digit value and lane — per-signature (4-D) and constant (3-D)
+    tables."""
     from tpunode.verify import kernel as K
 
     rng2 = np.random.default_rng(42)
     table4 = jnp.asarray(rng2.integers(-100, 100, (16, 3, F.NLIMBS, 16),
                                        dtype=np.int64).astype(np.int32))
-    table3 = jnp.asarray(rng2.integers(-100, 100, (16, 2, F.NLIMBS),
+    table3 = jnp.asarray(rng2.integers(-100, 100, (16, 3, F.NLIMBS),
                                        dtype=np.int64).astype(np.int32))
     digits = jnp.asarray(np.arange(16, dtype=np.int32))
     for table in (table4, table3):
-        tree = np.asarray(K._select_entry_tree(table, digits))
-        onehot = np.asarray(K._select_entry_onehot(table, digits))
-        assert np.array_equal(tree, onehot)
-        # and the tree really is a plain index per lane
+        tree = np.asarray(K._select_entry(table, digits))
+        assert tree.shape == (3, F.NLIMBS, 16)
         for b in range(16):
             want = np.asarray(table[b])
             if table.ndim == 4:
@@ -462,328 +429,3 @@ def test_prepare_batch_empty_native_parity():
         a = np.asarray(getattr(empty_py, name))
         b = np.asarray(getattr(empty_nat, name))
         assert np.array_equal(a, b), name
-
-
-@pytest.mark.slow  # a second full XLA compile (~2 min on CPU): the
-# tier-1 870s budget is seed-saturated — the cheap unit pins above plus
-# the campaign's zero-mismatch XLA run (PERF.md) carry tier-1; this
-# full-program bit-identity check runs in the slow tier
-def test_affine_matches_projective_and_oracle():
-    """ISSUE 8 acceptance: the affine XLA program's verdicts are
-    bit-identical to the projective program's AND the oracle's on a
-    batch covering all three algorithms, degenerate inputs, and an
-    off-curve pubkey (whose garbage table normalization must stay
-    masked)."""
-    from tpunode.verify import curve as C
-    from tpunode.verify.ecdsa_cpu import (
-        bip340_challenge,
-        lift_x,
-        schnorr_challenge,
-        sign_bip340,
-        sign_schnorr,
-        verify_batch_cpu,
-    )
-
-    items = []
-    for i in range(3):
-        priv = rng.getrandbits(256) % CURVE_N or 1
-        pub = point_mul(priv, GENERATOR)
-        z = rng.getrandbits(256)
-        r, s = sign(priv, z, rng.getrandbits(256) % CURVE_N or 1)
-        if i == 1:
-            z ^= 1
-        items.append((pub, z, r, s))
-    priv = 987654321
-    pub = point_mul(priv, GENERATOR)
-    r, s = sign_schnorr(priv, 44, 1717)
-    items.append((pub, schnorr_challenge(r, pub, 44), r, s, "schnorr"))
-    r, s = sign_bip340(priv, 45, 1718)
-    items.append((lift_x(pub.x), bip340_challenge(r, pub.x, 45), r, s,
-                  "bip340"))
-    items.append((Point(5, 7), 1, 2, 3))  # off-curve
-    items.append((None, 1, 2, 3))  # absent pubkey
-    expect = verify_batch_cpu(items)
-    assert True in expect and False in expect
-
-    got_proj = verify_batch_tpu(items, pad_to=8)
-    prev = C.set_point_form("affine")
-    try:
-        got_aff = verify_batch_tpu(items, pad_to=8)
-    finally:
-        C.set_point_form(prev)
-    assert got_proj == expect
-    assert got_aff == expect
-    assert got_aff == got_proj  # bit-identical verdicts
-
-
-@pytest.mark.slow  # compiles a second full XLA program (~2 min on CPU)
-def test_kernel_matches_oracle_dot_general_formulation():
-    """The XLA program under the dot_general limb-product formulation +
-    dedicated sqr (ISSUE 4): verdict parity with the oracle."""
-    from tpunode.verify import field as F
-
-    items, expected = _random_batch(8)
-    prev = F.field_modes()
-    try:
-        F.set_field_modes(mul="dot_general", sqr="half")
-        assert verify_batch_tpu(items, pad_to=8) == expected
-    finally:
-        F.set_field_modes(mul=prev[0], sqr=prev[1])
-
-
-# ---------- ISSUE 12: lazy reduction + window width ------------------------
-
-
-@pytest.fixture
-def restore_issue12_modes():
-    from tpunode.verify import field as F
-    from tpunode.verify import kernel as K
-
-    prev_f = F.field_modes()
-    prev_wb = K.window_bits()
-    yield
-    F.set_field_modes(mul=prev_f[0], sqr=prev_f[1], reduce=prev_f[2])
-    K.set_kernel_modes(window_bits=prev_wb)
-
-
-@pytest.mark.slow  # compiles a second full XLA program (~2 min on CPU)
-def test_kernel_lazy_matches_oracle(restore_issue12_modes):
-    """The XLA program under the lazy-reduction field pipeline, through
-    _verify_device_jit (verify_batch_tpu): verdicts bit-identical to the
-    eager program's and the oracle's."""
-    from tpunode.verify import field as F
-
-    items, expected = _random_batch(8)
-    F.set_field_modes(reduce="lazy")
-    assert verify_batch_tpu(items, pad_to=8) == expected
-
-
-@pytest.mark.slow  # compiles a full XLA program per width (~2 min each)
-def test_kernel_window_bits5_matches_oracle(restore_issue12_modes):
-    """window_bits=5 (27 rounds, 32-entry tables) vs window_bits=4 vs
-    the oracle: bit-identical verdicts."""
-    from tpunode.verify import kernel as K
-
-    items, expected = _random_batch(8)
-    K.set_kernel_modes(window_bits=4)
-    got4 = verify_batch_tpu(items, pad_to=8)
-    K.set_kernel_modes(window_bits=5)
-    got5 = verify_batch_tpu(items, pad_to=8)
-    assert got4 == expected
-    assert got5 == expected
-    assert got4 == got5
-
-
-def test_window5_digits_and_tables(restore_issue12_modes):
-    """Host-side 5-bit structure: digit extraction (including digits
-    that straddle 64-bit word edges — impossible at 4-bit, routine at
-    5), the 32-entry constant tables, and the windows()/bound wiring."""
-    from tpunode.verify import kernel as K
-    from tpunode.verify.ecdsa_cpu import GENERATOR, point_mul
-
-    K.set_kernel_modes(window_bits=5)
-    assert K.windows() == 27 and K.window_bits() == 5
-    rng5 = random.Random(0x5B175)
-    vals = [rng5.getrandbits(5 * 27) for _ in range(32)] + [0, 1, (1 << 135) - 1]
-    arr = K._ints_to_digits_np(vals)
-    assert arr.shape == (len(vals), 27)
-    for i, v in enumerate(vals):
-        assert list(arr[i]) == K._digits_base16(v), v
-        # digits reconstruct the value exactly (MSB-first base-32)
-        acc = 0
-        for d in arr[i]:
-            acc = (acc << 5) | int(d)
-        assert acc == v
-    g, lg, g_aff, lg_aff = K.window_tables()
-    assert g.shape == (32, 3, F.NLIMBS) and g_aff.shape == (32, 2, F.NLIMBS)
-    for k in (1, 2, 17, 31):
-        pt = point_mul(k, GENERATOR)
-        assert F.from_limbs(g[k, 0]) == pt.x
-        assert F.from_limbs(g[k, 1]) == pt.y
-    lam17 = point_mul(17 * K.LAMBDA % CURVE_N, GENERATOR)
-    assert F.from_limbs(lg[17, 0]) == lam17.x
-
-
-def test_window_bits_knob_validation_and_cache_key(restore_issue12_modes):
-    """set_kernel_modes validates window_bits, the ISSUE 13 native w5
-    path closes the PR 12 gap (``native=True`` no longer raises at
-    5-bit with a current library; only a STALE pre-w5 .so falls back to
-    Python — and then ``native=True`` still fails loudly rather than
-    silently down-grading), and both knobs ride the jit cache key."""
-    from tpunode.verify import cpu_native as CN
-    from tpunode.verify import field as F2
-    from tpunode.verify import kernel as K
-
-    with pytest.raises(ValueError):
-        K.set_kernel_modes(window_bits=6)
-    before = K.kernel_modes()
-    K.set_kernel_modes(window_bits=5)
-    assert K.kernel_modes() != before
-    assert K.kernel_modes()[-1] == 5
-    assert K.structure_modes()[-1] == 5
-    nv = CN.load_native_verifier()
-    if nv is not None and nv.supports_window_bits(5):
-        # ISSUE 13 acceptance: native=True works at w5 on a current lib
-        prep = K.prepare_batch([], native=True)
-        assert prep.count == 0 and prep.d1a.shape[0] == 27
-    F2.set_field_modes(reduce="lazy")
-    assert "lazy" in K.kernel_modes()
-
-
-def test_window_bits_stale_native_lib_falls_back(
-    restore_issue12_modes, monkeypatch
-):
-    """A pre-w5 libsecp_cpu.so (no ``secp_prepare_batch_w`` symbol):
-    auto prep quietly takes the Python path at 5-bit, ``native=True``
-    raises loudly, and the binding itself refuses the width."""
-    from tpunode.verify import cpu_native as CN
-    from tpunode.verify import kernel as K
-
-    nv = CN.load_native_verifier()
-    if nv is None:
-        pytest.skip("native verifier unavailable")
-    K.set_kernel_modes(window_bits=5)
-    monkeypatch.setattr(type(nv), "supports_window_bits",
-                        lambda self, wb: wb == 4)
-    items, _ = _random_batch(2)
-    prep = K.prepare_batch(items, pad_to=8)  # auto: silent Python path
-    assert prep.d1a.shape == (27, 8)
-    with pytest.raises(RuntimeError, match="window_bits=5"):
-        K.prepare_batch(items, native=True)
-    with pytest.raises(RuntimeError, match="window_bits=5"):
-        nv.prepare_batch_arrays(
-            b"", b"", b"", b"", b"", b"", 0, 0, window_bits=5
-        )
-
-
-def test_native_w5_prep_bit_identical_to_python(restore_issue12_modes):
-    """ISSUE 13 satellite acceptance: the native 5-bit batch prep
-    (word-straddling digit extraction in C++) is bit-identical to the
-    Python ``_ints_to_digits_np`` layout over every PreparedBatch field
-    — ECDSA + both Schnorr variants + invalid/missing lanes, tuple AND
-    raw paths — and the width-mismatch dispatch guard covers batches
-    prepped natively."""
-    import numpy as np
-
-    from tpunode.verify import cpu_native as CN
-    from tpunode.verify import kernel as K
-    from tpunode.verify.raw import pack_items
-
-    from tpunode.verify.ecdsa_cpu import (
-        bip340_challenge,
-        lift_x,
-        schnorr_challenge,
-        sign_bip340,
-        sign_schnorr,
-    )
-
-    nv = CN.load_native_verifier()
-    if nv is None or not nv.supports_window_bits(5):
-        pytest.skip("w5-capable native library unavailable")
-    items, _ = _random_batch(24)
-    for i in range(12):  # both Schnorr variants exercise the u1/u2 path
-        priv = rng.getrandbits(256) % CURVE_N or 1
-        pub = point_mul(priv, GENERATOR)
-        m = rng.getrandbits(256)
-        if i % 2:
-            r, s = sign_schnorr(priv, m, rng.getrandbits(256))
-            items.append((pub, schnorr_challenge(r, pub, m), r, s, "schnorr"))
-        else:
-            r, s = sign_bip340(priv, m, rng.getrandbits(256))
-            items.append(
-                (lift_x(pub.x), bip340_challenge(r, pub.x, m), r, s, "bip340")
-            )
-    items.append((None, 1, 1, 1))  # missing pubkey: host_valid False
-    items.append((GENERATOR, 5, 0, 7))  # r=0: invalid by inspection
-    fields = (
-        "d1a", "d1b", "d2a", "d2b", "n1a", "n1b", "n2a", "n2b",
-        "qx", "qy", "r1", "r2", "r2_valid", "host_valid",
-        "schnorr", "bip340",
-    )
-    K.set_kernel_modes(window_bits=5)
-    pn = K.prepare_batch(items, pad_to=48, native=True)
-    pp = K.prepare_batch(items, pad_to=48, native=False)
-    assert pn.d1a.shape == (27, 48)
-    for f in fields:
-        assert np.array_equal(
-            np.asarray(getattr(pn, f), dtype=np.int64),
-            np.asarray(getattr(pp, f), dtype=np.int64),
-        ), f"w5 native/python diverge on {f}"
-    pr = K.prepare_batch_raw(pack_items(items), pad_to=48)
-    for f in fields:
-        assert np.array_equal(
-            np.asarray(getattr(pr, f), dtype=np.int64),
-            np.asarray(getattr(pp, f), dtype=np.int64),
-        ), f"w5 raw-native/python diverge on {f}"
-    # the width-mismatch guard covers NATIVE-prepped batches too: a w5
-    # native prep dispatched after the global flips back must raise
-    K.set_kernel_modes(window_bits=4)
-    with pytest.raises(RuntimeError, match="window"):
-        K._dispatch_prep(pn)
-
-
-def test_window_flip_between_prep_and_dispatch_raises(restore_issue12_modes):
-    """window_bits is the one knob that changes HOST DATA layout: a
-    batch prepped at one width dispatched after the global flips must
-    raise loudly (review r12 — the silent alternative is wrong verdicts,
-    since the window loop takes its trip count from the data but its
-    doubling count from the global)."""
-    from tpunode.verify import kernel as K
-
-    K.set_kernel_modes(window_bits=4)
-    items, _ = _random_batch(2)
-    prep = K.prepare_batch(items, pad_to=8)
-    K.set_kernel_modes(window_bits=5)
-    with pytest.raises(RuntimeError, match="window"):
-        K._dispatch_prep(prep)
-
-
-def test_select_tree_handles_32_entries():
-    """The shared select-tree fold generalizes to 32 entries (5 levels)
-    and stays identical to the one-hot select."""
-    import numpy as np
-
-    from tpunode.verify.kernel import select_tree16
-
-    rng32 = np.random.default_rng(5)
-    entries = [jnp.asarray(rng32.integers(0, 100, size=(3, 4)).astype(np.int32))
-               for _ in range(32)]
-    digits = jnp.asarray(np.array([0, 7, 19, 31], dtype=np.int32))
-    out = np.asarray(select_tree16(entries, digits))
-    for lane, d in enumerate([0, 7, 19, 31]):
-        assert (out[:, lane] == np.asarray(entries[d])[:, lane]).all()
-
-
-def test_mode_flip_changes_the_traced_program():
-    """Flipping the formulation must change what a fresh trace of
-    verify_core CONTAINS (dot_general MACs present vs absent) — and the
-    jitted entry points carry field_modes as a static cache key, because
-    distinct jax.jit wrappers of one function SHARE a trace cache (a
-    per-mode dict of wrappers silently reuses the first formulation;
-    found the hard way in this PR's A/B measurements)."""
-    import numpy as np
-
-    from benchmarks.roofline import count_int_ops
-    from tpunode.verify import field as F
-
-    a = jnp.asarray(np.ones((F.NLIMBS, 4), np.int32))
-    b = jnp.asarray(np.full((F.NLIMBS, 4), 2, np.int32))
-    prev = F.field_modes()
-    try:
-        F.set_field_modes(mul="shift_add", sqr="half")
-        shift = count_int_ops(F.mul, a, b)
-        F.set_field_modes(mul="dot_general", sqr="half")
-        dot = count_int_ops(F.mul, a, b)
-    finally:
-        F.set_field_modes(mul=prev[0], sqr=prev[1])
-    assert shift.get("mac", 0) == 0  # pure VPU shift-add
-    # the 47x576 contraction: 576 MACs per output limb per lane
-    assert dot.get("mac", 0) == (2 * F.NLIMBS - 1) * F.NLIMBS * F.NLIMBS
-    # and the jitted entries key their caches on the modes (static args)
-    import inspect
-
-    from tpunode.verify import kernel as K
-    from tpunode.verify import pallas_kernel as PK
-
-    assert "field_modes" in inspect.signature(K._verify_device_jit).parameters
-    assert "field_modes" in inspect.signature(PK._verify_blocked_jit).parameters

@@ -300,6 +300,32 @@ async def test_receipts_bind_batch_verdicts_modes_and_rung(tmp_path):
     assert res["ok"] is True and res["records"] == 1
 
 
+@pytest.mark.asyncio
+async def test_receipt_modes_are_the_pinned_literal(tmp_path):
+    """PR 29 left ONE kernel formulation; a receipt keeps binding it with
+    the bytes receipts carried while it was selectable (the defaults'
+    tuple), so logs written before and after audit alike."""
+    import json
+
+    import tpunode.verify.kernel  # noqa: F401 — the device kernel is in play
+
+    pinned = ["shift_add", "half", "lazy", "projective", "tree", "scan", 4]
+    d = str(tmp_path / "receipts")
+    receipts = ReceiptLog(d)
+    async with ServeServer(
+        StubEngine(), _tenants(("t", "bulk", {})), port=0, receipts=receipts
+    ) as srv:
+        rep = await _rpc(srv.port, _frame("t", _rows(2)))
+    assert rep["ok"] is True
+    (rec,) = receipts.records(0, 10)
+    assert rec["modes"] == pinned
+    assert json.dumps(rec["modes"]) == (
+        '["shift_add", "half", "lazy", "projective", "tree", "scan", 4]'
+    )
+    receipts.close()
+    assert audit(d)["ok"] is True
+
+
 def test_tenant_registry_is_bounded():
     """The ``tenant=`` label source contract: names validated, unique,
     and hard-capped at MAX_TENANTS."""

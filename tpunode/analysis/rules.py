@@ -1011,99 +1011,6 @@ def _raw_lock(ctx: FileContext) -> None:
             )
 
 
-# --- jit-cache-key (ISSUE 18) -------------------------------------------------
-
-# The formulation-mode accessors (tpunode/verify/modes.py): any compiled
-# wrapper whose behaviour depends on the active modes must key on one of
-# these — PR 4's shared-trace-cache bug was a jit cache that silently
-# served one mode's trace to another.
-_MODE_FNS = frozenset({"kernel_modes", "field_modes", "structure_modes"})
-
-
-def _static_argnames_have_modes(call: ast.Call) -> "bool | None":
-    """True/False when the call carries static_argnames (do they include
-    a mode tuple?); None when neither static kwarg is present."""
-    saw = None
-    for kw in call.keywords:
-        if kw.arg == "static_argnums":
-            return True  # positional static key — accepted as-is
-        if kw.arg == "static_argnames":
-            saw = False
-            names: list = []
-            if isinstance(kw.value, (ast.Tuple, ast.List)):
-                names = [_literal(el) for el in kw.value.elts]
-            else:
-                names = [_literal(kw.value)]
-            if any(n is not None and "modes" in n for n in names):
-                saw = True
-    return saw
-
-
-def _scope_calls_mode_fn(ctx: FileContext, fstack: list) -> bool:
-    for f in fstack:
-        for sub in ast.walk(f):
-            if isinstance(sub, ast.Call):
-                q = ctx.resolve(sub.func)
-                if q is not None and q.rsplit(".", 1)[-1] in _MODE_FNS:
-                    return True
-    return False
-
-
-@rule(
-    "jit-cache-key",
-    "jax.jit wrapper in tpunode/verify/ is not keyed on the formulation "
-    "modes (thread kernel_modes()/field_modes()/structure_modes() "
-    "through static_argnums/static_argnames, or key the surrounding "
-    "cache dict on it)",
-)
-def _jit_cache_key(ctx: FileContext) -> None:
-    """PR 4's discovery, enforced: two formulations tracing through one
-    jit cache silently serve each other's compilations.  Every
-    ``jax.jit(...)`` (or ``partial(jax.jit, ...)``) in the verify layer
-    must either carry the mode tuple as a static argument or live in a
-    scope that computes its cache key from a mode accessor."""
-    path = ctx.path.replace(os.sep, "/")
-    if "verify" not in path.split("/") and not path.startswith("<"):
-        return  # in-memory sources ("<...>") stay lintable for tests
-
-    def visit(node: ast.AST, fstack: list) -> None:
-        for child in ast.iter_child_nodes(node):
-            stack = fstack
-            if isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                stack = fstack + [child]
-            elif isinstance(child, ast.Call):
-                check(child, fstack)
-            visit(child, stack)
-
-    def check(call: ast.Call, fstack: list) -> None:
-        qual = ctx.resolve(call.func)
-        if qual == "jax.jit":
-            jit = call
-        elif (
-            qual is not None
-            and qual.rsplit(".", 1)[-1] == "partial"
-            and call.args
-            and ctx.resolve(call.args[0]) == "jax.jit"
-        ):
-            jit = call
-        else:
-            return
-        static = _static_argnames_have_modes(jit)
-        if static is True:
-            return
-        if static is None and _scope_calls_mode_fn(ctx, fstack):
-            return
-        ctx.report(
-            "jit-cache-key", jit,
-            "jax.jit wrapper is not keyed on the formulation modes "
-            "(add the mode tuple to static_argnames/static_argnums or "
-            "key the enclosing cache on kernel_modes()/field_modes()/"
-            "structure_modes())",
-        )
-
-    visit(ctx.tree, [])
-
-
 # --- env-knob-doc (ISSUE 18) --------------------------------------------------
 
 _ENV_KNOB_RE = re.compile(r"^TPUNODE_[A-Z0-9_]+$")

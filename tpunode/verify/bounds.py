@@ -1,4 +1,4 @@
-"""Static per-limb bound tracker for the field pipeline (ISSUE 12).
+"""Static per-limb bound tracker for the field pipeline.
 
 field.py's int32-safety story used to live in docstrings ("every
 anti-diagonal sum stays below 2^31", "|non-top limb| <= 2^19", ...) and
@@ -10,13 +10,13 @@ convolutions, including the lazy wide-accumulator API), and every
 multiply/accumulate asserts int32 headroom as it happens.
 
 :func:`audit_formulas` replays the live RCB formulas (curve.pt_add /
-pt_double / pt_add_mixed — via their ``F=`` namespace parameter, the same
+pt_double — via their ``F=`` namespace parameter, the same
 seam the Pallas kernel and the roofline counter use) from the window
 loop's input bounds and additionally checks CLOSURE: output coordinate
 bounds must fit back inside the input contract, because the MSM feeds
 them back in every window.  :func:`assert_formulas_safe` is the
 trace-time hook — kernel.verify_core and the Pallas kernel call it (it
-is cached per reduce mode and costs microseconds), so a formula edit
+is cached and costs microseconds), so a formula edit
 that violates int32 headroom fails the very first trace with a
 :class:`BoundOverflow` naming the op, not a silent wrong verdict on
 device.
@@ -30,8 +30,8 @@ are conservative but exact integer arithmetic:
 * ``x >> RADIX`` -> bound (B + MASK) >> RADIX (arithmetic shift of a
   negative rounds toward -inf);
 * convolution    -> exact anti-diagonal sums of pairwise bound products
-  (identical for the shift_add / dot_general / half-product sqr
-  formulations — they compute the same sums, so ONE audit covers all).
+  (the full and the half-product squaring convolutions compute the same
+  sums; the half-product's doubled partials are checked besides).
 """
 
 from __future__ import annotations
@@ -143,8 +143,7 @@ def _pad(x: BVal, n: int) -> BVal:
 
 
 def _conv(a: BVal, b: BVal, sqr: bool = False) -> BVal:
-    """Anti-diagonal sums of pairwise bound products — the bound of every
-    limb-product formulation (they all compute these sums).  ``sqr``
+    """Anti-diagonal sums of pairwise bound products.  ``sqr``
     additionally checks the half-product path's DOUBLED cross partials
     (2*a_i*a_j must fit int32 individually, not just the sums)."""
     n = len(a.b)
@@ -276,24 +275,19 @@ def _coord_point(bound: int = COORD_BOUND) -> list:
     return [c, c, c]
 
 
-def audit_formulas(reduce: "str | None" = None) -> dict:
-    """Replay the live pt_add / pt_double / pt_add_mixed bodies (the
-    ACTIVE reduce mode, or ``reduce`` explicitly) from the window loop's
+def audit_formulas() -> dict:
+    """Replay the live pt_add / pt_double bodies from the window loop's
     input bounds; raise :class:`BoundOverflow` if any step can exceed
     int32 or an output coordinate bound escapes the COORD_BOUND closure
     the MSM relies on.  Returns the per-formula peak output bounds."""
-    from .curve import pt_add, pt_add_mixed, pt_double
+    from .curve import pt_add, pt_double
 
     bf = BoundField()
     p = _coord_point()
-    # mixed q: canonical table entries (<= 2^11), possibly negated — but
-    # lazy tables are reduce outputs (<= 2^12); take the looser bound
-    q_aff = [BVal.uniform(1 << 12), BVal.uniform(1 << 12)]
     out = {}
     for name, res in (
-        ("pt_add", pt_add(p, p, F=bf, reduce=reduce)),
-        ("pt_double", pt_double(p, F=bf, reduce=reduce)),
-        ("pt_add_mixed", pt_add_mixed(p, q_aff, F=bf, reduce=reduce)),
+        ("pt_add", pt_add(p, p, F=bf)),
+        ("pt_double", pt_double(p, F=bf)),
     ):
         peak = max(c.max() for c in res)
         if peak > COORD_BOUND:
@@ -308,10 +302,9 @@ def audit_formulas(reduce: "str | None" = None) -> dict:
 _AUDITED: dict = {}
 
 
-def assert_formulas_safe(reduce: "str | None" = None) -> None:
-    """Trace-time hook: audit the live formulas once per reduce mode (a
-    cached no-op after the first call).  Raises BoundOverflow — failing
-    the trace — when a formula edit breaks int32 headroom."""
-    mode = reduce or F.reduce_mode()
-    if mode not in _AUDITED:
-        _AUDITED[mode] = audit_formulas(mode)
+def assert_formulas_safe() -> None:
+    """Trace-time hook: audit the live formulas once (a cached no-op
+    after the first call).  Raises BoundOverflow — failing the trace —
+    when a formula edit breaks int32 headroom."""
+    if not _AUDITED:
+        _AUDITED.update(audit_formulas())

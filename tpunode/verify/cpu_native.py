@@ -79,34 +79,6 @@ class NativeVerifier:
             u8,  # bip340
             ctypes.c_int,  # nthreads
         ]
-        # Width-aware prep (ISSUE 13 satellite: 5-bit digit layout).
-        # Probe rather than require — a stale libsecp_cpu.so without the
-        # symbol keeps the 4-bit fast path, and kernel.py falls back to
-        # Python prep at w5.
-        try:
-            prep_w = self._lib.secp_prepare_batch_w
-        except AttributeError:
-            prep_w = None
-        self._prep_w = prep_w
-        if prep_w is not None:
-            prep_w.restype = ctypes.c_int
-            prep_w.argtypes = (
-                self._lib.secp_prepare_batch.argtypes
-                + [ctypes.c_int]  # window_bits
-            )
-
-    #: windows per supported window width (mirrors kernel.py's table)
-    _WINDOWS_BY_BITS = {4: 33, 5: 27}
-
-    def supports_window_bits(self, window_bits: int) -> bool:
-        """Can this library emit the given digit layout?  4-bit always;
-        5-bit needs the ``secp_prepare_batch_w`` symbol (ISSUE 13 — a
-        stale .so predating it preps w5 batches in Python instead)."""
-        if window_bits == 4:
-            return True
-        return window_bits in self._WINDOWS_BY_BITS and (
-            self._prep_w is not None
-        )
 
     def prepare_batch_arrays(
         self,
@@ -119,22 +91,14 @@ class NativeVerifier:
         count: int,
         size: int,
         nthreads: int = 0,
-        window_bits: int = 4,
     ):
         """Fill PreparedBatch arrays natively (see kernel.prepare_batch's
         fast path).  Returns the dict of limb-major numpy arrays.  Raises
         on a GLV bound violation (structurally impossible for in-range
-        scalars; nonzero means a bug, never a bad signature) and on an
-        unsupported ``window_bits`` (callers gate on
-        :meth:`supports_window_bits`)."""
+        scalars; nonzero means a bug, never a bad signature)."""
         import numpy as np
 
-        if not self.supports_window_bits(window_bits):
-            raise RuntimeError(
-                f"native prep does not support window_bits={window_bits} "
-                "(stale native/build/libsecp_cpu.so? run `make -C native`)"
-            )
-        nwin = self._WINDOWS_BY_BITS[window_bits]
+        nwin = 33  # kernel.WINDOWS: 4-bit digits of the GLV half-scalars
         out = {
             "d1a": np.zeros((nwin, size), np.int32),
             "d1b": np.zeros((nwin, size), np.int32),
@@ -150,22 +114,16 @@ class NativeVerifier:
             "schnorr": np.zeros(size, np.uint8),
             "bip340": np.zeros(size, np.uint8),
         }
-        args = (
+        bad = self._lib.secp_prepare_batch(
             px, py, z, r, s, present, count, size,
             out["d1a"], out["d1b"], out["d2a"], out["d2b"], out["negs"],
             out["qx"], out["qy"], out["r1"], out["r2"],
             out["r2_valid"], out["host_valid"], out["schnorr"],
             out["bip340"], nthreads,
         )
-        if self._prep_w is not None:
-            bad = self._prep_w(*args, window_bits)
-        else:
-            bad = self._lib.secp_prepare_batch(*args)
         if bad:
             raise ValueError(
                 f"native prep: {bad} GLV half-scalars out of range"
-                if bad > 0
-                else f"native prep rejected window_bits={window_bits}"
             )
         return out
 

@@ -28,68 +28,16 @@ __all__ = [
     "B3",
     "INFINITY",
     "pt_add",
-    "pt_add_mixed",
     "pt_double",
-    "pt_select",
     "make_point",
     "is_infinity",
-    "POINT_FORMS",
-    "point_form",
-    "set_point_form",
 ]
 
 B3 = 21  # 3 * b for y^2 = x^3 + 7
 
 
-# ---------- point-form knob (ISSUE 8) --------------------------------------
-#
-# Like field.py's limb-product formulation knobs: process-global, read at
-# TRACE time, so every jitted program that embeds the MSM keys its jit
-# cache on kernel.kernel_modes() (which includes point_form()) and a flip
-# retraces instead of silently keeping the old formulation.
-#
-# "projective" (default): per-signature Q/λQ window tables stay projective
-# (3 coords), window additions use the full 12M+2 RCB complete add.
-# "affine": the tables are batch-normalized to affine (2 coords) with one
-# Montgomery-trick inversion per lane (kernel._affine_tables), window
-# additions use the cheaper 11M+2 complete MIXED add below, and table
-# selects move a third less data.
-
-POINT_FORMS = ("projective", "affine")
-
-_POINT_FORM = F._env_mode("TPUNODE_POINT_FORM", POINT_FORMS, "projective")
-
-
-def point_form() -> str:
-    """Active MSM point formulation: "projective" | "affine"."""
-    return _POINT_FORM
-
-
-def set_point_form(form: "str | None") -> str:
-    """Select the MSM point form process-wide; returns the previous form
-    (None is a no-op, mirroring field.set_field_modes).  Programs traced
-    before the flip keep their form until their owner re-traces — which
-    every in-repo dispatch site does, because all of them key on
-    :func:`tpunode.verify.kernel.kernel_modes`."""
-    global _POINT_FORM
-    if form is None:
-        return _POINT_FORM
-    if form not in POINT_FORMS:
-        raise ValueError(f"point form {form!r} not in {POINT_FORMS}")
-    prev = _POINT_FORM
-    _POINT_FORM = form
-    return prev
-
-
 def make_point(x: jnp.ndarray, y: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack([x, y, z], axis=0)
-
-
-# The formulas read the process-global reduction discipline (ISSUE 12)
-# at trace time unless the caller pins it via their ``reduce=`` kwarg;
-# module-level binding because the ``F`` name is shadowed by the
-# namespace parameter inside the formula bodies.
-_active_reduce = F.reduce_mode
 
 
 def _mk(F_ns):
@@ -108,79 +56,27 @@ def is_infinity(p: jnp.ndarray) -> jnp.ndarray:
     return F.is_zero(p[2])
 
 
-def pt_select(mask: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Branch-free ``mask ? a : b`` over whole points."""
-    return jnp.where(mask, a, b)
-
-
-def pt_add(p: jnp.ndarray, q: jnp.ndarray, F=F, reduce=None) -> jnp.ndarray:
+def pt_add(p: jnp.ndarray, q: jnp.ndarray, F=F) -> jnp.ndarray:
     """Complete addition (RCB'16 Algorithm 7, a = 0): 12 muls, no exceptions.
 
-    ``F`` is the field-arithmetic namespace (mul/mul_t/mul_small_red with
-    field.py's contracts); the Pallas kernel passes its Mosaic-friendly
-    implementation so both device paths share these audited formulas.
-    ``reduce`` pins the reduction discipline ("eager"/"lazy", ISSUE 12) —
-    None reads the process-global :func:`field.reduce_mode` at trace
-    time.  The two bodies produce different limb representations but
-    identical values mod p (pinned in tests/test_field.py); int32 safety
-    of BOTH is checked by tpunode.verify.bounds at trace time.
-
-    Limb-bound audit against field.mul's contract (|non-top limb| <= 2^19,
-    |top limb| <= 2^15, pairwise top(a)*top(b) <= 2^30): every mul operand
-    below is a mul output (every limb <= 2^12), a 2-3-term sum of mul
-    outputs (<= 2^13.6, top included), or a mul_small_red result (non-top
-    <= 2^19, top <= 2^12) — the raw B3 scalings that used to exceed the
-    top-limb bound now go through mul_small_red.
-    """
-    if (reduce or _active_reduce()) == "lazy":
-        return _pt_add_lazy(p, q, F)
-    X1, Y1, Z1 = p[0], p[1], p[2]
-    X2, Y2, Z2 = q[0], q[1], q[2]
-    mul = F.mul
-
-    # coords are <= 2^13 (sums of <= 2 mul outputs): inside mul_t's contract
-    t0 = F.mul_t(X1, X2)
-    t1 = F.mul_t(Y1, Y2)
-    t2 = F.mul_t(Z1, Z2)
-    t3 = mul(X1 + Y1, X2 + Y2)
-    t3 = t3 - (t0 + t1)
-    t4 = mul(Y1 + Z1, Y2 + Z2)
-    t4 = t4 - (t1 + t2)
-    t5 = mul(X1 + Z1, X2 + Z2)
-    t5 = t5 - (t0 + t2)  # = X1*Z2 + X2*Z1
-    t0_3 = t0 + t0 + t0  # 3*X1*X2
-    t2_b3 = F.mul_small_red(t2, B3)  # reduced: keeps z3/t1m inside mul's contract
-    z3 = t1 + t2_b3
-    t1m = t1 - t2_b3
-    y3 = F.mul_small_red(t5, B3)  # reduced: y3 feeds two muls below
-    x3 = mul(t4, y3)
-    t2b = mul(t3, t1m)
-    x3 = t2b - x3
-    y3 = mul(y3, t0_3)
-    t1b = mul(t1m, z3)
-    y3 = t1b + y3
-    t0b = mul(t0_3, t3)
-    z3 = mul(z3, t4)
-    z3 = z3 + t0b
-    return _mk(F)(x3, y3, z3)
-
-
-def _pt_add_lazy(p: jnp.ndarray, q: jnp.ndarray, F=F) -> jnp.ndarray:
-    """The lazy-reduction body of :func:`pt_add` (ISSUE 12): same RCB
-    algebra, three fused carry/fold levers —
+    ``F`` is the field-arithmetic namespace (field.py's wide API:
+    mul_wide/mul_t_wide/acc_add/reduce_wide_loose/tighten/mul_small_red
+    with field.py's contracts); the Pallas kernel passes its
+    Mosaic-friendly implementation so both device paths share this one
+    body.  Lazy reduction, three fused carry/fold levers —
 
     * the three output coordinates, each a ±-sum of two products,
       accumulate as unreduced 47-limb wides and pay ONE reduction each
-      (3 reductions saved);
+      (3 reductions saved over a reduce-per-product body);
     * every reduction is the LOOSE tail (``reduce_wide_loose``: one
       carry round cheaper; outputs <= ~2^12.3, inside every consumer's
       contract);
     * shared tail operands get ONE hoisted carry round each instead of
       a fresh pair inside every full mul (6 rounds instead of 12).
 
-    Values differ limb-wise from the eager body's but are equal mod p;
-    the window loop's verdicts are bit-identical.  int32 safety and the
-    2^13 coordinate closure are checked by tpunode.verify.bounds."""
+    int32 safety and the 2^13 coordinate closure (inputs are sums of at
+    most two reduced products; outputs must fit back in) are CHECKED at
+    trace time by tpunode.verify.bounds, not argued here."""
     X1, Y1, Z1 = p[0], p[1], p[2]
     X2, Y2, Z2 = q[0], q[1], q[2]
     rw = F.reduce_wide_loose
@@ -209,130 +105,15 @@ def _pt_add_lazy(p: jnp.ndarray, q: jnp.ndarray, F=F) -> jnp.ndarray:
     return _mk(F)(x3, y3, z3)
 
 
-def pt_add_mixed(p: jnp.ndarray, q: jnp.ndarray, F=F, reduce=None) -> jnp.ndarray:
-    """Complete MIXED addition (RCB'16 Algorithm 8, a = 0): 11 muls + 2
-    reduced scalings — one full mul cheaper than :func:`pt_add` because
-    ``q`` is affine: a 2-coordinate ``(x2, y2)`` stack with Z2 = 1
-    implicit (the ISSUE 8 affine window tables), so t2 = Z1*Z2
-    degenerates to Z1 and the X1*Z2/Y1*Z2 cross terms to X1/Y1.
-
-    Complete in ``p`` (infinity, p = ±q all exact) but ``q`` CANNOT be
-    the point at infinity — affine coordinates can't represent it.  The
-    window loops handle the digit-0 (infinity) table entry by keeping
-    the accumulator unchanged via a branch-free select instead
-    (kernel.py / pallas_kernel.py), so the formula never sees it.
-
-    Limb-bound audit (same contracts as pt_add's): p's coords are <= 2^13
-    (sums of <= 2 mul outputs), q's are mul outputs or canonical table
-    constants (<= 2^12, possibly negated — sign-safe throughout).
-    mul_t legs: X1*x2, Y1*y2, y2*Z1, x2*Z1 all <= 2^13 x 2^12.  The
-    mul legs take sums <= 2^14 (non-top <= 2^19 trivially; pairwise
-    top*top <= 2^27 < 2^30).  mul_small_red on Z1 (limbs <= 2^13):
-    value*21 < 2^271 so non-top <= 2^11 + 2^11*2^7 <= 2^18.1 — z3/t1m
-    sums stay inside mul's |non-top| <= 2^19 input contract.
-
-    ``reduce`` as in :func:`pt_add`: the lazy body fuses the same three
-    output accumulations and hoists the shared-operand carry rounds.
-    """
-    if (reduce or _active_reduce()) == "lazy":
-        return _pt_add_mixed_lazy(p, q, F)
-    X1, Y1, Z1 = p[0], p[1], p[2]
-    x2, y2 = q[0], q[1]
-    mul = F.mul
-
-    t0 = F.mul_t(X1, x2)
-    t1 = F.mul_t(Y1, y2)
-    t3 = mul(X1 + Y1, x2 + y2)
-    t3 = t3 - (t0 + t1)  # = X1*y2 + x2*Y1
-    t4 = F.mul_t(y2, Z1)
-    t4 = t4 + Y1  # = Y1*Z2 + Y2*Z1 with Z2 = 1
-    t5 = F.mul_t(x2, Z1)
-    t5 = t5 + X1  # = X1*Z2 + X2*Z1 with Z2 = 1
-    t0_3 = t0 + t0 + t0  # 3*X1*X2
-    t2_b3 = F.mul_small_red(Z1, B3)  # b3*Z1*Z2 with Z2 = 1
-    z3 = t1 + t2_b3
-    t1m = t1 - t2_b3
-    y3 = F.mul_small_red(t5, B3)
-    x3 = mul(t4, y3)
-    t2b = mul(t3, t1m)
-    x3 = t2b - x3
-    y3 = mul(y3, t0_3)
-    t1b = mul(t1m, z3)
-    y3 = t1b + y3
-    t0b = mul(t0_3, t3)
-    z3 = mul(z3, t4)
-    z3 = z3 + t0b
-    return _mk(F)(x3, y3, z3)
-
-
-def _pt_add_mixed_lazy(p: jnp.ndarray, q: jnp.ndarray, F=F) -> jnp.ndarray:
-    """The lazy-reduction body of :func:`pt_add_mixed` (ISSUE 12): the
-    same fused-tail / loose-reduce / hoisted-carry levers as
-    :func:`_pt_add_lazy` over the mixed-add algebra (Z2 = 1)."""
-    X1, Y1, Z1 = p[0], p[1], p[2]
-    x2, y2 = q[0], q[1]
-    rw = F.reduce_wide_loose
-
-    t0 = rw(F.mul_t_wide(X1, x2))
-    t1 = rw(F.mul_t_wide(Y1, y2))
-    t3 = rw(F.mul_wide(X1 + Y1, x2 + y2))
-    t3 = t3 - (t0 + t1)  # = X1*y2 + x2*Y1
-    t4 = rw(F.mul_t_wide(y2, Z1))
-    t4 = t4 + Y1  # = Y1*Z2 + Y2*Z1 with Z2 = 1
-    t5 = rw(F.mul_t_wide(x2, Z1))
-    t5 = t5 + X1  # = X1*Z2 + X2*Z1 with Z2 = 1
-    t2_b3 = F.mul_small_red(Z1, B3)  # b3*Z1*Z2 with Z2 = 1
-    # hoisted carry rounds, one per shared operand (see _pt_add_lazy)
-    t3 = F.tighten(t3)
-    t4 = F.tighten(t4)
-    t0_3 = F.tighten(t0 + t0 + t0)  # 3*X1*X2
-    z3s = F.tighten(t1 + t2_b3)
-    t1m = F.tighten(t1 - t2_b3)
-    y3r = F.tighten(F.mul_small_red(t5, B3))
-    x3 = rw(F.mul_t_wide(t3, t1m) - F.mul_t_wide(t4, y3r))
-    y3 = rw(F.acc_add(F.mul_t_wide(t1m, z3s), F.mul_t_wide(y3r, t0_3)))
-    z3 = rw(F.acc_add(F.mul_t_wide(z3s, t4), F.mul_t_wide(t0_3, t3)))
-    return _mk(F)(x3, y3, z3)
-
-
-def pt_double(p: jnp.ndarray, F=F, reduce=None) -> jnp.ndarray:
+def pt_double(p: jnp.ndarray, F=F) -> jnp.ndarray:
     """Complete doubling (RCB'16 Algorithm 9, a = 0): 6 muls + 2 squarings.
 
     ``F`` as in :func:`pt_add`.  The two squarings (Y^2, Z^2) go through
-    ``F.sqr_t`` — the dedicated half-product path (~300 partials vs 576)
-    under the default sqr mode; same contract as ``mul_t`` and
-    bit-identical output.  ``reduce`` as in :func:`pt_add`."""
-    if (reduce or _active_reduce()) == "lazy":
-        return _pt_double_lazy(p, F)
-    X, Y, Z = p[0], p[1], p[2]
-    mul = F.mul
-
-    # coords are <= 2^13: inside mul_t's (== sqr_t's) contract
-    t0 = F.sqr_t(Y)
-    z3 = t0 * 8  # 8Y^2, |limb| <= 2^15
-    t1 = F.mul_t(Y, Z)
-    t2 = F.sqr_t(Z)
-    t2 = F.mul_small_red(t2, B3)  # b3*Z^2: non-top <= 2^16.6, top <= 2^12
-    x3 = mul(t2, z3)
-    y3 = t0 + t2
-    z3 = mul(t1, z3)
-    t2_3 = t2 + t2 + t2  # 3*b3*Z^2: <= 3*2^16.6 = 2^18.3 (mul-input safe)
-    t0 = t0 - t2_3
-    y3 = mul(t0, y3)
-    y3 = x3 + y3
-    t1 = F.mul_t(X, Y)
-    x3 = mul(t0, t1)
-    x3 = x3 + x3
-    return _mk(F)(x3, y3, z3)
-
-
-def _pt_double_lazy(p: jnp.ndarray, F=F) -> jnp.ndarray:
-    """The lazy-reduction body of :func:`pt_double` (ISSUE 12): the
-    eager body's interior ``x3 = b3·Z²·8Y²`` product never materializes
-    reduced — it fuses into y3's accumulation (one reduction saved) —
-    and the three shared operands (8Y², the b3·Z² scaling, and the
-    t0 - 3·t2 difference) each get ONE hoisted carry round instead of
-    per-mul input carries."""
+    the half-product ``F.sqr_t_wide``.  The interior ``b3·Z²·8Y²``
+    product never materializes reduced — it fuses into y3's accumulation
+    (one reduction saved) — and the three shared operands (8Y², the
+    b3·Z² scaling, and the t0 - 3·t2 difference) each get ONE hoisted
+    carry round instead of per-mul input carries."""
     X, Y, Z = p[0], p[1], p[2]
     rw = F.reduce_wide_loose
 

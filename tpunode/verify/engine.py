@@ -460,34 +460,6 @@ class VerifyConfig:
     breaker_threshold: int = 3
     breaker_window: float = 30.0
     breaker_cooldown: float = 5.0
-    # Field-arithmetic formulation (ISSUE 4): None keeps the process-wide
-    # mode (TPUNODE_FIELD_MUL / TPUNODE_FIELD_SQR env knobs, defaults
-    # measured in PERF.md's roofline section); "shift_add"/"dot_general"
-    # and "half"/"mul" select explicitly.  Applied process-globally at
-    # engine construction — every device program keys its jit cache on
-    # the modes, so the first dispatch traces the requested formulation.
-    field_mul: Optional[str] = None
-    field_sqr: Optional[str] = None
-    # MSM point form (ISSUE 8): None keeps the process-wide mode
-    # (TPUNODE_POINT_FORM env knob); "projective"/"affine" select
-    # explicitly.  Applied process-globally at engine construction like
-    # the field knobs — every device program keys its jit cache on
-    # kernel.kernel_modes(), so the first dispatch traces the requested
-    # formulation.  Verdicts are bit-identical across forms.
-    point_form: Optional[str] = None
-    # Field reduction discipline (ISSUE 12): None keeps the process-wide
-    # mode (TPUNODE_FIELD_REDUCE env knob); "eager"/"lazy" select
-    # explicitly.  "lazy" accumulates unreduced products in curve.py's
-    # formulas and pays one reduction per expression — values differ
-    # limb-wise, verdicts are bit-identical; int32 safety is asserted at
-    # trace time by tpunode.verify.bounds.
-    field_reduce: Optional[str] = None
-    # MSM window width (ISSUE 12): None keeps the process-wide mode
-    # (TPUNODE_WINDOW_BITS env knob); 4 keeps the 33-round/16-entry r3
-    # structure, 5 runs 27 rounds over 32-entry tables (the native prep
-    # emits both layouts since ISSUE 13; only a stale libsecp_cpu.so
-    # preps w5 batches in Python).
-    window_bits: Optional[int] = None
 
     def __post_init__(self):
         if self.device_batch < self.batch_size:
@@ -500,26 +472,6 @@ class VerifyConfig:
             )
         if self.fleet_queue < 1:
             raise ValueError("fleet_queue must be >= 1")
-        if (
-            self.field_mul is not None
-            or self.field_sqr is not None
-            or self.field_reduce is not None
-        ):
-            from . import field as _field
-
-            _field.set_field_modes(
-                mul=self.field_mul,
-                sqr=self.field_sqr,
-                reduce=self.field_reduce,
-            )
-        if self.point_form is not None:
-            from . import curve as _curve
-
-            _curve.set_point_form(self.point_form)
-        if self.window_bits is not None:
-            from . import kernel as _kernel
-
-            _kernel.set_kernel_modes(window_bits=self.window_bits)
 
 
 class _HostState:

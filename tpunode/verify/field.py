@@ -33,19 +33,12 @@ Key design points (bounds are load-bearing):
 
 Host<->device speaks Python ints via ``to_limbs``/``from_limbs``.
 
-**Two limb-product formulations** (ISSUE 4): the classic shift-add
-convolution (``shift_add``, the default) keeps everything on the VPU;
-``dot_general`` materializes the 24x24 partial-product rows and contracts
-them against a constant anti-diagonal scatter matrix with one
-``lax.dot_general`` — the formulation that maps onto the MXU (the TPU's
-wide-MAC unit, the analogue of the FPGA batch-ECDSA engines' DSP arrays).
-Squaring additionally has a **dedicated half-product path** (~300 partial
+**One limb-product formulation**: the shift-add convolution (everything
+on the VPU), with a dedicated **half-product squaring** (~300 partial
 products instead of 576, exploiting a_i*a_j symmetry) used by the pow
-ladders and doubling formulas.  Both knobs are process-global, selectable
-via ``TPUNODE_FIELD_MUL`` / ``TPUNODE_FIELD_SQR`` (see
-:func:`set_field_modes`); every jit cache keyed on :func:`field_modes`
-retraces on a flip.  All formulations compute IDENTICAL anti-diagonal
-sums, so the int32 overflow audit below applies verbatim to each.
+ladders and doubling formulas.  The alternatives (a ``dot_general``
+contraction, squaring through ``mul``) were read on the chip and deleted
+(PERF.md, PR 29).
 
 This replaces the capability the reference gets from libsecp256k1's field
 module (reference stack.yaml:5,9; SURVEY.md C9), redesigned for vector/matrix
@@ -54,12 +47,9 @@ units rather than translated from the C.
 
 from __future__ import annotations
 
-import os
-
 import numpy as np
 
 import jax.numpy as jnp
-from jax import lax
 
 __all__ = [
     "RADIX",
@@ -87,14 +77,6 @@ __all__ = [
     "select",
     "ZERO",
     "ONE",
-    "MUL_MODES",
-    "SQR_MODES",
-    "REDUCE_MODES",
-    "field_modes",
-    "mul_mode",
-    "sqr_mode",
-    "reduce_mode",
-    "set_field_modes",
 ]
 
 RADIX = 11
@@ -137,107 +119,6 @@ ZERO = jnp.zeros((NLIMBS, 1), dtype=jnp.int32)
 ONE = jnp.zeros((NLIMBS, 1), dtype=jnp.int32).at[0].set(1)
 
 
-# ---------- limb-product formulation knobs (ISSUE 4) ----------------------
-#
-# Process-global, read at TRACE time: every jitted program that embeds
-# field ops keys its jit cache on field_modes() (kernel.verify_device,
-# pallas_kernel.verify_blocked, multichip._FN_CACHE), so flipping a mode
-# retraces instead of silently keeping the old formulation.
-#
-# Defaults chosen by measurement (PERF.md roofline section): on cpu-jax
-# the fused shift-add chain beats the materialized dot_general outer
-# product, and the half-product sqr wins everywhere.
-
-MUL_MODES = ("shift_add", "dot_general")
-SQR_MODES = ("half", "mul")
-# Reduction discipline (ISSUE 12): "eager" reduces every product to 24
-# limbs on the spot (the r3-r11 behavior); "lazy" lets curve.py's RCB
-# formulas accumulate unreduced 47-limb convolutions (mul_wide/acc_add
-# below) and pay ONE _reduce_wide per accumulated expression, with
-# shared-operand carry rounds hoisted — the fused carry/fold rounds
-# ROADMAP item 1 names.  Values differ limb-wise between modes but are
-# equal mod p (pinned in tests/test_field.py); verdicts are
-# bit-identical.  int32 safety of every lazy chain is CHECKED at trace
-# time by tpunode.verify.bounds (not argued in comments).  "lazy" is
-# the default since round 12: −27% carry/fold vector ops in the op
-# model and a −9.5% measured step on the cpu-jax proxy @1024 (PERF.md;
-# campaign-clean on XLA and pallas-interpret; no device verdict yet —
-# ROADMAP S5).
-REDUCE_MODES = ("eager", "lazy")
-
-
-def _env_mode(var: str, allowed: tuple, default: str) -> str:
-    v = os.environ.get(var, "").strip().lower()
-    if not v:
-        return default
-    if v not in allowed:
-        # Fail fast: this is a measurement knob — silently falling back
-        # to the default would make an A/B run measure the wrong
-        # formulation and label it with the requested one.
-        raise ValueError(f"{var}={v!r} not in {allowed}")
-    return v
-
-
-_MUL_MODE = _env_mode("TPUNODE_FIELD_MUL", MUL_MODES, "shift_add")
-_SQR_MODE = _env_mode("TPUNODE_FIELD_SQR", SQR_MODES, "half")
-_REDUCE_MODE = _env_mode("TPUNODE_FIELD_REDUCE", REDUCE_MODES, "lazy")
-
-
-def mul_mode() -> str:
-    """Active limb-product formulation: "shift_add" | "dot_general"."""
-    return _MUL_MODE
-
-
-def sqr_mode() -> str:
-    """Active squaring path: "half" (dedicated ~half-product) | "mul"."""
-    return _SQR_MODE
-
-
-def reduce_mode() -> str:
-    """Active reduction discipline: "eager" | "lazy" (ISSUE 12)."""
-    return _REDUCE_MODE
-
-
-def field_modes() -> tuple:
-    """Hashable (mul_mode, sqr_mode, reduce_mode) — THE jit-cache key for
-    every program that embeds field ops (a trace bakes the formulation
-    in; the reduce mode changes curve.py's traced formula bodies)."""
-    return (_MUL_MODE, _SQR_MODE, _REDUCE_MODE)
-
-
-def set_field_modes(
-    mul: str | None = None,
-    sqr: str | None = None,
-    reduce: str | None = None,
-) -> tuple:
-    """Select the limb-product / squaring / reduction formulation
-    process-wide.
-
-    Returns the previous (mul_mode, sqr_mode, reduce_mode) so callers can
-    restore.  Programs traced BEFORE the flip keep their formulation until
-    their owner re-traces — which every in-repo dispatch site does,
-    because all of them key on :func:`field_modes`.
-    """
-    global _MUL_MODE, _SQR_MODE, _REDUCE_MODE
-    # Validate ALL before mutating any: a caller that catches the
-    # ValueError must find the process-global modes untouched, not
-    # half-flipped (which would silently mislabel every later trace).
-    if mul is not None and mul not in MUL_MODES:
-        raise ValueError(f"mul mode {mul!r} not in {MUL_MODES}")
-    if sqr is not None and sqr not in SQR_MODES:
-        raise ValueError(f"sqr mode {sqr!r} not in {SQR_MODES}")
-    if reduce is not None and reduce not in REDUCE_MODES:
-        raise ValueError(f"reduce mode {reduce!r} not in {REDUCE_MODES}")
-    prev = (_MUL_MODE, _SQR_MODE, _REDUCE_MODE)
-    if mul is not None:
-        _MUL_MODE = mul
-    if sqr is not None:
-        _SQR_MODE = sqr
-    if reduce is not None:
-        _REDUCE_MODE = reduce
-    return prev
-
-
 def _conv(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Limb convolution: (24, B) x (24, B) -> (47, B).
 
@@ -249,54 +130,6 @@ def _conv(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     for i in range(NLIMBS):
         out = out.at[i : i + NLIMBS].add(a[i] * b)
     return out
-
-
-# Constant scatter matrices for the dot_general formulation.  MUL: row k
-# of (47, 576) selects the partial products a_i*b_j with i+j == k — the
-# anti-diagonal sum becomes ONE contraction over 576, which is what
-# lax.dot_general maps onto the MXU.  SQR: only the 300 i <= j pairs are
-# materialized; off-diagonal entries carry weight 2 (a_i*a_j appears
-# twice in the square), so the contraction output is bit-identical to
-# the full convolution of a with itself.
-_MUL_PAIRS = [(i, j) for i in range(NLIMBS) for j in range(NLIMBS)]
-_SQR_PAIRS = [(i, j) for i in range(NLIMBS) for j in range(i, NLIMBS)]
-
-
-def _scatter(pairs, weighted: bool) -> np.ndarray:
-    m = np.zeros((2 * NLIMBS - 1, len(pairs)), dtype=np.int32)
-    for col, (i, j) in enumerate(pairs):
-        m[i + j, col] = 2 if (weighted and i != j) else 1
-    return m
-
-
-_MUL_SCATTER = jnp.asarray(_scatter(_MUL_PAIRS, weighted=False))
-_SQR_SCATTER = jnp.asarray(_scatter(_SQR_PAIRS, weighted=True))
-_SQR_I = np.array([i for i, _ in _SQR_PAIRS])
-_SQR_J = np.array([j for _, j in _SQR_PAIRS])
-
-
-def _contract(scatter: jnp.ndarray, partials: jnp.ndarray,
-              rest: tuple) -> jnp.ndarray:
-    """(47, NPAIRS) @ (NPAIRS, prod(rest)) -> (47,) + rest, int32-exact.
-
-    ``preferred_element_type=int32``: the accumulator must be exactly the
-    int32 carry-save arithmetic of the shift-add form (every anti-diagonal
-    sum is bounded inside int32 by the callers' contracts, so accumulation
-    order is irrelevant)."""
-    out = lax.dot_general(
-        scatter,
-        partials.reshape((partials.shape[0], -1)),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
-    return out.reshape((2 * NLIMBS - 1,) + rest)
-
-
-def _conv_dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """_conv as outer-product + one dot_general (same partials, same
-    anti-diagonal sums — bit-identical output)."""
-    p = (a[:, None] * b[None, :]).reshape((NLIMBS * NLIMBS,) + a.shape[1:])
-    return _contract(_MUL_SCATTER, p, a.shape[1:])
 
 
 def _sqr_conv(a: jnp.ndarray) -> jnp.ndarray:
@@ -311,23 +144,6 @@ def _sqr_conv(a: jnp.ndarray) -> jnp.ndarray:
         if i + 1 < NLIMBS:
             out = out.at[2 * i + 1 : i + NLIMBS].add(a[i] * d[i + 1 :])
     return out
-
-
-def _sqr_dot(a: jnp.ndarray) -> jnp.ndarray:
-    """Half-product squaring, dot_general form: gather the 300 i <= j
-    partial rows, contract with the 2-weighted scatter matrix."""
-    p = a[_SQR_I] * a[_SQR_J]
-    return _contract(_SQR_SCATTER, p, a.shape[1:])
-
-
-def _convolve(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    return _conv(a, b) if _MUL_MODE == "shift_add" else _conv_dot(a, b)
-
-
-def _square_conv(a: jnp.ndarray) -> jnp.ndarray:
-    if _SQR_MODE == "mul":
-        return _convolve(a, a)
-    return _sqr_conv(a) if _MUL_MODE == "shift_add" else _sqr_dot(a)
 
 
 def _carry(x: jnp.ndarray, rounds: int) -> jnp.ndarray:
@@ -420,7 +236,7 @@ def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """
     a = _carry(a, 1)
     b = _carry(b, 1)
-    return _reduce_wide(_convolve(a, b))  # sums < 2^28.6 (see contract)
+    return _reduce_wide(_conv(a, b))  # sums < 2^28.6 (see contract)
 
 
 def mul_t(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -432,24 +248,24 @@ def mul_t(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     and mul_small_red outputs do NOT.  Convolution bound: 24 * 2^13 * 2^13
     = 2^30.6 < 2^31.  Output identical contract to mul's.
     """
-    return _reduce_wide(_convolve(a, b))
+    return _reduce_wide(_conv(a, b))
 
 
 def sqr(a: jnp.ndarray) -> jnp.ndarray:
     """Modular square — mul(a, a)'s contract, via the dedicated
-    half-product path when ``sqr_mode() == "half"`` (the default: the pow
-    ladders spend most of their muls here).  The pairwise top*top <= 2^30
-    condition reduces to |top limb| <= 2^15, which mul's contract already
-    requires.  Bit-identical output to mul(a, a) in every mode."""
+    half-product path (the pow ladders spend most of their muls here).
+    The pairwise top*top <= 2^30 condition reduces to |top limb| <= 2^15,
+    which mul's contract already requires.  Bit-identical output to
+    mul(a, a)."""
     a = _carry(a, 1)
-    return _reduce_wide(_square_conv(a))
+    return _reduce_wide(_sqr_conv(a))
 
 
 def sqr_t(a: jnp.ndarray) -> jnp.ndarray:
     """``sqr`` for pre-tight operands — mul_t's contract (every |limb|
     <= 2^13).  The doubled cross partials 2*a_i*a_j <= 2^27 and the
     per-position sums equal mul_t's convolution sums (< 2^30.6)."""
-    return _reduce_wide(_square_conv(a))
+    return _reduce_wide(_sqr_conv(a))
 
 
 def mul_small_red(a: jnp.ndarray, k: int) -> jnp.ndarray:
@@ -467,12 +283,12 @@ def mul_small_red(a: jnp.ndarray, k: int) -> jnp.ndarray:
     return _fold_top(a * k)
 
 
-# ---------- lazy-reduction wide-accumulator API (ISSUE 12) ----------------
+# ---------- lazy-reduction wide-accumulator API ---------------------------
 #
 # A "wide" value is the unreduced 47-limb convolution of one product —
 # exactly what _reduce_wide consumes.  Wides of the SAME expression may be
 # summed limb-wise (acc_add) before the one shared reduction, eliminating
-# the interior carry/fold rounds the eager formulas pay per product.
+# the interior carry/fold rounds a reduce-per-product formula would pay.
 # Wides are plain (47, ...) int32 arrays: negation and subtraction are
 # ordinary elementwise arithmetic (value-exact, sign-correct).
 #
@@ -488,23 +304,23 @@ def mul_wide(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     the limb convolution.  Input contract identical to :func:`mul`'s;
     output is the (47, ...) wide for :func:`acc_add`/:func:`reduce_wide`.
     ``reduce_wide(mul_wide(a, b))`` is bit-identical to ``mul(a, b)``."""
-    return _convolve(_carry(a, 1), _carry(b, 1))
+    return _conv(_carry(a, 1), _carry(b, 1))
 
 
 def mul_t_wide(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """``mul_t`` minus the reduction tail (pre-tight operands, every
     |limb| <= 2^13 — :func:`mul_t`'s contract)."""
-    return _convolve(a, b)
+    return _conv(a, b)
 
 
 def sqr_wide(a: jnp.ndarray) -> jnp.ndarray:
     """``sqr`` minus the reduction tail (mul's input contract)."""
-    return _square_conv(_carry(a, 1))
+    return _sqr_conv(_carry(a, 1))
 
 
 def sqr_t_wide(a: jnp.ndarray) -> jnp.ndarray:
     """``sqr_t`` minus the reduction tail (mul_t's contract)."""
-    return _square_conv(a)
+    return _sqr_conv(a)
 
 
 def acc_add(*wides: jnp.ndarray) -> jnp.ndarray:

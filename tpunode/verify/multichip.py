@@ -51,7 +51,6 @@ from .ecdsa_cpu import Point
 from .sched import host_names
 from .kernel import (
     ARG_IS_2D,
-    kernel_modes,
     prepare_batch,
     prepare_batch_raw,
     verify_core,
@@ -191,10 +190,8 @@ def sharded_verify_fn(
     flag: its runtime lax.cond gating sheds the pows per shard already.
 
     ``B`` must be a multiple of the mesh size (callers pad; static shapes
-    also keep XLA from recompiling across batches).  Cached per mesh,
-    program variant, and formulation-mode tuple (kernel.kernel_modes():
-    field formulation + point form + select/ladder shape — all baked in
-    at trace time) so repeated batches reuse the compiled executable.
+    also keep XLA from recompiling across batches).  Cached per mesh and
+    program variant so repeated batches reuse the compiled executable.
     """
     if kernel not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown kernel {kernel!r}: auto|pallas|xla")
@@ -202,11 +199,7 @@ def sharded_verify_fn(
         kernel == "auto" and _mesh_is_tpu(mesh)
     )
     schnorr_free = bool(schnorr_free) and use_pallas
-    # kernel_modes() carries the field formulation AND the point-form/
-    # select/ladder knobs (ISSUE 8) — all read at trace time, so all part
-    # of the cache key.  The pallas branch additionally pins point_form
-    # explicitly so the impl can't drift from the keyed mode.
-    key = (mesh, use_pallas, interpret, block, schnorr_free, kernel_modes())
+    key = (mesh, use_pallas, interpret, block, schnorr_free)
     cached = _FN_CACHE.get(key)
     if cached is not None:
         return cached
@@ -223,9 +216,7 @@ def sharded_verify_fn(
 
         from .pallas_kernel import verify_blocked_impl
 
-        from .curve import point_form
-
-        kw = {"point_form": point_form()}
+        kw = {}
         if interpret:
             kw["interpret"] = True
         if block is not None:

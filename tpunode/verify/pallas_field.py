@@ -20,19 +20,15 @@ compile to straight-line vector code with no per-op dispatch.
 
 Functions mirror :mod:`field`'s API (``mul``/``mul_t``/``mul_small_red``/
 ``sqr``/``sqr_t``/``canonical``/``is_zero``/``eq``) so :mod:`curve`'s
-audited RCB formulas can be reused unchanged via their ``F=`` parameter —
-including the limb-product formulation knobs: :func:`field.mul_mode` /
-:func:`field.sqr_mode` select shift-add vs ``dot_general`` and the
-dedicated half-product squaring here exactly as in :mod:`field` (the
-dispatch reads the same process-global modes at trace time; pallas
-programs key their jit caches on ``field.field_modes()``).  Exactness is
-pinned against :mod:`field` property-style in tests/test_pallas_kernel.py.
+audited RCB formulas can be reused unchanged via their ``F=`` parameter
+(one formulation: shift-add convolution, half-product squaring).
+Exactness is pinned against Python ints and :mod:`field` in
+tests/test_pallas_kernel.py.
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
-from jax import lax
 
 from . import field as F
 
@@ -101,32 +97,6 @@ def _conv(a: jnp.ndarray, b_: jnp.ndarray) -> jnp.ndarray:
     return _tree_sum(terms)
 
 
-def _mul_scatter() -> jnp.ndarray:
-    """The (47, 576) anti-diagonal scatter matrix (field._MUL_SCATTER),
-    built from iota + integer ops INSIDE the traced computation: a pallas
-    kernel may not capture non-scalar constants, and this way the Mosaic
-    and XLA programs share one construction.  Column c encodes the pair
-    (i, j) = (c // 24, c % 24); row k selects i + j == k."""
-    shape = (2 * NLIMBS - 1, NLIMBS * NLIMBS)
-    k = lax.broadcasted_iota(jnp.int32, shape, 0)
-    c = lax.broadcasted_iota(jnp.int32, shape, 1)
-    return ((c // NLIMBS + c % NLIMBS) == k).astype(jnp.int32)
-
-
-def _conv_dot(a: jnp.ndarray, b_: jnp.ndarray) -> jnp.ndarray:
-    """field._conv_dot in concatenate form: the (576, B) partial-product
-    rows are a sublane concat of 24 row-broadcast multiplies (no gathers),
-    contracted against the anti-diagonal scatter matrix with one
-    dot_general — the MXU-mapped formulation."""
-    p = _cat(*[a[i : i + 1] * b_ for i in range(NLIMBS)])
-    return lax.dot_general(
-        _mul_scatter(),
-        p,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
-
-
 def _sqr_conv(a: jnp.ndarray) -> jnp.ndarray:
     """field._sqr_conv in concatenate form: out[i+j] += (2-δij)·a_i·a_j
     over i <= j — ~half the partial products, per-position sums identical
@@ -139,39 +109,6 @@ def _sqr_conv(a: jnp.ndarray) -> jnp.ndarray:
         t = row * (_cat(row, d[i + 1 :]) if i + 1 < NLIMBS else row)
         terms.append(_cat(_z(2 * i, b), t, _z(NLIMBS - 1 - i, b)))
     return _tree_sum(terms)
-
-
-def _sqr_dot(a: jnp.ndarray) -> jnp.ndarray:
-    """field._sqr_dot in concatenate form: the i <= j partial rows (cross
-    terms pre-doubled, j < i positions zero-padded so the pair layout and
-    scatter match _conv_dot's) contracted with the shared anti-diagonal
-    matrix.  ~Half the real multiplies; the contraction stays 576 wide —
-    on a real MXU the matmul cost is shape-bound, so sharing one scatter
-    costs nothing there while keeping the kernel free of a second
-    constant construction."""
-    b = a.shape[-1]
-    d = a + a
-    rows = []
-    for i in range(NLIMBS):
-        row = a[i : i + 1]
-        t = row * (_cat(row, d[i + 1 :]) if i + 1 < NLIMBS else row)
-        rows.append(t if i == 0 else _cat(_z(i, b), t))
-    return lax.dot_general(
-        _mul_scatter(),
-        _cat(*rows),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
-
-
-def _convolve(a: jnp.ndarray, b_: jnp.ndarray) -> jnp.ndarray:
-    return _conv(a, b_) if F.mul_mode() == "shift_add" else _conv_dot(a, b_)
-
-
-def _square_conv(a: jnp.ndarray) -> jnp.ndarray:
-    if F.sqr_mode() == "mul":
-        return _convolve(a, a)
-    return _sqr_conv(a) if F.mul_mode() == "shift_add" else _sqr_dot(a)
 
 
 def _pad(x: jnp.ndarray, n: int) -> jnp.ndarray:
@@ -221,23 +158,23 @@ def mul(a: jnp.ndarray, b_: jnp.ndarray) -> jnp.ndarray:
     """Modular multiply — identical contract to field.mul."""
     a = _carry(a, 1)
     b_ = _carry(b_, 1)
-    return _reduce_wide(_convolve(a, b_))
+    return _reduce_wide(_conv(a, b_))
 
 
 def mul_t(a: jnp.ndarray, b_: jnp.ndarray) -> jnp.ndarray:
     """field.mul_t: pre-tight operands (every |limb| <= 2^13)."""
-    return _reduce_wide(_convolve(a, b_))
+    return _reduce_wide(_conv(a, b_))
 
 
 def sqr(a: jnp.ndarray) -> jnp.ndarray:
-    """field.sqr: half-product squaring under the default sqr mode."""
+    """field.sqr: half-product squaring."""
     a = _carry(a, 1)
-    return _reduce_wide(_square_conv(a))
+    return _reduce_wide(_sqr_conv(a))
 
 
 def sqr_t(a: jnp.ndarray) -> jnp.ndarray:
     """field.sqr_t: squaring for pre-tight operands (mul_t's contract)."""
-    return _reduce_wide(_square_conv(a))
+    return _reduce_wide(_sqr_conv(a))
 
 
 def mul_small_red(a: jnp.ndarray, k: int) -> jnp.ndarray:
@@ -245,8 +182,8 @@ def mul_small_red(a: jnp.ndarray, k: int) -> jnp.ndarray:
     return _fold_top(a * k)
 
 
-# ---------- lazy-reduction wide-accumulator API (ISSUE 12) ----------------
-# Mirrors field.py's wide API in concatenate form so curve.py's lazy
+# ---------- lazy-reduction wide-accumulator API ---------------------------
+# Mirrors field.py's wide API in concatenate form so curve.py's
 # formula bodies run unchanged inside the Pallas kernel (the same ``F=``
 # seam).  Safety: identical op sequences, identical bounds — the ONE
 # bound-tracker audit (tpunode.verify.bounds) covers both namespaces.
@@ -254,22 +191,22 @@ def mul_small_red(a: jnp.ndarray, k: int) -> jnp.ndarray:
 
 def mul_wide(a: jnp.ndarray, b_: jnp.ndarray) -> jnp.ndarray:
     """field.mul_wide: mul minus the reduction tail -> (47, B) wide."""
-    return _convolve(_carry(a, 1), _carry(b_, 1))
+    return _conv(_carry(a, 1), _carry(b_, 1))
 
 
 def mul_t_wide(a: jnp.ndarray, b_: jnp.ndarray) -> jnp.ndarray:
     """field.mul_t_wide: pre-tight operands, bare convolution."""
-    return _convolve(a, b_)
+    return _conv(a, b_)
 
 
 def sqr_wide(a: jnp.ndarray) -> jnp.ndarray:
     """field.sqr_wide."""
-    return _square_conv(_carry(a, 1))
+    return _sqr_conv(_carry(a, 1))
 
 
 def sqr_t_wide(a: jnp.ndarray) -> jnp.ndarray:
     """field.sqr_t_wide."""
-    return _square_conv(a)
+    return _sqr_conv(a)
 
 
 def acc_add(*wides: jnp.ndarray) -> jnp.ndarray:

@@ -8,8 +8,8 @@ program per batch block:
 * the per-signature Q/λQ multiple tables live in VMEM scratch;
 * the accumulator and every field-op intermediate stay in vector
   registers/VMEM — zero HBM round-trips inside the window loop;
-* table entries are selected by 16-way compare-accumulate (no gathers,
-  no one-hot einsums);
+* table entries are selected by a 15-where binary select tree (no
+  gathers, no one-hot einsums);
 * the grid walks fixed-size lane blocks of the batch, Pallas
   double-buffering the block DMAs.
 
@@ -36,20 +36,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import field as F
 from . import pallas_field as PF
-from .curve import point_form, pt_add, pt_add_mixed, pt_double
+from . import bounds as _bounds
+from .curve import pt_add, pt_double
 from .kernel import (
     _EULER_DIGITS,
     _PM2_DIGITS,
     BETA,
     G_TABLE,
-    G_TABLE_AFF,
     LG_TABLE,
-    LG_TABLE_AFF,
-    select_mode,
+    WINDOW_BITS,
     select_tree16,
-    structure_modes,
-    window_bits,
-    window_tables,
 )
 
 __all__ = ["verify_blocked", "verify_blocked_impl", "BLOCK"]
@@ -63,26 +59,10 @@ BLOCK = 256
 _BETA_LIMBS = [int(x) for x in F.to_limbs(BETA)]
 _SEVEN_LIMBS = [7] + [0] * (F.NLIMBS - 1)
 
-# Constant G / λG tables as host numpy, shape (16, 3, NLIMBS) — and their
-# 2-coordinate affine views (16, 2, NLIMBS) for the affine point form:
-# broadcast over lanes at trace time (compile-time constants in-kernel).
-# The 5-bit window mode fetches its 32-entry tables from
-# kernel.window_tables() instead (see _const_table).
-_G_NP = np.asarray(G_TABLE)
-_LG_NP = np.asarray(LG_TABLE)
-_G_AFF_NP = np.asarray(G_TABLE_AFF)
-_LG_AFF_NP = np.asarray(LG_TABLE_AFF)
-
 
 def _const_table(tab_np: np.ndarray, b: int) -> jnp.ndarray:
-    """Constant window table operand.  4-bit windows keep the proven r3
-    layout: the (16, C, L) table broadcast over all ``b`` lanes.  5-bit
-    windows (ISSUE 12) pass ONE shared copy — shape (32, C, L, 1) — and
-    let the in-kernel selects broadcast it against the per-lane digit
-    rows: the per-lane duplication is pure VMEM waste, and at 32 entries
-    it would double a cost that was already ~1.2 MB per table."""
-    if window_bits() == 5:
-        return jnp.asarray(tab_np[:, :, :, None])
+    """Constant (16, 3, L) window table broadcast over all ``b`` lanes
+    (a compile-time constant in-kernel)."""
     return jnp.asarray(
         np.broadcast_to(tab_np[:, :, :, None], tab_np.shape + (b,))
     )
@@ -91,44 +71,22 @@ def _const_table(tab_np: np.ndarray, b: int) -> jnp.ndarray:
 def _select16(table, digit_row):
     """Branch-free 16-way select over window-table entries.
 
-    ``table``: (16, C, L, B) value or VMEM ref (C = 3 projective / 2
-    affine); ``digit_row``: (1, B).  Two formulations behind the
-    TPUNODE_SELECT16 knob (kernel.select_mode(), read at trace time):
-
-    * ``tree`` (default, ISSUE 8 lever 3): balanced 4-level binary
-      select tree — 15 wheres, each level resolving one digit bit; half
-      the one-hot form's data movement and no accumulate adds.
-    * ``onehot``: the r3 compare-accumulate (16 wheres + 15 adds).
-
-    Entry 0 is the infinity point — under the projective form the
-    complete RCB formulas make adding it a no-op; the affine window loop
-    handles digit 0 with a keep-accumulator select instead.
-
-    Entry count follows the table's leading axis (16 at 4-bit windows,
-    32 at 5-bit — ISSUE 12).  A shared constant table with a 1-lane
-    trailing axis broadcasts against the digit row inside each where.
+    ``table``: (16, 3, L, B) value or VMEM ref; ``digit_row``: (1, B).
+    The ONE shared fold (kernel.select_tree16): a balanced 4-level binary
+    select tree — 15 wheres, each level resolving one digit bit;
+    digit_row broadcasts over each (3, L, B) entry exactly like the XLA
+    path's.  Entry 0 is the infinity point — the complete RCB formulas
+    make adding it a no-op.
     """
-    ent_n = int(table.shape[0])
-    if select_mode() == "onehot":
-        out = None
-        for t in range(ent_n):
-            m = digit_row == t  # (1, B), broadcasts over (C, L, B)
-            contrib = jnp.where(m, table[t], 0)
-            out = contrib if out is None else out + contrib
-        return out
-    # the ONE shared fold (kernel.select_tree16): digit_row (1, B)
-    # broadcasts over each (C, L, B) entry exactly like the XLA path's
-    return select_tree16([table[t] for t in range(ent_n)], digit_row)
+    return select_tree16(
+        [table[t] for t in range(int(table.shape[0]))], digit_row
+    )
 
 
 def _signed(entry: jnp.ndarray, neg_row: jnp.ndarray) -> jnp.ndarray:
-    """Negate the point iff ``neg_row`` (1, B): -P = (X, -Y[, Z]) — works
-    on projective (3, L, B) and affine (2, L, B) entries alike."""
+    """Negate the point iff ``neg_row`` (1, B): -P = (X, -Y, Z)."""
     y = jnp.where(neg_row != 0, -entry[1], entry[1])
-    parts = [entry[0:1], y[None]]
-    if entry.shape[0] == 3:
-        parts.append(entry[2:3])
-    return jnp.concatenate(parts, axis=0)
+    return jnp.concatenate([entry[0:1], y[None], entry[2:3]], axis=0)
 
 
 def _kernel(
@@ -146,32 +104,17 @@ def _kernel(
     flags_ref,  # (4, B) int32: [r2_valid, host_valid, schnorr, bip340]
     # remaining refs depend on the STATIC variant (pallas passes inputs,
     # then outputs, then scratch, positionally):
-    #   projective full:         euler_ref, out_ref, qtab, lqtab, powtab
-    #   projective schnorr_free: out_ref, qtab, lqtab  (no digits/pow)
-    #   affine (either):         euler_ref, out_ref, qtab(2-coord),
-    #                            lqtab(2-coord), ztab, ptab, powtab
-    #   (affine always carries the digits + pow scratch: the batch
-    #   inversion's Fermat ladder needs the _PM2 digit row even when the
-    #   acceptance pows are pruned)
+    #   full:         euler_ref, out_ref, qtab, lqtab, powtab
+    #   schnorr_free: out_ref, qtab, lqtab  (no digits/pow)
     *rest,
     schnorr_free: bool = False,
-    point_form: str = "projective",
 ):
-    affine = point_form == "affine"
-    if affine:
-        (euler_ref, out_ref, qtab_ref, lqtab_ref, ztab_ref, ptab_ref,
-         powtab_ref) = rest
-    elif schnorr_free:
+    if schnorr_free:
         euler_ref = powtab_ref = None
         out_ref, qtab_ref, lqtab_ref = rest
     else:
         euler_ref, out_ref, qtab_ref, lqtab_ref, powtab_ref = rest
     b = out_ref.shape[-1]
-    # MSM structure from the ref shapes (ISSUE 12): table entries and
-    # window width off the Q-table scratch, window rounds off the digit
-    # stream — so ONE kernel body serves both widths.
-    ent_n = int(qtab_ref.shape[0])
-    wbits = (ent_n - 1).bit_length()
     nwin = int(d1a_ref.shape[0])
     L = F.NLIMBS
     zero = jnp.zeros((L, b), jnp.int32)
@@ -183,12 +126,11 @@ def _kernel(
     qx = qx_ref[:]
     qy = qy_ref[:]
 
-    # ---- windowed pow machinery (shared by the affine batch inversion
-    # and the jacobi/parity acceptance pows): 16-entry power table of
-    # ``t`` in powtab, then 64 4-bit windows with digits from SMEM row
-    # ``row`` of euler_ref.  fori_loop bodies (one mul each) instead of
-    # unrolled chains: the straight-line form dominated Mosaic compile
-    # time (the r3 finding).
+    # ---- windowed pow machinery (the jacobi/parity acceptance pows):
+    # 16-entry power table of ``t`` in powtab, then 64 4-bit windows with
+    # digits from SMEM row ``row`` of euler_ref.  fori_loop bodies (one
+    # mul each) instead of unrolled chains: the straight-line form
+    # dominated Mosaic compile time (the r3 finding).
     def pow_build_table(t):
         powtab_ref[0] = one
         powtab_ref[1] = t
@@ -216,61 +158,16 @@ def _kernel(
     # ---- per-signature Q table: [O, Q, 2Q, ..., 15Q] ----------------------
     # fori_loop bodies (one pt_add / one mul) instead of unrolled chains:
     # the straight-line table build dominated Mosaic compile time otherwise.
-    # Projective: 3-coordinate entries straight into qtab.  Affine (ISSUE
-    # 8): X/Y into the 2-coordinate qtab, Z into ztab, then one
-    # Montgomery-trick batch inversion per lane (prefix products in ptab,
-    # ONE shared Fermat Z^(p-2) ladder, suffix pass) normalizes every
-    # entry to affine in place.
     q1 = jnp.stack([qx, qy, one], axis=0)
-    if affine:
-        qtab_ref[0] = jnp.stack([zero, one], axis=0)
-        qtab_ref[1] = q1[0:2]
+    qtab_ref[0] = inf
+    qtab_ref[1] = q1
 
-        def build_step(k, acc):
-            nxt = pt_add(acc, q1, F=PF)
-            qtab_ref[pl.ds(k, 1)] = nxt[0:2][None]
-            ztab_ref[pl.ds(k, 1)] = nxt[2][None]
-            return nxt
+    def build_step(k, acc):
+        nxt = pt_add(acc, q1, F=PF)
+        qtab_ref[pl.ds(k, 1)] = nxt[None]
+        return nxt
 
-        lax.fori_loop(2, ent_n, build_step, q1)
-
-        # prefix products ptab[k] = z_2 * ... * z_k (ptab[1] = 1)
-        ptab_ref[1] = one
-        ptab_ref[2] = ztab_ref[2]
-
-        def prefix_step(k, carry):
-            ptab_ref[pl.ds(k, 1)] = PF.mul(
-                ptab_ref[pl.ds(k - 1, 1)][0], ztab_ref[pl.ds(k, 1)][0]
-            )[None]
-            return carry
-
-        lax.fori_loop(3, ent_n, prefix_step, 0)
-
-        # one shared Fermat ladder: (z_2 ... z_{ent_n-1})^(p-2)
-        pow_build_table(ptab_ref[ent_n - 1])
-        inv = lax.fori_loop(0, 64, pow_window_for(1), one)
-
-        # suffix pass: entering k, run = (z_2 ... z_k)^-1
-        def suffix_step(i, run):
-            k = ent_n - 1 - i
-            zinv = PF.mul(run, ptab_ref[pl.ds(k - 1, 1)][0])
-            e = qtab_ref[pl.ds(k, 1)][0]
-            qtab_ref[pl.ds(k, 1)] = jnp.stack(
-                [PF.mul(e[0], zinv), PF.mul(e[1], zinv)], axis=0
-            )[None]
-            return PF.mul(run, ztab_ref[pl.ds(k, 1)][0])
-
-        lax.fori_loop(0, ent_n - 2, suffix_step, inv)
-    else:
-        qtab_ref[0] = inf
-        qtab_ref[1] = q1
-
-        def build_step(k, acc):
-            nxt = pt_add(acc, q1, F=PF)
-            qtab_ref[pl.ds(k, 1)] = nxt[None]
-            return nxt
-
-        lax.fori_loop(2, ent_n, build_step, q1)
+    lax.fori_loop(2, 16, build_step, q1)
 
     # ---- λQ table: the endomorphism is additive, so scale each X by β ----
     beta = PF.const_col(_BETA_LIMBS, b)
@@ -283,7 +180,7 @@ def _kernel(
         ]
         return carry
 
-    lax.fori_loop(0, ent_n, lam_step, 0)
+    lax.fori_loop(0, 16, lam_step, 0)
 
     g_tab = g_ref[:]
     lg_tab = lg_ref[:]
@@ -294,38 +191,18 @@ def _kernel(
     n2b = negs_ref[3:4]
 
     # ---- Shamir/GLV window loop ------------------------------------------
-    if affine:
-        # mixed additions against 2-coordinate tables; digit 0 (the
-        # infinity entry, unrepresentable in affine) keeps the
-        # accumulator through a branch-free select
-        def window(w, acc):
-            for _ in range(wbits):
-                acc = pt_double(acc, F=PF)
-            for tab, dref, neg in (
-                (g_tab, d1a_ref, n1a),
-                (lg_tab, d1b_ref, n1b),
-                (qtab_ref, d2a_ref, n2a),
-                (lqtab_ref, d2b_ref, n2b),
-            ):
-                d = dref[pl.ds(w, 1)]
-                sel = _signed(_select16(tab, d), neg)
-                nxt = pt_add_mixed(acc, sel, F=PF)
-                acc = jnp.where(d == 0, acc, nxt)
-            return acc
-
-    else:
-        def window(w, acc):
-            for _ in range(wbits):
-                acc = pt_double(acc, F=PF)
-            da = d1a_ref[pl.ds(w, 1)]
-            db = d1b_ref[pl.ds(w, 1)]
-            dc = d2a_ref[pl.ds(w, 1)]
-            dd = d2b_ref[pl.ds(w, 1)]
-            acc = pt_add(acc, _signed(_select16(g_tab, da), n1a), F=PF)
-            acc = pt_add(acc, _signed(_select16(lg_tab, db), n1b), F=PF)
-            acc = pt_add(acc, _signed(_select16(qtab_ref, dc), n2a), F=PF)
-            acc = pt_add(acc, _signed(_select16(lqtab_ref, dd), n2b), F=PF)
-            return acc
+    def window(w, acc):
+        for _ in range(WINDOW_BITS):
+            acc = pt_double(acc, F=PF)
+        da = d1a_ref[pl.ds(w, 1)]
+        db = d1b_ref[pl.ds(w, 1)]
+        dc = d2a_ref[pl.ds(w, 1)]
+        dd = d2b_ref[pl.ds(w, 1)]
+        acc = pt_add(acc, _signed(_select16(g_tab, da), n1a), F=PF)
+        acc = pt_add(acc, _signed(_select16(lg_tab, db), n1b), F=PF)
+        acc = pt_add(acc, _signed(_select16(qtab_ref, dc), n2a), F=PF)
+        acc = pt_add(acc, _signed(_select16(lqtab_ref, dd), n2b), F=PF)
+        return acc
 
     acc = lax.fori_loop(0, nwin, window, inf)
 
@@ -351,8 +228,7 @@ def _kernel(
     if not schnorr_free:
         # jacobi(y(R)) for the BCH Schnorr lanes: y = Y/Z so jacobi(y) =
         # jacobi(Y·Z); Euler pow t^((p-1)/2) == 1 as a windowed 4-bit
-        # exponentiation (digit row 0), rebuilding the power table (the
-        # affine variant used it for the inversion)
+        # exponentiation (digit row 0)
         pow_build_table(PF.mul(Y, Z))
         pacc = lax.fori_loop(0, 64, pow_window_for(0), one)
         jac_ok = PF.eq(pacc, one)
@@ -393,56 +269,23 @@ def verify_blocked_impl(
     interpret: bool = False,
     block: int = BLOCK,
     schnorr_free: bool = False,
-    point_form: "str | None" = None,
 ) -> jnp.ndarray:
     """Un-jitted kernel body — reused inside shard_map by multichip.py
     (a jitted callee cannot be shard_mapped).  See :func:`verify_blocked`.
 
     ``schnorr_free`` statically prunes the jacobi/parity acceptance pows
     (see _kernel) — only set it when NO lane carries a schnorr/bip340
-    flag; verdicts are bit-identical for such batches.  ``point_form``
-    selects the projective or affine MSM variant (None = the process
-    global, curve.point_form()); verdicts are bit-identical across
-    forms."""
-    if point_form is None:
-        point_form = _active_point_form()
-    # Trace-time int32 bound audit of the live formulas (ISSUE 12): the
-    # Pallas and XLA programs share curve.py's bodies, so the one cached
-    # pure-Python replay covers this path too.
-    from . import bounds as _bounds
-
+    flag; verdicts are bit-identical for such batches."""
+    # Trace-time int32 bound audit of the live formulas: the Pallas and
+    # XLA programs share curve.py's bodies, so the one cached pure-Python
+    # replay covers this path too.
     _bounds.assert_formulas_safe()
-    affine = point_form == "affine"
     blk = block
     bsz = qx.shape[-1]
     if bsz % blk != 0:
         raise ValueError(f"batch {bsz} not a multiple of BLOCK={blk}")
     grid = bsz // blk
     nwin = int(d1a.shape[0])
-    wb = window_bits()
-    ent_n = 1 << wb
-    from .kernel import windows as _windows
-
-    # data/mode consistency (same guard as the XLA path): digit rows
-    # prepped at one window width under another width's global would
-    # produce silently wrong verdicts, not a shape error.
-    if nwin != _windows():
-        raise RuntimeError(
-            f"digit arrays carry {nwin} window rows but the active "
-            f"window_bits={wb} needs {_windows()}: re-prepare the "
-            "batch under the active mode"
-        )
-    # Constant G/λG tables for the active width: 4-bit keeps the module
-    # constants; 5-bit fetches the 32-entry tables (ONE shared VMEM copy
-    # — see _const_table).
-    if wb == 4:
-        g_np = _G_AFF_NP if affine else _G_NP
-        lg_np = _LG_AFF_NP if affine else _LG_NP
-    else:
-        g_full, lg_full, g_aff, lg_aff = window_tables()
-        g_np = np.asarray(g_aff if affine else g_full)
-        lg_np = np.asarray(lg_aff if affine else lg_full)
-    tab_lanes = 1 if wb == 5 else blk
 
     negs = jnp.stack(
         [a.astype(jnp.int32) for a in (n1a, n1b, n2a, n2b)], axis=0
@@ -460,9 +303,8 @@ def verify_blocked_impl(
     def col(rows):  # BlockSpec for a (rows, B) input walked along lanes
         return pl.BlockSpec((rows, blk), lambda i: (0, i))
 
-    coords = 2 if affine else 3
     tab_spec = pl.BlockSpec(
-        (ent_n, coords, F.NLIMBS, tab_lanes), lambda i: (0, 0, 0, 0)
+        (16, 3, F.NLIMBS, blk), lambda i: (0, 0, 0, 0)
     )
     in_specs = [
         tab_spec,
@@ -479,8 +321,8 @@ def verify_blocked_impl(
         col(4),
     ]
     operands = [
-        _const_table(g_np, blk),
-        _const_table(lg_np, blk),
+        _const_table(G_TABLE, blk),
+        _const_table(LG_TABLE, blk),
         d1a.astype(jnp.int32),
         d1b.astype(jnp.int32),
         d2a.astype(jnp.int32),
@@ -493,16 +335,14 @@ def verify_blocked_impl(
         flags,
     ]
     scratch = [
-        pltpu.VMEM((ent_n, coords, F.NLIMBS, blk), jnp.int32),
-        pltpu.VMEM((ent_n, coords, F.NLIMBS, blk), jnp.int32),
+        pltpu.VMEM((16, 3, F.NLIMBS, blk), jnp.int32),
+        pltpu.VMEM((16, 3, F.NLIMBS, blk), jnp.int32),
     ]
-    if affine or not schnorr_free:
+    if not schnorr_free:
         # Exponent digits live in SMEM: the kernel reads them with
         # dynamic scalar indices inside the window fori_loop, which is
-        # scalar memory's canonical job.  The projective schnorr_free variant
-        # omits the digits AND the (16, L, blk) pow-table scratch
-        # entirely; the affine variants always need both (the batch
-        # inversion's Fermat ladder reads the _PM2 digit row).
+        # scalar memory's canonical job.  The schnorr_free variant omits
+        # the digits AND the (16, L, blk) pow-table scratch entirely.
         in_specs.append(
             pl.BlockSpec((2, 64), lambda i: (0, 0), memory_space=pltpu.SMEM)
         )
@@ -512,19 +352,9 @@ def verify_blocked_impl(
                 axis=0,
             )
         )
-    if affine:
-        # Z column + prefix-product tables for the batch inversion: the
-        # 2-coordinate main tables free exactly 2 x (ent, L, blk) planes,
-        # so the affine variant's VMEM high-water stays ~level with the
-        # projective one's.
-        scratch.append(pltpu.VMEM((ent_n, F.NLIMBS, blk), jnp.int32))
-        scratch.append(pltpu.VMEM((ent_n, F.NLIMBS, blk), jnp.int32))
-    if affine or not schnorr_free:
-        # pow-ladder table: ALWAYS 16 entries (the constant-exponent
-        # ladders stay 4-bit regardless of the MSM window width)
         scratch.append(pltpu.VMEM((16, F.NLIMBS, blk), jnp.int32))
     out = pl.pallas_call(
-        partial(_kernel, schnorr_free=schnorr_free, point_form=point_form),
+        partial(_kernel, schnorr_free=schnorr_free),
         out_shape=jax.ShapeDtypeStruct((1, bsz), jnp.int32),
         grid=(grid,),
         in_specs=in_specs,
@@ -535,34 +365,9 @@ def verify_blocked_impl(
     return out[0].astype(jnp.bool_)
 
 
-def _active_point_form() -> str:
-    return point_form()
-
-
-@partial(
-    jax.jit,
-    static_argnames=(
-        "interpret", "block", "schnorr_free", "point_form", "field_modes",
-    ),
-)
+@partial(jax.jit, static_argnames=("interpret", "block", "schnorr_free"))
 def _verify_blocked_jit(*args, interpret: bool = False, block: int = BLOCK,
-                        schnorr_free: bool = False, point_form=None,
-                        field_modes=None):
-    # ``field_modes`` is only a jit-cache key (kernel.structure_modes():
-    # field formulation + select/ladder shape — the point form rides the
-    # EXPLICIT static arg, so including the global form here too would
-    # double-encode it): the knobs are process globals read at trace
-    # time, so a flip must force a retrace instead of reusing the stale
-    # executable.
-    del field_modes
-    return verify_blocked_impl(*args, interpret=interpret, block=block,
-                               schnorr_free=schnorr_free,
-                               point_form=point_form)
-
-
-def verify_blocked(*args, interpret: bool = False, block: int = BLOCK,
-                   schnorr_free: bool = False,
-                   point_form: "str | None" = None):
+                        schnorr_free: bool = False):
     """Drop-in replacement for :func:`kernel.verify_core` (same argument
     order — PreparedBatch.device_args) running the Pallas kernel over
     lane blocks of ``block`` (default BLOCK; tests use small blocks in
@@ -571,12 +376,12 @@ def verify_blocked(*args, interpret: bool = False, block: int = BLOCK,
     selects the ECDSA-only program variant (acceptance pows pruned at
     trace time) — callers must only set it when no lane carries a
     schnorr/bip340 flag (kernel._dispatch_prep derives it from the
-    prepared batch).  ``point_form`` selects the projective/affine MSM
-    (None = the process-global curve.point_form()).  Jit-cached per
-    explicit point form + kernel.structure_modes()."""
-    if point_form is None:
-        point_form = _active_point_form()
-    return _verify_blocked_jit(*args, interpret=interpret, block=block,
-                               schnorr_free=schnorr_free,
-                               point_form=point_form,
-                               field_modes=structure_modes())
+    prepared batch)."""
+    return verify_blocked_impl(*args, interpret=interpret, block=block,
+                               schnorr_free=schnorr_free)
+
+
+# The jitted function keeps its private name: it names the lowered module
+# and the device trace's kernel events, which the benchmark's trace
+# reduction and chip_smoke.py read.
+verify_blocked = _verify_blocked_jit
