@@ -1528,17 +1528,31 @@ long txx_parsed_inputs(void *h) {
   return static_cast<TxxHandle *>(h)->inputs;
 }
 
-long txx_prevouts_h(void *hp, int bch, long capacity, uint8_t *txids32,
-                    int64_t *vouts, uint8_t *wants) {
+// txx_prevouts' rows off the handle, each outpoint also as it stands on the
+// wire (txid ++ vout_le32, 36 bytes a row: the tail of the UTXO set's key, so
+// a batch read builds no key from parts; the txid is written twice so that
+// what asks by txid and what asks by outpoint each take a column whole and
+// neither is cut out of the other a row), for the whole region (subset ==
+// nullptr) or for the txs `subset` names, in its order (ISSUE 30: the rows
+// extract_subset takes, selected here instead of by three fancy indexings).
+// Returns the row count, -2 on capacity overflow, -3 on a bad tx index.
+long txx_outpoints_h(void *hp, int bch, const int32_t *subset, long n_subset,
+                     long capacity, uint8_t *txids32, uint8_t *outpoints36,
+                     int64_t *vouts, uint8_t *wants) {
   TxxHandle *h = static_cast<TxxHandle *>(hp);
   long flat = 0;
   static const uint8_t ZERO_TXID[32] = {0};
-  for (const TxSpan &tx : h->txs) {
+  const long n = subset ? n_subset : long(h->txs.size());
+  for (long k = 0; k < n; ++k) {
+    const long ti = subset ? long(subset[k]) : k;
+    if (ti < 0 || ti >= long(h->txs.size())) return -3;
+    const TxSpan &tx = h->txs[size_t(ti)];
     bool tx_has_wit = false;  // tx-level gate, see txx_prevouts
     for (const InSpan &in : tx.ins) tx_has_wit |= in.wit_count >= 1;
     for (const InSpan &in : tx.ins) {
       if (flat >= capacity) return -2;
       memcpy(txids32 + flat * 32, in.prevout, 32);
+      memcpy(outpoints36 + flat * 36, in.prevout, 36);
       uint32_t vout;
       memcpy(&vout, in.prevout + 32, 4);
       vouts[flat] = int64_t(vout);

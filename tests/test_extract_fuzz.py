@@ -73,7 +73,7 @@ def _native_path(data: bytes, n_txs: int, bch: bool):
     except ValueError:
         return None
     with region:
-        pt, pv, pw = region.scan_prevouts(bch)
+        pt, _, pv, pw = region.scan_outpoints(bch)
         ext = [-1] * len(pw)
         ext_scripts: list = [None] * len(pw)
         for i in pw.nonzero()[0]:

@@ -2,7 +2,9 @@ import os
 
 import pytest
 
-from tpunode.store import LogKV, MemoryKV, Namespaced, delete_op, open_store, put_op
+from tpunode.store import (
+    LogKV, MemoryKV, Namespaced, delete_op, get_many, open_store, put_op,
+)
 
 
 def _native(path):
@@ -106,6 +108,50 @@ def test_namespaced_views(kv):
     a.write_batch([delete_op(b"k")])
     assert a.get(b"k") is None
     assert b.get(b"k") == b"from-b"
+
+
+@pytest.mark.parametrize("view", ["plain", "namespaced", "nested"])
+def test_get_many_reads_what_get_reads_key_by_key(kv, view):
+    """The batch read (ISSUE 30): ``get_many(kv, keys, prefix)`` is
+    ``[kv.get(prefix + k) for k in keys]`` on every engine — LogKV's own
+    (one read of the index a key), the ``get``-a-key fallback of the
+    others — hits, misses, deleted keys, a repeated key, and the namespace
+    joined to the prefix once through one view or two."""
+    store = {"plain": kv, "namespaced": Namespaced(kv, b"u/"),
+             "nested": Namespaced(Namespaced(kv, b"a/"), b"b/")}[view]
+    kv.put(b"ox", b"outside every namespace")
+    for k in range(40):
+        store.put(b"o" + bytes([k]) * 36, b"v%d" % k)
+        store.put(b"p" + bytes([k]) * 36, b"other prefix")
+    store.write_batch([delete_op(b"o" + bytes([k]) * 36) for k in (3, 17)])
+    tails = [bytes([k]) * 36 for k in (0, 3, 5, 17, 39, 40, 5, 200)] + [b"x"]
+    got = get_many(store, tails, b"o")
+    assert got == [store.get(b"o" + t) for t in tails]
+    assert got == [b"v0", None, b"v5", None, b"v39", None, b"v5", None,
+                   b"outside every namespace" if view == "plain" else None]
+    assert get_many(store, [b"o" + t for t in tails]) == got  # no prefix
+    assert get_many(store, [], b"o") == []
+    assert get_many(store, iter(tails[:2]), b"o") == got[:2]  # any iterable
+    # a write between two reads is seen by the second
+    store.put(b"o" + bytes([3]) * 36, b"back")
+    assert get_many(store, tails[1:2], b"o") == [b"back"]
+
+
+def test_logkv_batch_read_leaves_the_single_reads_histogram_alone(tmp_path):
+    kv = LogKV(str(tmp_path / "kv.log"))
+    try:
+        kv.put(b"oa", b"1")
+        def samples() -> int:
+            hist = metrics.histogram("store.read_seconds")
+            return hist.count if hist is not None else 0
+
+        n0, tick0 = samples(), kv._read_tick
+        assert kv.get_many([b"a", b"b", b"c"] * 64, b"o") == [b"1", None, None] * 64
+        assert kv.get_many([], b"o") == []
+        # store.read_seconds stays one population: the 1-in-64 single reads
+        assert samples() == n0 and kv._read_tick == tick0
+    finally:
+        kv.close()
 
 
 def test_open_store_dispatch(tmp_path):

@@ -788,7 +788,7 @@ def test_taproot_heavy_mix_coverage():
     txs = gen_mixed_txs(48, seed=0x7A9, mix=_MIX_TAPROOT_HEAVY)
     data = b"".join(t.serialize() for t in txs)
     with txextract.ParsedTxRegion(data, len(txs)) as region:
-        pt, pv, pw = region.scan_prevouts(False)
+        pt, _, pv, pw = region.scan_outpoints(False)
         ext = [-1] * len(pw)
         scr: list = [None] * len(pw)
         for i in pw.nonzero()[0]:

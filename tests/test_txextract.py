@@ -374,7 +374,7 @@ def test_extract_range_sharded_matches_serial(cuts):
     txs = gen_mixed_txs(40, seed=0x5A5A)
     raw = _serialize_all(txs)
     with ParsedTxRegion(raw, len(txs)) as region:
-        pv_txids, pv_vouts, pv_wants = region.scan_prevouts(False)
+        pv_txids, _, pv_vouts, pv_wants = region.scan_outpoints(False)
         ext = [-1] * len(pv_wants)
         scr = [None] * len(pv_wants)
         for i in pv_wants.nonzero()[0]:
@@ -476,7 +476,7 @@ def test_extract_subset_matches_extract_range(subset):
 
     txs = gen_mixed_txs(40, seed=0x5A5A)
     with ParsedTxRegion(_serialize_all(txs), len(txs)) as region:
-        pv_txids, pv_vouts, pv_wants = region.scan_prevouts(False)
+        pv_txids, _, pv_vouts, pv_wants = region.scan_outpoints(False)
         ext = [-1] * len(pv_wants)
         scr = [None] * len(pv_wants)
         for i in pv_wants.nonzero()[0]:
@@ -596,3 +596,61 @@ def test_utxo_ops_blob_layout():
             seen_del = True
             n_del += 1
     assert (n_put, n_del) == (created, spent)
+
+
+# ---------------------------------------------------------------------------
+# the node's resolve walk (ISSUE 30) against the walk as it stood
+
+
+@pytest.mark.asyncio
+async def test_node_publishes_the_reference_walks_verdicts(monkeypatch):
+    """A sharded block and a relay drain through a node whose oracle rows
+    come from the columnar walk put on the bus exactly the ``TxVerdict``s
+    of a node whose rows come from the walk as it stood (a lookup chain a
+    row) — and both are what the generator built."""
+    import asyncio
+
+    from chipbench import gen
+    from tests.test_resolve_rows import MIX, reference_walk
+    from tests.test_verdict_reuse import a_node, block_of, tuples
+    from tpunode import node as node_mod
+    from tpunode.mempool import MempoolConfig
+
+    monkeypatch.setattr(node_mod.Node, "MIN_SHARD_TXS", 16)
+    mix = dict(MIX, adversarial_every=6)
+    job = gen.gen_job(gen.jobs_for(mix, 5, 150, 150)[0])
+    relayed = gen.gen_job(gen.jobs_for(mix, 6, 40, 40)[0])
+    oracle = gen.Oracle()
+    oracle.p2pk.update(job["p2pk"])
+    oracle.p2pk.update(relayed["p2pk"])
+    blk = block_of(job["raw"])
+    walks = {"columns": node_mod.Node._resolve_ext_rows, "lists": reference_walk}
+    seen: dict = {}
+    for port, (name, walk) in enumerate(walks.items(), 17930):
+        forms = []
+
+        def recorded(self, region, bch, subset=None, _walk=walk, _forms=forms):
+            out = _walk(self, region, bch, subset)
+            _forms.append(type(out[0]).__name__)
+            return out
+
+        monkeypatch.setattr(node_mod.Node, "_resolve_ext_rows", recorded)
+        async with asyncio.timeout(120):
+            async with a_node(oracle=oracle, utxo=True, port=port,
+                              mempool=MempoolConfig()) as d:
+                await d.relay(relayed["raw"])
+                n_relay = len(d.verdicts)
+                got, _, _ = await d.block(blk)
+                shards = [s for s in d.submissions if s[0] == "block"]
+                assert len(shards) > 1  # the block was cut into shards
+                seen[name] = (tuples(d.verdicts[:n_relay]), tuples(got))
+        assert set(forms) == {"list"}  # handed on as the extract's lists
+        assert len(forms) >= 2  # the drain's shard(s) and the block
+    assert seen["columns"] == seen["lists"]
+    relay, block = seen["columns"]
+    assert len(relay) == 40 and len(block) == 151
+    expect = dict(zip(job["txids"] + relayed["txids"],
+                      job["expect"] + relayed["expect"]))
+    for txid, valid, verdicts, _, error in relay + block[1:]:
+        assert error is None and verdicts == tuple(expect[txid])
+        assert valid == all(expect[txid])

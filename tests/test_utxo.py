@@ -59,6 +59,42 @@ def test_apply_lookup_spend_watermark():
     assert u.height == 1
 
 
+@pytest.mark.parametrize("store", ["memory", "log", "namespaced-log"])
+def test_lookup_many_is_lookup_row_by_row(store, tmp_path):
+    """The UTXO set's batch read (ISSUE 30): an outpoint is its 36 wire
+    bytes — txid ++ vout as four little-endian bytes, the key's tail as it
+    stands — and ``lookup_many`` answers each as ``lookup`` does: unspent
+    outputs, spent ones, unknown ones, an empty script, a vout past 31
+    bits, under the node's namespace or none."""
+    from tpunode.store import Namespaced
+    from tpunode.utxo import UTXO_NAMESPACE
+
+    kv = MemoryKV() if store == "memory" else LogKV(str(tmp_path / "u.log"))
+    view = Namespaced(kv, UTXO_NAMESPACE) if store.startswith("namesp") else kv
+    u = UtxoStore(view)
+    a, b, c = b"\x01" * 32, b"\x02" * 32, b"\x03" * 32
+    big = 0xFFFFFFF0
+    assert u.lookup_many([a + (0).to_bytes(4, "little")]) == [None]
+    u.apply(0, b"h0", spends=[], creates=[
+        (a, 0, 5000, b"\x51"), (a, 1, 7, b""), (b, big, 9, b"\x52\x53")])
+    u.apply(1, b"h1", spends=[(a, 0)], creates=[(c, 2, 11, b"\x54")])
+    asked = [(a, 0), (a, 1), (b, big), (b, 0), (c, 2), (c, 3), (a, 1)]
+    got = u.lookup_many([t + v.to_bytes(4, "little") for t, v in asked])
+    assert got == [u.lookup(t, v) for t, v in asked]
+    assert got == [None, (7, b""), (9, b"\x52\x53"), None, (11, b"\x54"),
+                   None, (7, b"")]
+    assert u.lookup_many([]) == []
+    # the watermark and undo rows share the store and never answer
+    assert u.lookup_many([b"!wm", b"U" + (1).to_bytes(8, "little")]) == [
+        None, None]
+    # a disconnect between two reads is seen by the second
+    assert u.disconnect()
+    assert u.lookup_many([a + (0).to_bytes(4, "little"),
+                          c + (2).to_bytes(4, "little")]) == [
+        (5000, b"\x51"), None]
+    kv.close()
+
+
 def test_apply_is_idempotent_below_watermark():
     u = UtxoStore(MemoryKV())
     u.apply(3, b"h3", spends=[], creates=[(b"\x03" * 32, 0, 1, b"")])

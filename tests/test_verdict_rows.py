@@ -112,7 +112,7 @@ def native_items(raws: list, oracle):
     """The native extraction as the node makes it: oracle rows for the inputs
     the parse marks, then one extract over the region."""
     with txextract.ParsedTxRegion(b"".join(raws), len(raws)) as region:
-        txids, vouts, wants = region.scan_prevouts(True)
+        txids, _, vouts, wants = region.scan_outpoints(True)
         ext, scripts = [-1] * len(wants), [None] * len(wants)
         for i in np.flatnonzero(wants).tolist():
             ext[i], scripts[i] = oracle(txids[i].tobytes(), int(vouts[i]))

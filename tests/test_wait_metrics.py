@@ -126,11 +126,12 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
     assert names[first:first + len(WAITS)] == list(WAITS)
     after = names[first + len(WAITS):]
     assert after[:len(CONNECT)] == list(CONNECT)
-    # PR 27's, appended in their turn, then PR 28's
+    # PR 27's, appended in their turn, then PR 28's, then PR 30's
     assert after[len(CONNECT):] == [
         "reuse.hit_share", "reuse.ms_per_block", "reuse.cpu_ms_per_block",
         "tip.relay_verdict_p50_ms", "tip.block_verdict_p50_ms",
-        "open.late_p99_ms", "open.verdict_p99_ms", "commit.ms_per_ktx"]
+        "open.late_p99_ms", "open.verdict_p99_ms", "commit.ms_per_ktx",
+        "resolve.us_per_input", "resolve.oracle_share"]
 
 
 def test_the_two_connect_metrics_read_the_utxo_connect_span():
@@ -261,3 +262,70 @@ def test_commit_ms_per_ktx_reads_the_commit_span_in_every_cell(cell):
     # a window in which no tx reached the engine: left out, not 0
     idle = harness.read_per_layer(ctx, reading(trace=None))
     assert "commit.ms_per_ktx" not in idle
+
+
+HOST_CPU_CELLS = {m["name"]: set(m["workloads"]) for m in BENCH["end_to_end"]
+                  if "workloads" in m}["host_cpu_ms_per_ksig"]
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_resolve_us_per_input_reads_the_resolve_span_in_every_cell(cell):
+    """ISSUE 30: open time of ``node.resolve`` per wanted row, in every cell,
+    traced or not.  The counter is new, so a commit without it (the parent)
+    reports nothing rather than a false 0."""
+    entry = {m["name"]: m for m in BENCH["per_layer"]}["resolve.us_per_input"]
+    assert set(entry["workloads"]) == CELLS
+    assert (entry["layer"], entry["moves"]) == ("extraction", "sigs_per_s")
+    assert (entry["unit"], entry["better"], entry["source"]) == (
+        "us/input", "lower", "program_span")
+    ctx = harness.Ctx(workload={"name": cell}, bench=BENCH, config={},
+                      traffic={}, seed=0, seconds=4.0, trace=False,
+                      rehearsal=None, t_start=0.0)
+    # two blocks of 133,344 wanted rows, 0.5 s of node.resolve each
+    window = dict(COUNTERS, **{
+        "node.resolve_rows": 266688.0, "node.resolve_oracle_calls": 266688.0,
+        "span.node.resolve.seconds": 1.0, "span.node.resolve.count": 2.0,
+        "span.node.resolve.cpu_seconds": 0.4})
+    got = harness.read_per_layer(ctx, reading(window, trace=None))
+    assert got["resolve.us_per_input"]["unit"] == "us/input"
+    assert got["resolve.us_per_input"]["value"] == pytest.approx(1e6 / 266688)
+    # the parent: the span laid over it or not, no row counter, no metric
+    parent = {k: v for k, v in window.items() if not k.startswith("node.res")}
+    assert "resolve.us_per_input" not in harness.read_per_layer(
+        ctx, reading(parent, trace=None))
+    assert "resolve.us_per_input" not in harness.read_per_layer(
+        ctx, reading(trace=None))
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_resolve_oracle_share_reads_the_two_counters(cell):
+    """ISSUE 30: the share of the wanted rows that reached the embedder's
+    ``prevout_lookup``.  It moves ``host_cpu_ms_per_ksig``, so it is listed
+    in the cells that report that metric: every cell but ``mempool``."""
+    entry = {m["name"]: m for m in BENCH["per_layer"]}["resolve.oracle_share"]
+    assert set(entry["workloads"]) == HOST_CPU_CELLS == CELLS - {
+        "bch-node.mempool"}
+    assert (entry["layer"], entry["moves"]) == ("extraction",
+                                                "host_cpu_ms_per_ksig")
+    assert (entry["unit"], entry["better"], entry["source"]) == (
+        "%", "lower", "program_counter")
+    ctx = harness.Ctx(workload={"name": cell}, bench=BENCH, config={},
+                      traffic={}, seed=0, seconds=4.0, trace=False,
+                      rehearsal=None, t_start=0.0)
+    # a tip block's unseen 5%: 340 rows, the mempool answers 40 of them
+    window = dict(COUNTERS, **{
+        "node.resolve_rows": 340.0, "node.resolve_oracle_calls": 300.0,
+        "span.node.resolve.seconds": 0.001})
+    got = harness.read_per_layer(ctx, reading(window, trace=None))
+    assert ("resolve.oracle_share" in got) == (cell in HOST_CPU_CELLS)
+    if cell in HOST_CPU_CELLS:
+        assert got["resolve.oracle_share"]["unit"] == "%"
+        assert got["resolve.oracle_share"]["value"] == pytest.approx(
+            100 * 300 / 340)
+        # every row answered by the program's own sources: 0, not nothing
+        own = dict(window, **{"node.resolve_oracle_calls": 0.0})
+        assert harness.read_per_layer(ctx, reading(own, trace=None))[
+            "resolve.oracle_share"]["value"] == 0.0
+    # no wanted row in the window (or the parent commit): left out
+    assert "resolve.oracle_share" not in harness.read_per_layer(
+        ctx, reading(trace=None))

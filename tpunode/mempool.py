@@ -397,6 +397,18 @@ class Mempool:
             return e.outputs[vout]
         return None
 
+    def lookup_prevouts(self, txids: "list[bytes]", vouts: "list[int]") -> list:
+        """:meth:`lookup_prevout` for every ``(txid, vout)`` pair, in
+        order (the resolve walk's batch read): the same rule, the entries
+        fetched in one call."""
+        return [
+            e.outputs[vout]
+            if e is not None and e.outputs is not None
+            and 0 <= vout < len(e.outputs)
+            else None
+            for e, vout in zip(self._seen.get_many(txids), vouts)
+        ]
+
     def finished(self) -> int:
         """Entries holding a relay verdict with its extraction stats:
         with none, the block path does not look anything up."""

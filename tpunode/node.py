@@ -150,6 +150,13 @@ def _native_extract_available() -> bool:
             log.info("[Node] native tx extractor unavailable; python path")
     return _native_extract_state
 
+
+def _rows_of(table: "np.ndarray") -> "list[bytes]":
+    """A C-contiguous ``(n, width)`` uint8 table as ``n`` ``bytes``, in one
+    conversion (no slice a row)."""
+    return table.reshape(-1).view(f"V{table.shape[1]}").tolist()
+
+
 def _prevout_info(res) -> "tuple[Optional[int], Optional[bytes]]":
     """Normalize a ``prevout_lookup`` result: plain satoshi amount (the
     pre-taproot form), an ``(amount, scriptPubKey)`` tuple, or None."""
@@ -1011,22 +1018,30 @@ class Node:
             return "ibd"
         return "block"
 
+    def _prevout_sources(self):
+        """``(mempool, utxo, embedder's prevout_lookup)``: the prevout
+        sources in their precedence, None where there is none to ask —
+        the mempool's unconfirmed outputs (a child spending an in-mempool
+        parent extracts with full prevout data; an empty mempool misses
+        every lookup and is left out), then the persistent UTXO store's
+        confirmed outputs (ISSUE 9), then ``cfg.prevout_lookup``."""
+        mempool = self.mempool
+        if mempool is not None and not mempool.size():
+            mempool = None
+        return mempool, self.utxo, self.cfg.prevout_lookup
+
     def _prevout_oracle(self):
-        """The prevout lookup the verify paths consult, in precedence
-        order: the mempool's unconfirmed outputs (a child spending an
-        in-mempool parent extracts with full prevout data), then the
-        persistent UTXO store's confirmed outputs (ISSUE 9), then the
-        embedder's ``cfg.prevout_lookup``.  None when nothing can answer
-        — block ingest then skips the whole scan_prevouts + per-input
-        lookup pass (hot path)."""
-        sources = []
-        if self.mempool is not None and self.mempool.size():
-            # an empty mempool misses every lookup: skip it entirely
-            sources.append(self.mempool.lookup_prevout)
-        if self.utxo is not None:
-            sources.append(self.utxo.lookup)
-        if self.cfg.prevout_lookup is not None:
-            sources.append(self.cfg.prevout_lookup)
+        """The prevout lookup the Python verify path consults: one call a
+        row through :meth:`_prevout_sources` in order, the first answer
+        that is not None wins.  None when nothing can answer."""
+        mempool, utxo, oracle = self._prevout_sources()
+        sources = [
+            lookup for lookup in (
+                mempool.lookup_prevout if mempool is not None else None,
+                utxo.lookup if utxo is not None else None,
+                oracle,
+            ) if lookup is not None
+        ]
         if not sources:
             return None
         if len(sources) == 1:
@@ -1442,39 +1457,71 @@ class Node:
         for peer, n in counts.items():
             self.cfg.pub.publish(VerifyShed(peer, n, pending))
 
-    def _resolve_ext_rows(
-        self, region, bch: bool, subset=None
-    ) -> "tuple[Optional[list[int]], Optional[list[Optional[bytes]]]]":
+    def _resolve_ext_rows(self, region, bch: bool, subset=None):
         """External-oracle rows for a parsed region: per-input amounts and
-        scriptPubKeys from the prevout oracle (mempool outputs first,
-        then ``cfg.prevout_lookup``), aligned with the region's flat
-        input order (only rows the tx-level wants gate marks are looked
-        up).  Shared by block and mempool ingest.  ``subset`` (ascending
-        tx indices): the rows of those txs alone, in that order — what
-        ``extract_subset`` takes."""
-        lookup = self._prevout_oracle()
-        if lookup is None:
-            return None, None
-        pv_txids, pv_vouts, pv_wants = region.scan_prevouts(bch)
-        if subset is not None:
-            n_in, _ = region.tx_layout()
-            keep = np.zeros(len(n_in), bool)
-            keep[subset] = True
-            rows = np.flatnonzero(np.repeat(keep, n_in))
-            pv_txids, pv_vouts, pv_wants = (
-                pv_txids[rows], pv_vouts[rows], pv_wants[rows]
-            )
-        ext: list[int] = [-1] * len(pv_wants)
-        ext_scripts: list[Optional[bytes]] = [None] * len(pv_wants)
-        for i in pv_wants.nonzero()[0]:
-            amt, script = _prevout_info(
-                lookup(pv_txids[i].tobytes(), int(pv_vouts[i]))
-            )
-            if amt is not None:
-                ext[int(i)] = amt
-            if script is not None:
-                ext_scripts[int(i)] = script
-        return ext, ext_scripts
+        scriptPubKeys, aligned with the region's flat input order —
+        ``(amounts, -1 unknown; scripts, None unknown)``, two lists, or
+        ``(None, None)`` when nothing can answer.  Only rows the tx-level
+        wants gate marks are looked up.  ``subset`` (ascending tx
+        indices): the rows of those txs alone, in that order — what
+        ``extract_subset`` takes.  Shared by block and mempool ingest.
+
+        :meth:`_prevout_sources` answer in their precedence, a source at
+        a time over the rows still unanswered, and the first answer that
+        is not None wins: the mempool's unconfirmed outputs and the UTXO
+        set in one batch read each, then the embedder's
+        ``cfg.prevout_lookup``, called with ``(bytes, int)`` once for
+        every row left, in ascending row order.  Every column of the
+        native scan is converted once a call and the rest is plain Python
+        over it (no numpy scalar a row; nothing here casts, which would
+        give the GIL up in the middle of the hold).  The rows go on as
+        lists: the extract converts them in its worker, and what it
+        cannot convert (a mempool output is a peer's u64) it refuses
+        under its caller's handler.  ONE hold of the loop: no ``await``
+        between the first read and the last, and nothing kept from one
+        call to the next."""
+        mempool, utxo, oracle = self._prevout_sources()
+        if mempool is None and utxo is None and oracle is None:
+            return None, None  # block ingest then skips the whole scan
+        with span("node.resolve"):
+            txids, outpoints, vouts, wants = region.scan_outpoints(bch, subset)
+            todo = wants.nonzero()[0].tolist()  # wanted, unanswered yet
+            metrics.inc("node.resolve_rows", len(todo))
+            txids = _rows_of(txids)
+            vouts = vouts.tolist()
+            amounts = [-1] * len(vouts)
+            scripts: list = [None] * len(vouts)
+
+            def absorb(rows: list, answers) -> list:
+                """``answers`` (one a row, in step) into the two lists;
+                -> the rows one of them left unanswered."""
+                left = []
+                for i, res in zip(rows, answers):
+                    if res is None:
+                        left.append(i)
+                    elif isinstance(res, tuple):  # as _prevout_info reads it
+                        if res[0] is not None:
+                            amounts[i] = res[0]
+                        if res[1]:
+                            scripts[i] = res[1]
+                    else:
+                        amounts[i] = res
+                return left
+
+            # the sources the program owns: one batch read each
+            if mempool is not None:
+                todo = absorb(todo, mempool.lookup_prevouts(
+                    [txids[i] for i in todo], [vouts[i] for i in todo]
+                ))
+            if utxo is not None:
+                keys = _rows_of(outpoints)
+                todo = absorb(todo, utxo.lookup_many([keys[i] for i in todo]))
+            if oracle is not None:
+                metrics.inc("node.resolve_oracle_calls", len(todo))
+                absorb(todo, map(
+                    oracle, [txids[i] for i in todo], [vouts[i] for i in todo]
+                ))
+            return amounts, scripts
 
     def _submit_verify_tx(self, peer, tx) -> None:
         """Mempool-tx ingest: append the tx's raw wire bytes to the batch
@@ -1893,7 +1940,7 @@ class Node:
         DER + pubkey decode run in C++ over the original wire bytes
         (tpunode/txextract.py), and the packed item arrays go to the engine
         with no per-item Python objects — for a block, not even Tx objects
-        (prevouts for the amount oracle come from ``scan_prevouts``, C++
+        (prevouts for the amount oracle come from ``scan_outpoints``, C++
         too).  Bit-identical verdicts to the Python path
         (tests/test_txextract.py); one behavioral difference: a
         malformed-region extract error fails the whole message's txs

@@ -60,6 +60,10 @@ class SeenLru:
         """The entry under the primary key (no alias resolution)."""
         return self._map.get(key, default)
 
+    def get_many(self, keys) -> list:
+        """:meth:`get` for every key, in order (a batch's one call)."""
+        return list(map(self._map.get, keys))
+
     def lookup(self, key):
         """The entry under ``key``, trying the alias index second."""
         e = self._map.get(key)

@@ -57,7 +57,7 @@ import threading
 import time
 import zlib
 from itertools import pairwise, repeat
-from typing import Callable, Iterator, Optional, Protocol, Sequence
+from typing import Callable, Iterable, Iterator, Optional, Protocol, Sequence
 
 import numpy as np
 
@@ -76,6 +76,7 @@ __all__ = [
     "Namespaced",
     "DeltaTail",
     "write_delta",
+    "get_many",
     "StoreCorruption",
     "StoreVersionError",
     "open_store",
@@ -189,6 +190,19 @@ def write_delta(
         own(blob, tail, ns)
     else:
         _write_delta_decoded(kv, blob, tail, ns)
+
+
+def get_many(
+    kv: "KVStore", keys: Iterable[bytes], prefix: bytes = b""
+) -> list[Optional[bytes]]:
+    """``[kv.get(prefix + k) for k in keys]``: through the store's own
+    ``get_many`` where it has one (one dict read a key, no call chain a
+    key), else a ``get`` a key."""
+    own = getattr(kv, "get_many", None)
+    if own is not None:
+        return own(keys, prefix)
+    get = kv.get
+    return [get(prefix + k) for k in keys]
 
 
 def _write_delta_decoded(
@@ -899,6 +913,15 @@ class LogKV:
         metrics.observe("store.read_seconds", time.perf_counter() - t0)
         return out
 
+    def get_many(
+        self, keys: Iterable[bytes], prefix: bytes = b""
+    ) -> list[Optional[bytes]]:
+        """:meth:`get` of ``prefix + k`` for every ``k``, in order: one
+        read of the index a key.  Unsampled: ``store.read_seconds`` stays
+        the single reads' histogram (its 1-in-64 tick does not move)."""
+        get = self._data.get
+        return [get(prefix + k) for k in keys]
+
     def put(self, key: bytes, value: bytes) -> None:
         self.write_batch([put_op(key, value)])
 
@@ -1161,6 +1184,12 @@ class Namespaced:
 
     def get(self, key: bytes) -> Optional[bytes]:
         return self._inner.get(self._k(key))
+
+    def get_many(
+        self, keys: Iterable[bytes], prefix: bytes = b""
+    ) -> list[Optional[bytes]]:
+        """The namespace joins the prefix once, not every key."""
+        return get_many(self._inner, keys, self._ns + prefix)
 
     def put(self, key: bytes, value: bytes) -> None:
         self._inner.put(self._k(key), value)

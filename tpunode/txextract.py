@@ -115,9 +115,11 @@ def load_txextract_lib() -> ctypes.CDLL:
             fn = getattr(lib, name)
             fn.restype = ctypes.c_long
             fn.argtypes = [ctypes.c_void_p]
-        lib.txx_prevouts_h.restype = ctypes.c_long
-        lib.txx_prevouts_h.argtypes = [
-            ctypes.c_void_p, ctypes.c_int, ctypes.c_long, u8, i64, u8,
+        lib.txx_outpoints_h.restype = ctypes.c_long
+        lib.txx_outpoints_h.argtypes = [
+            ctypes.c_void_p, ctypes.c_int,
+            ctypes.c_void_p, ctypes.c_long,  # subset (i32*) or NULL, n_subset
+            ctypes.c_long, u8, u8, i64, u8,
         ]
         lib.txx_extract_h.restype = ctypes.c_long
         lib.txx_extract_h.argtypes = [
@@ -419,21 +421,33 @@ class ParsedTxRegion:
         except Exception:
             pass
 
-    def scan_prevouts(
-        self, bch: bool = False
-    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Same rows as module-level :func:`scan_prevouts`, zero re-parse."""
+    def scan_outpoints(
+        self, bch: bool = False, subset=None
+    ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+        """Module-level :func:`scan_prevouts`' rows with zero re-parse,
+        each outpoint also as it stands on the wire: ``(txids (N,32),
+        outpoints (N,36) uint8 — txid ++ vout_le32, the tail of the UTXO
+        set's key —, vouts (N,) int64, wants (N,) uint8)``.  ``subset``
+        (tx indices): the rows of those txs alone, in that order — what
+        :meth:`extract_subset` takes."""
         assert self._h, "region closed"
         cap = max(1, self.n_inputs)
-        txids = np.zeros((cap, 32), np.uint8)
-        vouts = np.zeros(cap, np.int64)
-        wants = np.zeros(cap, np.uint8)
-        n = self._lib.txx_prevouts_h(
-            self._h, 1 if bch else 0, cap, txids, vouts, wants
+        txids = np.empty((cap, 32), np.uint8)
+        outpoints = np.empty((cap, 36), np.uint8)
+        vouts = np.empty(cap, np.int64)
+        wants = np.empty(cap, np.uint8)
+        if subset is None:
+            sub_ptr, n_sub = None, 0
+        else:
+            subset = np.ascontiguousarray(subset, np.int32)
+            sub_ptr, n_sub = subset.ctypes.data_as(ctypes.c_void_p), len(subset)
+        n = self._lib.txx_outpoints_h(
+            self._h, 1 if bch else 0, sub_ptr, n_sub, cap,
+            txids, outpoints, vouts, wants,
         )
         if n < 0:
-            raise ValueError(f"txx_prevouts_h failed ({n})")
-        return txids[:n], vouts[:n], wants[:n]
+            raise ValueError(f"txx_outpoints_h failed ({n})")
+        return txids[:n], outpoints[:n], vouts[:n], wants[:n]
 
     # -- tx-range sharding (ISSUE 11) ---------------------------------------
 

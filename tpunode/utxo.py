@@ -53,7 +53,7 @@ from typing import Iterable, Optional, Sequence
 
 from .events import events
 from .metrics import metrics
-from .store import BatchOp, KVStore, delete_op, put_op, write_delta
+from .store import BatchOp, KVStore, delete_op, get_many, put_op, write_delta
 
 __all__ = ["UtxoStore", "UTXO_NAMESPACE", "UNDO_DEPTH_DEFAULT"]
 
@@ -115,6 +115,19 @@ class UtxoStore:
         if raw is None:
             return None  # unknown or already spent
         return _AMOUNT.unpack_from(raw)[0], raw[_AMOUNT.size :]
+
+    def lookup_many(
+        self, outpoints: Iterable[bytes]
+    ) -> list[Optional[tuple[int, bytes]]]:
+        """:meth:`lookup` for every outpoint, in order.  An outpoint is
+        its 36 wire bytes — ``txid ++ vout_le32``, the key's tail as it
+        stands — so no key is built from parts, and the store is read in
+        one batch (``store.get_many``)."""
+        unpack, head = _AMOUNT.unpack_from, _AMOUNT.size
+        return [
+            None if raw is None else (unpack(raw)[0], raw[head:])
+            for raw in get_many(self._kv, outpoints, _OUT_PREFIX)
+        ]
 
     # -- block connect -------------------------------------------------------
 
