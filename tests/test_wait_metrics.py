@@ -126,12 +126,15 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
     assert names[first:first + len(WAITS)] == list(WAITS)
     after = names[first + len(WAITS):]
     assert after[:len(CONNECT)] == list(CONNECT)
-    # PR 27's, appended in their turn, then PR 28's, then PR 30's
+    # PR 27's, appended in their turn, then PR 28's, PR 30's, PR 31's
     assert after[len(CONNECT):] == [
         "reuse.hit_share", "reuse.ms_per_block", "reuse.cpu_ms_per_block",
         "tip.relay_verdict_p50_ms", "tip.block_verdict_p50_ms",
         "open.late_p99_ms", "open.verdict_p99_ms", "commit.ms_per_ktx",
-        "resolve.us_per_input", "resolve.oracle_share"]
+        "resolve.us_per_input", "resolve.oracle_share",
+        "utxo.lookup_us_per_row", "utxo.hit_share", "resolve.missing_share",
+        "utxo.snapshot_load_s", "utxo.entries", "store.rss_mb",
+        "store.compactions_in_window"]
 
 
 def test_the_two_connect_metrics_read_the_utxo_connect_span():
@@ -142,9 +145,11 @@ def test_the_two_connect_metrics_read_the_utxo_connect_span():
     by_name = {m["name"]: m for m in BENCH["per_layer"]}
     for name in CONNECT:
         entry = by_name[name]
-        # the cells that connect blocks: PR 27 appended the tip cell
+        # the cells that connect blocks: PR 27 appended the tip cell,
+        # PR 31 its two beside their siblings
         assert entry["workloads"] == ["bch-node.ibd", "bch-32mb.blocks",
-                                      "bch-tip.tip"]
+                                      "bch-tip.tip", "bch-utxo.ibd-spend",
+                                      "bch-32mb.single"]
         assert entry["layer"] == "UTXO connect / store"
         assert entry["moves"] == "host_cpu_ms_per_ksig"
         assert (entry["unit"], entry["better"]) == ("ms/block", "lower")
@@ -240,14 +245,15 @@ def test_commit_ms_per_ktx_reads_the_commit_span_in_every_cell(cell):
     """ISSUE 28: open time of ``node.commit`` per 1000 transactions handed
     to the engine (``node.verify_txs``), in every cell, traced or not; span
     and counter exist at the parent commit, so both sides of a pair read
-    it.  ``commit.ms_per_block`` stays ``ibd``'s alone."""
+    it.  ``commit.ms_per_block`` stays the two IBD cells'."""
     by_name = {m["name"]: m for m in BENCH["per_layer"]}
     entry = by_name["commit.ms_per_ktx"]
     assert set(entry["workloads"]) == CELLS
     assert (entry["layer"], entry["moves"]) == ("verdict publication",
                                                 "sigs_per_s")
     assert (entry["unit"], entry["better"]) == ("ms/ktx", "lower")
-    assert by_name["commit.ms_per_block"]["workloads"] == ["bch-node.ibd"]
+    ibd_cells = ["bch-node.ibd", "bch-utxo.ibd-spend"]
+    assert by_name["commit.ms_per_block"]["workloads"] == ibd_cells
     ctx = harness.Ctx(workload={"name": cell}, bench=BENCH, config={},
                       traffic={}, seed=0, seconds=4.0, trace=False,
                       rehearsal=None, t_start=0.0)
@@ -258,7 +264,7 @@ def test_commit_ms_per_ktx_reads_the_commit_span_in_every_cell(cell):
     got = harness.read_per_layer(ctx, reading(window, trace=None))
     assert got["commit.ms_per_ktx"]["unit"] == "ms/ktx"
     assert got["commit.ms_per_ktx"]["value"] == pytest.approx(1.58e6 / 133344)
-    assert ("commit.ms_per_block" in got) == (cell == "bch-node.ibd")
+    assert ("commit.ms_per_block" in got) == (cell in ibd_cells)
     # a window in which no tx reached the engine: left out, not 0
     idle = harness.read_per_layer(ctx, reading(trace=None))
     assert "commit.ms_per_ktx" not in idle

@@ -151,6 +151,9 @@ def _native_extract_available() -> bool:
     return _native_extract_state
 
 
+_NULL_TXID = b"\x00" * 32  # a coinbase input's outpoint
+
+
 def _rows_of(table: "np.ndarray") -> "list[bytes]":
     """A C-contiguous ``(n, width)`` uint8 table as ``n`` ``bytes``, in one
     conversion (no slice a row)."""
@@ -1518,9 +1521,13 @@ class Node:
                 todo = absorb(todo, utxo.lookup_many([keys[i] for i in todo]))
             if oracle is not None:
                 metrics.inc("node.resolve_oracle_calls", len(todo))
-                absorb(todo, map(
+                todo = absorb(todo, map(
                     oracle, [txids[i] for i in todo], [vouts[i] for i in todo]
                 ))
+            if todo:
+                # rows no source answered: the extractor marks such an
+                # input unsupported and nothing verifies it
+                metrics.inc("node.resolve_missing", len(todo))
             return amounts, scripts
 
     def _submit_verify_tx(self, peer, tx) -> None:
@@ -2285,7 +2292,12 @@ class Node:
                             elif oracle is not None and (
                                 wants_amount(tx, idx, self.cfg.net.bch)
                             ):
-                                amt, script = _prevout_info(oracle(*key))
+                                res = oracle(*key)
+                                # as the native walk counts (a coinbase's
+                                # null outpoint is no row of its)
+                                if res is None and key[0] != _NULL_TXID:
+                                    metrics.inc("node.resolve_missing")
+                                amt, script = _prevout_info(res)
                             else:
                                 amt = script = None
                             if amt is not None:

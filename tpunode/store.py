@@ -77,6 +77,8 @@ __all__ = [
     "DeltaTail",
     "write_delta",
     "get_many",
+    "count_prefix",
+    "key_growth",
     "StoreCorruption",
     "StoreVersionError",
     "open_store",
@@ -181,15 +183,16 @@ def _decode_delta(blob: bytes, ns: bytes = b"") -> tuple[list[BatchOp], int]:
 
 def write_delta(
     kv: "KVStore", blob: bytes, tail: DeltaTail, ns: bytes = b""
-) -> None:
+) -> int:
     """Apply a delta blob and its ``tail`` to ``kv`` as ONE atomic batch:
     through the store's own ``write_delta`` where it has one (LogKV frames
-    the blob natively), else decoded into a plain ``write_batch``."""
+    the blob natively), else decoded into a plain ``write_batch``.  ->
+    what the delta did to the number of keys (:func:`key_growth` of its
+    ops, the tail's aside)."""
     own = getattr(kv, "write_delta", None)
     if own is not None:
-        own(blob, tail, ns)
-    else:
-        _write_delta_decoded(kv, blob, tail, ns)
+        return own(blob, tail, ns)
+    return _write_delta_decoded(kv, blob, tail, ns)
 
 
 def get_many(
@@ -205,16 +208,36 @@ def get_many(
     return [get(prefix + k) for k in keys]
 
 
+def count_prefix(kv: "KVStore", prefix: bytes) -> int:
+    """How many keys start with ``prefix``: through the store's own
+    ``count_prefix`` where it has one (a walk of the keys: no sort, no
+    value read), else by counting a ``scan_prefix``."""
+    own = getattr(kv, "count_prefix", None)
+    if own is not None:
+        return own(prefix)
+    return sum(1 for _ in kv.scan_prefix(prefix))
+
+
+def key_growth(kv: "KVStore", ops: Sequence[BatchOp]) -> int:
+    """What ``ops`` will do to the number of keys in ``kv``, read before
+    they are written: the last op on a key decides whether it is there
+    afterwards, the store whether it was there before."""
+    after = {k: op == "put" for op, k, _ in ops}
+    return sum(after.values()) - sum(kv.get(k) is not None for k in after)
+
+
 def _write_delta_decoded(
     kv: "KVStore", blob: bytes, tail: DeltaTail, ns: bytes
-) -> None:
+) -> int:
     ops, n_puts = _decode_delta(blob, ns)
     del_keys = [k for _, k, _ in ops[n_puts:]]
+    grew = key_growth(kv, ops)
     ops.extend(tail(
         [k for _, k, _ in ops[:n_puts]], del_keys,
         [kv.get(k) for k in del_keys], len(ns),
     ))
     kv.write_batch(ops)
+    return grew
 
 
 def _split(buf: np.ndarray, lens: np.ndarray) -> list[bytes]:
@@ -960,7 +983,7 @@ class LogKV:
 
     def write_delta(
         self, blob: bytes, tail: DeltaTail, ns: bytes = b""
-    ) -> None:
+    ) -> int:
         """:meth:`write_batch` for a batch that arrives serialised: a v1
         *delta* blob (:func:`_decode_delta`'s format — a block's UTXO
         creates and spends from the extractor) with ``ns`` in front of
@@ -978,8 +1001,7 @@ class LogKV:
         key written through both APIs holds here as there."""
         lib = _delta_framer()
         if lib is None:
-            _write_delta_decoded(self, blob, tail, ns)
-            return
+            return _write_delta_decoded(self, blob, tail, ns)
         self._check_failed()
         if chaos.on:  # injected write failure (tpunode/chaos.py)
             chaos.maybe_raise("store.write", self.path)
@@ -1018,6 +1040,7 @@ class LogKV:
             # delete supersedes moves from live to dead
             head = _REC_V2.size
             moved = 0
+            before = len(data)
             for k, v in zip(put_keys, put_vals):
                 old = data.get(k)
                 if old is not None:
@@ -1026,6 +1049,7 @@ class LogKV:
             for k, old in zip(del_keys, map(data.pop, del_keys, repeat(None))):
                 if old is not None:
                     moved += head + len(k) + len(old)
+            grew = len(data) - before  # the delta's own: the tail is next
             put_bytes = (
                 head * n_puts + int(lens[:n_puts].sum()) + val_bytes
             )
@@ -1036,6 +1060,7 @@ class LogKV:
         if not metrics.disabled:
             metrics.observe("store.write_seconds", time.perf_counter() - t0)
             metrics.inc("store.writes", n + len(tail_ops))
+        return grew
 
     def write_batch_async(
         self, ops: Sequence[BatchOp]
@@ -1066,6 +1091,13 @@ class LogKV:
             metrics.inc("store.writes", len(ops))
         return self._writer.submit(ops)
 
+    def count_prefix(self, prefix: bytes) -> int:
+        """Keys that start with ``prefix``: one walk of the index's keys
+        under the lock (a writer may not resize it meanwhile), nothing
+        sorted, no value read."""
+        with self._lock:
+            return sum(map(bytes.startswith, self._data, repeat(prefix)))
+
     def scan_prefix(self, prefix: bytes) -> Iterator[tuple[bytes, bytes]]:
         with self._lock:  # stable order vs the group-commit thread
             keys = sorted(k for k in self._data if k.startswith(prefix))
@@ -1077,9 +1109,15 @@ class LogKV:
     # -- compaction ----------------------------------------------------------
 
     def _maybe_compact(self) -> None:
-        if self._dead_bytes < 1 << 20 or self._dead_bytes < 3 * self._live_bytes:
-            return
-        self.compact()
+        """After every write: compact once dead bytes dominate, and
+        publish the two sums the decision reads."""
+        if (
+            self._dead_bytes >= 1 << 20
+            and self._dead_bytes >= 3 * self._live_bytes
+        ):
+            self.compact()
+        metrics.set_gauge("store.live_bytes", float(self._live_bytes))
+        metrics.set_gauge("store.dead_bytes", float(self._dead_bytes))
 
     def compact(self) -> None:
         """Crash-atomic compaction: write a full v2 snapshot to
@@ -1202,7 +1240,7 @@ class Namespaced:
 
     def write_delta(
         self, blob: bytes, tail: DeltaTail, ns: bytes = b""
-    ) -> None:
+    ) -> int:
         """The blob goes down as it is, this view's namespace with it;
         the tail's ops come back in the caller's key space and get the
         namespace here."""
@@ -1212,7 +1250,10 @@ class Namespaced:
                 for op, k, v in tail(put_keys, del_keys, del_olds, strip)
             ]
 
-        write_delta(self._inner, blob, outer, self._ns + ns)
+        return write_delta(self._inner, blob, outer, self._ns + ns)
+
+    def count_prefix(self, prefix: bytes) -> int:
+        return count_prefix(self._inner, self._k(prefix))
 
     def scan_prefix(self, prefix: bytes) -> Iterator[tuple[bytes, bytes]]:
         n = len(self._ns)

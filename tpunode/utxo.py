@@ -41,9 +41,17 @@ store, which frames it natively into the records it appends and gives
 back only the keys and pre-spend values the undo record is made of
 (ISSUE 26) — the same bytes in the same one append as the reference path.
 
+A set can also arrive whole (ISSUE 31): :meth:`UtxoStore.load_snapshot`
+fills an EMPTY store from batches of entries — the node that starts from a
+UTXO snapshot (Bitcoin Core's ``loadtxoutset``) instead of replaying the
+chain.  No undo record (there is no block to disconnect), the watermark
+in the last batch; a crash mid-load leaves a marker and no watermark, and
+the next load starts over (ROBUSTNESS.md).
+
 Schema (within the namespaced view): ``b"o" + txid + vout_le32`` ->
 ``amount_le64 + scriptPubKey``; ``b"!wm"`` -> ``height_le64 + block_hash``;
-``b"U" + height_le64`` -> undo record.
+``b"U" + height_le64`` -> undo record; ``b"!ld"`` -> a snapshot load has
+begun (gone with the batch that writes the watermark).
 """
 
 from __future__ import annotations
@@ -53,9 +61,15 @@ from typing import Iterable, Optional, Sequence
 
 from .events import events
 from .metrics import metrics
-from .store import BatchOp, KVStore, delete_op, get_many, put_op, write_delta
+from .store import (
+    BatchOp, KVStore, count_prefix, delete_op, get_many, key_growth, put_op,
+    write_delta,
+)
+from .trace import span
 
-__all__ = ["UtxoStore", "UTXO_NAMESPACE", "UNDO_DEPTH_DEFAULT"]
+__all__ = [
+    "UtxoStore", "UTXO_NAMESPACE", "UNDO_DEPTH_DEFAULT", "snapshot_batch",
+]
 
 #: The namespace the node mounts the UTXO set under on its main store.
 UTXO_NAMESPACE = b"u/"
@@ -65,12 +79,16 @@ UTXO_NAMESPACE = b"u/"
 UNDO_DEPTH_DEFAULT = 100
 
 _WM_KEY = b"!wm"
+_LOAD_KEY = b"!ld"
 _OUT_PREFIX = b"o"
 _UNDO_PREFIX = b"U"
 _AMOUNT = struct.Struct("<q")
 _WM = struct.Struct("<q")
 _U32 = struct.Struct("<I")
+_PUT_V1 = struct.Struct("<BII")  # a delta blob's record head: op, klen, vlen
 _ZERO_TXID = b"\x00" * 32
+#: keys a delete batch when an unfinished load is cleared away
+_WIPE_BATCH = 1 << 16
 
 
 def _okey(txid: bytes, vout: int) -> bytes:
@@ -79,6 +97,21 @@ def _okey(txid: bytes, vout: int) -> bytes:
 
 def _ukey(height: int) -> bytes:
     return _UNDO_PREFIX + _WM.pack(height)
+
+
+def snapshot_batch(entries: Iterable[tuple[bytes, int, int, bytes]]) -> bytes:
+    """``(txid, vout, amount, script)`` entries as one batch of
+    :meth:`UtxoStore.load_snapshot`: a delta blob of puts (the format of
+    ``ParsedTxRegion.utxo_ops`` — ``op=1, klen, vlen`` little-endian, then
+    ``b"o" + txid + vout_le32`` and ``amount_le64 + script``).  The plain
+    way to make one; a loader with its set in columns packs the same
+    records without a Python object an entry."""
+    parts = []
+    for txid, vout, amount, script in entries:
+        key = _okey(txid, vout)
+        parts.append(_PUT_V1.pack(1, len(key), _AMOUNT.size + len(script)))
+        parts.append(key + _AMOUNT.pack(amount) + script)
+    return b"".join(parts)
 
 
 class UtxoStore:
@@ -95,6 +128,17 @@ class UtxoStore:
             self._block_hash = wm[_WM.size :] or None
         if self._height >= 0:
             metrics.set_gauge("utxo.height", float(self._height))
+        # a snapshot load that began and never wrote its watermark: what
+        # it left is no set (a prefix of one), and nothing connects over
+        # it until a load starts over
+        self._load_unfinished = wm is None and kv.get(_LOAD_KEY) is not None
+        if self._load_unfinished:
+            metrics.inc("utxo.load_unfinished")
+            events.emit("utxo.load_unfinished")
+        # live outputs: counted once at open (one walk of the index's
+        # keys, the only one there is), then kept by every write
+        self._entries = count_prefix(kv, _OUT_PREFIX)
+        metrics.set_gauge("utxo.entries", float(self._entries))
 
     # -- prevout oracle ------------------------------------------------------
 
@@ -107,6 +151,11 @@ class UtxoStore:
     @property
     def block_hash(self) -> Optional[bytes]:
         return self._block_hash
+
+    @property
+    def entries(self) -> int:
+        """Live outputs in the set (the ``utxo.entries`` gauge)."""
+        return self._entries
 
     def lookup(self, txid: bytes, vout: int) -> Optional[tuple[int, bytes]]:
         """The prevout-oracle callable (``NodeConfig.prevout_lookup``
@@ -122,12 +171,98 @@ class UtxoStore:
         """:meth:`lookup` for every outpoint, in order.  An outpoint is
         its 36 wire bytes — ``txid ++ vout_le32``, the key's tail as it
         stands — so no key is built from parts, and the store is read in
-        one batch (``store.get_many``)."""
+        one batch (``store.get_many``).  One ``utxo.lookup`` span a call;
+        ``utxo.lookup_rows`` counts what was asked and ``utxo.lookup_hits``
+        what the set held."""
         unpack, head = _AMOUNT.unpack_from, _AMOUNT.size
-        return [
-            None if raw is None else (unpack(raw)[0], raw[head:])
-            for raw in get_many(self._kv, outpoints, _OUT_PREFIX)
-        ]
+        with span("utxo.lookup"):
+            raws = get_many(self._kv, outpoints, _OUT_PREFIX)
+            out = [
+                None if raw is None else (unpack(raw)[0], raw[head:])
+                for raw in raws
+            ]
+        metrics.inc("utxo.lookup_rows", len(raws))
+        metrics.inc("utxo.lookup_hits", len(raws) - raws.count(None))
+        return out
+
+    # -- snapshot load (ISSUE 31) --------------------------------------------
+
+    def load_snapshot(
+        self, height: int, block_hash: bytes, batches: Iterable[bytes]
+    ) -> int:
+        """Fill an EMPTY set from a snapshot taken at ``(height,
+        block_hash)``; -> the entries loaded.  ``batches`` yields delta
+        blobs of puts (:func:`snapshot_batch`'s format); each goes through
+        the store's own write path as it stands (``store.write_delta``: one
+        native framing, one append, one fsync under ``fsync=True``, no
+        Python object an entry but the two the index keeps), so a batch is
+        also one hold of the store's lock: some 10^5 entries is a good
+        size.  No undo record — there is no block under a snapshot to
+        disconnect to.
+
+        A store with a watermark refuses (``ValueError``): a snapshot never
+        lands on a set that blocks built.  The crash contract: a marker is
+        durable before the first entry, and the batch that carries the
+        watermark takes it away; a crash in between leaves the marker and
+        no watermark, the next open reports ``utxo.load_unfinished`` and
+        connects nothing, and the next ``load_snapshot`` clears what is
+        there and starts over."""
+        if self._height >= 0:
+            raise ValueError(
+                f"load_snapshot needs an empty set: the watermark is at "
+                f"height {self._height}"
+            )
+        if height < 0 or not block_hash:
+            raise ValueError("a snapshot is taken at a block")
+        with span("utxo.load"):
+            if self._load_unfinished or self._entries:
+                self._wipe_outputs()
+            self._kv.write_batch([put_op(_LOAD_KEY, b"\x01")])
+            self._load_unfinished = True
+            held = b""  # one behind, so that the last batch is known
+            for blob in batches:
+                if held:
+                    self._entries += write_delta(
+                        self._kv, held, lambda *_: ()
+                    )
+                held = blob
+            closing = [
+                put_op(_WM_KEY, _WM.pack(height) + block_hash),
+                delete_op(_LOAD_KEY),
+            ]
+            if held:
+                self._entries += write_delta(
+                    self._kv, held, lambda *_: closing
+                )
+            else:
+                self._kv.write_batch(closing)
+            self._load_unfinished = False
+            self._height, self._block_hash = height, block_hash
+        metrics.set_gauge("utxo.height", float(height))
+        metrics.set_gauge("utxo.entries", float(self._entries))
+        metrics.inc("utxo.loaded", self._entries)
+        events.emit("utxo.snapshot", height=height, entries=self._entries)
+        return self._entries
+
+    def _wipe_outputs(self) -> None:
+        """Delete every output row, in bounded batches: what an unfinished
+        load left behind."""
+        keys: list[bytes] = []
+        for key, _ in self._kv.scan_prefix(_OUT_PREFIX):
+            keys.append(key)
+            if len(keys) >= _WIPE_BATCH:
+                self._kv.write_batch([delete_op(k) for k in keys])
+                keys = []
+        if keys:
+            self._kv.write_batch([delete_op(k) for k in keys])
+        self._entries = 0
+
+    def _refuse_over_unfinished_load(self) -> None:
+        if self._load_unfinished:
+            raise RuntimeError(
+                "the UTXO set holds an unfinished snapshot load: call "
+                "load_snapshot again before connecting blocks"
+            )
 
     # -- block connect -------------------------------------------------------
 
@@ -154,6 +289,7 @@ class UtxoStore:
         if height <= self._height:
             metrics.inc("utxo.skipped")
             return False
+        self._refuse_over_unfinished_load()
         ops: list[BatchOp] = []
         created_keys: list[bytes] = []
         spent_pairs: list[tuple[bytes, bytes]] = []
@@ -219,6 +355,7 @@ class UtxoStore:
         if height <= self._height:
             metrics.inc("utxo.skipped")
             return False
+        self._refuse_over_unfinished_load()
 
         def tail(put_keys, del_keys, del_olds, strip):
             spent_pairs, created_keys = [], []
@@ -232,8 +369,8 @@ class UtxoStore:
                 height, block_hash, spent_pairs, created_keys
             )
 
-        write_delta(self._kv, blob, tail)
-        self._advance(height, block_hash, created, spent)
+        grew = write_delta(self._kv, blob, tail)
+        self._advance(height, block_hash, created, spent, grew)
         events.emit(
             "utxo.block", height=height, created=created, spent=spent,
         )
@@ -250,11 +387,12 @@ class UtxoStore:
         spent: int,
     ) -> bool:
         """One atomic connect: delta + undo record + watermark."""
+        grew = key_growth(self._kv, ops)  # the delta's: live outputs
         ops.extend(
             self._tail_ops(height, block_hash, spent_pairs, created_keys)
         )
         self._kv.write_batch(ops)
-        self._advance(height, block_hash, created, spent)
+        self._advance(height, block_hash, created, spent, grew)
         return True
 
     def _tail_ops(
@@ -283,10 +421,13 @@ class UtxoStore:
         return ops
 
     def _advance(
-        self, height: int, block_hash: bytes, created: int, spent: int
+        self, height: int, block_hash: bytes, created: int, spent: int,
+        grew: int,
     ) -> None:
         self._height, self._block_hash = height, block_hash
+        self._entries += grew
         metrics.set_gauge("utxo.height", float(height))
+        metrics.set_gauge("utxo.entries", float(self._entries))
         metrics.inc("utxo.applied")
         metrics.inc("utxo.created", created)
         metrics.inc("utxo.spent", spent)
@@ -363,6 +504,7 @@ class UtxoStore:
             pos += klen
         for key, val in restores:
             ops.append(put_op(key, val))
+        grew = key_growth(self._kv, ops)  # output rows only, so far
         ops.append(delete_op(_ukey(self._height)))
         if prior_height >= 0:
             ops.append(put_op(
@@ -374,7 +516,9 @@ class UtxoStore:
         disconnected = self._height
         self._height = prior_height
         self._block_hash = prior_hash if prior_height >= 0 else None
+        self._entries += grew
         metrics.set_gauge("utxo.height", float(max(prior_height, -1)))
+        metrics.set_gauge("utxo.entries", float(self._entries))
         metrics.inc("utxo.disconnected")
         events.emit(
             "utxo.undo", height=disconnected,
