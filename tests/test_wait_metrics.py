@@ -126,7 +126,8 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
     assert names[first:first + len(WAITS)] == list(WAITS)
     after = names[first + len(WAITS):]
     assert after[:len(CONNECT)] == list(CONNECT)
-    # PR 27's, appended in their turn, then PR 28's, PR 30's, PR 31's
+    # PR 27's, appended in their turn, then PR 28's, PR 30's, PR 31's,
+    # PR 32's
     assert after[len(CONNECT):] == [
         "reuse.hit_share", "reuse.ms_per_block", "reuse.cpu_ms_per_block",
         "tip.relay_verdict_p50_ms", "tip.block_verdict_p50_ms",
@@ -134,7 +135,7 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
         "resolve.us_per_input", "resolve.oracle_share",
         "utxo.lookup_us_per_row", "utxo.hit_share", "resolve.missing_share",
         "utxo.snapshot_load_s", "utxo.entries", "store.rss_mb",
-        "store.compactions_in_window"]
+        "store.compactions_in_window", "stream.early_share"]
 
 
 def test_the_two_connect_metrics_read_the_utxo_connect_span():

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import contextvars
 import functools
 import logging
 import os
@@ -1565,6 +1566,11 @@ class Node:
     # Minimum txs per extraction shard: below this the per-shard native
     # call overhead beats the parallelism.
     MIN_SHARD_TXS = 64
+    # Most txs in one extract job of a big block (ISSUE 32): a wave of the
+    # four workers' jobs is then about one device_batch lane (4 x 3,072
+    # txs x 2.75 items a tx = 33.8k items of 32,768), and the first lane
+    # leaves a sixth of the way into the block's extract.  (_n_extract_jobs)
+    STREAM_SHARD_TXS = 3072
 
     def _pool_for(self, host: Optional[str]) -> Optional[ThreadPoolExecutor]:
         """The extract pool feeding ``host`` (ISSUE 19): its lazy
@@ -1644,17 +1650,14 @@ class Node:
             tr.end(rec)
 
     @staticmethod
-    def _extract_and_close(region, subset=None, **kw):
-        """Worker-thread tail of a shard extract: the thread that runs
-        the native extract (of the whole region, or of ``subset``) also
-        frees the handle.  Closing from the loop
-        side would race a cancelled-but-still-running extract (awaiting
-        an executor future stops WAITING on cancellation, it does not
-        stop the thread) — txx_parse_free under a live txx_extract_h2 is
-        a native use-after-free (review finding)."""
+    def _extract_and_close(region, **kw):
+        """Worker-thread tail of a drain shard's extract: the thread that
+        runs the native extract also frees the handle.  Closing from the
+        loop side would race a cancelled-but-still-running extract
+        (awaiting an executor future stops WAITING on cancellation, it
+        does not stop the thread) — txx_parse_free under a live
+        txx_extract_h2 is a native use-after-free (review finding)."""
         try:
-            if subset is not None:
-                return region.extract_subset(subset, **kw)
             return region.extract(**kw)
         finally:
             region.close()
@@ -1949,15 +1952,26 @@ class Node:
         with no per-item Python objects — for a block, not even Tx objects
         (prevouts for the amount oracle come from ``scan_outpoints``, C++
         too).  Bit-identical verdicts to the Python path
-        (tests/test_txextract.py); one behavioral difference: a
-        malformed-region extract error fails the whole message's txs
-        (the Python path can fail per tx)."""
+        (tests/test_txextract.py).
+
+        A message's txs are cut into shards (:meth:`_n_extract_jobs`),
+        and each shard is a chain of its own — extract job →
+        ``verify_raw`` → ``node.commit`` — that goes to the engine the
+        moment ITS job is out of the pool (ISSUE 32): a big block's first
+        lanes run while its later shards are still being extracted.  One
+        behavioral difference to the Python path: an extract error fails
+        every tx of the message that has no verdict yet (the failed job's
+        and those of the jobs not handed on; the Python path can fail
+        per tx)."""
         assert self.verify_engine is not None
         from .txextract import ParsedTxRegion
 
         bch = self.cfg.net.bch
+        relay = block is None
 
-        def _publish_extract_error(e: Exception) -> None:
+        def _publish_extract_error(e: Exception, ranges=None) -> None:
+            """Error verdicts for the txs still to verify at positions
+            ``ranges`` (``(lo, hi)`` pairs; None: all of them)."""
             self._verify_failure("extract", e)
             txids: list[bytes] = []
             try:
@@ -1967,6 +1981,8 @@ class Node:
                 else:
                     src = txs if txs is not None else block.txs
                     txids = [tx.txid for tx in src]
+                if ranges is not None:
+                    txids = [t for lo, hi in ranges for t in txids[lo:hi]]
             except Exception:
                 # tx region unparseable (lazy tx/block): one aggregate
                 # verdict, and the peer dies as under eager decode
@@ -1976,19 +1992,28 @@ class Node:
                 self._publish_verdict(
                     TxVerdict(peer, txid, False, (), ExtractStats(),
                               error=f"extract: {e}"),
-                    relay=block is None,
+                    relay=relay,
                 )
 
         block_txids: Optional[list[bytes]] = None
         subset = None  # a block's tx indices still to verify; None = all
         region: Optional[ParsedTxRegion] = None
         delta = None  # the block's (ops blob, created, spent), if wanted
-        submitted = False  # once the extract job is in a worker thread,
-        # that thread owns region.close (see _extract_and_close)
+        submitted = False  # once its jobs are in the pool, the last of
+        # them out closes the region (_close_when_done)
+        cfuts: list = []  # a shard's extract job in the pool, in tx order,
+        jobs: list[asyncio.Future] = []  # and the same as the loop awaits it
+        commits: list[asyncio.Task] = []  # the shards handed on
+        failed = None  # the first job that raised
+        # a shard's chain is the message's child in its trace, beside
+        # node.extract and not under it: spawned in the context as it is here
+        outside = contextvars.copy_context()
         try:
             # ONE native parse feeds both the prevout listing and the
             # extraction (ParsedTxRegion; the amount-oracle path used to
-            # parse the region twice more).
+            # parse the region twice more).  The span stays open until
+            # the LAST extract job is out: a block's first commits begin
+            # inside it.
             with span("node.extract"):
                 try:
                     # shared worker pool (ISSUE 10): several blocks'
@@ -2023,83 +2048,100 @@ class Node:
                 # consults its intra-block map FIRST, so resolving every
                 # wants-marked input here matches the Python path's
                 # block_outs -> prevout_lookup precedence (an in-block hit
-                # shadows whatever the oracle would have said).
+                # shadows whatever the oracle would have said).  One hold
+                # for the whole message, before any job: every shard's
+                # rows are read from the sources at the same moment.
                 ext, ext_scripts = self._resolve_ext_rows(
                     region, bch, subset
                 )
-                # BLOCK regions shard across the worker pool as contiguous
-                # tx ranges (ISSUE 11), exactly like mempool drains: the
-                # intra-block prevout map is built ONCE on the shared
-                # handle (read-only for the range jobs), so sharded
-                # extraction is bit-identical to serial (pinned by
-                # tests/test_txextract.py).  With relay verdicts read, the
-                # jobs are runs of the txs still to verify (ISSUE 27).
-                n_todo = region.n_txs if subset is None else len(subset)
-                shard_block = (
-                    block is not None
-                    and self._extract_workers > 1
-                    and n_todo >= 2 * self.MIN_SHARD_TXS
+                if block_txids is not None:
+                    # block connect: evict confirmed txs from the mempool —
+                    # the whole block's, answered from relay or not.  The
+                    # txids come from the native parse — no Python parse —
+                    # and leave before the first job does, so before any
+                    # verdict of the engine's.
+                    assert self.mempool is not None
+                    self.mempool.confirmed(block_txids)
+                # every shard is its own engine submission (the lane
+                # packer coalesces them into full device lanes);
+                # planner-era backfill rides the "ibd" class beneath live
+                # traffic
+                priority = (
+                    self._block_priority() if block is not None else "mempool"
                 )
-                try:
-                    if n_todo == 0:
-                        shards = []  # every tx answered: nothing to extract
-                    elif shard_block:
-                        submitted = True
-                        shards = await self._extract_block_sharded(
-                            region, bch, ext, ext_scripts, subset
+                # block affinity (ISSUE 19): a block's shards share one key
+                # (the block hash) so the whole block verifies on one host —
+                # its shards pack together instead of scattering
+                aff = None
+                if self._fleet_affine():
+                    try:
+                        aff = affinity_key(
+                            block.header.hash if block is not None
+                            else txs[0].txid if txs else b""
                         )
-                    else:
+                    except Exception:
+                        aff = None
+                # Contiguous tx ranges (ISSUE 11) — with relay verdicts
+                # read, runs of the txs still to verify (ISSUE 27) — go to
+                # the pool together; the intra-block prevout map is built
+                # ONCE on the shared handle (read-only for the jobs), so
+                # sharded extraction is bit-identical to serial (pinned
+                # by tests/test_txextract.py).
+                n_todo = region.n_txs if subset is None else len(subset)
+                ranges: list = []
+                try:
+                    if n_todo:  # else every tx was answered from relay
                         submitted = True
-                        shards = [await self._run_extract_owned(
-                            region,
-                            subset=subset,
-                            bch=bch,
-                            intra_amounts=n_txs > 1,
-                            ext_amounts=ext,
-                            ext_scripts=ext_scripts,
-                        )]
+                        cfuts, ranges = await self._submit_extract_jobs(
+                            region, bch, ext, ext_scripts, subset,
+                            self._n_extract_jobs(n_todo)
+                            if block is not None else 1,
+                        )
+                        jobs = [asyncio.wrap_future(f) for f in cfuts]
                 except asyncio.CancelledError:
                     raise
                 except Exception as e:
                     _publish_extract_error(e)
                     return
-            if block_txids is not None:
-                # block connect: evict confirmed txs from the mempool —
-                # the whole block's, answered from relay or not.  The
-                # txids come from the native parse — no Python parse —
-                # and arrive before the engine's verdicts do.
-                assert self.mempool is not None
-                self.mempool.confirmed(block_txids)
-            metrics.inc(
-                "node.verify_txs", sum(it.n_txs for it in shards)
-            )
-            metrics.inc(
-                "node.verify_inputs",
-                sum(int(it.tx_n_inputs.sum()) for it in shards),
-            )
-            # every shard is its own engine submission (the lane packer
-            # coalesces them into full device lanes); planner-era
-            # backfill rides the "ibd" class beneath live traffic
-            priority = (
-                self._block_priority() if block is not None else "mempool"
-            )
-            # block affinity (ISSUE 19): a block's shards share one key
-            # (the block hash) so the whole block verifies on one host —
-            # its shards pack together instead of scattering
-            aff = None
-            if self._fleet_affine():
-                try:
-                    aff = affinity_key(
-                        block.header.hash if block is not None
-                        else txs[0].txid if txs else b""
+                # Hand the shards on in tx order, each when its job (and
+                # every job before it) is out: the pool runs them in that
+                # order, and a block's verdicts reach the bus in runs of
+                # the block's own order.
+                for k, job in enumerate(jobs):
+                    try:
+                        items = await job
+                    except asyncio.CancelledError:
+                        raise
+                    except Exception as e:
+                        # One verdict a tx: this job's txs and those of
+                        # every job after it get the error (queued jobs
+                        # are cancelled, running ones finish and are
+                        # dropped); the shards before it are with the
+                        # engine, keep their chains and publish what it
+                        # says.
+                        failed = e
+                        for later in jobs[k + 1:]:
+                            later.cancel()
+                        _publish_extract_error(e, ranges[k:])
+                        break
+                    metrics.inc("node.verify_txs", items.n_txs)
+                    metrics.inc(
+                        "node.verify_inputs", int(items.tx_n_inputs.sum())
                     )
-                except Exception:
-                    aff = None
-            clean = all(await asyncio.gather(*(
-                self._commit_items(peer, it, priority, aff,
-                                   relay=block is None)
-                for it in shards
-            )))
+                    if block is not None:
+                        # does the hand-off run ahead of the extract?
+                        metrics.inc("node.stream_items", items.count)
+                        if not all(f.done() for f in cfuts[k + 1:]):
+                            metrics.inc(
+                                "node.stream_early_items", items.count
+                            )
+                    commits.append(outside.run(
+                        self._verify_tasks.add_child,
+                        self._commit_items(peer, items, priority, aff,
+                                           relay=relay),
+                        "verify-shard-commit",
+                    ))
+            clean = all(await asyncio.gather(*commits)) and not failed
             if block is not None and clean:
                 # persistent UTXO connect only AFTER the block's verdicts
                 # are published: the watermark means "verified AND
@@ -2110,6 +2152,10 @@ class Node:
         finally:
             if region is not None and not submitted:
                 region.close()
+            # cancelled (or crashed) mid-way: queued jobs never run, and a
+            # shard whose verdicts are not out publishes none
+            for work in jobs + commits:
+                work.cancel()
             if tracked:
                 self._verify_pending -= 1
             # the item's pipeline trace (if any) ends with its verdicts
@@ -2179,55 +2225,73 @@ class Node:
                 self._publish_verdict(TxVerdict(peer, *row), relay=relay)
         return True
 
-    async def _extract_block_sharded(self, region, bch: bool, ext,
-                                     ext_scripts, subset=None) -> list:
-        """Split a parsed BLOCK region into contiguous per-worker
-        tx-range sub-extractions (ISSUE 11) — or, with ``subset`` (the
-        txs no relay verdict answered, ISSUE 27), into runs of it.  The
-        shared intra-block prevout map is built once (off-loop) before
-        the jobs go to the pool; each job's oracle rows are its slice of
-        the rows ``_resolve_ext_rows`` gave (the whole region's, or the
-        subset's).  Close ownership is collective: the region is
-        freed when the LAST submitted job finishes (or every queued job
-        is cancelled before running) — never under a live extract."""
+    def _n_extract_jobs(self, n: int) -> int:
+        """How many extract jobs a block with ``n`` txs to verify is cut
+        into.  Under ``2 * MIN_SHARD_TXS`` one; then a job a worker, as
+        a relay drain's batch; and from ``STREAM_SHARD_TXS`` txs a
+        worker on, jobs of at most that many txs each, so that the first
+        of them is out — and its items with the engine — a small part of
+        the way into the extract."""
+        workers = self._extract_workers
+        if workers <= 1 or n < 2 * self.MIN_SHARD_TXS:
+            return 1
+        return max(
+            min(workers, n // self.MIN_SHARD_TXS),
+            -(-n // self.STREAM_SHARD_TXS),
+        )
+
+    async def _submit_extract_jobs(self, region, bch: bool, ext,
+                                   ext_scripts, subset, n_jobs: int):
+        """Cut a parsed region — or ``subset``, the txs of a block that
+        no relay verdict answered (ISSUE 27) — into ``n_jobs`` contiguous
+        runs of equal size and submit one extract job each to the worker
+        pool: ``-> (the jobs' concurrent futures, their (lo, hi) runs)``,
+        in tx order.  Several jobs share the intra-block prevout map,
+        built once (off-loop) before they go (one job builds its own);
+        each job's oracle rows are its slice of the rows
+        ``_resolve_ext_rows`` gave (the whole region's, or the subset's).
+        Close ownership is collective: the region is freed when the LAST
+        submitted job finishes (or every queued job is cancelled before
+        running) — never under a live extract."""
         n = region.n_txs if subset is None else len(subset)
-        w = min(self._extract_workers, n // self.MIN_SHARD_TXS)
-        if region.n_txs > 1:
-            await self._run_extract(region.build_intra)
-        if subset is None:
-            off = region.input_offsets()
+        intra = region.n_txs > 1
+        size = -(-n // n_jobs)
+        ranges = [(lo, min(lo + size, n)) for lo in range(0, n, size)]
+        if len(ranges) > 1:
+            if intra:
+                await self._run_extract(region.build_intra)
+            if subset is None:
+                off = region.input_offsets()
+            else:
+                off = np.zeros(n + 1, np.int64)
+                np.cumsum(region.tx_layout()[0][subset], out=off[1:])
+            rows = [(int(off[lo]), int(off[hi])) for lo, hi in ranges]
         else:
-            off = np.zeros(n + 1, np.int64)
-            np.cumsum(region.tx_layout()[0][subset], out=off[1:])
-        size = (n + w - 1) // w
-        jobs = []
-        for lo in range(0, n, size):
-            hi = min(lo + size, n)
-            fl, fh = int(off[lo]), int(off[hi])
-            job = (
-                functools.partial(region.extract_range, lo, hi)
-                if subset is None
-                else functools.partial(region.extract_subset, subset[lo:hi])
-            )
-            jobs.append(functools.partial(
-                job,
-                bch=bch,
-                intra_amounts=region.n_txs > 1,
-                ext_amounts=ext[fl:fh] if ext is not None else None,
-                ext_scripts=(
-                    ext_scripts[fl:fh] if ext_scripts is not None else None
-                ),
-            ))
+            rows = [(None, None)]  # the one job takes every row
         assert self._extract_pool is not None  # built with the engine
-        cfuts = []
+        cfuts: list = []
         try:
-            for job in jobs:
-                cfuts.append(self._extract_pool.submit(job))
+            for (lo, hi), (fl, fh) in zip(ranges, rows):
+                job = (
+                    functools.partial(region.extract_range, lo, hi)
+                    if subset is None
+                    else functools.partial(
+                        region.extract_subset, subset[lo:hi]
+                    )
+                )
+                cfuts.append(self._extract_pool.submit(
+                    job,
+                    bch=bch,
+                    intra_amounts=intra,
+                    ext_amounts=ext[fl:fh] if ext is not None else None,
+                    ext_scripts=(
+                        ext_scripts[fl:fh]
+                        if ext_scripts is not None else None
+                    ),
+                ))
         finally:
             self._close_when_done(region, cfuts)
-        return list(await asyncio.gather(
-            *(asyncio.wrap_future(f) for f in cfuts)
-        ))
+        return cfuts, ranges
 
     @staticmethod
     def _close_when_done(region, cfuts: list) -> None:

@@ -504,10 +504,13 @@ class ParsedTxRegion:
         assert self._h, "region closed"
         if not (0 <= tx_lo <= tx_hi <= self.n_txs):
             raise ValueError(f"bad tx range [{tx_lo}, {tx_hi})")
-        _, caps = self.tx_layout()
-        capacity = max(1, int(caps[tx_lo:tx_hi].sum()))
+        if (tx_lo, tx_hi) == (0, self.n_txs):
+            capacity = self.capacity  # the parse's own: no layout call
+        else:
+            _, caps = self.tx_layout()
+            capacity = int(caps[tx_lo:tx_hi].sum())
         return self._extract_impl(
-            tx_lo, tx_hi, capacity, bch, intra_amounts, ext_amounts,
+            tx_lo, tx_hi, max(1, capacity), bch, intra_amounts, ext_amounts,
             ext_scripts,
         )
 
@@ -556,10 +559,8 @@ class ParsedTxRegion:
         input order; None/empty = unknown).  Needed for taproot: a P2TR
         keypath spend is detected from the prevout script and its BIP341
         digest signs over every input's amount AND script."""
-        assert self._h, "region closed"
-        return self._extract_impl(
-            0, self.n_txs, max(1, self.capacity), bch, intra_amounts,
-            ext_amounts, ext_scripts,
+        return self.extract_range(
+            0, self.n_txs, bch, intra_amounts, ext_amounts, ext_scripts
         )
 
     def _extract_impl(
