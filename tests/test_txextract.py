@@ -616,6 +616,14 @@ async def test_node_publishes_the_reference_walks_verdicts(monkeypatch):
     from tpunode import node as node_mod
     from tpunode.mempool import MempoolConfig
 
+    from tpunode.verify import cpu_native
+
+    # the C++ verifier is built and loaded at its first use; on a fresh
+    # checkout, beside five other test workers, that took longer than the
+    # 10 s the drive below waits for its first verdicts (the driver's run
+    # of PR 35's tree).  Build it before anything is timed.  The "ports"
+    # are labels of an in-memory pipe: nothing binds them.
+    cpu_native.load_native_verifier()
     monkeypatch.setattr(node_mod.Node, "MIN_SHARD_TXS", 16)
     mix = dict(MIX, adversarial_every=6)
     job = gen.gen_job(gen.jobs_for(mix, 5, 150, 150)[0])

@@ -102,7 +102,7 @@ class Drive:
                 PeerMessage(self.peer, MsgTx(lazy_tx(raw))))
         if wait:
             await poll_until(lambda: len(self.verdicts) - n0 >= len(raws),
-                             what="relay verdicts")
+                             timeout=60, what="relay verdicts")
             mp = self.node.mempool
             if mp is not None:  # the mailbox has taken them in too
                 await poll_until(
@@ -117,7 +117,7 @@ class Drive:
         c0 = {k: metrics.get(k) for k in REUSE}
         self.node._peer_pub.publish(PeerMessage(self.peer, MsgBlock(blk)))
         await poll_until(lambda: len(self.verdicts) - n0 >= blk.tx_count,
-                         what="the block's verdicts")
+                         timeout=60, what="the block's verdicts")
         await asyncio.sleep(0.05)  # one too many would show now
         got = self.verdicts[n0:]
         order = {tx.txid: k for k, tx in enumerate(blk.txs)}

@@ -127,7 +127,7 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
     after = names[first + len(WAITS):]
     assert after[:len(CONNECT)] == list(CONNECT)
     # PR 27's, appended in their turn, then PR 28's, PR 30's, PR 31's,
-    # PR 32's
+    # PR 32's, PR 36's seven
     assert after[len(CONNECT):] == [
         "reuse.hit_share", "reuse.ms_per_block", "reuse.cpu_ms_per_block",
         "tip.relay_verdict_p50_ms", "tip.block_verdict_p50_ms",
@@ -135,7 +135,13 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
         "resolve.us_per_input", "resolve.oracle_share",
         "utxo.lookup_us_per_row", "utxo.hit_share", "resolve.missing_share",
         "utxo.snapshot_load_s", "utxo.entries", "store.rss_mb",
-        "store.compactions_in_window", "stream.early_share"]
+        "store.compactions_in_window", "stream.early_share",
+        "ibd.head_wait_share", "ibd.stall_recover_ms",
+        "ibd.rerequested_share", "ibd.duplicate_share", "ibd.longest_gap_s",
+        "peer.reconnect_ms", "wan.late_p99_ms"]
+    # ... which only their own cell lists: no other cell's run reads them
+    for name in after[-7:]:
+        assert by_name[name]["workloads"] == ["bch-wan.ibd-faults"], name
 
 
 def test_the_two_connect_metrics_read_the_utxo_connect_span():
@@ -147,10 +153,10 @@ def test_the_two_connect_metrics_read_the_utxo_connect_span():
     for name in CONNECT:
         entry = by_name[name]
         # the cells that connect blocks: PR 27 appended the tip cell,
-        # PR 31 its two beside their siblings
+        # PR 31 its two beside their siblings, PR 36 the IBD from a network
         assert entry["workloads"] == ["bch-node.ibd", "bch-32mb.blocks",
                                       "bch-tip.tip", "bch-utxo.ibd-spend",
-                                      "bch-32mb.single"]
+                                      "bch-32mb.single", "bch-wan.ibd-faults"]
         assert entry["layer"] == "UTXO connect / store"
         assert entry["moves"] == "host_cpu_ms_per_ksig"
         assert (entry["unit"], entry["better"]) == ("ms/block", "lower")
@@ -246,14 +252,14 @@ def test_commit_ms_per_ktx_reads_the_commit_span_in_every_cell(cell):
     """ISSUE 28: open time of ``node.commit`` per 1000 transactions handed
     to the engine (``node.verify_txs``), in every cell, traced or not; span
     and counter exist at the parent commit, so both sides of a pair read
-    it.  ``commit.ms_per_block`` stays the two IBD cells'."""
+    it.  ``commit.ms_per_block`` stays the IBD cells'."""
     by_name = {m["name"]: m for m in BENCH["per_layer"]}
     entry = by_name["commit.ms_per_ktx"]
     assert set(entry["workloads"]) == CELLS
     assert (entry["layer"], entry["moves"]) == ("verdict publication",
                                                 "sigs_per_s")
     assert (entry["unit"], entry["better"]) == ("ms/ktx", "lower")
-    ibd_cells = ["bch-node.ibd", "bch-utxo.ibd-spend"]
+    ibd_cells = ["bch-node.ibd", "bch-utxo.ibd-spend", "bch-wan.ibd-faults"]
     assert by_name["commit.ms_per_block"]["workloads"] == ibd_cells
     ctx = harness.Ctx(workload={"name": cell}, bench=BENCH, config={},
                       traffic={}, seed=0, seconds=4.0, trace=False,

@@ -437,7 +437,7 @@ class _ChaosConnection:
         if spec.action == "garbage":
             return self._chaos.garbage(len(chunk))
         # partial: a mid-frame cut — half the chunk, then EOF, so the
-        # reader hits DecodeHeaderError("connection closed mid-frame")
+        # reader hits EmptyHeader("connection closed mid-frame")
         self._eof = True
         return chunk[: max(1, len(chunk) // 2)]
 

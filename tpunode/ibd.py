@@ -1,5 +1,5 @@
 """Block-fetch-driven IBD: the fetch planner behind ``NodeConfig.ibd``
-(ISSUE 11 / ROADMAP item 5).
+(ISSUE 11 / ROADMAP item 5; the network's faults: ISSUE 36).
 
 The node's block ingest used to be embedder-driven: headers synced through
 the chain actor, but block BODIES only arrived when the embedding process
@@ -17,45 +17,72 @@ Shape (deliberately the mempool fetcher's, tpunode/mempool.py):
 * block hashes come from an incrementally-maintained height->hash view of
   the best chain (one O(1) step per new header, one bounded walk per
   reorg) — never an O(n) ancestor walk per batch;
-* ``getdata`` batches (``batch_blocks`` hashes each) are spread across the
-  online peer fleet best-RTT-first with a per-peer in-flight cap; a
-  failed/timed-out batch retries from another peer (its ``tried`` set
-  rotates the fleet), and a dead peer's batches reassign immediately;
+* ``getdata`` batches (``batch_blocks`` hashes each, a ``ping`` behind
+  them) are spread across the online peer fleet, the peer that has served
+  its batches fastest first, with a per-peer in-flight cap;
+* delivery is kept **per block**: the node tells the planner of every
+  block as it arrives (:meth:`BlockFetcher.block_arrived`), from whichever
+  peer, so a batch that fails half way asks another peer for its missing
+  blocks only;
+* a batch fails when its peer answers the trailing ``ping`` with blocks
+  still missing (it has finished answering: the reference's sentinel,
+  Peer.hs:349-387), when the peer dies (immediate reassignment), or when
+  it **stalls**: no block of any batch has come from the peer for
+  ``stall_timeout`` seconds (Bitcoin Core's ``BLOCK_STALLING_TIMEOUT``, 2
+  s).  A staller is disconnected (``PeerStalling``: the fleet bans the
+  address for a while, tpunode/peermgr.py) and never handed another
+  batch; the timeout doubles with every stall, up to 64 s, and falls
+  back as batches complete — a network that is slow as a whole does not
+  lose every peer in turn.  Time the event loop itself was held counts
+  against no peer;
 * delivered blocks arrive through the NORMAL peer-message path (the wire
   loop publishes them; ``node._peer_events`` routes them into verify
   ingest + UTXO connect) — the planner never touches block bytes, so
   admission stays single-path exactly like mempool fetch;
 * scheduling is watermark-gated: at most ``max_lead`` blocks beyond the
-  watermark are ever in flight (bounded by the node's out-of-order
-  parking), and planning defers while verify-ingest pressure is high —
-  the planner can saturate the pipeline but never outrun it into the
-  shed path;
+  watermark are ever scheduled (bounded by the node's out-of-order
+  parking), the blocks on the wire plus those in verification never
+  exceed the node's shed bound (``pending_cap``), and planning defers
+  while verify-ingest pressure is high — the planner can saturate the
+  pipeline but never outrun it into the shed path;
 * a delivered-but-stuck head batch (its blocks shed, or lost to an engine
   failure) is re-fetched after ``refetch_after`` seconds — the watermark
   can stall but never wedge.
 
-Telemetry: ``ibd.*`` metrics/events (OBSERVABILITY.md).  Engine-side, the
-node submits planner-era block batches at the ``ibd`` priority — beneath
-live ``block``/``mempool`` traffic in the lane packer — so a backfilling
-node still serves fresh verdicts first (tpunode/verify/sched.py).
+Telemetry: ``ibd.*`` metrics/events and the ``ibd.head_wait`` /
+``ibd.stall`` spans (OBSERVABILITY.md).  Engine-side, the node submits
+planner-era block batches at the ``ibd`` priority — beneath live
+``block``/``mempool`` traffic in the lane packer — so a backfilling node
+still serves fresh verdicts first (tpunode/verify/sched.py).
 """
 
 from __future__ import annotations
 
 import asyncio
 import logging
+import random
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Callable, Optional
 
-from .actors import LinkedTasks, Supervisor
+from .actors import LinkedTasks
 from .events import events
 from .metrics import metrics
-from .peer import get_blocks
+from .peer import PeerStalling
+from .trace import record_span, span
+from .wire import InvType, InvVector, MsgGetData, MsgPing
 
 __all__ = ["IbdConfig", "BlockFetcher"]
 
 log = logging.getLogger("tpunode.ibd")
+
+# the stall timeout doubles up to this (Bitcoin Core's
+# BLOCK_STALLING_TIMEOUT_MAX) and falls back by a sixth a completed batch
+STALL_TIMEOUT_MAX = 64.0
+STALL_TIMEOUT_DECAY = 0.85
+# a tick this much later than asked for means the loop was held: blocks
+# may be waiting unread in the sockets, and no peer is charged for it
+LOOP_HELD = 0.05
 
 
 @dataclass
@@ -68,41 +95,54 @@ class IbdConfig:
     batch_blocks: int = 16
     # concurrent batches per peer
     max_inflight_per_peer: int = 2
-    # per-batch RPC timeout (the trailing-ping sentinel bounds the wait)
+    # a batch that is still incomplete this long after it was asked for
+    # goes to another peer, however steadily it trickles in
     fetch_timeout: float = 45.0
     # max blocks scheduled beyond the UTXO watermark: bounds in-flight
-    # memory AND stays inside Node.MAX_VERIFY_PENDING (64 messages) and
-    # MAX_UTXO_PENDING (128 parked) so healthy syncs never shed
+    # memory AND stays inside Node.MAX_UTXO_PENDING (128 parked); what is
+    # on the wire or in verification is held under
+    # Node.MAX_VERIFY_PENDING (64 messages) besides, so healthy syncs
+    # never shed
     max_lead: int = 48
     # a delivered head batch whose blocks still have not connected after
     # this long is re-fetched (heals shed/failed ingest; in a healthy sync
-    # this never fires, keeping verdicts exactly-once)
+    # this never fires)
     refetch_after: float = 30.0
-    # planner cadence (timeouts/retries are detected on ticks; deliveries
-    # and chain events wake it immediately)
+    # planner cadence (stalls and timeouts are detected on ticks;
+    # deliveries and chain events wake it immediately)
     tick_interval: float = 0.5
+    # a peer that owes blocks and has sent none for this long is a
+    # staller: disconnected, its missing blocks asked of another peer
+    # (Bitcoin Core's BLOCK_STALLING_TIMEOUT_DEFAULT)
+    stall_timeout: float = 2.0
 
 
 class _Batch:
     """One scheduled getdata window: heights ``[lo, hi]`` on the best
     chain.  States: queued -> fetching -> delivered (-> dropped once the
-    watermark passes ``hi``); failures return it to queued."""
+    watermark passes ``hi``); failures return it to queued, with the
+    blocks that did arrive struck from ``missing``."""
 
     __slots__ = (
-        "lo", "hi", "hashes", "state", "peer", "task", "tried",
-        "attempts", "delivered_at",
+        "lo", "hi", "hashes", "missing", "state", "peer", "nonce", "tried",
+        "attempts", "sent_at", "delivered_at", "stalled_at",
     )
 
     def __init__(self, lo: int, hi: int, hashes: list[bytes]):
         self.lo = lo
         self.hi = hi
         self.hashes = hashes
+        self.missing: set[bytes] = set(hashes)
         self.state = "queued"
         self.peer = None
-        self.task: Optional[asyncio.Task] = None
+        self.nonce = 0  # of the ping behind the getdata
         self.tried: set = set()
         self.attempts = 0
+        self.sent_at = 0.0
         self.delivered_at = 0.0
+        # set on the lowest batch a staller held: its last sign of
+        # progress, until the missing blocks are asked of another peer
+        self.stalled_at = 0.0
 
 
 class BlockFetcher:
@@ -119,6 +159,9 @@ class BlockFetcher:
         pressure: Callable[[], bool],
         pressure_key: Optional[Callable[[bytes], bool]] = None,
         on_failure=None,
+        *,
+        pending: Callable[[], int],
+        pending_cap: int,
     ):
         self.cfg = cfg
         self._net = net
@@ -130,13 +173,20 @@ class BlockFetcher:
         # target verify host is over its feed ceiling — _assign skips
         # just that batch instead of deferring the whole plan
         self._pressure_key = pressure_key
+        # messages the node has in verification, and how many it takes
+        # before it sheds: blocks on the wire count against the same bound
+        self._pending = pending
+        self._pending_cap = pending_cap
         self._tasks = LinkedTasks(name="ibd", on_failure=on_failure)
-        # fetch RPCs are crash-isolated: one failed getdata must never
-        # tear the node down (failure returns the batch to queued)
-        self._fetchers = Supervisor(name="ibd-fetch")
         self._wake = asyncio.Event()
         self._batches: dict[int, _Batch] = {}  # keyed by lo height
+        self._want: dict[bytes, _Batch] = {}  # scheduled, not yet arrived
         self._inflight: dict[object, int] = {}
+        self._progress: dict[object, float] = {}  # peer -> its last block
+        self._pace: dict[object, float] = {}  # peer -> seconds a block
+        self._stallers: set = set()  # killed, not yet reported gone
+        self._stall_timeout = cfg.stall_timeout
+        self._head_wait: Optional[span] = None
         self._hashes: dict[int, bytes] = {}  # best-chain height -> hash
         self._cache_best: Optional[bytes] = None
         self._cache_floor = 1 << 62  # lowest height the view covers
@@ -146,6 +196,7 @@ class BlockFetcher:
         self._fetched_blocks = 0
         self._refetches = 0
         self._retries = 0
+        self._stalls = 0
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -154,23 +205,47 @@ class BlockFetcher:
         return self
 
     async def __aexit__(self, *exc) -> None:
-        await self._fetchers.aclose()
         await self._tasks.__aexit__(*exc)
+        self._set_head_wait(False)
 
     # -- wiring from the node's routers (event-loop only) ---------------------
 
     def nudge(self) -> None:
-        """Chain activity (new best header) or a delivered block: plan."""
+        """Chain activity (new best header) or a connected block: plan."""
         self._wake.set()
+
+    def block_arrived(self, peer, block_hash: bytes) -> None:
+        """A ``block`` message came in.  Whoever sent it, a block that was
+        scheduled is delivered, and its sender has shown progress."""
+        b = self._want.pop(block_hash, None)
+        if b is None:
+            return
+        now = time.monotonic()
+        b.missing.discard(block_hash)
+        self._progress[peer] = now
+        self._set_head_wait(False)  # the engine is about to have it
+        if not b.missing:
+            self._delivered(b, now)
+
+    def pong(self, peer, nonce: int) -> None:
+        """The ping behind a getdata came back: the peer has finished
+        answering (it answers in order), so what is still missing of that
+        batch it does not have — ask elsewhere, without waiting."""
+        for b in self._batches.values():
+            if b.state == "fetching" and b.peer is peer and b.nonce == nonce:
+                self._failed(b, "incomplete")
+                return
 
     def peer_gone(self, peer) -> None:
         """A peer died: its in-flight batches reassign immediately instead
-        of waiting out the RPC timeout."""
-        self._inflight.pop(peer, None)
-        for b in self._batches.values():
+        of waiting out a timeout."""
+        for b in list(self._batches.values()):
             if b.state == "fetching" and b.peer is peer:
-                if b.task is not None and not b.task.done():
-                    b.task.cancel()  # -> _fetch's finally requeues it
+                self._failed(b, "peer gone")
+        self._inflight.pop(peer, None)
+        self._progress.pop(peer, None)
+        self._pace.pop(peer, None)
+        self._stallers.discard(peer)
         self._wake.set()
 
     # -- introspection --------------------------------------------------------
@@ -198,20 +273,31 @@ class BlockFetcher:
             "fetched_blocks": self._fetched_blocks,
             "retries": self._retries,
             "refetches": self._refetches,
+            "stalls": self._stalls,
+            "stall_timeout": self._stall_timeout,
         }
 
     # -- planner --------------------------------------------------------------
 
     async def _main_loop(self) -> None:
+        tick = self.cfg.tick_interval
         while True:
+            t0 = time.monotonic()
             try:
-                await asyncio.wait_for(
-                    self._wake.wait(), self.cfg.tick_interval
-                )
+                await asyncio.wait_for(self._wake.wait(), tick)
             except (asyncio.TimeoutError, TimeoutError):
                 pass
+            held = time.monotonic() - t0 - tick
+            if held > LOOP_HELD:
+                self._forgive(held)
             self._wake.clear()
             self._plan()
+
+    def _forgive(self, seconds: float) -> None:
+        """The loop did not run for ``seconds``: nobody's silence."""
+        for b in self._batches.values():
+            if b.state == "fetching":
+                b.sent_at += seconds
 
     def _best(self):
         try:
@@ -233,7 +319,7 @@ class BlockFetcher:
             )
         # connected batches retire; stale cache entries prune
         for lo in [lo for lo, b in self._batches.items() if b.hi <= wm]:
-            del self._batches[lo]
+            self._drop(self._batches.pop(lo))
         for h in [h for h in self._hashes if h <= wm]:
             del self._hashes[h]
         self._cache_floor = max(self._cache_floor, wm + 1)
@@ -243,9 +329,13 @@ class BlockFetcher:
                 events.emit("ibd.synced", height=wm)
                 log.info("[IBD] watermark reached header tip %d", wm)
             metrics.set_gauge("ibd.inflight_blocks", 0.0)
+            self._set_head_wait(False)
             return
         self.synced.clear()
         now = time.monotonic()
+        self._check_peers(now)
+        # the engine has nothing and the network owes blocks
+        self._set_head_wait(bool(self._want) and self._pending() == 0)
         # head-of-line healing: the batch holding wm+1 was delivered but
         # never connected (shed under pressure, or its ingest failed) —
         # after the grace window, fetch it again
@@ -260,6 +350,12 @@ class BlockFetcher:
         ):
             head.state = "queued"
             head.tried.clear()
+            head.missing = {
+                hh for h, hh in zip(range(head.lo, head.hi + 1), head.hashes)
+                if h > wm
+            }
+            for hh in head.missing:
+                self._want[hh] = head
             self._refetches += 1
             metrics.inc("ibd.refetches")
             events.emit("ibd.refetch", lo=head.lo, hi=head.hi)
@@ -278,10 +374,7 @@ class BlockFetcher:
                 if h > wm  # connected heights are pruned from the view
             )
         ]:
-            b = self._batches.pop(lo)
-            if b.task is not None and not b.task.done():
-                b.state = "dropped"  # _fetch_done ignores it
-                b.task.cancel()
+            self._drop(self._batches.pop(lo))
             metrics.inc("ibd.reorg_dropped")
         # extend the plan over every uncovered height up to the lead
         # horizon.  Not just past the highest batch: after a reorg unwind
@@ -297,17 +390,80 @@ class BlockFetcher:
                 ]
                 if any(h is None for h in hashes):
                     break  # header gap (mid-reorg): replan on the next tick
-                self._batches[next_h] = _Batch(next_h, b_hi, hashes)
+                b = self._batches[next_h] = _Batch(next_h, b_hi, hashes)
+                for hh in hashes:
+                    self._want[hh] = b
                 next_h = b_hi + 1
-        metrics.set_gauge(
-            "ibd.inflight_blocks",
-            float(sum(
-                b.hi - b.lo + 1
-                for b in self._batches.values()
-                if b.state == "fetching"
-            )),
-        )
+        metrics.set_gauge("ibd.inflight_blocks", float(self._on_wire()))
         self._assign()
+
+    def _on_wire(self) -> int:
+        """Blocks asked for and not yet here."""
+        return sum(
+            len(b.missing) for b in self._batches.values()
+            if b.state == "fetching"
+        )
+
+    def _drop(self, b: _Batch) -> None:
+        """The batch leaves the plan (connected, or reorged away)."""
+        if b.state == "fetching":
+            self._release(b.peer)
+        b.state = "dropped"
+        for hh in b.missing:
+            if self._want.get(hh) is b:
+                del self._want[hh]
+
+    def _set_head_wait(self, waiting: bool) -> None:
+        """``ibd.head_wait`` is open while the engine has nothing in
+        verification and the planner has scheduled blocks that are not
+        here: on the wire, or with no peer to ask."""
+        if waiting and self._head_wait is None:
+            self._head_wait = span("ibd.head_wait")
+            self._head_wait.__enter__()
+        elif not waiting and self._head_wait is not None:
+            self._head_wait.__exit__(None, None, None)
+            self._head_wait = None
+
+    def _check_peers(self, now: float) -> None:
+        """Stalls and overdue batches, on every plan pass."""
+        stalled: dict[object, float] = {}
+        for b in list(self._batches.values()):
+            if b.state != "fetching":
+                continue
+            # a peer works through its batches in order: a block of any
+            # of them is progress on all
+            last = max(b.sent_at, self._progress.get(b.peer, 0.0))
+            if now - last > self._stall_timeout:
+                stalled[b.peer] = max(last, stalled.get(b.peer, 0.0))
+            elif now - b.sent_at > self.cfg.fetch_timeout:
+                self._failed(b, "timeout")
+        for peer, last in stalled.items():
+            held = sorted(
+                (b for b in self._batches.values()
+                 if b.state == "fetching" and b.peer is peer),
+                key=lambda b: b.lo,
+            )
+            self._stalls += 1
+            metrics.inc("ibd.stalls")
+            events.emit(
+                "ibd.stall", peer=getattr(peer, "label", "?"),
+                lo=held[0].lo, hi=held[-1].hi,
+                idle=round(now - last, 3),
+                timeout=round(self._stall_timeout, 3),
+            )
+            log.warning(
+                "[IBD] peer %s stalls the download (%.1fs without a "
+                "block): disconnecting", getattr(peer, "label", "?"),
+                now - last,
+            )
+            held[0].stalled_at = last
+            for b in held:
+                self._failed(b, "stall")
+            self._stallers.add(peer)
+            peer.kill(PeerStalling(f"no block for {now - last:.1f}s"))
+            self._stall_timeout = min(
+                STALL_TIMEOUT_MAX, 2.0 * self._stall_timeout
+            )
 
     def _uncovered(self, lo: int, hi: int) -> list[tuple[int, int]]:
         """Height ranges in ``[lo, hi]`` not covered by any batch."""
@@ -352,14 +508,25 @@ class BlockFetcher:
     def _assign(self) -> None:
         """Hand queued batches to online peers with capacity, lowest
         heights first (the watermark only advances contiguously)."""
-        peers = self._peer_mgr.get_peers()  # online, best median RTT first
+        # online, best median RTT first; then by what the planner itself
+        # has seen: the fastest server of blocks first, a peer that has
+        # not served a batch yet (pace 0) before all, to find out
+        peers = sorted(
+            (o for o in self._peer_mgr.get_peers()
+             if o.peer not in self._stallers),
+            key=lambda o: self._pace.get(o.peer, 0.0),
+        )
         if not peers:
             return
         cap = self.cfg.max_inflight_per_peer
+        on_wire = self._on_wire()
+        room = self._pending_cap - self._pending()
         for lo in sorted(self._batches):
             b = self._batches[lo]
             if b.state != "queued":
                 continue
+            if on_wire and on_wire + len(b.missing) > room:
+                break  # more would arrive than the node takes unshed
             if (
                 self._pressure_key is not None
                 and b.hashes
@@ -386,55 +553,72 @@ class BlockFetcher:
                     self._retries += 1
                     metrics.inc("ibd.rotations")
                 continue
-            b.state = "fetching"
-            b.peer = pick
-            self._inflight[pick] = self._inflight.get(pick, 0) + 1
-            metrics.inc("ibd.fetches")
-            b.task = self._fetchers.add_child(
-                self._fetch(b, pick), name=f"ibd-fetch-{b.lo}"
-            )
+            self._request(b, pick)
+            on_wire += len(b.missing)
 
-    async def _fetch(self, b: _Batch, peer) -> None:
-        """One getdata batch.  The returned blocks are DISCARDED here:
-        every served block also arrives through the peer-message path
-        (the wire loop publishes it), which is where ingest happens —
-        this task only acks delivery for the planner's bookkeeping."""
-        ok = False
-        try:
-            res = await get_blocks(
-                self._net, self.cfg.fetch_timeout, peer, b.hashes
-            )
-            ok = res is not None
-        except asyncio.CancelledError:
-            raise  # finally still runs: the batch requeues
-        except Exception as e:
-            log.debug("[IBD] fetch [%d,%d] failed: %s", b.lo, b.hi, e)
-        finally:
-            self._fetch_done(b, peer, ok)
+    def _request(self, b: _Batch, peer) -> None:
+        """One getdata for what the batch still misses, and the ping that
+        says when the peer is through with it.  The blocks themselves
+        arrive through the peer-message path (``block_arrived``)."""
+        now = time.monotonic()
+        if b.attempts:
+            metrics.inc("ibd.blocks_rerequested", len(b.missing))
+        if b.stalled_at:
+            record_span("ibd.stall", now - b.stalled_at)
+            b.stalled_at = 0.0
+        b.state = "fetching"
+        b.peer = peer
+        b.sent_at = now
+        b.nonce = random.getrandbits(64)
+        self._inflight[peer] = self._inflight.get(peer, 0) + 1
+        metrics.inc("ibd.fetches")
+        t = InvType.WITNESS_BLOCK if self._net.segwit else InvType.BLOCK
+        peer.send_message(MsgGetData(tuple(
+            InvVector(t, hh) for hh in b.hashes if hh in b.missing
+        )))
+        peer.send_message(MsgPing(b.nonce))
 
-    def _fetch_done(self, b: _Batch, peer, ok: bool) -> None:
+    def _release(self, peer) -> None:
         n = self._inflight.get(peer, 0) - 1
         if n > 0:
             self._inflight[peer] = n
         else:
             self._inflight.pop(peer, None)
-        if b.state != "fetching" or b.peer is not peer:
-            return  # already retired or reassigned (peer_gone raced)
-        b.task = None
-        if ok:
-            b.state = "delivered"
-            b.delivered_at = time.monotonic()
-            self._fetched_blocks += b.hi - b.lo + 1
-            metrics.inc("ibd.blocks", b.hi - b.lo + 1)
-        else:
-            b.state = "queued"
-            b.peer = None
-            b.tried.add(peer)
-            b.attempts += 1
-            metrics.inc("ibd.batch_failures")
-            events.emit(
-                "ibd.batch_failed", lo=b.lo, hi=b.hi,
-                attempts=b.attempts,
-                peer=getattr(peer, "label", "?"),
+
+    def _delivered(self, b: _Batch, now: float) -> None:
+        """Every block of the batch is here."""
+        if b.state == "fetching":
+            self._release(b.peer)
+            if not b.attempts:  # a whole batch from one peer: its pace
+                took = (now - b.sent_at) / len(b.hashes)
+                old = self._pace.get(b.peer)
+                self._pace[b.peer] = (
+                    took if old is None else 0.75 * old + 0.25 * took
+                )
+            self._stall_timeout = max(
+                self.cfg.stall_timeout,
+                STALL_TIMEOUT_DECAY * self._stall_timeout,
             )
+        b.state = "delivered"
+        b.peer = None
+        b.delivered_at = now
+        self._fetched_blocks += b.hi - b.lo + 1
+        metrics.inc("ibd.blocks", b.hi - b.lo + 1)
+        self._wake.set()
+
+    def _failed(self, b: _Batch, why: str) -> None:
+        """The peer will not complete the batch: what is missing goes
+        back to the queue, for another peer."""
+        peer = b.peer
+        self._release(peer)
+        b.state = "queued"
+        b.peer = None
+        b.tried.add(peer)
+        b.attempts += 1
+        metrics.inc("ibd.batch_failures")
+        events.emit(
+            "ibd.batch_failed", lo=b.lo, hi=b.hi,
+            attempts=b.attempts, missing=len(b.missing), why=why,
+            peer=getattr(peer, "label", "?"),
+        )
         self._wake.set()

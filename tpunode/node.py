@@ -323,6 +323,13 @@ class NodeConfig:
                 "NodeConfig.ibd requires utxo=True: the persistent UTXO "
                 "watermark is the fetch planner's sync cursor"
             )
+        if self.ibd is not None and self.ibd.max_lead > Node.MAX_UTXO_PENDING:
+            raise ValueError(
+                f"IbdConfig.max_lead {self.ibd.max_lead} exceeds the "
+                f"{Node.MAX_UTXO_PENDING} verified blocks the node parks "
+                "for their UTXO connect: one more would be dropped and "
+                "fetched again"
+            )
         if self.serve_port is not None:
             if self.verify is None:
                 raise ValueError(
@@ -411,6 +418,8 @@ class Node:
                 pressure=self._ibd_pressure,
                 pressure_key=self._ibd_pressure_key,
                 on_failure=self._component_failed,
+                pending=lambda: self._verify_pending,
+                pending_cap=self.MAX_VERIFY_PENDING,
             )
             if cfg.ibd is not None
             else None
@@ -423,6 +432,11 @@ class Node:
         # (concurrent block verification finishes in any order); bounded —
         # beyond the cap a block is dropped and re-delivery heals
         self._utxo_pending: dict[int, object] = {}
+        # blocks taken in and not yet through: in verification, or
+        # verified and waiting (parked) for their UTXO connect.  One that
+        # is delivered again meanwhile — a refetch racing a slow peer, a
+        # peer that sends a block twice — is dropped, not verified again
+        self._blocks_taken: set[bytes] = set()
         self.mempool: Optional[Mempool] = (
             Mempool(
                 cfg.mempool,
@@ -998,11 +1012,12 @@ class Node:
         """Should the IBD planner defer scheduling more block batches?
         Half the shed bound: the planner can keep the pipeline saturated
         but a delivery burst must never reach MAX_VERIFY_PENDING (every
-        shed block costs a refetch round-trip later)."""
-        return (
-            self._verify_pending >= self.MAX_VERIFY_PENDING // 2
-            or len(self._utxo_pending) >= self.MAX_UTXO_PENDING // 2
-        )
+        shed block costs a refetch round-trip later).  Parked blocks
+        are no pressure: the planner schedules at most ``max_lead`` <=
+        MAX_UTXO_PENDING blocks beyond the watermark, so what it asked
+        for always has room to park, and only the watermark's successor
+        — which a deferred plan would not ask for — un-parks them."""
+        return self._verify_pending >= self.MAX_VERIFY_PENDING // 2
 
     def _ibd_pressure_key(self, block_hash: bytes) -> bool:
         """Per-batch IBD gate (ISSUE 19): is this block's target verify
@@ -1083,16 +1098,23 @@ class Node:
                 return None  # different branch: not covered
         return bn.height
 
-    def _connect_block_utxo(self, block, delta=None) -> None:
+    def _block_taken(self, block_hash: bytes) -> bool:
+        """Is this block in verification, or verified and parked?"""
+        return block_hash in self._blocks_taken
+
+    def _connect_block_utxo(self, block, delta=None) -> bool:
         """Schedule the persistent UTXO connect for an ingested block
         (supervised; ordering enforced by ``_utxo_lock``).  ``delta``:
         the block's ``ParsedTxRegion.utxo_ops()`` where its verification
-        made one (``_verify_txs_native``), so the connect parses nothing."""
+        made one (``_verify_txs_native``), so the connect parses nothing.
+        True where a connect was scheduled: it then owns the block's
+        entry in ``_blocks_taken``."""
         if self.utxo is None:
-            return
+            return False
         self._verify_tasks.add_child(
             self._apply_block_utxo(block, delta), name="utxo-connect"
         )
+        return True
 
     async def _apply_block_utxo(self, block, delta=None) -> None:
         """Apply one block's spends/creates + watermark atomically.  The
@@ -1104,12 +1126,29 @@ class Node:
             # headers-first sync means this is rare: a block whose header
             # the chain has not accepted cannot be assigned a height
             metrics.inc("utxo.no_header")
+            self._blocks_taken.discard(block.header.hash)
             return
         assert self.utxo is not None
+        moved = False
+        try:
+            moved = await self._apply_or_park(bn, block, delta)
+        finally:
+            # applied, skipped or dropped: the block is through, and a
+            # re-delivery is judged on the watermark.  Parked: still here
+            parked = self._utxo_pending.get(bn.height)
+            if parked is None or parked[0] is not block:
+                self._blocks_taken.discard(bn.hash)
+        if moved and self.ibd is not None:
+            # the watermark may have moved: the planner retires finished
+            # batches and schedules further ahead
+            self.ibd.nudge()
+
+    async def _apply_or_park(self, bn, block, delta) -> bool:
+        """-> whether a connect was attempted (else: skipped or parked)."""
         async with self._utxo_lock:
             if bn.height <= self.utxo.height:
                 metrics.inc("utxo.skipped")
-                return
+                return False
             # CONTIGUOUS connects only: applying height N+2 over a
             # watermark of N would silently drop N+1's whole delta (its
             # later re-delivery lands below the watermark and is skipped
@@ -1122,7 +1161,7 @@ class Node:
                 # block on a fresh store): nothing to park for — the
                 # drain loop could never reach it
                 metrics.inc("utxo.skipped")
-                return
+                return False
             if bn.height > expected:
                 if len(self._utxo_pending) < self.MAX_UTXO_PENDING:
                     self._utxo_pending[bn.height] = (block, delta)
@@ -1133,18 +1172,18 @@ class Node:
                         "utxo.out_of_order", height=bn.height,
                         watermark=self.utxo.height,
                     )
-                return
+                return False
             await self._utxo_apply_one(bn.height, block, delta)
             # drain parked successors now contiguous with the watermark
             while True:
                 nxt = self._utxo_pending.pop(self.utxo.height + 1, None)
                 if nxt is None:
                     break
-                await self._utxo_apply_one(self.utxo.height + 1, *nxt)
-        if self.ibd is not None:
-            # the watermark may have moved: the planner retires finished
-            # batches and schedules further ahead
-            self.ibd.nudge()
+                try:
+                    await self._utxo_apply_one(self.utxo.height + 1, *nxt)
+                finally:
+                    self._blocks_taken.discard(nxt[0].header.hash)
+        return True
 
     # Bound on parked out-of-order block connects (blocks are held alive
     # while parked; MAX_VERIFY_PENDING already bounds how many can be in
@@ -1183,6 +1222,8 @@ class Node:
                 # parked blocks were fetched against the OLD branch state
                 # and may now be stale — drop them, re-delivery heals
                 # (the fetch planner replans against the new best chain)
+                for parked, _ in self._utxo_pending.values():
+                    self._blocks_taken.discard(parked.header.hash)
                 self._utxo_pending.clear()
                 bn = self.chain.get_block(block.header.hash)
                 expected = max(self.utxo.height + 1, 1)
@@ -1373,6 +1414,9 @@ class Node:
                     mgr.ping(p, msg.nonce)
                 elif isinstance(msg, MsgPong):
                     mgr.pong(p, msg.nonce)
+                    if self.ibd is not None:
+                        # the ping behind a getdata: the peer is through
+                        self.ibd.pong(p, msg.nonce)
                 elif isinstance(msg, MsgAddr):
                     mgr.addrs(p, [na for _, na in msg.addrs])
                 elif isinstance(msg, MsgHeaders):
@@ -1398,6 +1442,8 @@ class Node:
                     # never parses its txs in Python.  Confirmation
                     # eviction rides the ingest path (txids are computed
                     # there, natively when possible).
+                    if self.ibd is not None:
+                        self.ibd.block_arrived(p, msg.block.header.hash)
                     self._submit_verify(p, block=msg.block)
                 elif isinstance(msg, MsgBlock) and (
                     self.mempool is not None or self.utxo is not None
@@ -1406,6 +1452,8 @@ class Node:
                     # connect the persistent UTXO set
                     if self.mempool is not None:
                         self.mempool.block_connected(msg.block)
+                    if self.ibd is not None:
+                        self.ibd.block_arrived(p, msg.block.header.hash)
                     if self._persisted_height(msg.block) is None:
                         self._connect_block_utxo(msg.block)
                     else:
@@ -1900,6 +1948,12 @@ class Node:
             metrics.inc("node.block_replay_skipped")
             _discard_active_trace()
             return
+        if block is not None and self._block_taken(block.header.hash):
+            # above the watermark, and already here: its verdicts are
+            # out or on their way, exactly once
+            metrics.inc("node.block_duplicate_skipped")
+            _discard_active_trace()
+            return
         n_txs = block.tx_count if block is not None else len(txs)
         if self._verify_pending >= self.MAX_VERIFY_PENDING:
             metrics.inc("node.verify_dropped", n_txs)
@@ -1910,6 +1964,7 @@ class Node:
             return
         self._verify_pending += 1
         if block is not None:
+            self._blocks_taken.add(block.header.hash)
             raw = block.raw_txs
         if raw is not None and _native_extract_available():
             coro = self._verify_txs_native(peer, raw, n_txs, block=block, txs=txs)
@@ -1923,6 +1978,7 @@ class Node:
                     # kill the peer); with lazy blocks it surfaces here —
                     # report it and kill the peer, never crash the router.
                     self._verify_pending -= 1
+                    self._blocks_taken.discard(block.header.hash)
                     self._verify_failure("block-decode", e)
                     self._publish_verdict(
                         TxVerdict(peer, b"", False, (), ExtractStats(),
@@ -1999,6 +2055,7 @@ class Node:
         subset = None  # a block's tx indices still to verify; None = all
         region: Optional[ParsedTxRegion] = None
         delta = None  # the block's (ops blob, created, spent), if wanted
+        connecting = False  # handed to the UTXO connect, which lets go
         submitted = False  # once its jobs are in the pool, the last of
         # them out closes the region (_close_when_done)
         cfuts: list = []  # a shard's extract job in the pool, in tx order,
@@ -2148,8 +2205,11 @@ class Node:
                 # applied", so a crash mid-verify must leave the block
                 # unpersisted for its re-delivery to re-verify (extract/
                 # engine failure paths return before reaching here)
-                self._connect_block_utxo(block, delta)
+                connecting = self._connect_block_utxo(block, delta)
         finally:
+            if block is not None and not connecting:
+                # not on its way to the UTXO set: a re-delivery verifies
+                self._blocks_taken.discard(block.header.hash)
             if region is not None and not submitted:
                 region.close()
             # cancelled (or crashed) mid-way: queued jobs never run, and a
@@ -2331,6 +2391,7 @@ class Node:
         oracle = self._prevout_oracle()
         per_tx: list[tuple[Tx, ExtractStats, list, Optional[asyncio.Task]]] = []
         clean = True  # no extract/engine error verdicts published
+        connecting = False
         try:
             with span("node.extract"):
                 for tx in txs:
@@ -2444,8 +2505,10 @@ class Node:
                 # cleanly (mirrors the native path): the watermark means
                 # "verified AND applied" — an error-verdict block stays
                 # unpersisted so its re-delivery re-verifies
-                self._connect_block_utxo(block)
+                connecting = self._connect_block_utxo(block)
         finally:
+            if block is not None and not connecting:
+                self._blocks_taken.discard(block.header.hash)
             self._verify_pending -= 1
             for _, _, _, task in per_tx:
                 if task is not None and not task.done():

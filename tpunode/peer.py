@@ -65,6 +65,7 @@ __all__ = [
     "NotNetworkPeer",
     "PeerNoSegWit",
     "PeerTimeout",
+    "PeerStalling",
     "UnknownPeer",
     "PeerTooOld",
     "EmptyHeader",
@@ -147,6 +148,12 @@ class PeerNoSegWit(PeerError):
 
 class PeerTimeout(PeerError):
     pass
+
+
+class PeerStalling(PeerError):
+    """The peer was asked for blocks, held the head of the download
+    window and sent none for the planner's stall timeout (tpunode/ibd.py;
+    Bitcoin Core's ``BLOCK_STALLING_TIMEOUT``)."""
 
 
 class UnknownPeer(PeerError):
@@ -256,15 +263,19 @@ class ConnectionReader:
         self._buf = bytearray()
 
     async def read_exact(self, n: int) -> bytes:
-        """Read exactly n bytes; raises EmptyHeader on EOF at a message
-        boundary, DecodeHeaderError on EOF mid-item (reference semantics of
-        Peer.hs:256-268)."""
+        """Read exactly n bytes; raises EmptyHeader on EOF, at a message
+        boundary or in the middle of a frame.  (The reference raises a
+        decode error mid-item, Peer.hs:256-268, and forgets the peer
+        either way; here a decode error is a protocol fault that bans the
+        address, and a stream that merely ENDS — a peer process that
+        exits, a NAT that drops the flow — is a lost connection like a
+        reset: ISSUE 36.)"""
         while len(self._buf) < n:
             chunk = await self._conn.read_chunk()
             if not chunk:
                 if not self._buf:
                     raise EmptyHeader("connection closed")
-                raise DecodeHeaderError("connection closed mid-frame")
+                raise EmptyHeader("connection closed mid-frame")
             self._buf.extend(chunk)
         out = bytes(self._buf[:n])
         del self._buf[:n]

@@ -30,6 +30,7 @@ from .actors import (
 )
 from .events import events
 from .metrics import metrics
+from .trace import record_span
 from .params import NODE_NETWORK, PROTOCOL_VERSION, Network
 from .peer import (
     CannotDecodePayload,
@@ -43,6 +44,7 @@ from .peer import (
     PeerIsMyself,
     PeerMisbehaving,
     PeerSentBadHeaders,
+    PeerStalling,
     PeerTimeout,
     PeerTooOld,
     NotNetworkPeer,
@@ -79,6 +81,10 @@ _BAN_ERRORS = (
     CannotDecodePayload,
     DecodeHeaderError,
     PayloadTooLarge,
+    # held the head of the block download window (tpunode/ibd.py): the
+    # address sits out a timed ban, so a reconnect does not hand the
+    # window's head to the same staller again at once
+    PeerStalling,
 )
 
 
@@ -129,6 +135,7 @@ class _AddrState:
     failures: int = 0  # consecutive session deaths (reset on handshake)
     score: int = 0  # misbehavior incidents (never auto-reset)
     banned_until: float = 0.0  # monotonic: timed ban horizon
+    lost_at: float = 0.0  # monotonic: an ONLINE session died (0: none did)
 
 
 @dataclass
@@ -225,6 +232,8 @@ class PeerMgr:
         # ISSUE 7: per-address backoff/ban state + the dial-rate window
         self._addr_state: dict[SockAddr, _AddrState] = {}
         self._dial_times: deque[float] = deque()
+        # a lost online peer's redial, due when its backoff / ban is over
+        self._redials: dict[SockAddr, asyncio.TimerHandle] = {}
         self._burst: Optional[int] = (
             None
             if cfg.reconnect_burst < 0
@@ -241,6 +250,9 @@ class PeerMgr:
         return self
 
     async def __aexit__(self, *exc) -> None:
+        for handle in self._redials.values():
+            handle.cancel()
+        self._redials.clear()
         await self.supervisor.aclose()
         await self._tasks.__aexit__(*exc)
 
@@ -350,6 +362,15 @@ class PeerMgr:
         # reference logConnectedPeers (PeerMgr.hs:285-290)
         st = self._addr_state.get(o.address)
         if st is not None:
+            if st.lost_at:
+                # the address is back: lost -> handshaken again
+                back = time.monotonic() - st.lost_at
+                st.lost_at = 0.0
+                record_span("peer.reconnect", back)
+                events.emit(
+                    "peer.reconnect", peer=o.peer.label,
+                    seconds=round(back, 6),
+                )
             # success reset (ISSUE 7): a completed handshake clears the
             # dial backoff — misbehavior score deliberately persists
             st.backoff = 0.0
@@ -483,6 +504,15 @@ class PeerMgr:
             )
         if o.online:
             self.cfg.pub.publish(PeerDisconnected(o.peer))
+            # a peer that WAS online is redialled the moment its backoff
+            # (and ban) is over, not at the connect loop's next draw of
+            # 0.1-5 s: a connection reset costs the fleet its backoff
+            # and a handshake.  Failed dials stay with the jittered loop.
+            # (a banned address is away by design: its return is not a
+            # reconnect's time)
+            if st.banned_until <= now and not st.lost_at:
+                st.lost_at = now
+            self._redial_at(o.address, max(st.not_before, st.banned_until))
         self._peers.remove(o)
         # the address returns to the book behind its backoff/ban horizon
         # (gossip addresses used to vanish on death; static peers were
@@ -515,6 +545,20 @@ class PeerMgr:
         if any(o.address == sa for o in self._peers):
             return
         self._addresses.add(sa)
+
+    def _redial_at(self, sa: SockAddr, when: float) -> None:
+        def due() -> None:
+            self._redials.pop(sa, None)
+            if len(self._peers) < self.cfg.max_peers:
+                self.mailbox.send(_Connect(sa))
+
+        old = self._redials.pop(sa, None)
+        if old is not None:
+            old.cancel()
+        loop = asyncio.get_running_loop()
+        self._redials[sa] = loop.call_later(
+            max(0.0, when - time.monotonic()), due
+        )
 
     def _dialable(self, sa: SockAddr, now: float) -> bool:
         """Is this address past its backoff and ban horizons (ISSUE 7)?"""

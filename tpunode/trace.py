@@ -51,7 +51,7 @@ from typing import Iterator, Optional
 from .metrics import metrics
 from .tracectx import _ACTIVE as _active_trace
 
-__all__ = ["span", "profile_to"]
+__all__ = ["span", "record_span", "profile_to"]
 
 log = logging.getLogger("tpunode.trace")
 
@@ -154,6 +154,17 @@ class span:
         if ann is not None:
             self._ann = None
             ann.__exit__(None, None, None)
+
+
+def record_span(name: str, seconds: float) -> None:
+    """A span whose two ends lie in different calls (a peer lost in one
+    message and back in another, a stall seen on one tick and answered on
+    a later one): the caller kept the start and hands in the length.  The
+    same histogram and counters as :class:`span`; no trace node, no
+    annotation."""
+    if not metrics.disabled:
+        keys = _names(name)
+        metrics.time_span(keys[0], keys[1], keys[2], seconds, keys[3], None)
 
 
 @contextlib.contextmanager
