@@ -1,6 +1,7 @@
 import asyncio
 import os
 import random
+import time
 
 import pytest
 
@@ -454,3 +455,90 @@ async def test_rung_failure_fails_over_within_dispatch(monkeypatch):
     async with eng:
         assert await eng.verify(items) == expected
     assert seen[-1] == "oracle"
+
+
+# --- the lane rule by class, through the engine (ISSUE 37) -------------------
+
+
+def _spy_lanes(eng) -> list:
+    """Item counts of the payloads of every lane the engine dispatches."""
+    lanes: list = []
+    orig = eng._dispatch_multi
+
+    def spy(payloads, target=None):
+        lanes.append([len(p) for p in payloads])
+        return orig(payloads, target)
+
+    eng._dispatch_multi = spy
+    return lanes
+
+
+def _cut_counts() -> dict:
+    return {
+        k: v for k, v in metrics.snapshot().items()
+        if k.startswith("sched.lanes_cut_")
+    }
+
+
+@pytest.mark.asyncio
+async def test_block_cuts_at_its_own_deadline_and_takes_ibd_along():
+    """A lone `ibd` submission outlives 1 x max_wait (nobody waits on
+    it); a `block` one queued behind it cuts the lane at ITS deadline,
+    before the `ibd` one's own, and the `ibd` items ride in the room
+    left."""
+    metrics.reset()
+    ibd_items, ibd_exp = make_items(3, tamper_every=2)
+    blk_items, blk_exp = make_items(2)
+    async with VerifyEngine(
+        VerifyConfig(backend="cpu", batch_size=64, max_wait=0.2)
+    ) as eng:
+        lanes = _spy_lanes(eng)
+        t0 = time.monotonic()
+        f1 = asyncio.ensure_future(eng.verify(ibd_items, priority="ibd"))
+        await asyncio.sleep(0.1)
+        f2 = asyncio.ensure_future(eng.verify(blk_items, priority="block"))
+        assert await f2 == blk_exp and await f1 == ibd_exp
+        # not at the `ibd` one's 1 x (0.2 s), as one deadline for every
+        # class would have it, but at the `block` one's own
+        assert time.monotonic() - t0 >= 0.3
+    assert lanes == [[2, 3]]
+    assert _cut_counts() == {'sched.lanes_cut_deadline{priority="block"}': 1}
+
+
+@pytest.mark.parametrize("cls,n,want_lanes,want_slots,want_cuts", [
+    # ibd between the shapes: full small lanes, the rest on its deadline
+    ("ibd", 20, [[8], [8], [4]], 24, {"full": 2, "deadline": 1}),
+    # ibd over the big shape: as before, then the small rule again
+    ("ibd", 40, [[32], [8]], 40, {"full": 2}),
+    # a class with a waiter between the shapes: one lane, padded to big
+    ("block", 20, [[20]], 32, {"deadline": 1}),
+    ("bulk", 33, [[32], [1]], 40, {"full": 1, "deadline": 1}),
+])
+@pytest.mark.asyncio
+async def test_lanes_slots_and_cut_counters_by_class(
+    monkeypatch, cls, n, want_lanes, want_slots, want_cuts
+):
+    """The tpu rung behind a fake device: what is cut, what it is padded
+    to (`verify.tpu_slots` beside `verify.tpu_items`) and why."""
+    from tests.test_chaos import _fake_device
+
+    _fake_device(monkeypatch)
+    metrics.reset()
+    items, expected = make_items(n, tamper_every=5)
+    cfg = VerifyConfig(
+        backend="auto", batch_size=8, device_batch=32, min_tpu_batch=1,
+        max_wait=0.02, pipeline_depth=1,
+    )
+    async with VerifyEngine(cfg) as eng:
+        eng._warmup_done.wait(5)
+        assert eng.device_state == "ready"
+        lanes = _spy_lanes(eng)
+        assert await eng.verify(items, priority=cls) == expected
+    assert lanes == want_lanes
+    assert metrics.get("verify.tpu_items") == n
+    assert metrics.get("verify.tpu_slots") == want_slots
+    assert _cut_counts() == {
+        'sched.lanes_cut_%s{priority="%s"}' % (r, cls): c
+        for r, c in want_cuts.items()
+    }
+    assert metrics.get("sched.lanes") == len(want_lanes)

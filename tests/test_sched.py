@@ -32,6 +32,7 @@ from tpunode.verify.engine import VerifyConfig, VerifyEngine
 from tpunode.verify.sched import (
     AffinityMap,
     FleetDispatcher,
+    LINGER,
     LanePacker,
     PRIORITIES,
     Submission,
@@ -41,14 +42,14 @@ from tpunode.verify.sched import (
 )
 from tpunode.watchdog import Watchdog, WatchdogConfig
 
-from tests.test_engine import make_items
+from tests.test_engine import _cut_counts, make_items
 
 
-def _sub(n: int, priority: str = "bulk", payload=None) -> Submission:
+def _sub(n: int, priority: str = "bulk", payload=None, **kw) -> Submission:
     fut: asyncio.Future = asyncio.get_running_loop().create_future()
     return Submission(
         payload if payload is not None else list(range(n)), fut, None,
-        priority,
+        priority, **kw,
     )
 
 
@@ -176,6 +177,212 @@ def test_slice_payload_list_and_raw():
     part = slice_payload(raw, 2, 5)
     assert len(part) == 3
     assert part.to_tuples() == raw.to_tuples()[2:5]
+
+
+# --- the lane rule by class (ISSUE 37) ---------------------------------------
+# Times are the tests' own: submissions carry explicit enqueue stamps and
+# decide() / cut() are asked at a chosen ``now``.
+
+SMALL, BIG, WAIT = 4096, 32768, 0.025
+IBD = LINGER["ibd"] * WAIT  # how long a lone `ibd` submission lingers
+
+
+@pytest.mark.asyncio
+async def test_oldest_by_class_survives_partial_claims():
+    p = LanePacker(small=SMALL, max_wait=WAIT)
+    assert p.oldest_by_class() == {} and p.oldest_enqueued() is None
+    ibd = _sub(10, "ibd", enqueued=10.0)
+    p.push(ibd)
+    p.push(_sub(6, "ibd", enqueued=11.0))
+    p.push(_sub(2, "mempool", enqueued=12.0))
+    assert p.oldest_by_class() == {"ibd": 10.0, "mempool": 12.0}
+    assert p.oldest_enqueued() == 10.0
+    lane = p.pop_lane(6)  # mempool's 2, then 4 of the oldest ibd's 10
+    assert [(s.priority, lo, hi) for s, lo, hi in lane.slices] == [
+        ("mempool", 0, 2), ("ibd", 0, 4)
+    ]
+    # the part-claimed submission is still its class's oldest, at the
+    # time it was enqueued
+    assert p.oldest_by_class() == {"ibd": 10.0} and ibd.enqueued == 10.0
+    p.pop_lane(6)  # the rest of it
+    assert p.oldest_by_class() == {"ibd": 11.0}
+
+
+@pytest.mark.asyncio
+async def test_oldest_by_class_survives_fleet_reroutes():
+    f = FleetDispatcher(
+        ["h0", "h1"], packer=LanePacker(small=SMALL, max_wait=WAIT)
+    )
+    on = {h: [k for k in range(64) if f.affinity.prefer(k) == h][:2]
+          for h in f.hosts}
+    old = _sub(5, "ibd", enqueued=5.0, affinity=on["h0"][0])
+    part = _sub(9, "ibd", enqueued=6.0, affinity=on["h0"][1])
+    new = _sub(3, "ibd", enqueued=7.0, affinity=on["h1"][0])
+    blk = _sub(1, "block", enqueued=7.01)  # keyless: the central packer
+    for s in (old, part, new, blk):
+        f.push(s)
+    assert f._packers["h0"].pop_lane(7).total == 7  # old whole, 2 of part
+    assert f.oldest_by_class() == {"ibd": 6.0, "block": 7.01}
+    f.deactivate("h0")  # part's remainder is pushed into h1's packer
+    assert f._packers["h0"].pending() == 0
+    # ... BEHIND nothing: it is older than what h1 held, so it is that
+    # packer's oldest, under the stamp it was given at its enqueue
+    assert f._packers["h1"].oldest_by_class() == {"ibd": 6.0}
+    assert f.oldest_by_class() == {"ibd": 6.0, "block": 7.01}
+    assert part.enqueued == 6.0 and f.uncut_pending() == 7 + 3 + 1
+    lane = f._packers["h1"].pop_lane(8)
+    assert [(s, lo, hi) for s, lo, hi in lane.slices] == [
+        (part, 2, 9), (new, 0, 1)
+    ]
+    # the fleet's linger reads the same table over all its packers:
+    # block's 1x (7.035) comes before the ibd left's own (7.0 + IBD)
+    d = f.decide(BIG, 7.02)
+    assert (d.cut, d.priority, d.size) == (None, "block", BIG)
+    assert d.wait == pytest.approx(0.015)
+
+
+@pytest.mark.parametrize("cls", PRIORITIES)
+@pytest.mark.asyncio
+async def test_lone_submission_is_cut_at_its_classes_linger(cls):
+    """`ibd` lingers LINGER["ibd"] x max_wait, every class with a waiter
+    1 x."""
+    assert LINGER == {"block": 1, "mempool": 1, "ibd": 2, "bulk": 1}
+    linger = IBD if cls == "ibd" else WAIT
+    metrics.reset()
+    p = LanePacker(small=SMALL, max_wait=WAIT)
+    p.push(_sub(3, cls, enqueued=100.0))
+    d = p.decide(BIG, 100.0)
+    assert d.cut is None and d.priority == cls
+    assert d.wait == pytest.approx(linger)
+    d = p.decide(BIG, 100.0 + linger - 1e-4)
+    assert d.cut is None and d.wait == pytest.approx(1e-4)
+    d = p.decide(BIG, 100.0 + linger)
+    assert (d.cut, d.priority, d.wait) == ("deadline", cls, 0.0)
+    assert d.size == (SMALL if cls == "ibd" else BIG)
+    assert p.cut(BIG, now=100.0 + linger).total == 3
+    assert _cut_counts() == {
+        'sched.lanes_cut_deadline{priority="%s"}' % cls: 1
+    }
+
+
+@pytest.mark.asyncio
+async def test_mempool_deadline_cuts_and_takes_ibd_along():
+    metrics.reset()
+    p = LanePacker(small=SMALL, max_wait=WAIT)
+    p.push(_sub(5, "ibd", enqueued=100.0))  # its own deadline: 100 + IBD
+    due = 100.0 + (IBD + WAIT) / 2  # mempool's: sooner, enqueued later
+    p.push(_sub(2, "mempool", enqueued=due - WAIT))
+    d = p.decide(BIG, due - 0.005)
+    assert d.cut is None and d.priority == "mempool"
+    assert d.wait == pytest.approx(0.005)
+    d = p.decide(BIG, due)
+    # mempool's deadline, mempool's goal (the big shape), as before
+    assert (d.cut, d.priority, d.size) == ("deadline", "mempool", BIG)
+    lane = p.cut(BIG, now=due)
+    assert [(s.priority, hi - lo) for s, lo, hi in lane.slices] == [
+        ("mempool", 2), ("ibd", 5)
+    ]
+    assert lane.target == BIG and p.pending() == 0
+    # an ibd submission old enough decides itself, though mempool is there
+    p.push(_sub(5, "ibd", enqueued=200.0))
+    p.push(_sub(2, "mempool", enqueued=200.0 + IBD - 0.01))
+    d = p.decide(BIG, 200.0 + IBD)
+    assert (d.cut, d.priority, d.size) == ("deadline", "ibd", BIG)
+    assert p.cut(BIG, now=200.0 + IBD).total == 7
+    assert _cut_counts() == {
+        'sched.lanes_cut_deadline{priority="mempool"}': 1,
+        'sched.lanes_cut_deadline{priority="ibd"}': 1,
+    }
+
+
+@pytest.mark.parametrize("pending", [SMALL + 1, 2 * SMALL + 5, BIG - 1])
+@pytest.mark.asyncio
+async def test_ibd_between_the_shapes_is_cut_at_the_small_one(pending):
+    """No lane falls over the pad cliff: a chunk over ``batch_size`` is
+    padded to ``device_batch``, so `ibd`-only work between the two is
+    cut at exactly ``batch_size`` and the rest lingers on."""
+    metrics.reset()
+    p = LanePacker(small=SMALL, max_wait=WAIT)
+    first = _sub(SMALL - 10, "ibd", enqueued=1.0)
+    rest = _sub(pending - first.n, "ibd", enqueued=2.0)
+    p.push(first)
+    p.push(rest)
+    d = p.decide(BIG, 2.0)  # no deadline is due: the goal is queued
+    assert (d.cut, d.priority, d.size, d.wait) == ("full", "ibd", SMALL, 0.0)
+    lane = p.cut(BIG, now=2.0)
+    assert lane.total == lane.target == SMALL and lane.occupancy == 1.0
+    assert p.pending() == pending - SMALL
+    # the remainder is not re-stamped
+    assert p.oldest_by_class() == {"ibd": 2.0} and rest.enqueued == 2.0
+    assert rest.taken == 10
+    d = p.decide(BIG, 2.0)
+    if p.pending() >= SMALL:
+        assert (d.cut, d.size) == ("full", SMALL)
+    else:
+        assert d.cut is None and d.wait == pytest.approx(IBD)
+    assert _cut_counts() == {'sched.lanes_cut_full{priority="ibd"}': 1}
+
+
+@pytest.mark.parametrize("cls,pending,want", [
+    ("ibd", BIG, ("full", BIG)),
+    ("ibd", BIG + 10, ("full", BIG)),
+    ("block", BIG + 10, ("full", BIG)),
+    ("block", SMALL + 1, (None, BIG)),  # lingers, then one padded lane
+    ("bulk", BIG - 1, (None, BIG)),
+])
+@pytest.mark.asyncio
+async def test_other_lanes_are_cut_as_before(cls, pending, want):
+    metrics.reset()
+    p = LanePacker(small=SMALL, max_wait=WAIT)
+    p.push(_sub(pending, cls, enqueued=1.0))
+    d = p.decide(BIG, 1.0)
+    assert (d.cut, d.size) == want and d.priority == cls
+    lane = p.cut(BIG, now=1.0 + IBD)
+    assert lane.total == min(pending, BIG) and lane.target == BIG
+    reason = "full" if pending >= BIG else "deadline"
+    assert _cut_counts() == {
+        'sched.lanes_cut_%s{priority="%s"}' % (reason, cls): 1
+    }
+
+
+@pytest.mark.asyncio
+async def test_a_class_with_a_waiter_sets_the_big_goal_for_ibd_too():
+    p = LanePacker(small=SMALL, max_wait=WAIT)
+    p.push(_sub(SMALL + 100, "ibd", enqueued=1.0))
+    p.push(_sub(1, "bulk", enqueued=1.0))
+    d = p.decide(BIG, 1.0)
+    assert (d.cut, d.priority, d.size) == (None, "bulk", BIG)
+    # before the device is up there is one shape, whatever is queued
+    assert p.decide(SMALL, 1.0)[:3] == ("full", "bulk", SMALL)
+    # a packer built bare has one shape and no linger
+    bare = LanePacker()
+    bare.push(_sub(3, "ibd", enqueued=1.0))
+    assert bare.decide(64, 1.0)[:3] == ("deadline", "ibd", 64)
+    assert bare.cut(64).total == 3 and bare.cut(64) is None
+
+
+@pytest.mark.asyncio
+async def test_fleet_cuts_by_the_same_rule():
+    """cut_next / pop_any cut through the chosen packer's rule."""
+    metrics.reset()
+    f = FleetDispatcher(
+        ["h0", "h1"], packer=LanePacker(small=8, max_wait=WAIT)
+    )
+    key = next(k for k in range(64) if f.affinity.prefer(k) == "h1")
+    f.push(_sub(11, "ibd", enqueued=1.0, affinity=key))
+    lane, host = f.cut_next(64, now=1.0)
+    assert (lane.total, lane.target, host) == (8, 8, "h1")
+    f.push(_sub(2, "block", enqueued=1.0))
+    lane, host = f.cut_next(64, now=1.0)  # the central packer's block first
+    assert (lane.total, lane.target, host) == (2, 64, "h0")
+    f.deactivate("h0")
+    f.deactivate("h1")
+    assert f.pop_any(64, now=1.0).total == 3  # the ibd left, dark fleet
+    assert _cut_counts() == {
+        'sched.lanes_cut_full{priority="ibd"}': 1,
+        'sched.lanes_cut_deadline{priority="block"}': 1,
+        'sched.lanes_cut_deadline{priority="ibd"}': 1,
+    }
 
 
 # --- fleet dispatcher units (ISSUE 13) ---------------------------------------

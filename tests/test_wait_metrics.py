@@ -127,7 +127,7 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
     after = names[first + len(WAITS):]
     assert after[:len(CONNECT)] == list(CONNECT)
     # PR 27's, appended in their turn, then PR 28's, PR 30's, PR 31's,
-    # PR 32's, PR 36's seven
+    # PR 32's, PR 36's seven, PR 37's two
     assert after[len(CONNECT):] == [
         "reuse.hit_share", "reuse.ms_per_block", "reuse.cpu_ms_per_block",
         "tip.relay_verdict_p50_ms", "tip.block_verdict_p50_ms",
@@ -138,10 +138,17 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
         "store.compactions_in_window", "stream.early_share",
         "ibd.head_wait_share", "ibd.stall_recover_ms",
         "ibd.rerequested_share", "ibd.duplicate_share", "ibd.longest_gap_s",
-        "peer.reconnect_ms", "wan.late_p99_ms"]
-    # ... which only their own cell lists: no other cell's run reads them
-    for name in after[-7:]:
+        "peer.reconnect_ms", "wan.late_p99_ms",
+        "sched.full_cut_share", "kernel.slots_per_item"]
+    # ... PR 36's only their own cell lists: no other cell's run reads them
+    for name in after[-9:-2]:
         assert by_name[name]["workloads"] == ["bch-wan.ibd-faults"], name
+    # PR 37's move the CPU metric, so every cell that reports it lists them
+    cpu = next(m for m in BENCH["end_to_end"]
+               if m["name"] == "host_cpu_ms_per_ksig")["workloads"]
+    for name in after[-2:]:
+        assert by_name[name]["workloads"] == cpu, name
+        assert by_name[name]["moves"] == "host_cpu_ms_per_ksig"
 
 
 def test_the_two_connect_metrics_read_the_utxo_connect_span():

@@ -406,7 +406,9 @@ class VerifyConfig:
     # item 4).  Work under ``batch_size`` pads to the small shape, bigger
     # work is chunked at this size; warmup compiles both shapes.
     device_batch: int = 32768
-    max_wait: float = 0.025  # seconds to linger for a fuller batch
+    # seconds a class with a waiter lingers for a fuller lane; a class's
+    # multiple of it is sched.LINGER (ibd backfill: 2)
+    max_wait: float = 0.025
     # Streaming pipeline width (ISSUE 10): how many packed lanes may be
     # in flight at once, each in its own dispatch thread.  2 overlaps
     # lane N+1's host prep + transfer with lane N's kernel (JAX async
@@ -652,7 +654,9 @@ class VerifyEngine:
         # Lane-packing scheduler (ISSUE 10): submissions (with their
         # futures and trace positions) queue here; the pipeline loop
         # pops packed lanes from it.
-        self._packer = LanePacker()
+        self._packer = LanePacker(
+            small=self.cfg.batch_size, max_wait=self.cfg.max_wait
+        )
         # Per-inflight dispatch start times keyed by a monotonic token
         # (ISSUE 10 watchdog satellite): with pipeline_depth > 1 a single
         # scalar would misattribute or miss stalls — the watchdog's
@@ -1114,10 +1118,9 @@ class VerifyEngine:
             return self._fleet.uncut_pending()
         return self._packer.pending()
 
-    def _uncut_oldest(self) -> Optional[float]:
-        if self._fleet is not None:
-            return self._fleet.oldest_enqueued()
-        return self._packer.oldest_enqueued()
+    def _uncut(self):
+        """Who holds the uncut work: the fleet's packers, or the one."""
+        return self._fleet if self._fleet is not None else self._packer
 
     async def _run(self) -> None:
         """Pipeline scheduler loop: linger toward full lanes, then keep up
@@ -1137,25 +1140,27 @@ class VerifyEngine:
                 while not self._uncut_pending():
                     await self._kick.wait()
                     self._kick.clear()
-            target = self._lane_target()
             # Event-driven fill (VERDICT r4 weak #6 — the former 2 ms poll
             # burned ≤500 wakes/s per linger window): sleep until either a
-            # new enqueue kicks, or the linger deadline passes.  The
-            # deadline anchors on the OLDEST queued submission, so a
-            # remainder lingers for later submissions to pack with only
-            # while its submitter is younger than max_wait (ISSUE 10:
-            # max-linger — a lone small batch still dispatches promptly).
+            # new enqueue kicks, or the linger deadline passes.  Both the
+            # deadline and the fill goal belong to the CLASS of what is
+            # queued (sched.decide_lane, ISSUE 37): each class's oldest
+            # submission may wait LINGER[class] x max_wait and the
+            # earliest deadline cuts, so a remainder lingers for later
+            # submissions to pack with only while its own class allows
+            # (ISSUE 10: max-linger — a lone small batch still dispatches
+            # promptly); ibd backfill, which nobody awaits block by block,
+            # waits longer and is full at the small shape.
             with span("sched.linger"):
-                while self._uncut_pending() < target:
-                    oldest = self._uncut_oldest()
-                    if oldest is None:
-                        break
-                    remain = oldest + self.cfg.max_wait - time.monotonic()
-                    if remain <= 0:
+                while self._uncut_pending():
+                    d = self._uncut().decide(
+                        self._lane_target(), time.monotonic()
+                    )
+                    if d.cut is not None:
                         break
                     try:
                         await asyncio.wait_for(
-                            self._kick.wait(), timeout=remain
+                            self._kick.wait(), timeout=d.wait
                         )
                     except asyncio.TimeoutError:
                         break
@@ -1168,7 +1173,7 @@ class VerifyEngine:
             # admission: a free pipeline slot (more work keeps queueing —
             # and packing fuller lanes — while every slot is busy)
             await self._acquire_slot()
-            lane = self._packer.pop_lane(self._lane_target())
+            lane = self._packer.cut(self._lane_target())
             if lane is None:
                 self._slots.release()
                 continue
@@ -1849,6 +1854,7 @@ class VerifyEngine:
                     self._dispatch_chunk(chunk, pad_to=pad, host=host)
                 )
                 metrics.inc("verify.tpu_items", len(chunk))
+                metrics.inc("verify.tpu_slots", pad)
         out: list[bool] = []
         for p in pending:
             out.extend(p if isinstance(p, list) else collect_verdicts(*p))

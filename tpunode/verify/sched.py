@@ -21,9 +21,23 @@ This module owns the queue instead:
   submission may span several lanes; several submissions may share one.
   Per-item futures still resolve exactly once with exactly their items'
   verdicts (verdict conservation — the chaos SOAK invariant).
-* **Max-linger deadline** — a lone small submission is dispatched as a
-  partial lane once its linger expires; ``min_tpu_batch`` degrades from
-  a routing rule to a shed-only floor applied at dispatch time.
+* **Max-linger deadline, by class** — a lone small submission is
+  dispatched as a partial lane once its linger expires;
+  ``min_tpu_batch`` degrades from a routing rule to a shed-only floor
+  applied at dispatch time.  The linger is a latency budget, so it
+  belongs to the class of what is queued (ISSUE 37): each class's oldest
+  unclaimed submission may wait ``LINGER[class] × max_wait``, and the
+  earliest such deadline over the queued classes cuts the lane, taking
+  lower classes along in the room left.  ``block``, ``mempool`` and
+  ``bulk`` have a caller waiting on a verdict (multiple 1); nobody waits
+  on one block of ``ibd`` backfill — the planner's window does — so it
+  lingers longer, toward a full lane.
+* **Fill goal, by class** — queued work is a full lane at the big
+  compiled shape, except while only ``SMALL_LANE`` classes (``ibd``) are
+  queued: the planner's window never queues a big lane, the slot cost is
+  flat across the two shapes, so such work is full at the small shape and
+  is cut at exactly that — never a little over it, which the device pads
+  to the big shape (:func:`decide_lane`).
 
 The packer is plain data + arithmetic on the event loop; the engine's
 pipeline (``VerifyConfig.pipeline_depth``) pulls lanes from it.
@@ -57,7 +71,9 @@ a starvation source.
 
 Telemetry: ``sched.queue_depth{priority=}`` gauges, the
 ``sched.pack_efficiency`` histogram (lane occupancy at dispatch),
-``sched.lanes`` / ``sched.packed_submissions`` counters, and the fleet
+``sched.lanes`` / ``sched.packed_submissions`` counters,
+``sched.lanes_cut_full{priority=}`` / ``sched.lanes_cut_deadline{priority=}``
+(why each lane was cut, by the class that decided), and the fleet
 surface — ``sched.host_depth{host=}`` gauges, ``sched.steals`` /
 ``sched.requeued`` counters, ``sched.steal`` events, plus the affine
 feed surface: ``sched.affinity_routed{host=}`` / ``sched.affinity_spilled``
@@ -74,7 +90,7 @@ import asyncio
 import collections
 import hashlib
 import time
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 from ..events import events
 from ..metrics import metrics
@@ -82,6 +98,10 @@ from ..metrics import metrics
 __all__ = [
     "OCCUPANCY_BUCKETS",
     "PRIORITIES",
+    "LINGER",
+    "SMALL_LANE",
+    "LaneDecision",
+    "decide_lane",
     "affinity_key",
     "host_names",
     "AffinityMap",
@@ -97,6 +117,25 @@ __all__ = [
 # outranks bulk (API default / re-index) traffic.
 PRIORITIES = ("block", "mempool", "ibd", "bulk")
 
+# How long a class's oldest unclaimed submission may linger for a fuller
+# lane, in multiples of ``VerifyConfig.max_wait`` (ISSUE 37).  That wait
+# is a latency budget: ``block`` and ``mempool`` verdicts have a user
+# behind them, ``bulk`` is ``verify_raw``'s default and the serve path's
+# tenants, who wait too.  ``ibd`` is planner-era backfill: no caller
+# waits on one block of it, the planner's window of ``max_lead`` blocks
+# does, and a lane costs the host the same whatever it holds.  2, 4 and 6
+# were read on the chip (PERF.md §6, PR 37): each step costs less CPU a
+# signature and verifies more (at 6 IBD's lanes leave full, cut by the
+# goal and not the clock); 2 is kept until the benchmark's IBD chain is
+# long enough to measure the others' rate (ROADMAP B1).
+LINGER = {"block": 1, "mempool": 1, "ibd": 2, "bulk": 1}
+
+# Classes that fill toward the SMALL compiled shape: while nothing else
+# is queued, ``batch_size`` items are a full lane.  The planner keeps
+# ``max_lead`` blocks beyond the watermark, far under ``device_batch``
+# items, and a device slot costs the same in either shape.
+SMALL_LANE = frozenset({"ibd"})
+
 # Linear occupancy buckets (0.05 steps): lane occupancy lives in [0, 1],
 # which the duration-shaped default bounds would quantize uselessly.
 OCCUPANCY_BUCKETS = tuple(i / 20 for i in range(1, 21))
@@ -105,6 +144,52 @@ metrics.describe(
     "node.verdict_latency",
     "submit->verdict-publish latency per priority class (seconds)",
 )
+
+
+class LaneDecision(NamedTuple):
+    """What the linger loop does with the queued work at one instant."""
+
+    cut: Optional[str]  # "full" | "deadline"; None: keep lingering
+    priority: str  # the class whose fill goal or deadline decides
+    size: int  # items to cut the lane at
+    wait: float  # seconds until the deciding deadline (0.0 on a cut)
+
+
+def decide_lane(
+    pending: int,
+    oldest: dict[str, float],
+    small: Optional[int],
+    big: int,
+    max_wait: float,
+    now: float,
+) -> LaneDecision:
+    """The lane rule (ISSUE 37), as arithmetic on what is queued.
+
+    ``pending``: unclaimed items; ``oldest``: the oldest unclaimed
+    submission's enqueue time for each class that has one (at least one
+    has).  ``small`` / ``big``: the two compiled shapes (``small`` None
+    or over ``big``: there is one, ``big``).
+
+    *Fill goal*: ``big``, unless only :data:`SMALL_LANE` classes are
+    queued — then ``small``.  *Cut size*: the goal; ``big`` once that
+    much is queued.  So ``small``-goal work between the shapes is cut at
+    exactly ``small`` and its remainder lingers on under its own enqueue
+    times: a lane a little over ``small`` would be padded to ``big``.
+    *Deadline*: the earliest ``oldest[class] + LINGER[class] × max_wait``
+    (ties to the higher class)."""
+    small = big if small is None else min(small, big)
+    queued = [p for p in PRIORITIES if p in oldest]
+    big_cls = next((p for p in queued if p not in SMALL_LANE), None)
+    goal, goal_cls = (small, queued[0]) if big_cls is None else (big, big_cls)
+    deadline, _, deadline_cls = min(
+        (oldest[p] + LINGER[p] * max_wait, i, p) for i, p in enumerate(queued)
+    )
+    size = big if pending >= big else goal
+    if pending >= goal:
+        return LaneDecision("full", goal_cls, size, 0.0)
+    if deadline <= now:
+        return LaneDecision("deadline", deadline_cls, size, 0.0)
+    return LaneDecision(None, deadline_cls, size, deadline - now)
 
 
 def slice_payload(payload, lo: int, hi: int):
@@ -343,10 +428,22 @@ class LanePacker:
     last-writer-win the same gauge keys as the central packer.  The
     counters/histogram stay on — they are process totals and sum
     correctly across packers.
+
+    ``small`` and ``max_wait`` are the lane rule's two constants of the
+    deployment (``VerifyConfig.batch_size`` / ``.max_wait``) for
+    :meth:`decide` and :meth:`cut`; a packer built without them has one
+    shape and no linger, and :meth:`pop_lane` needs neither.
     """
 
-    def __init__(self, gauge: bool = True):
+    def __init__(
+        self,
+        gauge: bool = True,
+        small: Optional[int] = None,
+        max_wait: float = 0.0,
+    ):
         self._gauge_on = gauge
+        self.small = small
+        self.max_wait = max_wait
         self._q: dict[str, collections.deque[Submission]] = {
             p: collections.deque() for p in PRIORITIES
         }
@@ -365,7 +462,14 @@ class LanePacker:
         # submission must not inflate the depth by items already cut
         # into lanes (ISSUE 19).
         rem = sub.n - sub.taken
-        self._q[sub.priority].append(sub)
+        q = self._q[sub.priority]
+        i = len(q)
+        # A re-routed submission is older than what its new packer
+        # holds: it goes in by enqueue time, so q[0] stays the class's
+        # oldest (the linger deadline anchors on it) and the class FIFO.
+        while i and q[i - 1].enqueued > sub.enqueued:
+            i -= 1
+        q.insert(i, sub)
         self._pending_items += rem
         self._depth[sub.priority] += rem
         if self._gauge_on:
@@ -388,12 +492,16 @@ class LanePacker:
         """Unclaimed items per priority (stats/debug endpoints)."""
         return dict(self._depth)
 
+    def oldest_by_class(self) -> dict[str, float]:
+        """Enqueue time of the oldest unclaimed submission of each class
+        that has one — the linger deadlines anchor on these.  A
+        submission keeps its time while lanes claim parts of it and when
+        a fleet re-route pushes it into another packer."""
+        return {p: q[0].enqueued for p, q in self._q.items() if q}
+
     def oldest_enqueued(self) -> Optional[float]:
-        """Enqueue time of the oldest queued submission (any class) —
-        the linger deadline anchors on it so a lone low-priority
-        submission still dispatches promptly."""
-        heads = [q[0].enqueued for q in self._q.values() if q]
-        return min(heads) if heads else None
+        """Enqueue time of the oldest queued submission (any class)."""
+        return min(self.oldest_by_class().values(), default=None)
 
     def head_class(self) -> Optional[int]:
         """Index into PRIORITIES of the highest class with unclaimed
@@ -452,6 +560,31 @@ class LanePacker:
         metrics.observe(
             "sched.pack_efficiency", lane.occupancy, buckets=OCCUPANCY_BUCKETS
         )
+        return lane
+
+    def decide(self, target: int, now: float) -> LaneDecision:
+        """:func:`decide_lane` over this packer's queue (which must not
+        be empty), ``target`` being the big shape."""
+        return decide_lane(
+            self._pending_items, self.oldest_by_class(), self.small, target,
+            self.max_wait, now,
+        )
+
+    def cut(self, target: int, now: Optional[float] = None
+            ) -> Optional[PackedLane]:
+        """Cut the lane the rule gives at ``now``, whether or not it
+        says to linger on (the caller has decided to cut), and count it
+        under the reason and the class that decided."""
+        if not self._pending_items:
+            return None
+        d = self.decide(target, time.monotonic() if now is None else now)
+        lane = self.pop_lane(d.size)
+        if lane is not None:
+            metrics.inc(
+                "sched.lanes_cut_full" if d.cut == "full"
+                else "sched.lanes_cut_deadline",
+                labels={"priority": d.priority},
+            )
         return lane
 
     # -- shutdown -------------------------------------------------------------
@@ -518,7 +651,13 @@ class FleetDispatcher:
         # fallback; per-host packers run gauge-silenced so they don't
         # stomp the central sched.queue_depth series.
         self.affinity = AffinityMap(hosts)
-        self._packers: dict = {h: LanePacker(gauge=False) for h in hosts}
+        self._packers: dict = {
+            h: LanePacker(
+                gauge=False, small=self.packer.small,
+                max_wait=self.packer.max_wait,
+            )
+            for h in hosts
+        }
         self.affinity_routed = 0
         self.affinity_spilled = 0
         # feed starvation: take attempts that found the host's own
@@ -597,12 +736,22 @@ class FleetDispatcher:
                 out[k] += v
         return out
 
-    def oldest_enqueued(self) -> Optional[float]:
-        heads = [self.packer.oldest_enqueued()] + [
-            p.oldest_enqueued() for p in self._packers.values()
-        ]
-        heads = [h for h in heads if h is not None]
-        return min(heads) if heads else None
+    def oldest_by_class(self) -> dict[str, float]:
+        """Per class, the oldest unclaimed submission over the central
+        and every per-host packer."""
+        out = self.packer.oldest_by_class()
+        for p in self._packers.values():
+            for k, t in p.oldest_by_class().items():
+                out[k] = min(t, out.get(k, t))
+        return out
+
+    def decide(self, target: int, now: float) -> LaneDecision:
+        """The linger loop's question over everything uncut (which must
+        not be nothing): the same rule as one packer's, on the sums."""
+        return decide_lane(
+            self.uncut_pending(), self.oldest_by_class(), self.packer.small,
+            target, self.packer.max_wait, now,
+        )
 
     def feed_depth(self, host: str) -> int:
         """Uncut items homed to ``host`` plus items already cut into
@@ -686,7 +835,7 @@ class FleetDispatcher:
         return best
 
     def cut_next(
-        self, target: int
+        self, target: int, now: Optional[float] = None
     ) -> tuple[Optional[PackedLane], Optional[str]]:
         """Cut the globally most-urgent feedable lane and place it.
 
@@ -721,18 +870,20 @@ class FleetDispatcher:
         if best_key is None:
             return None, None
         if best_host is not None:
-            lane = self._packers[best_host].pop_lane(target)
+            lane = self._packers[best_host].cut(target, now)
             if lane is None:  # only failed-submission residue queued
                 return None, None
             self._queues[best_host].append(lane)
             self._gauge(best_host)
             return lane, best_host
-        lane = self.packer.pop_lane(target)
+        lane = self.packer.cut(target, now)
         if lane is None:
             return None, None
         return lane, self.assign(lane)
 
-    def pop_any(self, target: int) -> Optional[PackedLane]:
+    def pop_any(
+        self, target: int, now: Optional[float] = None
+    ) -> Optional[PackedLane]:
         """Cut a lane from ANY packer, priority-first (dark fleet: the
         engine's local-CPU fallback drains the affine packers too, so
         affinity never strands work when every host is down)."""
@@ -747,7 +898,7 @@ class FleetDispatcher:
                 best_key, best_packer = key, p
         if best_packer is None:
             return None
-        return best_packer.pop_lane(target)
+        return best_packer.cut(target, now)
 
     def take(self, host: str, steal: bool = True) -> Optional[PackedLane]:
         """Next lane for ``host``: its own queue head, else (``steal``)
