@@ -65,7 +65,7 @@ async def test_attributor_captures_blocking_frame():
     att = asyncsan.LoopAttributor(threshold=0.05, interval=0.02)
     att.start()
     try:
-        await asyncio.sleep(0.1)  # let the heartbeat+sampler establish
+        await asyncio.sleep(0.1)  # let the selector stamps+sampler establish
         time.sleep(0.4)  # the deliberate sync freeze
         await asyncio.sleep(0.05)
         blocked = att.last_blocked()
@@ -251,7 +251,7 @@ async def test_node_sanitizers_catch_injected_block_and_leak(monkeypatch):
                 assert loop.get_debug() is True
                 assert node._attributor is not None
                 assert node._watchdog.attributor is node._attributor
-                await asyncio.sleep(0.15)  # heartbeat/watchdog baseline
+                await asyncio.sleep(0.15)  # selector-stamp/watchdog baseline
                 # inject the two defects
                 leaked = spawn_supervised(
                     asyncio.sleep(30), name="leaky-test-task"

@@ -127,7 +127,10 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
     after = names[first + len(WAITS):]
     assert after[:len(CONNECT)] == list(CONNECT)
     # PR 27's, appended in their turn, then PR 28's, PR 30's, PR 31's,
-    # PR 32's, PR 36's seven, PR 37's two
+    # PR 32's, PR 36's seven, PR 37's two, PR 38's sixteen ("node loop")
+    loop = [m["name"] for m in BENCH["per_layer"] if m["layer"] == "node loop"]
+    assert len(loop) == 16 and after[-16:] == loop
+    after = after[:-16]
     assert after[len(CONNECT):] == [
         "reuse.hit_share", "reuse.ms_per_block", "reuse.cpu_ms_per_block",
         "tip.relay_verdict_p50_ms", "tip.block_verdict_p50_ms",

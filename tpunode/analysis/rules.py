@@ -476,8 +476,14 @@ KNOWN_LAYERS = frozenset({
     "bus",        # Publisher/user bus (tpunode/actors.py)
     "chain",      # header-chain actor (tpunode/chain.py)
     "chaos",      # fault injection (tpunode/chaos.py, ISSUE 7)
+    "cpu",        # the process's CPU time by thread role
+                  # (tpunode/asyncsan.py's collector, ISSUE 38)
     "events",     # event-log self-metrics (tpunode/events.py)
+    "gc",         # the collector's pauses by generation
+                  # (tpunode/asyncsan.py's gc.callbacks entry, ISSUE 38)
     "ibd",        # block-fetch-driven IBD planner (tpunode/ibd.py, ISSUE 11)
+    "loop",       # the event loop's clock: idle, CPU, holds by name
+                  # (tpunode/asyncsan.py, ISSUE 38)
     "mempool",    # mempool subsystem (tpunode/mempool.py)
     "mesh",       # pod-scale fleet: host health, sub-mesh shrink/regrow
                   # (tpunode/verify/engine.py, ISSUE 13; also the

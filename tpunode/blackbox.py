@@ -28,17 +28,21 @@ disk flood.  Bundles always land in an in-memory ring (``/flightrecords``
 endpoint); with ``TPUNODE_BLACKBOX_DIR`` (or ``FlightRecorderConfig.dir``)
 set, each is also written as one JSON file.  Stdlib-only, never imports
 jax; safe to fire from the engine's dispatch worker threads (one lock,
-sources wrapped so a broken provider degrades to an error string).
+sources wrapped so a broken provider degrades to an error string).  A
+trigger emitted ON an event loop (``watchdog.stall``, ``slo.burn``) is
+admitted there and built and written on a thread of the recorder's own:
+the dump never holds the loop that has just been held (ISSUE 38).
 """
 
 from __future__ import annotations
 
+import asyncio
 import json
 import logging
 import os
-import threading
 import time
 from collections import deque
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Callable, Optional
 
@@ -108,6 +112,9 @@ class FlightRecorder:
         self._dumps = 0
         self._write_errors = 0
         self._unsub: Optional[Callable[[], None]] = None
+        # one worker, made on the first trigger that fires on a loop: its
+        # queue keeps bundles in trigger order
+        self._off_loop: Optional[ThreadPoolExecutor] = None
 
     # -- wiring ---------------------------------------------------------------
 
@@ -117,31 +124,59 @@ class FlightRecorder:
             self._unsub = self.log.subscribe(self._on_event)
 
     def detach(self) -> None:
+        """Unsubscribe; a bundle being built off the loop is finished
+        first (it is the record of why the node is going down)."""
         if self._unsub is not None:
             self._unsub()
             self._unsub = None
+        with self._lock:
+            pool, self._off_loop = self._off_loop, None
+        if pool is not None:
+            pool.shutdown(wait=True)
 
     def _on_event(self, ev: dict) -> None:
         type_ = ev.get("type")
-        if type_ in TRIGGERS or (
+        if not (type_ in TRIGGERS or (
             type_ == "verify.breaker" and ev.get("to") == "open"
-        ):
+        )):
+            return
+        try:
+            asyncio.get_running_loop()
+        except RuntimeError:  # a worker thread's emit: build it here
             self.record(reason=type_, trigger=ev)
+            return
+        if self._admit(force=False):
+            with self._lock:
+                if self._off_loop is None:
+                    self._off_loop = ThreadPoolExecutor(
+                        max_workers=1, thread_name_prefix="blackbox"
+                    )
+                pool = self._off_loop
+            pool.submit(self._capture, type_, ev)
 
     # -- recording ------------------------------------------------------------
 
     def record(
         self, reason: str, trigger: Optional[dict] = None, force: bool = False
     ) -> Optional[dict]:
-        """Build one bundle now (rate-limited unless ``force``); returns
-        the bundle, or None when suppressed."""
+        """Build one bundle now, in the caller's thread (rate-limited
+        unless ``force``); returns the bundle, or None when suppressed."""
+        if not self._admit(force):
+            return None
+        return self._capture(reason, trigger)
+
+    def _admit(self, force: bool) -> bool:
+        """The rate limit: may a bundle be built now?"""
         now = time.monotonic()
         with self._lock:
             if not force and now - self._last_dump < self.cfg.min_interval:
                 self._suppressed += 1
                 metrics.inc("blackbox.suppressed")
-                return None
+                return False
             self._last_dump = now
+        return True
+
+    def _capture(self, reason: str, trigger: Optional[dict]) -> dict:
         bundle = self._build(reason, trigger)
         bundle["path"] = self._write(bundle)
         with self._lock:

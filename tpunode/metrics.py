@@ -216,6 +216,12 @@ class Metrics:
         # Weak refs: a churned Timeline must not be kept alive (or
         # called) by the process-global registry.
         self._drop_hooks: list = []
+        # on_collect callbacks (ISSUE 38): weakly-referenced zero-arg
+        # callables run OUTSIDE the lock at the top of every whole-registry
+        # read, so numbers that are clocks somebody else keeps (a thread's
+        # CPU time, the loop's time in its selector) are read when somebody
+        # looks and cost nothing when nobody does.
+        self._collectors: list = []
         self._created = time.monotonic()
 
     def describe(self, name: str, help_: str) -> None:
@@ -347,6 +353,39 @@ class Metrics:
                     r for r in self._drop_hooks if r() is not None
                 ]
 
+    def on_collect(self, fn: Callable[[], None]) -> None:
+        """Register a zero-arg callback run once at the top of every
+        :meth:`snapshot`, :meth:`flat_sample` and
+        :meth:`render_prometheus`, in the reader's thread, before the
+        registry is read: it brings counters up to date from a clock kept
+        elsewhere (``inc`` / ``inc_batch`` with the delta since its last
+        run).  Held by WEAK reference like :meth:`on_drop`; one that
+        raises is counted (``trace.collector_errors``), never raised into
+        the reader."""
+        with self._lock:
+            self._collectors.append(_weak_callable(fn))
+
+    def _collect(self) -> None:
+        if not self._collectors or self.disabled:
+            return
+        with self._lock:
+            refs = list(self._collectors)
+        dead = False
+        for ref in refs:
+            fn = ref()
+            if fn is None:
+                dead = True
+                continue
+            try:
+                fn()
+            except Exception:
+                self.inc("trace.collector_errors")
+        if dead:
+            with self._lock:
+                self._collectors = [
+                    r for r in self._collectors if r() is not None
+                ]
+
     # -- read path -----------------------------------------------------------
 
     def get(self, name: str, labels: Optional[dict] = None) -> float:
@@ -419,6 +458,7 @@ class Metrics:
     def snapshot(self) -> dict[str, float]:
         """Flat counters+gauges dict; labeled series render as
         ``name{k="v",...}`` keys."""
+        self._collect()
         with self._lock:
             out = {_render_key(n, lk): c.value for (n, lk), c in self._counters.items()}
             out.update(
@@ -440,6 +480,7 @@ class Metrics:
         ``.count`` collides with its legacy shadow counter of the same
         name — they track the same quantity, so the overwrite is a
         no-op."""
+        self._collect()
         with self._lock:
             out = {
                 _render_key(n, lk): c.value
@@ -492,6 +533,7 @@ class Metrics:
                 parts.append(extra)
             return "{" + ",".join(parts) + "}" if parts else ""
 
+        self._collect()
         with self._lock:
             counters = {k: c.value for k, c in self._counters.items()}
             gauges = dict(self._gauges)

@@ -483,7 +483,7 @@ class Node:
         self._started_at: Optional[float] = None
         self._stats_reporter: Optional[StatsReporter] = None
         self._watchdog: Optional[Watchdog] = None
-        self._attributor = None  # asyncsan.LoopAttributor when enabled
+        self._attributor = None  # asyncsan.LoopAttributor: the loop's clock
         self.debug_server: Optional[DebugServer] = None
         self.timeline: Optional[Timeline] = None
         self.blackbox: Optional[FlightRecorder] = None
@@ -517,11 +517,14 @@ class Node:
         # ordering constraint, reference Node.hs:183-192 + PeerMgr.hs:245-247).
         self._owner = asyncio.current_task()
         if asyncsan.enabled():
-            # opt-in runtime sanitizers (TPUNODE_ASYNCSAN, ANALYSIS.md):
-            # asyncio debug mode + tight slow-callback reporting, and the
-            # blocked-loop attributor whose captured frames upgrade the
-            # watchdog's event_loop stall events
+            # opt-in runtime sanitizer (TPUNODE_ASYNCSAN, ANALYSIS.md):
+            # asyncio debug mode + tight slow-callback reporting
             asyncsan.install()
+        if self.cfg.watchdog_interval > 0:
+            # the loop's clock runs whenever the watchdog does: the loop's
+            # idle time, its holds by name (their frames also upgrade the
+            # watchdog's event_loop stall events) and the CPU by thread
+            # role, read when the registry is
             self._attributor = asyncsan.LoopAttributor()
             self._attributor.start()
         if threadsan.enabled():
@@ -534,7 +537,7 @@ class Node:
             return await self._start()
         except BaseException:
             # a failed start never reaches __aexit__: don't leak the
-            # attributor's sampler thread + heartbeat chain
+            # attributor's sampler thread or leave the selector wrapped
             if self._attributor is not None:
                 self._attributor.stop()
                 self._attributor = None

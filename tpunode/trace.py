@@ -51,7 +51,8 @@ from typing import Iterator, Optional
 from .metrics import metrics
 from .tracectx import _ACTIVE as _active_trace
 
-__all__ = ["span", "record_span", "profile_to"]
+__all__ = ["span", "record_span", "record_span_totals", "open_annotation",
+           "profile_to"]
 
 log = logging.getLogger("tpunode.trace")
 
@@ -141,19 +142,30 @@ class span:
     # thread.  Ending it twice writes it once.
 
     def _annotate(self) -> None:
-        if self._ann is None and _jax_profiler is not None:
-            try:
-                ann = _jax_profiler.TraceAnnotation(self._name)
-                ann.__enter__()
-                self._ann = ann
-            except Exception:  # profiler unavailable on this backend
-                pass
+        if self._ann is None:
+            self._ann = open_annotation(self._name)
 
     def _end_annotation(self) -> None:
         ann = self._ann
         if ann is not None:
             self._ann = None
             ann.__exit__(None, None, None)
+
+
+def open_annotation(name: str):
+    """An entered ``TraceAnnotation`` while a :func:`profile_to` capture
+    is active, else None.  For a region whose ends lie in different
+    threads (the loop's holds: the sampler thread sees one begin, the
+    loop ends it): whoever ends it calls ``__exit__(None, None, None)``
+    once."""
+    if not _profiling:
+        return None
+    try:
+        ann = _jax_profiler.TraceAnnotation(name)
+        ann.__enter__()
+        return ann
+    except Exception:  # profiler unavailable on this backend
+        return None
 
 
 def record_span(name: str, seconds: float) -> None:
@@ -165,6 +177,16 @@ def record_span(name: str, seconds: float) -> None:
     if not metrics.disabled:
         keys = _names(name)
         metrics.time_span(keys[0], keys[1], keys[2], seconds, keys[3], None)
+
+
+def record_span_totals(name: str, seconds: float, count: float) -> None:
+    """A span entered too often to pay a registry update an entry (the
+    loop's wait in its selector: tens of thousands a second): the caller
+    added up seconds and entries in plain attributes and hands in what
+    came since it last did.  ``span.<name>.seconds`` / ``.count`` only:
+    no histogram, no trace node, no annotation."""
+    keys = _names(name)
+    metrics.inc_batch(((keys[1], seconds, None), (keys[2], count, None)))
 
 
 @contextlib.contextmanager

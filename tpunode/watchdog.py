@@ -61,8 +61,9 @@ class Watchdog:
         mailboxes: Iterable[Mailbox] = (),
         engine=None,  # anything with dispatch_inflight_seconds() -> float
         log_: Optional[EventLog] = None,
-        attributor=None,  # asyncsan.LoopAttributor (or None): names the
-        # frame that froze the loop, merged into event_loop stall events
+        attributor=None,  # asyncsan.LoopAttributor (or None): the loop's
+        # clock, whose capture of the frame that froze the loop is merged
+        # into event_loop stall events
     ):
         self.cfg = cfg or WatchdogConfig()
         self.mailboxes = list(mailboxes)
