@@ -150,8 +150,7 @@ def run_campaign(n_base: int, batch: int, pallas: bool = False) -> dict:
         def device_verify(chunk, pad_to):
             prep = prepare_batch(chunk, pad_to=pad_to)
             out = verify_blocked(
-                *(jnp.asarray(a) for a in prep.device_args),
-                interpret=True, block=32,
+                jnp.asarray(prep.buf), interpret=True, block=32
             )
             return collect_verdicts(out, len(chunk))
     else:

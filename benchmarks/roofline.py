@@ -11,7 +11,7 @@ drift):
    formulas (`curve.pt_add` / `curve.pt_double`) are executed with a
    counting field namespace, and the per-program totals are assembled
    from `verify/kernel.py`'s actual structure (WINDOWS, the half-scalar
-   count from `_DEVICE_FIELDS`, table lengths `2**WINDOW_BITS`, the
+   count from the lane layout, table lengths `2**WINDOW_BITS`, the
    64-digit constant-exponent pow ladders).
 
 2. **Limb ops per field op** — MAC counts come from `field.py`'s limb
@@ -129,11 +129,7 @@ def field_op_model() -> dict:
     tab_entries = 1 << K.WINDOW_BITS
     wb = K.WINDOW_BITS
     nwin = K.WINDOWS
-    halves = sum(
-        1
-        for name, nd in K._DEVICE_FIELDS
-        if nd == 2 and name.startswith("d")
-    )  # the 4 GLV half-scalar digit streams
+    halves = K.FIELD_ROW0 // K.HALF_WORDS  # the 4 GLV half-scalar digit streams
     pow_digits = len(K._EULER_DIGITS)  # 64 4-bit windows
     assert len(K._PM2_DIGITS) == pow_digits
 

@@ -651,10 +651,6 @@ const uint64_t GLV_B1N[2] = {0x6F547FA90ABFE4C3ULL, 0xE4437ED6010E8828ULL};
 const uint64_t GLV_A2[3] = {0x57C1108D9D44CFD8ULL, 0x14CA50F7A8E2F3F6ULL, 1ULL};
 // b2 == a1
 
-constexpr int PREP_RADIX = 11;
-constexpr int PREP_NLIMBS = 24;
-// windows per window width: 33 x 4-bit (default), 27 x 5-bit (ISSUE 13)
-
 // ---- fixed-width helpers on little-endian u64 arrays ----------------------
 
 // out[no] = a[na] * b[nb] (no >= na+nb)
@@ -762,26 +758,23 @@ inline void glv_halves(const Fe &k, const uint64_t c1[3], const uint64_t c2[3],
 constexpr int PREP_WINDOW_BITS = 4;
 constexpr int PREP_WINDOWS = 33;
 
-// MSB-first 4-bit window digits of abs into out[w * size + lane] (4-bit
-// digits never straddle 64-bit word edges) — bit-identical to kernel.py's
-// _ints_to_digits_np.
-inline void write_digits(const uint64_t abs[3], int32_t *out, int size,
-                         int lane) {
-  for (int w = 0; w < PREP_WINDOWS; ++w) {
-    int sh = PREP_WINDOW_BITS * (PREP_WINDOWS - 1 - w);
-    out[w * size + lane] = (int32_t)((abs[sh / 64] >> (sh % 64)) & 0xF);
-  }
-}
+// A lane's wire buffer (kernel.py, "the lane's wire form"): int32
+// (PREP_ROWS, size), batch minor, little-endian 32-bit words down a column.
+constexpr int PREP_HALF_WORDS = 5;   // a GLV half-scalar magnitude, 132 bits
+constexpr int PREP_FIELD_WORDS = 8;  // a field element
+constexpr int PREP_FIELD_ROW0 = 4 * PREP_HALF_WORDS;  // qx, qy, r1, r2
+constexpr int PREP_FLAGS_ROW = PREP_FIELD_ROW0 + 4 * PREP_FIELD_WORDS;
+constexpr int PREP_ROWS = PREP_FLAGS_ROW + 1;
+// flag bits 0..3 are the half-scalars' signs (n1a, n1b, n2a, n2b)
+constexpr uint32_t PREP_R2_VALID = 1u << 4, PREP_HOST_VALID = 1u << 5,
+                   PREP_SCHNORR = 1u << 6, PREP_BIP340 = 1u << 7;
 
-// radix-11 little-endian limbs of a (canonical) into out[j * size + lane].
-inline void write_limbs(const Fe &a, int32_t *out, int size, int lane) {
-  for (int j = 0; j < PREP_NLIMBS; ++j) {
-    int sh = PREP_RADIX * j;
-    int w = sh / 64, off = sh % 64;
-    uint64_t lo = a.v[w] >> off;
-    if (off > 64 - PREP_RADIX && w + 1 < 4) lo |= a.v[w + 1] << (64 - off);
-    out[j * size + lane] = (int32_t)(lo & ((1u << PREP_RADIX) - 1));
-  }
+// The low ``nwords`` 32-bit words of v into out[(row + k) * size + lane].
+inline void write_words(const uint64_t *v, int nwords, int32_t *out, int row,
+                        int size, int lane) {
+  for (int k = 0; k < nwords; ++k)
+    out[(size_t)(row + k) * size + lane] =
+        (int32_t)(uint32_t)(v[k / 2] >> (32 * (k % 2)));
 }
 
 }  // namespace
@@ -822,23 +815,23 @@ int secp_verify_batch_mt(const uint8_t *px, const uint8_t *py,
   return valid.load();
 }
 
+// Rows of the buffer secp_prepare_batch fills: the wrapper allocates by it
+// and refuses a library whose layout is another.
+int secp_prepare_rows() { return PREP_ROWS; }
+
 // Host prep for one device batch.  All byte inputs are 32-byte big-endian,
 // one entry per item; ``present[i]`` carries the RawBatch algorithm code
-// (0 = absent, 1 = ECDSA, 2 = BCH Schnorr — for Schnorr, ``z`` is the
-// precomputed challenge e, u1 = s and u2 = n - e need no inversion, and
-// ``r`` is an Fp x-coordinate with no r+n candidate).  int32 outputs are
-// (rows, size) C-contiguous, zero-initialized by the caller; lanes >= count
-// stay zero.  The digit arrays are PREP_WINDOWS (33) rows tall.  Returns
-// the number of GLV bound violations (0 = success; cannot occur for
-// in-range scalars — a nonzero return means a bug and the caller must
-// refuse the batch).
+// (0 = absent, 1 = ECDSA, 2 = BCH Schnorr, 3 = BIP340 — for Schnorr, ``z``
+// is the precomputed challenge e, u1 = s and u2 = n - e need no inversion,
+// and ``r`` is an Fp x-coordinate with no r+n candidate).  ``lane`` is the
+// (PREP_ROWS, size) wire buffer, zero-initialized by the caller; columns
+// >= count, and those of rows refused here, stay zero.  Returns the number
+// of GLV bound violations (0 = success; cannot occur for in-range scalars
+// — a nonzero return means a bug and the caller must refuse the batch).
 int secp_prepare_batch(const uint8_t *px, const uint8_t *py, const uint8_t *z,
                        const uint8_t *r, const uint8_t *s,
                        const uint8_t *present, int count, int size,
-                       int32_t *d1a, int32_t *d1b, int32_t *d2a, int32_t *d2b,
-                       uint8_t *negs, int32_t *qx, int32_t *qy, int32_t *r1,
-                       int32_t *r2, uint8_t *r2_valid, uint8_t *host_valid,
-                       uint8_t *schnorr, uint8_t *bip340, int nthreads) {
+                       int32_t *lane, int nthreads) {
   // half-scalars live in abs[0..2]: bits >= 132 sit at abs[2] >> 4
   constexpr int bound_shift = PREP_WINDOW_BITS * PREP_WINDOWS - 128;
   // ---- serial: validity + Montgomery batch inversion of s (ECDSA rows) ----
@@ -873,13 +866,13 @@ int secp_prepare_batch(const uint8_t *px, const uint8_t *py, const uint8_t *z,
   auto work = [&](int lo, int hi) {
     for (int i = lo; i < hi; ++i) {
       if (!ok[i]) continue;
-      host_valid[i] = 1;
+      uint32_t flags = PREP_HOST_VALID;
       Fe zi = fe_from_be(z + 32 * i);
       while (ge(zi, FN.m)) sub_mod_raw(zi, FN.m);
       Fe ri = fe_from_be(r + 32 * i);
       Fe u1, u2;
       if (is_sch[i]) {
-        (present[i] == 2 ? schnorr : bip340)[i] = 1;
+        flags |= present[i] == 2 ? PREP_SCHNORR : PREP_BIP340;
         u1 = fe_from_be(s + 32 * i);  // u1 = s (< n, checked)
         u2 = Fe{{0, 0, 0, 0}};        // u2 = n - e (mod n)
         if (!is_zero(zi)) {
@@ -898,28 +891,31 @@ int secp_prepare_batch(const uint8_t *px, const uint8_t *py, const uint8_t *z,
       glv_c(GLV_G1, u2, c1);
       glv_c(GLV_G2, u2, c2);
       glv_halves(u2, c1, c2, h[2], h[3]);
-      int32_t *dsts[4] = {d1a, d1b, d2a, d2b};
       for (int j = 0; j < 4; ++j) {
         // |k| >= 2^132: outside the window range
         if (h[j].abs[2] >> bound_shift) {
           violations.fetch_add(1, std::memory_order_relaxed);
           continue;
         }
-        write_digits(h[j].abs, dsts[j], size, i);
-        negs[j * size + i] = h[j].neg ? 1 : 0;
+        write_words(h[j].abs, PREP_HALF_WORDS, lane, j * PREP_HALF_WORDS,
+                    size, i);
+        if (h[j].neg) flags |= 1u << j;
       }
-      write_limbs(fe_from_be(px + 32 * i), qx, size, i);
-      write_limbs(fe_from_be(py + 32 * i), qy, size, i);
-      write_limbs(ri, r1, size, i);
+      const Fe q[3] = {fe_from_be(px + 32 * i), fe_from_be(py + 32 * i), ri};
+      for (int j = 0; j < 3; ++j)
+        write_words(q[j].v, PREP_FIELD_WORDS, lane,
+                    PREP_FIELD_ROW0 + j * PREP_FIELD_WORDS, size, i);
       // r + n < p ?  (ECDSA-only: Schnorr compares x(R) to r over Fp)
       if (!is_sch[i]) {
         Fe rn = ri;
         uint64_t carry = mp_add(rn.v, 4, FN.m, 4);
         if (!carry && !ge(rn, FP.m)) {
-          write_limbs(rn, r2, size, i);
-          r2_valid[i] = 1;
+          write_words(rn.v, PREP_FIELD_WORDS, lane,
+                      PREP_FIELD_ROW0 + 3 * PREP_FIELD_WORDS, size, i);
+          flags |= PREP_R2_VALID;
         }
       }
+      lane[(size_t)PREP_FLAGS_ROW * size + i] = (int32_t)flags;
     }
   };
   int T = nthreads > 0 ? nthreads : (int)std::thread::hardware_concurrency();

@@ -136,12 +136,12 @@ def test_pallas_interpret():
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
 
-    from tpunode.verify.kernel import prepare_batch
+    from tpunode.verify.kernel import expand_lane, prepare_batch
     from tpunode.verify.pallas_kernel import verify_blocked_impl
 
     items, expect = _batch(8)
     prep = prepare_batch(items, pad_to=8)
-    args = tuple(jnp.asarray(a) for a in prep.device_args)
+    args = expand_lane(jnp.asarray(prep.buf))
     out = verify_blocked_impl(*args, interpret=True, block=8)
     assert [bool(b) for b in out[:8]] == expect
     del jax
@@ -298,12 +298,12 @@ def test_vectors_pallas_interpret():
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
 
-    from tpunode.verify.kernel import prepare_batch
+    from tpunode.verify.kernel import expand_lane, prepare_batch
     from tpunode.verify.pallas_kernel import verify_blocked_impl
 
     items, expect = _vector_items()
     prep = prepare_batch(items, pad_to=32)
-    args = tuple(jnp.asarray(a) for a in prep.device_args)
+    args = expand_lane(jnp.asarray(prep.buf))
     out = verify_blocked_impl(*args, interpret=True, block=32)
     assert [bool(b) for b in out[: len(expect)]] == expect
     del jax
@@ -312,16 +312,14 @@ def test_vectors_pallas_interpret():
 def test_native_prep_parity():
     import numpy as np
 
+    from tests.lane_ref import flag
     from tpunode.verify.cpu_native import load_native_verifier
-    from tpunode.verify.kernel import _DEVICE_FIELDS, prepare_batch
+    from tpunode.verify.kernel import prepare_batch
 
     if load_native_verifier() is None:
         pytest.skip("native prep unavailable")
     items, _ = _batch(12)
     a = prepare_batch(items, pad_to=16, native=False)
     b = prepare_batch(items, pad_to=16, native=True)
-    for name, _nd in _DEVICE_FIELDS:
-        assert np.array_equal(
-            np.asarray(getattr(a, name)), np.asarray(getattr(b, name))
-        ), name
-    assert np.asarray(a.bip340).sum() == 12
+    assert np.array_equal(a.buf, b.buf)
+    assert flag(a.buf, "bip340").sum() == 12

@@ -233,41 +233,33 @@ def test_pallas_kernel_inside_shard_map_interpret():
     without TPU hardware."""
     import numpy as np
 
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from tpunode.verify.kernel import ARG_IS_2D, prepare_batch
-    from tpunode.verify.multichip import sharded_verify_fn
+    from tpunode.verify.kernel import prepare_batch
+    from tpunode.verify.multichip import _put_lane, sharded_verify_fn
 
     mesh = make_mesh(2)
     block = 8
     items, expect = make_items(2 * block)  # one block per shard
     prep = prepare_batch(items, pad_to=2 * block)
     fn = sharded_verify_fn(mesh, kernel="pallas", interpret=True, block=block)
-    shard_2d = NamedSharding(mesh, P(None, "batch"))
-    shard_1d = NamedSharding(mesh, P("batch"))
-    args = [
-        jax.device_put(np.asarray(a), shard_2d if is2d else shard_1d)
-        for a, is2d in zip(prep.device_args, ARG_IS_2D)
-    ]
-    ok, total = fn(*args)
+    buf = _put_lane(prep, mesh)
+    assert len(buf.addressable_shards) == 2  # the batch axis is split
+    assert buf.addressable_shards[0].data.shape == (prep.buf.shape[0], block)
+    ok, total = fn(buf)
     got = [bool(b) for b in np.asarray(ok)]
     assert got == expect
     assert int(total) == sum(expect)
     # padding path: 3 items over 2 shards pads each shard to one block
     items3, expect3 = make_items(3)
     prep3 = prepare_batch(items3, pad_to=2 * block)
-    args3 = [
-        jax.device_put(np.asarray(a), shard_2d if is2d else shard_1d)
-        for a, is2d in zip(prep3.device_args, ARG_IS_2D)
-    ]
-    ok3, total3 = fn(*args3)
+    ok3, total3 = fn(_put_lane(prep3, mesh))
     assert [bool(b) for b in np.asarray(ok3)[:3]] == expect3
     assert int(total3) == sum(expect3)  # padded lanes reject for free
 
 
 def test_sharded_mixed_algorithms():
     """All three signature algorithms through shard_map on the CPU mesh:
-    the per-lane schnorr/bip340 flags must shard with the batch like every
-    other 1-D lane array (ARG_IS_2D derives them from _DEVICE_FIELDS)."""
+    the per-lane schnorr/bip340 flags are a row of the lane's one buffer
+    and shard with the batch like every other row."""
     from tpunode.verify.ecdsa_cpu import (
         bip340_challenge,
         lift_x,
@@ -334,28 +326,22 @@ def test_sharded_schnorr_free_verdict_parity():
     and the two variants must be cached as distinct executables."""
     import numpy as np
 
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from tpunode.verify.kernel import ARG_IS_2D, prepare_batch
-    from tpunode.verify.multichip import sharded_verify_fn
+    from tpunode.verify.kernel import prepare_batch
+    from tpunode.verify.multichip import _put_lane, sharded_verify_fn
 
     mesh = make_mesh(2)
     block = 8
     items, expect = make_items(2 * block)  # ECDSA-only
     prep = prepare_batch(items, pad_to=2 * block)
     assert prep.schnorr_free  # the one safe derivation (host flags)
-    shard_2d = NamedSharding(mesh, P(None, "batch"))
-    shard_1d = NamedSharding(mesh, P("batch"))
-    args = [
-        jax.device_put(np.asarray(a), shard_2d if is2d else shard_1d)
-        for a, is2d in zip(prep.device_args, ARG_IS_2D)
-    ]
+    buf = _put_lane(prep, mesh)
     fn_full = sharded_verify_fn(mesh, kernel="pallas", interpret=True,
                                 block=block)
     fn_free = sharded_verify_fn(mesh, kernel="pallas", interpret=True,
                                 block=block, schnorr_free=True)
     assert fn_full is not fn_free  # distinct cache entries
-    ok_full, tot_full = fn_full(*args)
-    ok_free, tot_free = fn_free(*args)
+    ok_full, tot_full = fn_full(buf)
+    ok_free, tot_free = fn_free(buf)
     got_full = [bool(b) for b in np.asarray(ok_full)]
     got_free = [bool(b) for b in np.asarray(ok_free)]
     assert got_full == expect

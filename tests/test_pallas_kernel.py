@@ -25,6 +25,7 @@ from tpunode.verify.ecdsa_cpu import (
     verify,
     verify_batch_cpu,
 )
+from tests.lane_ref import DEVICE_FIELDS, expand_np, flag
 from tpunode.verify.kernel import prepare_batch
 from tpunode.verify.pallas_kernel import verify_blocked
 
@@ -107,9 +108,7 @@ def test_pallas_kernel_interpret_matches_oracle(native):
     CPU oracle, fed by both prep paths."""
     items, expected = _mixed_items(9)
     prep = prepare_batch(items, pad_to=16, native=native)
-    out = verify_blocked(
-        *(jnp.asarray(a) for a in prep.device_args), interpret=True, block=8
-    )
+    out = verify_blocked(jnp.asarray(prep.buf), interpret=True, block=8)
     got = [bool(x) for x in np.asarray(out)[: prep.count]]
     assert got == expected
     assert verify_batch_cpu(items) == expected
@@ -128,15 +127,14 @@ def test_oversized_der_scalars_rejected_on_all_backends():
     nat = load_native_verifier()
     if nat is not None:
         assert nat.verify_batch(attack) == want
-    prep = prepare_batch(attack, pad_to=8, native=False)
-    assert not prep.host_valid.any()
-    prep = prepare_batch(attack, pad_to=8, native=True)
-    assert not np.asarray(prep.host_valid).any()
+    for native in (False, True):
+        prep = prepare_batch(attack, pad_to=8, native=native)
+        assert not flag(prep.buf, "host_valid").any()
 
 
 def test_native_prep_bit_identical_to_python():
-    """secp_prepare_batch emits bit-identical PreparedBatch arrays
-    (digits, negs, limbs, masks) to the Python reference path."""
+    """secp_prepare_batch writes the Python reference path's buffer, byte
+    for byte, and so bit-identical digits, negs, limbs and masks."""
     from tpunode.verify.cpu_native import load_native_verifier
 
     if load_native_verifier() is None:
@@ -152,25 +150,10 @@ def test_native_prep_bit_identical_to_python():
     ]
     py = prepare_batch(items, pad_to=32, native=False)
     nat = prepare_batch(items, pad_to=32, native=True)
-    for name in (
-        "d1a",
-        "d1b",
-        "d2a",
-        "d2b",
-        "n1a",
-        "n1b",
-        "n2a",
-        "n2b",
-        "qx",
-        "qy",
-        "r1",
-        "r2",
-        "r2_valid",
-        "host_valid",
-    ):
-        a = np.asarray(getattr(py, name)).astype(np.int64)
-        b = np.asarray(getattr(nat, name)).astype(np.int64)
-        assert np.array_equal(a, b), name
+    assert py.buf.tobytes() == nat.buf.tobytes()
+    a, b = expand_np(py.buf), expand_np(nat.buf)
+    for name, _nd in DEVICE_FIELDS:
+        assert np.array_equal(a[name], b[name]), name
 
 
 def test_pallas_schnorr_free_variant_matches_oracle():
@@ -179,11 +162,10 @@ def test_pallas_schnorr_free_variant_matches_oracle():
     the oracle AND to the full program on an ECDSA-only batch."""
     items, expected = _mixed_items(9)
     prep = prepare_batch(items, pad_to=16)
-    assert not (prep.schnorr.any() or prep.bip340.any())  # ECDSA-only
-    args = tuple(jnp.asarray(a) for a in prep.device_args)
-    pruned = verify_blocked(*args, interpret=True, block=8,
-                            schnorr_free=True)
-    full = verify_blocked(*args, interpret=True, block=8)
+    assert prep.schnorr_free  # ECDSA-only
+    buf = jnp.asarray(prep.buf)
+    pruned = verify_blocked(buf, interpret=True, block=8, schnorr_free=True)
+    full = verify_blocked(buf, interpret=True, block=8)
     got = [bool(x) for x in np.asarray(pruned)[: prep.count]]
     assert got == expected
     assert np.array_equal(np.asarray(pruned), np.asarray(full))
@@ -202,9 +184,9 @@ def test_dispatch_derives_schnorr_free_from_flags(monkeypatch):
 
     seen = []
 
-    def fake_blocked(*args, schnorr_free=False, **kw):
+    def fake_blocked(buf, schnorr_free=False):
         seen.append(schnorr_free)
-        return jnp.zeros((args[8].shape[-1],), dtype=jnp.bool_)
+        return jnp.zeros((buf.shape[-1],), dtype=jnp.bool_)
 
     monkeypatch.setattr(PK, "verify_blocked", fake_blocked)
     monkeypatch.setattr(K, "_pallas_usable", lambda b: True)
@@ -252,7 +234,8 @@ def test_pallas_two_grid_steps_match_oracle():
     lazy against the eager program; the oracle is the reference now.)"""
     items, expected = _mixed_items(9)
     prep = prepare_batch(items, pad_to=16)
-    args = tuple(jnp.asarray(a) for a in prep.device_args)
-    out = verify_blocked(*args, interpret=True, block=8, schnorr_free=True)
+    out = verify_blocked(
+        jnp.asarray(prep.buf), interpret=True, block=8, schnorr_free=True
+    )
     got = [bool(x) for x in np.asarray(out)[: prep.count]]
     assert got == expected

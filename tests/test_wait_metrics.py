@@ -127,7 +127,10 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
     after = names[first + len(WAITS):]
     assert after[:len(CONNECT)] == list(CONNECT)
     # PR 27's, appended in their turn, then PR 28's, PR 30's, PR 31's,
-    # PR 32's, PR 36's seven, PR 37's two, PR 38's sixteen ("node loop")
+    # PR 32's, PR 36's seven, PR 37's two, PR 38's sixteen ("node loop"),
+    # PR 39's two ("host prep": what a lane's transfer is, by counters)
+    assert after[-2:] == ["transfer.bytes_per_slot", "transfer.calls_per_lane"]
+    after = after[:-2]
     loop = [m["name"] for m in BENCH["per_layer"] if m["layer"] == "node loop"]
     assert len(loop) == 16 and after[-16:] == loop
     after = after[:-16]
@@ -149,7 +152,9 @@ def test_the_nine_wait_metrics_are_listed_in_every_cell():
     # PR 37's move the CPU metric, so every cell that reports it lists them
     cpu = next(m for m in BENCH["end_to_end"]
                if m["name"] == "host_cpu_ms_per_ksig")["workloads"]
-    for name in after[-2:]:
+    # ... and PR 39's likewise
+    for name in (*after[-2:], "transfer.bytes_per_slot",
+                 "transfer.calls_per_lane"):
         assert by_name[name]["workloads"] == cpu, name
         assert by_name[name]["moves"] == "host_cpu_ms_per_ksig"
 

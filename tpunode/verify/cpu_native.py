@@ -21,6 +21,10 @@ __all__ = ["NativeVerifier", "load_native_verifier"]
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _LIB_PATH = os.path.join(_REPO_ROOT, "native", "build", "libsecp_cpu.so")
 
+# Rows of a lane's wire buffer as secp_prepare_batch writes it (PREP_ROWS in
+# secp256k1.cpp; kernel.ROWS on the jax side, which this module stays free of).
+LANE_ROWS = 53
+
 
 def _ensure_built() -> str:
     from ..native import ensure_native_lib
@@ -49,11 +53,12 @@ class NativeVerifier:
         self._lib.secp_verify_batch_mt.argtypes = (
             self._lib.secp_verify_batch.argtypes + [ctypes.c_int]  # nthreads
         )
-        import numpy as _np
         from numpy.ctypeslib import ndpointer
 
-        i32 = ndpointer(_np.int32, flags="C_CONTIGUOUS")
-        u8 = ndpointer(_np.uint8, flags="C_CONTIGUOUS")
+        self._lib.secp_prepare_rows.restype = ctypes.c_int
+        self._lib.secp_prepare_rows.argtypes = []
+        if self._lib.secp_prepare_rows() != LANE_ROWS:
+            raise RuntimeError("libsecp_cpu.so writes another lane layout")
         self._lib.secp_prepare_batch.restype = ctypes.c_int
         self._lib.secp_prepare_batch.argtypes = [
             ctypes.c_char_p,  # px
@@ -64,23 +69,11 @@ class NativeVerifier:
             ctypes.c_char_p,  # present
             ctypes.c_int,  # count
             ctypes.c_int,  # size
-            i32,  # d1a
-            i32,  # d1b
-            i32,  # d2a
-            i32,  # d2b
-            u8,  # negs
-            i32,  # qx
-            i32,  # qy
-            i32,  # r1
-            i32,  # r2
-            u8,  # r2_valid
-            u8,  # host_valid
-            u8,  # schnorr
-            u8,  # bip340
+            ndpointer(np.int32, ndim=2, flags="C_CONTIGUOUS"),  # lane buffer
             ctypes.c_int,  # nthreads
         ]
 
-    def prepare_batch_arrays(
+    def prepare_lane(
         self,
         px: bytes,
         py: bytes,
@@ -91,41 +84,21 @@ class NativeVerifier:
         count: int,
         size: int,
         nthreads: int = 0,
-    ):
-        """Fill PreparedBatch arrays natively (see kernel.prepare_batch's
-        fast path).  Returns the dict of limb-major numpy arrays.  Raises
-        on a GLV bound violation (structurally impossible for in-range
-        scalars; nonzero means a bug, never a bad signature)."""
-        import numpy as np
-
-        nwin = 33  # kernel.WINDOWS: 4-bit digits of the GLV half-scalars
-        out = {
-            "d1a": np.zeros((nwin, size), np.int32),
-            "d1b": np.zeros((nwin, size), np.int32),
-            "d2a": np.zeros((nwin, size), np.int32),
-            "d2b": np.zeros((nwin, size), np.int32),
-            "negs": np.zeros((4, size), np.uint8),
-            "qx": np.zeros((24, size), np.int32),
-            "qy": np.zeros((24, size), np.int32),
-            "r1": np.zeros((24, size), np.int32),
-            "r2": np.zeros((24, size), np.int32),
-            "r2_valid": np.zeros(size, np.uint8),
-            "host_valid": np.zeros(size, np.uint8),
-            "schnorr": np.zeros(size, np.uint8),
-            "bip340": np.zeros(size, np.uint8),
-        }
+    ) -> np.ndarray:
+        """Fill one lane's ``(LANE_ROWS, size)`` int32 wire buffer natively
+        (layout: kernel.py, "the lane's wire form"; kernel.prepare_batch's
+        fast path).  Raises on a GLV bound violation (structurally
+        impossible for in-range scalars; nonzero means a bug, never a bad
+        signature)."""
+        buf = np.zeros((LANE_ROWS, size), np.int32)
         bad = self._lib.secp_prepare_batch(
-            px, py, z, r, s, present, count, size,
-            out["d1a"], out["d1b"], out["d2a"], out["d2b"], out["negs"],
-            out["qx"], out["qy"], out["r1"], out["r2"],
-            out["r2_valid"], out["host_valid"], out["schnorr"],
-            out["bip340"], nthreads,
+            px, py, z, r, s, present, count, size, buf, nthreads
         )
         if bad:
             raise ValueError(
                 f"native prep: {bad} GLV half-scalars out of range"
             )
-        return out
+        return buf
 
     def verify_batch(self, items: Sequence[tuple]) -> list[bool]:
         """items: (pubkey|None, z, r, s) ECDSA tuples or 5-tuples tagged

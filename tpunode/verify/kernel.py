@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..metrics import metrics
 from ..trace import span
 from . import bounds as _bounds
 from . import field as F
@@ -55,6 +56,7 @@ __all__ = [
     "glv_split",
     "kernel_modes",
     "prepare_batch",
+    "expand_lane",
     "verify_core",
     "verify_device",
     "verify_batch_tpu",
@@ -144,49 +146,36 @@ LG_TABLE = _table_np(
     Point(BETA * GENERATOR.x % CURVE_P, GENERATOR.y)
 )  # table of λG = φ(G)
 
-# One annotated list drives PreparedBatch.__slots__, the device_args order
-# (== verify_core's signature order), and the 2-D/1-D split shard_map
-# callers need — so the three can't drift apart.
-_DEVICE_FIELDS = (
-    ("d1a", 2),
-    ("d1b", 2),
-    ("d2a", 2),
-    ("d2b", 2),
-    ("n1a", 1),
-    ("n1b", 1),
-    ("n2a", 1),
-    ("n2b", 1),
-    ("qx", 2),
-    ("qy", 2),
-    ("r1", 2),
-    ("r2", 2),
-    ("r2_valid", 1),
-    ("host_valid", 1),
-    ("schnorr", 1),  # per-lane algorithm: BCH Schnorr instead of ECDSA
-    ("bip340", 1),  # per-lane algorithm: BIP340 (taproot) Schnorr
+# --- the lane's wire form ---------------------------------------------------
+# One C-contiguous int32 buffer ``(ROWS, B)`` a lane, batch minor: written by
+# host prep (native or numpy), handed to the device in one transfer, expanded
+# to digits, limbs and masks by the jitted programs (:func:`expand_lane`).
+# Rows are little-endian 32-bit words down a column: HALF_WORDS for each GLV
+# half-scalar magnitude |u1a|, |u1b|, |u2a|, |u2b| (132 bits used), then
+# FIELD_WORDS for each of qx, qy, r1, r2, then one row of flag bits.
+HALF_WORDS = 5
+FIELD_WORDS = 8
+FIELD_ROW0 = 4 * HALF_WORDS
+FLAGS_ROW = FIELD_ROW0 + 4 * FIELD_WORDS
+ROWS = FLAGS_ROW + 1
+# bit positions in the flag row: the half-scalars' signs, then verify_core's
+# four masks (`schnorr` / `bip340`: the lane's algorithm instead of ECDSA)
+FLAG_NAMES = (
+    "n1a", "n1b", "n2a", "n2b", "r2_valid", "host_valid", "schnorr", "bip340"
 )
-
-# For shard_map callers: which device_args are 2-D (batch trailing) vs 1-D.
-ARG_IS_2D = tuple(nd == 2 for _, nd in _DEVICE_FIELDS)
+_HOST_VALID = 1 << FLAG_NAMES.index("host_valid")
+_ALGO_FLAGS = 1 << FLAG_NAMES.index("schnorr") | 1 << FLAG_NAMES.index("bip340")
 
 
 class PreparedBatch:
-    """Host-prepared device inputs for one batch of signatures.
+    """One host-prepared lane: ``buf`` is the ``(ROWS, B)`` int32 wire
+    buffer (layout above), ``count`` the items it holds before padding."""
 
-    Limb-major layout: digit arrays ``(WINDOWS, B)``, limb arrays
-    ``(NLIMBS, B)``, masks ``(B,)``.  ``device_args`` yields the arrays in
-    :func:`verify_core` argument order so callers stay decoupled from it.
-    """
+    __slots__ = ("buf", "count")
 
-    __slots__ = tuple(name for name, _ in _DEVICE_FIELDS) + ("count",)
-
-    def __init__(self, **kw):
-        for k, v in kw.items():
-            setattr(self, k, v)
-
-    @property
-    def device_args(self) -> tuple:
-        return tuple(getattr(self, name) for name, _ in _DEVICE_FIELDS)
+    def __init__(self, buf: np.ndarray, count: int):
+        self.buf = buf
+        self.count = count
 
     @property
     def schnorr_free(self) -> bool:
@@ -194,7 +183,7 @@ class PreparedBatch:
         program variants with the jacobi/parity acceptance pows pruned.
         The ONE derivation every dispatch site must use — a wrong True
         would accept jacobi/parity forgeries."""
-        return not (np.any(self.schnorr) or np.any(self.bip340))
+        return not np.any(self.buf[FLAGS_ROW] & _ALGO_FLAGS)
 
 
 def _batch_inverse_mod_n(values: list[int]) -> list[int]:
@@ -222,45 +211,11 @@ def _batch_inverse_mod_n(values: list[int]) -> list[int]:
     return out
 
 
-def _digits_base16(v: int) -> list[int]:
-    """WINDOWS base-16 digits of a nonnegative int, most significant first."""
-    return [(v >> (4 * (WINDOWS - 1 - i))) & 0xF for i in range(WINDOWS)]
-
-
-def _ints_to_limbs_np(vals: list[int]) -> np.ndarray:
-    """Vectorized ``F.to_limbs``: 256-bit ints -> (len, NLIMBS) int32.
-
-    Python-loop limb extraction dominates host prep at batch 4096 (~15 ms
-    per array x 4 arrays); this does one ``to_bytes`` per int and then
-    numpy uint64 shifts — ~10x faster.  Bit-identical to F.to_limbs
-    (tested in tests/test_kernel.py::test_np_conversions_match_scalar).
-    """
-    n = len(vals)
-    buf = b"".join(v.to_bytes(32, "little") for v in vals)
-    words = np.frombuffer(buf, dtype="<u8").reshape(n, 4)
-    out = np.zeros((n, F.NLIMBS), dtype=np.int32)
-    for i in range(F.NLIMBS):
-        w, off = divmod(F.RADIX * i, 64)
-        lo = words[:, w] >> np.uint64(off)
-        if off > 64 - F.RADIX and w + 1 < 4:  # limb straddles a word edge
-            lo = lo | (words[:, w + 1] << np.uint64(64 - off))
-        out[:, i] = (lo & np.uint64(F.MASK)).astype(np.int32)
-    return out
-
-
-def _ints_to_digits_np(vals: list[int]) -> np.ndarray:
-    """Vectorized ``_digits_base16``: ints < 2^132 -> (len, WINDOWS)
-    int32, MSB-first (4-bit digits never straddle 64-bit word edges)."""
-    n = len(vals)
-    buf = b"".join(v.to_bytes(24, "little") for v in vals)
-    words = np.frombuffer(buf, dtype="<u8").reshape(n, 3)
-    out = np.zeros((n, WINDOWS), dtype=np.int32)
-    for j in range(WINDOWS):
-        w, off = divmod(4 * (WINDOWS - 1 - j), 64)
-        out[:, j] = ((words[:, w] >> np.uint64(off)) & np.uint64(0xF)).astype(
-            np.int32
-        )
-    return out
+def _pack_words(vals: list[int], nwords: int) -> np.ndarray:
+    """Nonnegative ints below 2^(32·nwords) -> ``(nwords, len)`` int32:
+    each int's little-endian 32-bit words down a column."""
+    buf = b"".join(v.to_bytes(4 * nwords, "little") for v in vals)
+    return np.frombuffer(buf, dtype="<i4").reshape(len(vals), nwords).T
 
 
 def _item_algo(item: tuple) -> Optional[str]:
@@ -276,19 +231,20 @@ def prepare_batch(
     pad_to: Optional[int] = None,
     native: Optional[bool] = None,
 ) -> PreparedBatch:
-    """Host-side preparation: (pubkey|None, z, r, s[, "schnorr"]) -> device
-    arrays.  ECDSA items carry the sighash in ``z``; Schnorr items carry
-    the PRECOMPUTED challenge ``e`` (u1 = s, u2 = n - e — no inversion).
+    """Host-side preparation: (pubkey|None, z, r, s[, "schnorr"]) -> the
+    lane's wire buffer.  ECDSA items carry the sighash in ``z``; Schnorr
+    items carry the PRECOMPUTED challenge ``e`` (u1 = s, u2 = n - e — no
+    inversion).
 
     Invalid-by-inspection entries (bad ranges, missing/infinite pubkey) are
-    masked out host-side (``host_valid``); their lanes carry dummy values so
+    masked out host-side (no ``host_valid`` bit); their columns stay zero so
     shapes stay static.  ``pad_to`` pads the batch to a fixed size to avoid
     recompilation across batches.
 
     ``native=None`` auto-selects the C++ fast path (secp_prepare_batch
-    in native/secp256k1 — batch inversion, GLV split, digit/limb
-    conversion; bit-identical outputs, ~10x the Python rate) when the
-    library loads; ``native=False`` forces the pure-Python reference path.
+    in native/secp256k1 — batch inversion, GLV split, word packing; a
+    byte-identical buffer, ~10x the Python rate) when the library loads;
+    ``native=False`` forces the pure-Python reference path.
     """
     if native is not False:
         prep = _prepare_batch_native(items, pad_to)
@@ -299,20 +255,8 @@ def prepare_batch(
     count = len(items)
     size = pad_to or count
     assert size >= count
-    d1a = np.zeros((size, WINDOWS), dtype=np.int32)
-    d1b = np.zeros((size, WINDOWS), dtype=np.int32)
-    d2a = np.zeros((size, WINDOWS), dtype=np.int32)
-    d2b = np.zeros((size, WINDOWS), dtype=np.int32)
-    negs = np.zeros((4, size), dtype=bool)
-    qx = np.zeros((size, F.NLIMBS), dtype=np.int32)
-    qy = np.zeros((size, F.NLIMBS), dtype=np.int32)
-    r1 = np.zeros((size, F.NLIMBS), dtype=np.int32)
-    r2 = np.zeros((size, F.NLIMBS), dtype=np.int32)
-    r2v = np.zeros((size,), dtype=bool)
-    hv = np.zeros((size,), dtype=bool)
-    sch = np.zeros((size,), dtype=bool)
-    b340 = np.zeros((size,), dtype=bool)
-
+    buf = np.zeros((ROWS, size), dtype=np.int32)
+    flags = [0] * count
     s_vals = []
     s_idx = []
     for i, item in enumerate(items):
@@ -323,112 +267,66 @@ def prepare_batch(
         if tag is not None:
             if not (0 <= r < CURVE_P and 0 <= s < CURVE_N):
                 continue
-            hv[i] = True
-            (sch if tag == "schnorr" else b340)[i] = True
+            flags[i] = _HOST_VALID | 1 << FLAG_NAMES.index(tag)
         else:
             if not (0 < r < CURVE_N and 0 < s < CURVE_N):
                 continue
-            hv[i] = True
+            flags[i] = _HOST_VALID
             s_vals.append(s)
             s_idx.append(i)
     with span("verify.batch_inv", cpu=True):
         s_inv = _batch_inverse_mod_n(s_vals) if s_vals else []
     inv_by_idx = dict(zip(s_idx, s_inv))
 
-    digit_arrays = (d1a, d1b, d2a, d2b)
     bound = 1 << (WINDOW_BITS * WINDOWS)
-    # Gather per-valid-lane scalars, then convert in bulk with numpy
-    # (the per-int Python limb/digit loops dominate prep otherwise).
+    # Gather per-valid-lane scalars, then pack in bulk with numpy.
     idxs: list[int] = []
     half_abs: tuple[list[int], ...] = ([], [], [], [])
-    gx: list[int] = []
-    gy: list[int] = []
-    gr1: list[int] = []
+    fields: tuple[list[int], ...] = ([], [], [])  # qx, qy, r1
     r2_idx: list[int] = []
-    gr2: list[int] = []
+    r2_vals: list[int] = []
     for i, item in enumerate(items):
-        if not hv[i]:
+        if not flags[i]:
             continue
         q, z, r, s = item[:4]
         idxs.append(i)
-        if sch[i] or b340[i]:
-            u1 = s % CURVE_N
-            u2 = (CURVE_N - z % CURVE_N) % CURVE_N
-        else:
+        ecdsa = flags[i] == _HOST_VALID
+        if ecdsa:
             w = inv_by_idx[i]
             u1 = (z % CURVE_N) * w % CURVE_N
             u2 = r * w % CURVE_N
-        halves = glv_split(u1) + glv_split(u2)
-        for j, k in enumerate(halves):
+        else:
+            u1 = s % CURVE_N
+            u2 = (CURVE_N - z % CURVE_N) % CURVE_N
+        for j, k in enumerate(glv_split(u1) + glv_split(u2)):
             if abs(k) >= bound:  # not assert: -O must not strip a consensus guard
                 raise ValueError(
                     f"GLV half-scalar out of window range: |{k}| >= 2^"
                     f"{WINDOW_BITS * WINDOWS} (item {i}, half {j})"
                 )
-            negs[j, i] = k < 0
+            flags[i] |= (k < 0) << j  # FLAG_NAMES[j]: n1a, n1b, n2a, n2b
             half_abs[j].append(abs(k))
-        gx.append(q.x)
-        gy.append(q.y)
-        gr1.append(r)
-        if not (sch[i] or b340[i]) and r + CURVE_N < CURVE_P:
+        for col, v in zip(fields, (q.x, q.y, r)):
+            col.append(v)
+        if ecdsa and r + CURVE_N < CURVE_P:
+            flags[i] |= 1 << FLAG_NAMES.index("r2_valid")
             r2_idx.append(i)
-            gr2.append(r + CURVE_N)
+            r2_vals.append(r + CURVE_N)
     if idxs:
         ii = np.array(idxs)
-        for j, dst in enumerate(digit_arrays):
-            dst[ii] = _ints_to_digits_np(half_abs[j])
-        qx[ii] = _ints_to_limbs_np(gx)
-        qy[ii] = _ints_to_limbs_np(gy)
-        r1[ii] = _ints_to_limbs_np(gr1)
+        for j, vals in enumerate(half_abs):
+            row = j * HALF_WORDS
+            buf[row:row + HALF_WORDS, ii] = _pack_words(vals, HALF_WORDS)
+        for j, vals in enumerate(fields):
+            row = FIELD_ROW0 + j * FIELD_WORDS
+            buf[row:row + FIELD_WORDS, ii] = _pack_words(vals, FIELD_WORDS)
     if r2_idx:
-        jj = np.array(r2_idx)
-        r2[jj] = _ints_to_limbs_np(gr2)
-        r2v[jj] = True
-
-    t = np.ascontiguousarray
-    return PreparedBatch(
-        d1a=t(d1a.T),
-        d1b=t(d1b.T),
-        d2a=t(d2a.T),
-        d2b=t(d2b.T),
-        n1a=t(negs[0]),
-        n1b=t(negs[1]),
-        n2a=t(negs[2]),
-        n2b=t(negs[3]),
-        qx=t(qx.T),
-        qy=t(qy.T),
-        r1=t(r1.T),
-        r2=t(r2.T),
-        r2_valid=r2v,
-        host_valid=hv,
-        schnorr=sch,
-        bip340=b340,
-        count=count,
-    )
-
-
-def _prepared_from_native(out: dict, count: int) -> PreparedBatch:
-    """PreparedBatch over the arrays NativeVerifier.prepare_batch_arrays
-    filled (already limb-major; masks come back as uint8)."""
-    return PreparedBatch(
-        d1a=out["d1a"],
-        d1b=out["d1b"],
-        d2a=out["d2a"],
-        d2b=out["d2b"],
-        n1a=out["negs"][0].astype(bool),
-        n1b=out["negs"][1].astype(bool),
-        n2a=out["negs"][2].astype(bool),
-        n2b=out["negs"][3].astype(bool),
-        qx=out["qx"],
-        qy=out["qy"],
-        r1=out["r1"],
-        r2=out["r2"],
-        r2_valid=out["r2_valid"].astype(bool),
-        host_valid=out["host_valid"].astype(bool),
-        schnorr=out["schnorr"].astype(bool),
-        bip340=out["bip340"].astype(bool),
-        count=count,
-    )
+        row = FIELD_ROW0 + 3 * FIELD_WORDS
+        buf[row:row + FIELD_WORDS, np.array(r2_idx)] = _pack_words(
+            r2_vals, FIELD_WORDS
+        )
+    buf[FLAGS_ROW, :count] = flags
+    return PreparedBatch(buf, count)
 
 
 def _prepare_batch_native(
@@ -439,8 +337,7 @@ def _prepare_batch_native(
 
     Python packs fixed-width byte columns and prechecks ranges (so every
     packed int fits 32 bytes); the native side redoes the r/s range checks,
-    then does the heavy big-int work per item.  Output arrays are written
-    directly in limb-major layout — no transposes.
+    then does the heavy big-int work per item and writes the wire buffer.
     """
     from .cpu_native import load_native_verifier
 
@@ -472,7 +369,7 @@ def _prepare_batch_native(
             zs.append(zero32)
             rs.append(zero32)
             ss.append(zero32)
-    out = nv.prepare_batch_arrays(
+    buf = nv.prepare_lane(
         b"".join(px),
         b"".join(py),
         b"".join(zs),
@@ -482,7 +379,7 @@ def _prepare_batch_native(
         count,
         size,
     )
-    return _prepared_from_native(out, count)
+    return PreparedBatch(buf, count)
 
 
 def prepare_batch_raw(raw, pad_to: Optional[int] = None) -> PreparedBatch:
@@ -498,7 +395,7 @@ def prepare_batch_raw(raw, pad_to: Optional[int] = None) -> PreparedBatch:
     count = len(raw)
     size = pad_to or count
     assert size >= count
-    out = nv.prepare_batch_arrays(
+    buf = nv.prepare_lane(
         raw.px.tobytes(),
         raw.py.tobytes(),
         raw.z.tobytes(),
@@ -508,7 +405,44 @@ def prepare_batch_raw(raw, pad_to: Optional[int] = None) -> PreparedBatch:
         count,
         size,
     )
-    return _prepared_from_native(out, count)
+    return PreparedBatch(buf, count)
+
+
+def expand_lane(buf: jnp.ndarray) -> tuple:
+    """The wire buffer ``(ROWS, B)`` -> :func:`verify_core`'s sixteen
+    arguments, in its order: four ``(WINDOWS, B)`` MSB-first base-16 digit
+    arrays, four ``(B,)`` sign masks, four ``(NLIMBS, B)`` radix-2^11 limb
+    arrays, four ``(B,)`` masks.  The first operations of both jitted
+    programs: shifts and masks over ~230 int32 rows, once a lane."""
+    u = lax.bitcast_convert_type(buf, jnp.uint32)
+    size = buf.shape[1]
+    # a word's eight digits, most significant first
+    shifts = np.arange(28, -1, -4, dtype=np.uint32)[None, :, None]
+
+    def digits(row):
+        # words most significant first -> (40, B), less the seven digits
+        # above bit 131 (zero: prep bounds |k| < 2^132)
+        w = u[row:row + HALF_WORDS][::-1]
+        d = (w[:, None, :] >> shifts) & 0xF
+        return d.reshape(8 * HALF_WORDS, size)[-WINDOWS:].astype(jnp.int32)
+
+    def limbs(row):
+        out = []
+        for i in range(F.NLIMBS):
+            w, off = divmod(F.RADIX * i, 32)
+            lo = u[row + w] >> off
+            if off > 32 - F.RADIX and w + 1 < FIELD_WORDS:  # straddles words
+                lo = lo | (u[row + w + 1] << (32 - off))
+            out.append(lo & F.MASK)
+        return jnp.stack(out).astype(jnp.int32)
+
+    flags = [(u[FLAGS_ROW] >> b) & 1 != 0 for b in range(len(FLAG_NAMES))]
+    return (
+        *(digits(j * HALF_WORDS) for j in range(4)),
+        *flags[:4],
+        *(limbs(FIELD_ROW0 + j * FIELD_WORDS) for j in range(4)),
+        *flags[4:],
+    )
 
 
 def _build_q_table(qx: jnp.ndarray, qy: jnp.ndarray) -> jnp.ndarray:
@@ -702,11 +636,12 @@ def verify_core(
 
 
 @jax.jit
-def _verify_device_jit(*args):
-    return verify_core(*args)
+def _verify_device_jit(buf):
+    return verify_core(*expand_lane(buf))
 
 
-# Jitted :func:`verify_core`.  The jitted function keeps its private name:
+# The jitted XLA program over a lane's wire buffer (:func:`verify_core`
+# behind :func:`expand_lane`).  The jitted function keeps its private name:
 # it names the lowered module (so the persistent compile cache's key) and
 # chip_smoke.py reads its cache size.
 verify_device = _verify_device_jit
@@ -722,13 +657,21 @@ def _pallas_usable(batch: int) -> bool:
     return batch % BLOCK == 0 and jax.devices()[0].platform == "tpu"
 
 
+def count_transfer(buf: np.ndarray) -> None:
+    """One host-to-device call of one lane's buffer (every dispatch site
+    counts it where it makes the call)."""
+    metrics.inc("verify.transfers")
+    metrics.inc("verify.transfer_bytes", buf.nbytes)
+
+
 def _dispatch_prep(prep: PreparedBatch) -> tuple[jnp.ndarray, int]:
     # host->device transfer and kernel enqueue are separate spans (both
     # are async under JAX dispatch: these time the enqueue, the blocking
     # tail shows up in verify.readback)
     with span("verify.transfer", cpu=True):
-        args = tuple(jnp.asarray(a) for a in prep.device_args)
-    if _pallas_usable(args[8].shape[-1]):
+        buf = jnp.asarray(prep.buf)
+    count_transfer(prep.buf)
+    if _pallas_usable(buf.shape[-1]):
         from .pallas_kernel import verify_blocked
 
         # STATIC program choice from the host-side flags: an ECDSA-only
@@ -737,11 +680,11 @@ def _dispatch_prep(prep: PreparedBatch) -> tuple[jnp.ndarray, int]:
         # program below gets the same effect at runtime via lax.cond.
         with span("verify.kernel", cpu=True):
             return (
-                verify_blocked(*args, schnorr_free=prep.schnorr_free),
+                verify_blocked(buf, schnorr_free=prep.schnorr_free),
                 prep.count,
             )
     with span("verify.kernel", cpu=True):
-        return verify_device(*args), prep.count
+        return verify_device(buf), prep.count
 
 
 def dispatch_batch_tpu(

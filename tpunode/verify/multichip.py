@@ -7,9 +7,8 @@ axis; ring/Ulysses-style sequence parallelism is deliberately unnecessary
 here and documented as such), so the multi-chip design is pure DP:
 
 * a 1-D ``Mesh`` over all chips, axis ``"batch"``;
-* every input array sharded along its batch dimension — the minor-most
-  axis for limb-major arrays (see field.py), the only axis for the masks —
-  so host→device transfer is split per chip;
+* a lane's one wire buffer (kernel.py) sharded along its batch dimension,
+  the minor-most axis, so host→device transfer is split per chip;
 * ``shard_map`` runs the same single-chip program :func:`kernel.verify_core`
   on each shard — zero inter-chip traffic in the hot loop;
 * one ``psum`` over ICI reduces the per-shard valid-counts so every chip
@@ -50,7 +49,9 @@ from .ecdsa_cpu import Point
 # topology callers keep one import site.
 from .sched import host_names
 from .kernel import (
-    ARG_IS_2D,
+    PreparedBatch,
+    count_transfer,
+    expand_lane,
     prepare_batch,
     prepare_batch_raw,
     verify_core,
@@ -171,8 +172,9 @@ def sharded_verify_fn(
     block: Optional[int] = None,
     schnorr_free: bool = False,
 ):
-    """Jitted verify step sharded over ``mesh``: same signature as
-    :func:`kernel.verify_core`, returns ``(ok: (B,) bool, total: int32)``.
+    """Jitted verify step sharded over ``mesh``: same argument as
+    :func:`kernel.verify_device` (a lane's wire buffer, placed by
+    :func:`_put_lane`), returns ``(ok: (B,) bool, total: int32)``.
 
     ``kernel``: "auto" picks the Pallas program per shard on an all-TPU
     mesh (per-shard batch must then be BLOCK-aligned — callers pad), the
@@ -203,13 +205,9 @@ def sharded_verify_fn(
     cached = _FN_CACHE.get(key)
     if cached is not None:
         return cached
-    # limb-major layout: batch is the trailing axis of the 2-D arrays.
     # On a hybrid mesh the batch dimension shards over host AND chip
     # jointly (axis-name tuple) — same program, wider denominator.
     axes = _batch_axes(mesh)
-    spec_2d = P(None, axes)
-    spec_1d = P(axes)
-    in_specs = tuple(spec_2d if is2d else spec_1d for is2d in ARG_IS_2D)
 
     if use_pallas:
         from functools import partial
@@ -227,8 +225,8 @@ def sharded_verify_fn(
     else:
         _core = verify_core
 
-    def step(*args):
-        ok = _core(*args)
+    def step(buf):
+        ok = _core(*expand_lane(buf))
         total = lax.psum(jnp.sum(ok.astype(jnp.int32)), axes)
         return ok, total
 
@@ -238,8 +236,8 @@ def sharded_verify_fn(
     sharded = shard_map(
         step,
         mesh=mesh,
-        in_specs=in_specs,
-        out_specs=(spec_1d, P()),
+        in_specs=(P(None, axes),),  # batch is the buffer's trailing axis
+        out_specs=(P(axes), P()),
         check_vma=False,
     )
     fn = jax.jit(sharded)
@@ -258,12 +256,20 @@ def _mesh_quantum(mesh: Mesh) -> int:
     return n
 
 
+def _put_lane(prep: PreparedBatch, mesh: Mesh) -> jax.Array:
+    """One host-to-device call: the lane's buffer, its batch axis split
+    over the mesh."""
+    sharding = NamedSharding(mesh, P(None, _batch_axes(mesh)))
+    count_transfer(prep.buf)
+    return jax.device_put(prep.buf, sharding)
+
+
 def dispatch_raw_sharded(
     raw, mesh: Mesh, pad_to: Optional[int] = None, kernel: str = "auto"
 ) -> tuple:
     """ASYNC sharded dispatch of a packed RawBatch (ISSUE 10): host prep
-    at a mesh-aligned shape, per-chip ``device_put`` (the host→device
-    transfer is split per chip), sharded program enqueue.  Returns the
+    at a mesh-aligned shape, one ``device_put`` that splits the lane's
+    buffer per chip, sharded program enqueue.  Returns the
     ``(ok device array, count)`` handle — collect with
     :func:`kernel.collect_verdicts`; JAX async dispatch means the caller
     can prep the next lane while this one computes, exactly like the
@@ -282,17 +288,11 @@ def dispatch_raw_sharded(
     size = (size + quantum - 1) // quantum * quantum
     with span("verify.prepare", cpu=True):
         prep = prepare_batch_raw(raw, pad_to=size)
-    axes = _batch_axes(mesh)
-    shard_2d = NamedSharding(mesh, P(None, axes))
-    shard_1d = NamedSharding(mesh, P(axes))
     with span("verify.transfer", cpu=True):
-        args = [
-            jax.device_put(np.asarray(a), shard_2d if is2d else shard_1d)
-            for a, is2d in zip(prep.device_args, ARG_IS_2D)
-        ]
+        buf = _put_lane(prep, mesh)
     fn = sharded_verify_fn(mesh, kernel, schnorr_free=prep.schnorr_free)
     with span("verify.kernel", cpu=True):
-        ok, _total = fn(*args)
+        ok, _total = fn(buf)
     return ok, prep.count
 
 
@@ -314,17 +314,9 @@ def verify_batch_sharded(
     size = max(size, len(items))
     size = (size + quantum - 1) // quantum * quantum
     prep = prepare_batch(items, pad_to=size)
-
-    axes = _batch_axes(mesh)
-    shard_2d = NamedSharding(mesh, P(None, axes))
-    shard_1d = NamedSharding(mesh, P(axes))
-    args = [
-        jax.device_put(np.asarray(a), shard_2d if is2d else shard_1d)
-        for a, is2d in zip(prep.device_args, ARG_IS_2D)
-    ]
-
     # schnorr_free comes from the host prep flags (the ONE safe derivation
     # — kernel.PreparedBatch): an ECDSA-only sharded batch sheds the
     # acceptance pows exactly like the single-chip dispatcher.
-    ok, _total = sharded_verify_fn(mesh, schnorr_free=prep.schnorr_free)(*args)
+    fn = sharded_verify_fn(mesh, schnorr_free=prep.schnorr_free)
+    ok, _total = fn(_put_lane(prep, mesh))
     return [bool(b) for b in np.asarray(ok)[: prep.count]]

@@ -17,7 +17,7 @@ Why: under plain XLA the same math is per-op dispatch/HBM bound (~41k
 sigs/s ceiling at batch 8k on one v5e chip — measured round 3); in a
 single Mosaic program the arithmetic runs from VMEM at VPU rate.
 
-Inputs/outputs match :func:`kernel.verify_core` (same PreparedBatch host
+Inputs/outputs match :func:`kernel.verify_device` (same PreparedBatch host
 prep, same verdict vector), pinned against the CPU oracle in
 tests/test_pallas_kernel.py.
 """
@@ -45,6 +45,7 @@ from .kernel import (
     G_TABLE,
     LG_TABLE,
     WINDOW_BITS,
+    expand_lane,
     select_tree16,
 )
 
@@ -366,19 +367,19 @@ def verify_blocked_impl(
 
 
 @partial(jax.jit, static_argnames=("interpret", "block", "schnorr_free"))
-def _verify_blocked_jit(*args, interpret: bool = False, block: int = BLOCK,
+def _verify_blocked_jit(buf, *, interpret: bool = False, block: int = BLOCK,
                         schnorr_free: bool = False):
-    """Drop-in replacement for :func:`kernel.verify_core` (same argument
-    order — PreparedBatch.device_args) running the Pallas kernel over
-    lane blocks of ``block`` (default BLOCK; tests use small blocks in
-    interpret mode).  Batch size must be a multiple of the block size
-    (prepare_batch pads to the engine's fixed shape).  ``schnorr_free``
-    selects the ECDSA-only program variant (acceptance pows pruned at
-    trace time) — callers must only set it when no lane carries a
-    schnorr/bip340 flag (kernel._dispatch_prep derives it from the
-    prepared batch)."""
-    return verify_blocked_impl(*args, interpret=interpret, block=block,
-                               schnorr_free=schnorr_free)
+    """Drop-in replacement for :func:`kernel.verify_device` (one argument:
+    the lane's wire buffer, expanded here by :func:`kernel.expand_lane`)
+    running the Pallas kernel over lane blocks of ``block`` (default BLOCK;
+    tests use small blocks in interpret mode).  Batch size must be a
+    multiple of the block size (prepare_batch pads to the engine's fixed
+    shape).  ``schnorr_free`` selects the ECDSA-only program variant
+    (acceptance pows pruned at trace time) — callers must only set it when
+    no lane carries a schnorr/bip340 flag (kernel._dispatch_prep derives it
+    from the prepared batch)."""
+    return verify_blocked_impl(*expand_lane(buf), interpret=interpret,
+                               block=block, schnorr_free=schnorr_free)
 
 
 # The jitted function keeps its private name: it names the lowered module
