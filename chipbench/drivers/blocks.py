@@ -13,6 +13,31 @@ from chipbench import gen, harness
 from chipbench.peers import ClosedLoop, Remote
 
 
+def backlog(traffic: dict, seconds: float) -> dict:
+    """The blocks a run of ``seconds`` is given, from the traffic file alone.
+
+    The window closes early once the last frame is sent.  When it opens,
+    the ramp's blocks are done and ``outstanding`` more are sent; from then
+    on one is sent for each that is answered, so a node that answers R
+    blocks a second sends the last at window second ``left / R``.  Made
+    are the blocks a window of ``seconds`` sends at
+    ``backlog.holds_to_blocks_per_s``, ``backlog.ramp_allowance_blocks``
+    for the ramp (it takes ``ramp_blocks``, and one more for every block
+    that still compiled) and ``outstanding``.  Returned beside the count:
+    the rate up to which the window runs whole, the rate up to which it is
+    still open when a traced run's capture begins, and the rate the file
+    says was measured (all in signatures a second)."""
+    b = traffic["backlog"]
+    sigs_block = gen.totals(traffic["mix"], traffic["txs_per_block"])["sigs"]
+    left = math.ceil(b["holds_to_blocks_per_s"] * seconds)
+    made = left + b["ramp_allowance_blocks"] + traffic["outstanding"]
+    span = harness.capture_seconds(traffic, seconds)
+    return {"blocks": made, "sigs": made * sigs_block,
+            "window_holds_to": left * sigs_block / seconds,
+            "capture_holds_to": left * sigs_block / (seconds - span),
+            "measured": b["measured_sigs_per_s"]}
+
+
 class Driver:
     def __init__(self, ctx):
         self.ctx = ctx
@@ -22,10 +47,7 @@ class Driver:
         self.loop = ClosedLoop([], [], t["outstanding"])
         self.remote = Remote(ctx.config["network"], on_ready=self.loop.pump)
         self.n_txs = t["txs_per_block"]
-        self.n_blocks = math.ceil(
-            t["backlog"]["parent_blocks_per_s"] * t["backlog"]["factor"]
-            * (ctx.seconds + t["backlog"]["ramp_allowance_s"])
-        ) + t["ramp_blocks"] + t["outstanding"]
+        self.n_blocks = backlog(t, ctx.seconds)["blocks"]
         self.counts: dict = {}  # txid -> verdicts so far
         self.have: list = []  # per block: how many txids have reached it
         self.coinbase: dict = {}  # coinbase txid -> block
@@ -136,8 +158,11 @@ class Driver:
         lat = [1e3 * (t - self.loop.sent[k]) for k, t, _ in inside]
         sigs_block = self.totals["sigs"]
         secs = closed.t - opened.t
+        sent = [sum(t <= edge.t for t in self.loop.sent.values())
+                for edge in (opened, closed)]
         harness.line("blocks", window_s=secs, blocks_in_window=len(inside),
                      blocks_done=len(self.done), blocks_made=self.n_blocks,
+                     sent_at_open=sent[0], sent_at_close=sent[1],
                      sigs_per_block=sigs_block, verdict_ms=lat,
                      utxo_height=self.utxo_height)
         if len(inside) < 3:
@@ -150,4 +175,5 @@ class Driver:
             "sigs_per_s": n * sigs_block / span,
             "verdict_p50_ms": harness.quantile(lat, 0.5),
             "host_cpu_ms_per_ksig": cpu * 1e6 / (n * sigs_block),
-        }, {"verdict_ms": lat, "sigs_in_window": n * sigs_block})
+        }, {"verdict_ms": lat, "sigs_in_window": n * sigs_block,
+            "backlog_left_share": [100.0 * (1 - sent[1] / self.n_blocks)]})

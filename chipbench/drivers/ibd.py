@@ -13,6 +13,30 @@ from chipbench import gen, harness
 from chipbench.peers import Remote
 
 
+def backlog(traffic: dict, seconds: float) -> dict:
+    """The chain a run of ``seconds`` is given, from the traffic file alone.
+
+    The window opens ``ramp_seconds`` after the first verdict and closes
+    early once ``steady_until_share`` of the chain's verdicts are out, so a
+    node that verifies R signatures a second closes it at window second
+    ``share * chain / R - ramp``.  The chain is the shortest whose window
+    runs its ``seconds`` at ``backlog.holds_to_sigs_per_s``.  Returned
+    beside its length: the rate up to which the window runs whole, the rate
+    up to which it is still open when a traced run's capture begins, and
+    the rate the file says was measured (all in signatures a second)."""
+    b = traffic["backlog"]
+    sigs_block = gen.totals(traffic["mix"], traffic["txs_per_block"])["sigs"]
+    share, ramp = traffic["steady_until_share"], traffic["ramp_seconds"]
+    blocks = max(b["min_blocks"], math.ceil(
+        b["holds_to_sigs_per_s"] * (seconds + ramp) / (share * sigs_block)))
+    steady = share * blocks * sigs_block
+    span = harness.capture_seconds(traffic, seconds)
+    return {"blocks": blocks, "sigs": blocks * sigs_block,
+            "window_holds_to": steady / (seconds + ramp),
+            "capture_holds_to": steady / (seconds + ramp - span),
+            "measured": b["measured_sigs_per_s"]}
+
+
 class Driver:
     def __init__(self, ctx):
         self.ctx = ctx
@@ -21,11 +45,7 @@ class Driver:
         self.offered = harness.Offered({}, {}, {}, self.oracle.p2pk)
         t = ctx.traffic
         self.per_block = t["txs_per_block"]
-        sigs_block = gen.totals(t["mix"], self.per_block)["sigs"]
-        want = (t["backlog"]["parent_sigs_per_s"] * t["backlog"]["factor"]
-                * (ctx.seconds + t["ramp_seconds"]))
-        self.n_blocks = max(t["backlog"]["min_blocks"],
-                            math.ceil(want / sigs_block))
+        self.n_blocks = backlog(t, ctx.seconds)["blocks"]
         self.block_of: dict = {}  # txid -> height
         self.first_verdict = None
 
@@ -47,12 +67,15 @@ class Driver:
         parts = await harness.gather_jobs(ctx, gen.block_bodies_job, jobs)
         bodies = []
         for part in parts:
+            # whole-column updates: 0.8M txs a chain, on the loop the
+            # engine's warm-up shares
             self.oracle.p2pk.update(part["p2pk"])
-            for i, (txid, raw, exp) in enumerate(
-                    zip(part["txids"], part["raw"], part["expect"])):
-                self.offered.expect[txid] = exp
-                self.offered.raw[txid] = raw
-                self.block_of[txid] = (part["first_tx"] + i) // self.per_block
+            self.offered.expect.update(zip(part["txids"], part["expect"]))
+            self.offered.raw.update(zip(part["txids"], part["raw"]))
+            first = part["first_tx"] // self.per_block
+            self.block_of.update(zip(part["txids"], (
+                b for b in range(first, first + len(part["bodies"]))
+                for _ in range(self.per_block))))
             for body in part["bodies"]:
                 self.block_of[body[1]] = len(bodies)
                 self.offered.expect[body[1]] = ()  # a coinbase signs nothing
@@ -128,4 +151,6 @@ class Driver:
             "host_cpu_ms_per_ksig":
                 (closed.cpu - opened.cpu) * 1e6 / in_window,
         }, {"sigs_in_window": in_window, "blocks_in_window":
-            in_window / (self.totals["sigs"] / self.n_blocks)})
+            in_window / (self.totals["sigs"] / self.n_blocks),
+            "backlog_left_share": [100.0 * (
+                1 - closed.n_verdicts / len(self.offered.expect))]})

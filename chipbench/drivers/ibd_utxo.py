@@ -87,6 +87,9 @@ class HeldRemote(Remote):
             writer.write(self._headers_reply([]))
 
 
+backlog = ibd.backlog  # the same chain, sized the same way
+
+
 class Driver(ibd.Driver):
     def __init__(self, ctx):
         from tpunode.metrics import metrics

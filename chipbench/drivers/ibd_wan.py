@@ -20,6 +20,9 @@ from chipbench.drivers import ibd
 from chipbench.peers_wan import WanRemote
 
 
+backlog = ibd.backlog  # the same chain, sized the same way
+
+
 class Driver(ibd.Driver):
     CONNECT_EARLY = True  # eight dials take the connect loop ~20 s
 

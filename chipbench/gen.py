@@ -95,19 +95,25 @@ def plan_adversarial(mix: dict, seed: int, first_tx: int, count: int,
 
 
 def totals(mix: dict, count: int) -> dict:
-    """What ``count`` txs of this mix hold, for any seed."""
+    """What ``count`` txs of this mix hold, for any seed: whole turns of
+    the pattern, the txs of a last turn cut short, and one adversarial tx
+    for every whole group, kinds in rotation (``plan_adversarial``)."""
     tot = collections.Counter()
     pattern = mix["pattern"]
-    for t in range(count):
-        for kind in pattern[t % len(pattern)]:
-            tot["inputs"] += 1
-            tot["sigs"] += SIGS[kind]
-            tot["items"] += ITEMS[kind]
-            tot["in." + kind] += 1
-    tot["txs"] = count
-    for kind in plan_adversarial(mix, 0, 0, count, count).values():
-        tot["adv." + kind] += 1
-    return dict(tot)
+    turns, rest = divmod(count, len(pattern))
+    for p, kinds in enumerate(pattern):
+        n = turns + (p < rest)
+        for kind in kinds:
+            tot["inputs"] += n
+            tot["sigs"] += n * SIGS[kind]
+            tot["items"] += n * ITEMS[kind]
+            tot["in." + kind] += n
+    every, kinds = mix.get("adversarial_every", 0), mix.get("adversarial", [])
+    if every and kinds:
+        rounds, more = divmod(count // every, len(kinds))
+        for i, kind in enumerate(kinds):
+            tot["adv." + kind] += rounds + (i < more)
+    return {**{k: v for k, v in tot.items() if v}, "txs": count}
 
 
 def _sign_input(kind, adv, keys, nonces, mid, txin, amount):

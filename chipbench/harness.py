@@ -305,6 +305,26 @@ async def traced(ctx: Ctx, start_at: float, seconds: float) -> str:
     return out
 
 
+def capture_seconds(traffic: dict, seconds: float) -> float:
+    """How long a traced run's capture is: the window's last seconds."""
+    return min(traffic.get("trace_seconds", 4.0), seconds / 2)
+
+
+def backlog_over(ctx: Ctx, closed_at: float, capture_at: float) -> str:
+    """What a traced run says, and exits on, when its driver closed the
+    window before the capture began: the backlog was over, and a capture
+    of an idle node must not become a line of anybody's record."""
+    sized = {k: v for k, v in ctx.traffic.get("backlog", {}).items()
+             if k != "note"}
+    return (f"chipbench: {ctx.workload['name']}: the backlog was over at "
+            f"window second {closed_at:.2f}, before the traced capture "
+            f"begins at window second {capture_at:.2f} of {ctx.seconds:g}: "
+            f"nothing of the program is left to capture.  The backlog is "
+            f"sized by the 'backlog' section of chipbench/traffic/"
+            f"{ctx.workload['traffic']}.json ({json.dumps(sized)}): resize it "
+            f"from the rate this run reads")
+
+
 def per_second_rates(t: list, weight: list, t0: float, t1: float) -> list:
     """Weights per whole second of ``[t0, t1)``."""
     n = int(t1 - t0)
@@ -339,12 +359,22 @@ class Offered:
     p2pk: dict  # the oracle's table
 
 
+def reference_module(config: dict):
+    """The plain reference a configuration is held to: the module of
+    ``chipbench/`` its file names under ``reference`` (``reference`` where
+    it names none).  Its ``check_job`` takes ``{"raw": [raw tx], "p2pk":
+    the driver's prevout table, "checks": {...}}`` in a worker process and
+    returns ``[(txid, per-signature verdicts)]``."""
+    return importlib.import_module(
+        "chipbench." + config.get("reference", "reference"))
+
+
 async def run_reference(ctx: Ctx, offered: Offered, txids: list,
                         checks: dict | None = None) -> dict:
-    """The plain reference over ``txids`` in the worker processes:
-    txid -> per-signature verdicts.  ``checks`` weakens it (the controls)."""
-    from chipbench import reference
-
+    """The configuration's plain reference over ``txids`` in the worker
+    processes: txid -> per-signature verdicts.  ``checks`` weakens it (the
+    controls)."""
+    reference = reference_module(ctx.config)
     per = len(txids) // (4 * ctx.pool._processes) + 1
     jobs = [{"raw": [offered.raw[t] for t in txids[i:i + per]],
              "p2pk": offered.p2pk, "checks": checks or {}}
@@ -355,8 +385,8 @@ async def run_reference(ctx: Ctx, offered: Offered, txids: list,
 
 async def decide_correct(ctx: Ctx, offered: Offered, sink: Sink,
                          window: tuple, extra_checks: list) -> tuple:
-    """-> (correct, attempted, failed).  Every number compared is printed
-    beside its limit; every limit is 0 (exact comparisons)."""
+    """-> (correct, attempted, failed, compared).  Every number compared
+    is printed beside its limit; every limit is 0 (exact comparisons)."""
     got = collections.Counter()
     wrong = 0
     for txid, v in zip(sink.txids, sink.verdicts):
@@ -399,7 +429,7 @@ async def decide_correct(ctx: Ctx, offered: Offered, sink: Sink,
         line("compared", name=name, value=value, limit=0)
     failed = wrong + missing + extra + sink.errors + sink.shed
     correct = all(v == 0 for _, v in checks)
-    return correct, attempted, failed
+    return correct, attempted, failed, checks
 
 
 # ---- per-layer metrics ------------------------------------------------------
@@ -540,15 +570,21 @@ async def _run(ctx: Ctx, driver, making, metrics, gc) -> dict:
                 if ctx.trace:
                     # the last seconds of the window: the profiler's own
                     # stop, which stalls the process, falls after it
-                    span = min(ctx.traffic.get("trace_seconds", 4.0),
-                               ctx.seconds / 2)
+                    span = capture_seconds(ctx.traffic, ctx.seconds)
+                    capture_at = opened.t + ctx.seconds - span
                     tracing = asyncio.ensure_future(
-                        traced(ctx, opened.t + ctx.seconds - span, span))
+                        traced(ctx, capture_at, span))
                 while (time.monotonic() < opened.t + ctx.seconds
                        and not driver.closed_early(sink)):
                     await asyncio.sleep(0.02)
                 closed = mark(sink)
                 note(ctx, "window closes")
+                if tracing is not None and closed.t < capture_at:
+                    tracing.cancel()
+                    with contextlib.suppress(asyncio.CancelledError):
+                        await tracing
+                    raise SystemExit(backlog_over(
+                        ctx, closed.t - opened.t, capture_at - opened.t))
                 trace_dir = await tracing if tracing is not None else None
                 await driver.drain(node, sink)
                 final = metrics.snapshot()
@@ -569,7 +605,7 @@ async def _run(ctx: Ctx, driver, making, metrics, gc) -> dict:
     moved = [(n + "_moved", int(final.get(n, 0) - base.get(n, 0)))
              for n in GUARANTEE_COUNTERS
              if not (ctx.rehearsal is not None and n == "verify.cpu_items")]
-    correct, attempted, failed = await decide_correct(
+    correct, attempted, failed, compared = await decide_correct(
         ctx, driver.offered, sink, (opened.t, closed.t),
         moved + driver.extra_checks()
         + [("compilations_inside_the_window", len(in_window))])
@@ -608,4 +644,11 @@ async def _run(ctx: Ctx, driver, making, metrics, gc) -> dict:
     result["device"] = device
     if ctx.rehearsal is not None:
         result["rehearsal"] = True
+    # each number compared beside its limit: the result's last key, and the
+    # last lines of standard error
+    result["compared"] = {name: {"value": value, "limit": 0}
+                          for name, value in compared}
+    for name, value in compared:
+        print(f"compared {name} = {value} (limit 0)", file=sys.stderr)
+    sys.stderr.flush()
     return result

@@ -1,5 +1,10 @@
 """A cell at tiny size on the CPU: the C++ rung stands where the chip
-would, so this debugs the harness and says nothing about a device."""
+would, so this debugs the harness and says nothing about a device.
+
+What makes a mix tiny is data, in the traffic file's own ``rehearsal``
+section: ``traffic`` is merged over the file and ``config`` over the
+configuration, so a cell that arrives as new files rehearses without an
+edit here."""
 
 import asyncio
 import time
@@ -9,24 +14,21 @@ from chipbench import harness
 CPU = {"verify": {"backend": "cpu", "batch_size": 64, "device_batch": 256,
                   "mesh_devices": 0}}
 
-# per traffic mix: numbers small enough for a test
-TINY = {
-    "ibd": {"backlog": {"parent_sigs_per_s": 6000, "min_blocks": 48},
-            "ramp_seconds": 0.3, "reference_sample_txs": 60,
-            "blocks_per_job": 16},
-    "mempool": {"pool": {"parent_txs_per_s": 800, "extra_txs": 256},
-                "ramp_seconds": 0.3, "reference_sample_txs": 60,
-                "txs_per_job": 400},
-    "blocks": {"txs_per_block": 512, "backlog": {"parent_blocks_per_s": 5},
-               "reference_sample_txs": 60, "txs_per_job": 128},
-}
+
+def tiny(traffic: str) -> dict:
+    """The ``rehearsal.traffic`` overrides of ``traffic/<traffic>.json``."""
+    return harness.load_json(harness.ROOT, "chipbench", "traffic",
+                             traffic + ".json")["rehearsal"]["traffic"]
 
 
 def rehearse(cell: str, seconds: float = 3.0, seed: int = 7, trace=False,
              config=None, traffic=None) -> dict:
     bench, wl, cfg, tr = harness.load_cell(cell)
-    r = harness.Rehearsal(harness.deep_merge(CPU, config or {}),
-                          TINY[wl["traffic"]] if traffic is None else traffic)
+    small = tr.get("rehearsal", {})
+    r = harness.Rehearsal(
+        harness.deep_merge(harness.deep_merge(CPU, small.get("config", {})),
+                           config or {}),
+        small.get("traffic", {}) if traffic is None else traffic)
     ctx = harness.Ctx(wl, bench, harness.deep_merge(cfg, r.config),
                       harness.deep_merge(tr, r.traffic), seed, seconds, trace,
                       r, time.monotonic())

@@ -8,7 +8,7 @@ import sys
 import pytest
 
 from chipbench import gen, harness, reference, secp, tracered
-from chipbench.tests.rehearse import TINY, rehearse
+from chipbench.tests.rehearse import rehearse, tiny
 
 ROOT = harness.ROOT
 BENCH = harness.load_json(ROOT, "BENCHMARK.json")
@@ -81,6 +81,29 @@ def test_stratified_generator_counts_do_not_depend_on_the_seed():
     assert a["adversarial"].keys() != b["adversarial"].keys()
 
 
+def _totals_by_walking(mix: dict, count: int) -> dict:
+    """``gen.totals`` as it was before PR 40: one step a tx (the reference
+    for the closed form, which a 0.8M-tx chain's set-up wanted)."""
+    tot = collections.Counter()
+    for t in range(count):
+        for kind in mix["pattern"][t % len(mix["pattern"])]:
+            tot["inputs"] += 1
+            tot["sigs"] += gen.SIGS[kind]
+            tot["items"] += gen.ITEMS[kind]
+            tot["in." + kind] += 1
+    tot["txs"] = count
+    for kind in gen.plan_adversarial(mix, 0, 0, count, count).values():
+        tot["adv." + kind] += 1
+    return dict(tot)
+
+
+@pytest.mark.parametrize("every", [8, 16, 128])
+def test_totals_in_closed_form_equal_totals_by_walking(every):
+    mix = dict(MIX, adversarial_every=every)
+    for count in (0, 1, 7, 8, 9, 63, 64, 100, 250, 1000, 4097, 66672):
+        assert gen.totals(mix, count) == _totals_by_walking(mix, count), count
+
+
 def test_signatures_verify_under_the_programs_python_oracle():
     """Valid by construction, and invalid exactly where the generator says:
     the program's Python reference extraction over its Python oracle, and
@@ -136,7 +159,7 @@ def _decide(checks: secp.Checks) -> bool:
         sink.add(_Ev(txid, reference.tx_verdicts(raw, oracle, checks)), 0.5)
     harness.start_pool(ctx)
     try:
-        correct, attempted, failed = asyncio.run(harness.decide_correct(
+        correct, attempted, failed, _ = asyncio.run(harness.decide_correct(
             ctx, offered, sink, (0.0, 1.0), []))
     finally:
         ctx.pool.terminate()
@@ -158,11 +181,15 @@ def test_correct_sees_a_weakened_verifier(name, checks, want):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_every_cell_runs_end_to_end_on_the_cpu(cell, capfd):
+    """Every cell of BENCHMARK.json, from its own files alone: what makes
+    it tiny is its traffic file's ``rehearsal`` section."""
     config = {}
     if json.load(open(os.path.join(ROOT, "chipbench", "configs",
                                    cell.split(".")[0] + ".json")))["chips"] == 4:
         config = {"verify": {"mesh_hosts": 4}}
     res = rehearse(cell, config=config)
+    assert list(res)[-1] == "compared"  # each number beside its limit, last
+    assert all(v["limit"] == 0 for v in res["compared"].values())
     assert res["correct"] is True and res["failed"] == 0 and res["attempted"] > 0
     assert res["rehearsal"] is True
     assert not DEVICE_ONLY & set(res["device"])
@@ -210,7 +237,7 @@ def test_a_run_off_its_rung_reads_not_correct():
     """Items served by the Python oracle instead of the configured rung:
     the verdicts are right and the run is still not correct."""
     res = rehearse(RELAY, seconds=4.0, config={"verify": {"backend": "oracle"}},
-                   traffic=dict(TINY["mempool"], outstanding_per_peer=8))
+                   traffic=dict(tiny("mempool"), outstanding_per_peer=8))
     assert res["correct"] is False and res["failed"] == 0
 
 
@@ -260,9 +287,8 @@ def test_a_new_cell_is_new_files_and_appended_entries(tmp_path):
                                "layer": "fixture", "moves": "sigs_per_s",
                                "workloads": ["fixture-node.trickle"]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    code = ("import json; from chipbench.tests.rehearse import rehearse, TINY; "
-            "print(json.dumps(rehearse('fixture-node.trickle', trace=True, "
-            "traffic=TINY['mempool'])))")
+    code = ("import json; from chipbench.tests.rehearse import rehearse; "
+            "print(json.dumps(rehearse('fixture-node.trickle', trace=True)))")
     p = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True, cwd=root,
         env=dict(os.environ, JAX_PLATFORMS="cpu",

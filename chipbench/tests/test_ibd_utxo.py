@@ -4,23 +4,17 @@ a snapshot short of one spendable entry, a connect that skips its deletes
 and a driver that leaves the prevout callback in each read ``correct:
 false``.
 
-Importing this file gives ``rehearse.TINY`` the two mixes' tiny sizes, so
-that ``test_chipbench.py``'s walk over every cell of ``BENCHMARK.json``
-finds them when the directory is run as a whole (``python -m pytest
-chipbench/tests``)."""
+The tiny sizes are the traffic files' ``rehearsal`` sections; the snapshot's
+is repeated here for the tests that count against it."""
 
 import json
 
 import pytest
 
 from chipbench.drivers import ibd_utxo
-from chipbench.tests.rehearse import TINY, rehearse
+from chipbench.tests.rehearse import rehearse, tiny
 
 SPEND, SINGLE = "bch-utxo.ibd-spend", "bch-32mb.single"
-TINY.update({
-    "ibd-spend": dict(TINY["ibd"]),
-    "single": dict(TINY["blocks"]),
-})
 # the tiny chain spends 26,496 outpoints: a snapshot a little larger
 SMALL_SET = {"node": {"utxo_snapshot": {"entries": 30000}}}
 
@@ -133,7 +127,7 @@ def test_the_snapshot_is_the_seeds_and_the_generators(monkeypatch):
     def made(seed):
         bench, wl, cfg, tr = harness.load_cell(SPEND)
         cfg = harness.deep_merge(cfg, SMALL_SET)
-        tr = harness.deep_merge(tr, TINY["ibd-spend"])
+        tr = harness.deep_merge(tr, tiny("ibd-spend"))
         ctx = harness.Ctx(wl, bench, cfg, tr, seed, 1.0, False,
                           harness.Rehearsal(), time.monotonic())
 

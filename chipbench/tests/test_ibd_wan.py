@@ -7,9 +7,7 @@ emulator with no round-trip time, one that ignores the uplink, a fault
 schedule that falls after the window.  And ``reference_wan.py`` against a
 three-block example worked by hand.
 
-Importing this file gives ``rehearse.TINY`` the mix's tiny size, so that
-``test_chipbench.py``'s walk over every cell of ``BENCHMARK.json`` finds it
-when the directory is run as a whole (``python -m pytest chipbench/tests``).
+The chain's tiny size is the same section's ``traffic``.
 """
 
 import json
@@ -17,10 +15,9 @@ import json
 import pytest
 
 from chipbench import peers_wan, reference_wan
-from chipbench.tests.rehearse import TINY, rehearse
+from chipbench.tests.rehearse import rehearse, tiny
 
 CELL = "bch-wan.ibd-faults"
-TINY["ibd-faults"] = dict(TINY["ibd"])
 
 
 # a fault schedule as a run on the chip has it (8 s, 20 s): after a test's window
@@ -119,7 +116,7 @@ def test_an_emulator_that_is_no_link_reads_not_correct(
 
 def test_a_fault_schedule_after_the_window_reads_not_correct(capfd):
     res = rehearse(CELL, seconds=4.0,
-                   traffic=dict(TINY["ibd-faults"], **LATE))
+                   traffic=dict(tiny("ibd-faults"), **LATE))
     assert res["correct"] is False and res["failed"] == 0
     out = _compared(capfd)
     assert out["fault_outside_the_window"] == 2

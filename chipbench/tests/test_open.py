@@ -3,10 +3,7 @@
 true``; a reuse that answers what it should verify reads ``correct:
 false``; the schedule is the seed's; the generator's lateness is reported.
 
-Importing this file gives ``rehearse.TINY`` the two mixes' tiny sizes, so
-that ``test_chipbench.py``'s walk over every cell of ``BENCHMARK.json``
-finds them when the directory is run as a whole (``python -m pytest
-chipbench/tests``)."""
+The tiny sizes are the traffic files' ``rehearsal`` sections."""
 
 import time
 
@@ -14,18 +11,9 @@ import pytest
 
 from chipbench import harness
 from chipbench.drivers import open as open_driver
-from chipbench.tests.rehearse import TINY, rehearse
+from chipbench.tests.rehearse import rehearse, tiny
 
 TIP, RELAY_OPEN = "bch-tip.tip", "bch-node.relay-open"
-TINY.update({
-    "tip": {"txs_per_s": 400, "block_every_s": 0.5, "known_lag_s": 0.2,
-            "ramp_seconds": 0.3, "ramp_blocks": 1, "schedule_slack_s": 3.0,
-            "reference_sample_txs": 60, "txs_per_job": 400,
-            "mix": {"adversarial_every": 16}},
-    "relay-open": {"txs_per_s": 500, "ramp_seconds": 0.3,
-                   "schedule_slack_s": 3.0, "reference_sample_txs": 60,
-                   "txs_per_job": 400, "mix": {"adversarial_every": 16}},
-})
 
 
 def _lines(capfd, kind: str) -> list:
@@ -67,7 +55,7 @@ def test_the_open_relay_cell_reports_its_end_to_end_metrics(capfd):
 
 
 def test_a_cell_reports_only_the_end_to_end_metrics_its_traffic_lists():
-    res = rehearse(TIP, traffic=dict(TINY["tip"], end_to_end=[
+    res = rehearse(TIP, traffic=dict(tiny("tip"), end_to_end=[
         "sigs_per_s", "host_cpu_ms_per_ksig"]))
     assert res["correct"] is True
     assert set(res["metrics"]) == {"sigs_per_s", "host_cpu_ms_per_ksig",
@@ -112,7 +100,7 @@ def test_a_reuse_that_answers_what_it_should_verify_reads_not_correct(
 
 def _driver(cell: str, seed: int, seconds: float = 3.0):
     bench, wl, cfg, tr = harness.load_cell(cell)
-    tr = harness.deep_merge(tr, TINY[wl["traffic"]])
+    tr = harness.deep_merge(tr, tiny(wl["traffic"]))
     ctx = harness.Ctx(wl, bench, cfg, tr, seed, seconds, False,
                       harness.Rehearsal(), time.monotonic())
     return open_driver.Driver(ctx)
