@@ -16,7 +16,7 @@ import contextlib
 import random
 import time
 
-from tpunode.params import NODE_NETWORK, Network
+from tpunode.params import NODE_NETWORK, NODE_WITNESS, Network
 from tpunode.util import Reader
 from tpunode.wire import (
     Block,
@@ -165,14 +165,18 @@ async def _fake_remote(
     getdata_blocks: list[Block] = (),
     relay: "TxRelay | None" = None,
     serve_blocks: bool = True,
+    services: "int | None" = None,
 ) -> None:
     """The remote endpoint: speaks real wire bytes over the pipe."""
     if send_version_first:
-        local = NetworkAddress.from_host_port("::1", 0, services=NODE_NETWORK)
+        if services is None:
+            # a segwit network's node refuses a peer without the witness bit
+            services = NODE_NETWORK | (NODE_WITNESS if net.segwit else 0)
+        local = NetworkAddress.from_host_port("::1", 0, services=services)
         remote = NetworkAddress.from_host_port("::1", 0)
         ver = MsgVersion(
             version=70012,
-            services=NODE_NETWORK,
+            services=services,
             timestamp=int(time.time()),
             addr_recv=remote,
             addr_from=local,
@@ -204,6 +208,7 @@ def dummy_peer_connect(
     getdata_blocks: list[Block] = (),
     relay: "TxRelay | None" = None,
     serve_blocks: bool = True,
+    services: "int | None" = None,
 ):
     """Transport factory injected as ``NodeConfig.connect``
     (reference ``dummyPeerConnect`` NodeSpec.hs:94-133).  ``relay`` gives
@@ -218,7 +223,7 @@ def dummy_peer_connect(
         task = asyncio.get_running_loop().create_task(
             _fake_remote(
                 net, blocks, to_node, from_node, send_version_first,
-                getdata_blocks, relay, serve_blocks,
+                getdata_blocks, relay, serve_blocks, services,
             )
         )
         try:

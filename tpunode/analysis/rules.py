@@ -479,6 +479,9 @@ KNOWN_LAYERS = frozenset({
     "cpu",        # the process's CPU time by thread role
                   # (tpunode/asyncsan.py's collector, ISSUE 38)
     "events",     # event-log self-metrics (tpunode/events.py)
+    "extract",    # the extractor's own phases: digests by kind, x-only key
+                  # lifts, unsupported inputs (tpunode/node.py
+                  # _count_extracted, ISSUE 42)
     "gc",         # the collector's pauses by generation
                   # (tpunode/asyncsan.py's gc.callbacks entry, ISSUE 38)
     "ibd",        # block-fetch-driven IBD planner (tpunode/ibd.py, ISSUE 11)

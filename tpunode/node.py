@@ -130,6 +130,37 @@ def _parse_region(raw: bytes, n_txs: int, want_delta: bool,
         raise
 
 
+def _count_extracted(items) -> None:
+    """One extract job's counters (ISSUE 42): the inputs it called
+    unsupported (nothing verifies them) and what the native extractor
+    says of its own phases — inputs and accumulated seconds by digest
+    kind, the x-only key lifts.  Called by the job, in ITS thread, never
+    on the loop: the loop's hold of a finished job is what it was."""
+    rows = [("extract.unsupported_inputs", int(items.tx_unsupported.sum()),
+             None)]
+    ph = items.phase_counts()
+    if ph:
+        for kind in ("legacy", "bip143", "bip341"):
+            labels = {"kind": kind}
+            rows.append(("extract.digest_inputs", ph[kind + "_inputs"], labels))
+            rows.append(("extract.digest_seconds", ph[kind + "_ns"] * 1e-9,
+                         labels))
+        rows += [
+            ("extract.lift_calls", ph["lift_calls"], None),
+            ("extract.lift_cache_hits", ph["lift_hits"], None),
+            ("extract.lift_seconds", ph["lift_ns"] * 1e-9, None),
+        ]
+    metrics.inc_batch(rows)
+
+
+def _extract_counted(job, **kw):
+    """An extract job as the worker pool runs it: the job, then its
+    counters."""
+    items = job(**kw)
+    _count_extracted(items)
+    return items
+
+
 def _hash_rows(rows) -> "list[bytes]":
     """An ``(n, 32)`` uint8 array of hashes as a list of ``bytes``."""
     blob = rows.tobytes()
@@ -1709,7 +1740,7 @@ class Node:
         does not stop the thread) — txx_parse_free under a live
         txx_extract_h2 is a native use-after-free (review finding)."""
         try:
-            return region.extract(**kw)
+            return _extract_counted(region.extract, **kw)
         finally:
             region.close()
 
@@ -2343,6 +2374,7 @@ class Node:
                     )
                 )
                 cfuts.append(self._extract_pool.submit(
+                    _extract_counted,
                     job,
                     bch=bch,
                     intra_amounts=intra,
@@ -2454,6 +2486,7 @@ class Node:
                         continue
                     metrics.inc("node.verify_txs")
                     metrics.inc("node.verify_inputs", stats.total_inputs)
+                    metrics.inc("extract.unsupported_inputs", stats.unsupported)
                     task = None
                     if items:
                         task = spawn_supervised(

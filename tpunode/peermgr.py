@@ -31,7 +31,7 @@ from .actors import (
 from .events import events
 from .metrics import metrics
 from .trace import record_span
-from .params import NODE_NETWORK, PROTOCOL_VERSION, Network
+from .params import NODE_NETWORK, NODE_WITNESS, PROTOCOL_VERSION, Network
 from .peer import (
     CannotDecodePayload,
     DecodeHeaderError,
@@ -43,6 +43,7 @@ from .peer import (
     PeerError,
     PeerIsMyself,
     PeerMisbehaving,
+    PeerNoSegWit,
     PeerSentBadHeaders,
     PeerStalling,
     PeerTimeout,
@@ -76,6 +77,7 @@ _BAN_ERRORS = (
     PeerMisbehaving,
     PeerSentBadHeaders,
     NotNetworkPeer,
+    PeerNoSegWit,
     DuplicateVersion,
     PeerIsMyself,
     CannotDecodePayload,
@@ -321,6 +323,19 @@ class PeerMgr:
                 reason="not-network-peer",
             )
             p.kill(NotNetworkPeer(p.label))
+            return
+        if self.cfg.net.segwit and v.services & NODE_WITNESS == 0:
+            # it would serve blocks and txs without their witnesses, and
+            # every segwit input of them would read invalid or unsupported
+            # (reference: the segwit flag of the network, PeerMgr.hs:282)
+            log.warning(
+                "[PeerMgr] peer %s lacks the witness service bit; killing",
+                p.label,
+            )
+            events.emit(
+                "peer.handshake", peer=p.label, ok=False, reason="no-segwit"
+            )
+            p.kill(PeerNoSegWit(p.label))
             return
         if any(o.nonce == v.nonce for o in self._peers):
             log.warning("[PeerMgr] peer %s is myself (nonce match); killing", p.label)

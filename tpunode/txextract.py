@@ -156,6 +156,7 @@ def load_txextract_lib() -> ctypes.CDLL:
             u8, u8, u8, u8, u8, u8,  # z px py r s present
             i32, i32, i32, i32, i32, i32,  # item_*
             u8, i32, i32, i32, i32, i32, i32,  # txids + tx_*
+            i64,  # stats (PHASES slots, added to)
         ]
         # subset of a block (ISSUE 27): the txs a relay verdict did not answer
         lib.txx_extract_subset_h.restype = ctypes.c_long
@@ -168,6 +169,7 @@ def load_txextract_lib() -> ctypes.CDLL:
             u8, u8, u8, u8, u8, u8,  # z px py r s present
             i32, i32, i32, i32, i32, i32,  # item_*
             u8, i32, i32, i32, i32, i32, i32,  # txids + tx_*
+            i64,  # stats
         ]
         lib.txx_wire_hashes_h.restype = ctypes.c_long
         lib.txx_wire_hashes_h.argtypes = [ctypes.c_void_p, u8]
@@ -200,6 +202,16 @@ def have_native_extract() -> bool:
         return False
 
 
+# What an extract call says of its own phases (the native ``ExtractStat``
+# slots, in order): inputs and accumulated nanoseconds by digest kind, and
+# the x-only key lifts (BIP340 ``lift_x``: a field square root unless the
+# call's cache holds the key).
+PHASES = (
+    "legacy_inputs", "legacy_ns", "bip143_inputs", "bip143_ns",
+    "bip341_inputs", "bip341_ns", "lift_calls", "lift_hits", "lift_ns",
+)
+
+
 @dataclass
 class RawSigItems:
     """Extraction result in device-ready form.
@@ -213,7 +225,8 @@ class RawSigItems:
     items) — collapse device verdicts to per-signature verdicts with
     :meth:`combine`.  Per-tx arrays carry txids and the ExtractStats
     counters (``tx_extracted`` counts inputs, ``tx_items`` device items,
-    ``tx_sigs`` signatures).
+    ``tx_sigs`` signatures).  ``phases``: the call's :data:`PHASES` counts
+    and nanoseconds (int64; None where an instance was built by hand).
     """
 
     count: int
@@ -236,9 +249,17 @@ class RawSigItems:
     tx_sigs: np.ndarray
     tx_coinbase: np.ndarray
     tx_unsupported: np.ndarray
+    phases: Optional[np.ndarray] = None
 
     def __len__(self) -> int:
         return self.count
+
+    def phase_counts(self) -> dict:
+        """:data:`PHASES` name -> the call's count or nanoseconds; empty
+        where none were kept."""
+        if self.phases is None:
+            return {}
+        return dict(zip(PHASES, self.phases.tolist()))
 
     @property
     def n_txs(self) -> int:
@@ -596,6 +617,7 @@ class ParsedTxRegion:
             tx_sigs=np.zeros(nt, np.int32),
             tx_coinbase=np.zeros(nt, np.int32),
             tx_unsupported=np.zeros(nt, np.int32),
+            phases=np.zeros(len(PHASES), np.int64),
         )
         flags = (1 if bch else 0) | (2 if intra_amounts else 0)
         if ext_amounts is None and ext_scripts is not None:
@@ -639,7 +661,7 @@ class ParsedTxRegion:
             out.item_sig, out.item_key, out.item_nsigs, out.item_nkeys,
             out.txids, out.tx_n_inputs, out.tx_extracted,
             out.tx_items, out.tx_sigs,
-            out.tx_coinbase, out.tx_unsupported,
+            out.tx_coinbase, out.tx_unsupported, out.phases,
         )
         if count < 0:
             raise ValueError(f"native extraction failed ({count})")

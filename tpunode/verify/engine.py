@@ -76,6 +76,8 @@ import time
 from dataclasses import dataclass
 from typing import Callable, Optional, Sequence
 
+import numpy as np
+
 from .. import threadsan
 from ..actors import spawn_supervised
 from ..chaos import ChaosPartition, chaos
@@ -113,6 +115,33 @@ class HostLost(RuntimeError):
 # (pubkey, z, r, s) for ECDSA; 5-tuples append "schnorr" (BCH) or
 # "bip340" (taproot) with the precomputed challenge in the z position.
 VerifyItem = tuple  # see raw.pack_items for the per-algorithm rules
+
+# RawBatch.present -> the algorithm's name ("none": an auto-invalid row)
+_ALGORITHMS = ("none", "ecdsa", "schnorr", "bip340")
+
+
+def _count_algorithms(payloads: list) -> None:
+    """``verify.items_in{algo=}``: the device items of a lane by signature
+    algorithm, counted by the dispatch thread that takes the lane (ISSUE
+    42; never on the loop: a numpy call there gives the GIL up in the
+    middle of a hold).  All four series move together, so each exists
+    from the first lane on."""
+    n = [0, 0, 0, 0]
+    for p in payloads:
+        present = getattr(p, "present", None)
+        if present is not None:
+            for i, k in enumerate(np.bincount(present, minlength=4).tolist()):
+                n[i] += k
+        else:
+            for it in p:
+                if it[0] is None:
+                    n[0] += 1
+                else:
+                    n[_ALGORITHMS.index(it[4]) if len(it) > 4 else 1] += 1
+    metrics.inc_batch(
+        ("verify.items_in", k, {"algo": a}) for a, k in zip(_ALGORITHMS, n)
+    )
+
 
 log = logging.getLogger("tpunode.verify")
 
@@ -1383,6 +1412,7 @@ class VerifyEngine:
         _dispatch_multi's ledger charge — this IS the dispatch thread."""
         self._tls.classes = classes
         self._tls.tenants = tenants
+        _count_algorithms(payloads)
         try:
             with _activate_trace(act):
                 if host is None and backend is None:
