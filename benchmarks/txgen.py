@@ -486,16 +486,21 @@ def _coinbase(height: int) -> Tx:
 
 
 def assemble_chain(
-    net: Network, all_txs: list[Tx], txs_per_block: int
+    net: Network, all_txs: list[Tx], txs_per_block: int,
+    n_blocks: Optional[int] = None,
 ) -> list[Block]:
     """Pack ``all_txs`` into consecutive regtest blocks on top of the
     genesis (a coinbase + ``txs_per_block`` txs each; correct prev-links,
-    merkle roots, PoW by nonce grinding against the trivial target)."""
+    merkle roots, PoW by nonce grinding against the trivial target).
+    ``n_blocks`` with ``txs_per_block=0``: that many blocks of a coinbase
+    alone — nothing to sign."""
     target = bits_to_target(net.genesis.bits)
     prev = genesis_node(net).header.hash
     t0 = net.genesis.timestamp
     blocks = []
-    for h in range(len(all_txs) // txs_per_block):
+    if n_blocks is None:
+        n_blocks = len(all_txs) // txs_per_block
+    for h in range(n_blocks):
         txs = [_coinbase(h + 1)] + all_txs[h * txs_per_block : (h + 1) * txs_per_block]
         merkle = build_merkle_root([t.txid for t in txs])
         nonce = 0

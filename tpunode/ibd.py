@@ -20,6 +20,14 @@ Shape (deliberately the mempool fetcher's, tpunode/mempool.py):
 * ``getdata`` batches (``batch_blocks`` hashes each, a ``ping`` behind
   them) are spread across the online peer fleet, the peer that has served
   its batches fastest first, with a per-peer in-flight cap;
+* the planner's unit of work is the batch (ISSUE 43): the plan grows by
+  whole batches — its open edge waits until ``batch_blocks`` heights are
+  free under the lead's horizon; only the last batch before the header
+  tip and a gap with batches on both sides are scheduled short — and the
+  pass that runs for every connected block ends, when that block freed
+  less than a batch and nothing is queued, before anything that sorts,
+  takes the metrics' lock or walks the view of the chain: a pass costs
+  the same at 10^3 headers as at 10^6;
 * delivery is kept **per block**: the node tells the planner of every
   block as it arrives (:meth:`BlockFetcher.block_arrived`), from whichever
   peer, so a batch that fails half way asks another peer for its missing
@@ -41,7 +49,8 @@ Shape (deliberately the mempool fetcher's, tpunode/mempool.py):
   admission stays single-path exactly like mempool fetch;
 * scheduling is watermark-gated: at most ``max_lead`` blocks beyond the
   watermark are ever scheduled (bounded by the node's out-of-order
-  parking), the blocks on the wire plus those in verification never
+  parking; the lead steps between ``max_lead - batch_blocks + 1`` and
+  ``max_lead``), the blocks on the wire plus those in verification never
   exceed the node's shed bound (``pending_cap``), and planning defers
   while verify-ingest pressure is high — the planner can saturate the
   pipeline but never outrun it into the shed path;
@@ -83,6 +92,9 @@ STALL_TIMEOUT_DECAY = 0.85
 # a tick this much later than asked for means the loop was held: blocks
 # may be waiting unread in the sockets, and no peer is charged for it
 LOOP_HELD = 0.05
+# ``ibd.inflight_blocks`` is sampled this often between the planner's
+# requests: writing a gauge takes the metrics registry's lock on the loop
+GAUGE_INTERVAL = 0.5
 
 
 @dataclass
@@ -91,7 +103,11 @@ class IbdConfig:
     total in-flight block count under the node's verify-pending and
     out-of-order-parking bounds, so a healthy sync never sheds."""
 
-    # blocks per getdata batch (one peer round-trip)
+    # blocks per getdata batch (one peer round-trip).  The plan grows by
+    # whole batches: the open edge at the lead's horizon waits until this
+    # many heights are uncovered, so every getdata asks this many blocks
+    # but the last before the header tip, a gap between two batches (a
+    # reorg unwind, a dropped batch) and a re-request of what is missing
     batch_blocks: int = 16
     # concurrent batches per peer
     max_inflight_per_peer: int = 2
@@ -102,7 +118,8 @@ class IbdConfig:
     # memory AND stays inside Node.MAX_UTXO_PENDING (128 parked); what is
     # on the wire or in verification is held under
     # Node.MAX_VERIFY_PENDING (64 messages) besides, so healthy syncs
-    # never shed
+    # never shed.  A ceiling: the scheduled lead runs between
+    # max_lead - batch_blocks + 1 and max_lead, stepping by a batch
     max_lead: int = 48
     # a delivered head batch whose blocks still have not connected after
     # this long is re-fetched (heals shed/failed ingest; in a healthy sync
@@ -190,6 +207,13 @@ class BlockFetcher:
         self._hashes: dict[int, bytes] = {}  # best-chain height -> hash
         self._cache_best: Optional[bytes] = None
         self._cache_floor = 1 << 62  # lowest height the view covers
+        self._cache_top = 0  # highest height the view covers
+        # the plan's open edge: every height between the watermark and it
+        # is covered by a batch and none above it is; 0 = not known (the
+        # watermark moved back, a batch was dropped, a header was missing)
+        self._edge = 0
+        self._gauges: dict[str, float] = {}  # as last written
+        self._gauged_at = 0.0
         self._target = 0
         self._announced = False
         self.synced = asyncio.Event()  # wm reached the header tip once
@@ -311,24 +335,25 @@ class BlockFetcher:
             return
         self._target = best.height
         wm = self._utxo.height
-        metrics.set_gauge("ibd.target", float(self._target))
+        self._gauge("ibd.target", float(self._target))
         if not self._announced and self._target > wm:
             self._announced = True
             events.emit(
                 "ibd.start", watermark=wm, target=self._target,
             )
-        # connected batches retire; stale cache entries prune
+        # connected batches retire; so do the view's heights at or under
+        # the watermark, which are contiguous from its floor
         for lo in [lo for lo, b in self._batches.items() if b.hi <= wm]:
             self._drop(self._batches.pop(lo))
-        for h in [h for h in self._hashes if h <= wm]:
-            del self._hashes[h]
+        for h in range(self._cache_floor, wm + 1):
+            self._hashes.pop(h, None)
         self._cache_floor = max(self._cache_floor, wm + 1)
         if wm >= self._target:
             if self._target > 0 and not self.synced.is_set():
                 self.synced.set()
                 events.emit("ibd.synced", height=wm)
                 log.info("[IBD] watermark reached header tip %d", wm)
-            metrics.set_gauge("ibd.inflight_blocks", 0.0)
+            self._gauge("ibd.inflight_blocks", 0.0)
             self._set_head_wait(False)
             return
         self.synced.clear()
@@ -359,43 +384,93 @@ class BlockFetcher:
             self._refetches += 1
             metrics.inc("ibd.refetches")
             events.emit("ibd.refetch", lo=head.lo, hi=head.hi)
+        horizon = min(self._target, wm + self.cfg.max_lead)
+        if (
+            best.hash == self._cache_best
+            and self._cache_floor == wm + 1  # the view stands as it is
+            and self._edge
+            and not self._room(self._edge, horizon)
+            and not any(b.state == "queued" for b in self._batches.values())
+        ):
+            # a connected block that frees less than a batch: nothing to
+            # schedule and nobody to ask.  This is the pass that runs for
+            # every block of a sync, so it ends here, before anything
+            # that sorts, takes the metrics' lock or reads the view
+            if now - self._gauged_at >= GAUGE_INTERVAL:
+                self._gauge_on_wire(now)
+            return
         if self._pressure():
             metrics.inc("ibd.deferred")
             return  # the tick retries once ingest drains
-        self._refresh_hashes(best)
-        # a reorg may have rewritten heights under planned batches: a
-        # batch whose hashes no longer match the best-chain view fetches
-        # orphaned blocks nobody can connect — drop it and replan
-        for lo in [
-            lo for lo, b in self._batches.items()
-            if any(
-                self._hashes.get(h) != hh
-                for h, hh in zip(range(b.lo, b.hi + 1), b.hashes)
-                if h > wm  # connected heights are pruned from the view
-            )
-        ]:
-            self._drop(self._batches.pop(lo))
-            metrics.inc("ibd.reorg_dropped")
-        # extend the plan over every uncovered height up to the lead
-        # horizon.  Not just past the highest batch: after a reorg unwind
-        # the watermark sits BELOW surviving batches, and the gap in
-        # front of them is exactly what must be fetched next.
-        horizon = min(self._target, wm + self.cfg.max_lead)
-        for lo, hi in self._uncovered(max(wm + 1, 1), horizon):
-            next_h = lo
-            while next_h <= hi:
-                b_hi = min(next_h + self.cfg.batch_blocks - 1, hi)
-                hashes = [
-                    self._hashes.get(h) for h in range(next_h, b_hi + 1)
-                ]
-                if any(h is None for h in hashes):
-                    break  # header gap (mid-reorg): replan on the next tick
-                b = self._batches[next_h] = _Batch(next_h, b_hi, hashes)
-                for hh in hashes:
-                    self._want[hh] = b
-                next_h = b_hi + 1
-        metrics.set_gauge("ibd.inflight_blocks", float(self._on_wire()))
+        if self._refresh_hashes(best):
+            # a reorg may have rewritten heights under planned batches: a
+            # batch whose hashes no longer match the best-chain view
+            # fetches orphaned blocks nobody can connect — drop it and
+            # replan.  (A view that did not move rewrote nothing.)
+            for lo in [
+                lo for lo, b in self._batches.items()
+                if any(
+                    self._hashes.get(h) != hh
+                    for h, hh in zip(range(b.lo, b.hi + 1), b.hashes)
+                    if h > wm  # connected heights are pruned from the view
+                )
+            ]:
+                self._drop(self._batches.pop(lo))
+                metrics.inc("ibd.reorg_dropped")
+        # extend the plan up to the lead horizon.  Not just past the
+        # highest batch: after a reorg unwind the watermark sits BELOW
+        # surviving batches, and the gap in front of them is exactly what
+        # must be fetched next — a gap with a batch above it is filled at
+        # once, at whatever size it has
+        holes, edge = self._uncovered(max(wm + 1, 1))
+        known = True
+        for lo, hi in holes:
+            known &= lo <= horizon and self._schedule(
+                lo, min(hi, horizon)) > hi
+        # the open edge grows by whole batches: it waits for a batch's
+        # room under the horizon, or for the header tip
+        n = self._room(edge, horizon)
+        known &= self._schedule(edge, edge + n - 1) == edge + n
+        self._edge = edge + n if known else 0  # 0: a header was missing
         self._assign()
+        self._gauge_on_wire(now)
+
+    def _room(self, edge: int, horizon: int) -> int:
+        """Heights the plan's open ``edge`` can grow by under ``horizon``:
+        whole batches, and before the header tip whatever is left."""
+        n = horizon - edge + 1
+        if horizon < self._target:
+            n -= n % self.cfg.batch_blocks
+        return max(n, 0)
+
+    def _schedule(self, lo: int, hi: int) -> int:
+        """Batches of ``batch_blocks`` over ``[lo, hi]``, the last as
+        short as it comes.  -> the first height left uncovered: ``hi + 1``
+        unless the view lacks a header (mid-reorg: the next pass goes
+        on)."""
+        while lo <= hi:
+            b_hi = min(lo + self.cfg.batch_blocks - 1, hi)
+            hashes = [self._hashes.get(h) for h in range(lo, b_hi + 1)]
+            if any(h is None for h in hashes):
+                break
+            b = self._batches[lo] = _Batch(lo, b_hi, hashes)
+            for hh in hashes:
+                self._want[hh] = b
+            lo = b_hi + 1
+        return lo
+
+    def _gauge(self, name: str, value: float) -> None:
+        """``metrics.set_gauge`` takes the registry's lock, on the loop:
+        only a value that moved is written."""
+        if self._gauges.get(name) != value:
+            self._gauges[name] = value
+            metrics.set_gauge(name, value)
+
+    def _gauge_on_wire(self, now: float) -> None:
+        """``ibd.inflight_blocks`` is a sample: taken when the planner has
+        asked for blocks, and otherwise every ``GAUGE_INTERVAL``."""
+        self._gauged_at = now
+        self._gauge("ibd.inflight_blocks", float(self._on_wire()))
 
     def _on_wire(self) -> int:
         """Blocks asked for and not yet here."""
@@ -465,34 +540,33 @@ class BlockFetcher:
                 STALL_TIMEOUT_MAX, 2.0 * self._stall_timeout
             )
 
-    def _uncovered(self, lo: int, hi: int) -> list[tuple[int, int]]:
-        """Height ranges in ``[lo, hi]`` not covered by any batch."""
-        gaps: list[tuple[int, int]] = []
-        cur = lo
+    def _uncovered(self, lo: int) -> tuple[list[tuple[int, int]], int]:
+        """Where no batch covers the heights from ``lo`` up: the holes
+        that have a batch above them, and the open edge, the first height
+        above every batch."""
+        holes: list[tuple[int, int]] = []
+        edge = lo
         for b_lo, b_hi in sorted(
             (b.lo, b.hi) for b in self._batches.values()
         ):
-            if b_lo > cur:
-                gaps.append((cur, min(b_lo - 1, hi)))
-            cur = max(cur, b_hi + 1)
-            if cur > hi:
-                break
-        if cur <= hi:
-            gaps.append((cur, hi))
-        return [(a, b) for a, b in gaps if a <= b]
+            if b_lo > edge:
+                holes.append((edge, b_lo - 1))
+            edge = max(edge, b_hi + 1)
+        return holes, edge
 
-    def _refresh_hashes(self, best) -> None:
+    def _refresh_hashes(self, best) -> bool:
         """Maintain the height->hash view of the best chain: O(1) per tip
         extension, one bounded walk down to the first already-agreeing
         entry after a reorg.  The view covers ``[watermark+1, best]`` —
         ``_cache_floor`` tracks its lower edge so a reorg unwind that
         moves the watermark BACKWARD re-fills the newly-needed heights
         (early-stopping on an agreeing entry is only sound when the
-        cached range already reaches the floor)."""
+        cached range already reaches the floor).  -> whether the view
+        moved."""
         floor = max(self._utxo.height, 0)
         covered = self._cache_floor <= floor + 1
         if best.hash == self._cache_best and covered:
-            return
+            return False
         node = best
         while node is not None and node.height > floor:
             if covered and self._hashes.get(node.height) == node.hash:
@@ -501,9 +575,12 @@ class BlockFetcher:
             node = self._chain.get_block(node.header.prev)
         self._cache_floor = min(self._cache_floor, floor + 1)
         self._cache_best = best.hash
-        # a reorg may have shortened the chain: drop orphaned heights
-        for h in [h for h in self._hashes if h > best.height]:
-            del self._hashes[h]
+        # a reorg may have shortened the chain: drop orphaned heights,
+        # which are contiguous down from the view's old top
+        for h in range(best.height + 1, self._cache_top + 1):
+            self._hashes.pop(h, None)
+        self._cache_top = best.height
+        return True
 
     def _assign(self) -> None:
         """Hand queued batches to online peers with capacity, lowest
