@@ -24,6 +24,8 @@
 #include <cstdint>
 #include <cstring>
 #include <ctime>
+#include <memory>
+#include <mutex>
 #include <string>
 #include <unordered_map>
 #include <vector>
@@ -2357,6 +2359,162 @@ long txx_utxo_ops_h(void *hp, uint8_t prefix, long cap, uint8_t *out,
   if (created) *created = n_created;
   if (spent) *spent = n_spent;
   return pos;
+}
+
+// ---- the in-flight output view (ISSUE 44) --------------------------------
+//
+// outpoint -> (amount, scriptPubKey) for every output of a block that is
+// parsed and not yet connected.  A block's rows are copied out of its parse
+// handle (the handle goes when the block's extraction ends, the view's copy
+// when it connects), so a publish is one call whatever the block's size and
+// holds no interpreter lock; lookups are one call a batch.  Two blocks in
+// flight may have made the same outpoint (the same tx on two branches):
+// the newer one answers, and forgetting either leaves the other's rows
+// indexed.
+
+struct ViewBlock {
+  std::vector<OutpointKey> keys;
+  std::vector<int64_t> amounts;
+  std::vector<int64_t> ends;  // script i: scripts[ends[i-1] .. ends[i])
+  std::vector<uint8_t> scripts;
+  void add(const uint8_t *txid, uint32_t vout, int64_t amount,
+           const uint8_t *script, size_t slen) {
+    OutpointKey key;
+    memcpy(key.b, txid, 32);
+    memcpy(key.b + 32, &vout, 4);
+    keys.push_back(key);
+    amounts.push_back(amount);
+    scripts.insert(scripts.end(), script, script + slen);
+    ends.push_back(int64_t(scripts.size()));
+  }
+};
+
+struct ViewRef {
+  const ViewBlock *block;
+  uint32_t row;
+};
+
+struct OutputView {
+  std::mutex mu;
+  std::unordered_map<OutpointKey, ViewRef, OutpointHash> index;
+  std::unordered_map<std::string, std::unique_ptr<ViewBlock>> blocks;
+  bool overlap = false;
+
+  void index_block(const ViewBlock *b) {
+    for (size_t i = 0; i < b->keys.size(); ++i)
+      index[b->keys[i]] = ViewRef{b, uint32_t(i)};
+  }
+
+  long forget(const std::string &hash) {
+    auto it = blocks.find(hash);
+    if (it == blocks.end()) return 0;
+    std::unique_ptr<ViewBlock> b = std::move(it->second);
+    blocks.erase(it);
+    for (const OutpointKey &k : b->keys) {
+      auto f = index.find(k);
+      if (f != index.end() && f->second.block == b.get()) index.erase(f);
+    }
+    if (overlap) {
+      size_t total = 0;
+      for (const auto &kv : blocks) {
+        index_block(kv.second.get());
+        total += kv.second->keys.size();
+      }
+      overlap = index.size() != total;
+    }
+    return long(b->keys.size());
+  }
+
+  long install(const uint8_t *block_hash, std::unique_ptr<ViewBlock> b) {
+    std::string hash(reinterpret_cast<const char *>(block_hash), 32);
+    std::lock_guard<std::mutex> g(mu);
+    forget(hash);  // delivered again: the newer parse's
+    size_t before = index.size(), n = b->keys.size();
+    index_block(b.get());
+    if (index.size() - before != n) overlap = true;
+    blocks[hash] = std::move(b);
+    return long(n);
+  }
+};
+
+void *txx_view_new() { return new OutputView; }
+
+void txx_view_free(void *vp) { delete static_cast<OutputView *>(vp); }
+
+long txx_view_size(void *vp) {
+  OutputView *v = static_cast<OutputView *>(vp);
+  std::lock_guard<std::mutex> g(v->mu);
+  return long(v->index.size());
+}
+
+// Every output of the parsed region `hp` as block `block_hash`'s.  Returns
+// the rows added.
+long txx_view_publish_h(void *vp, void *hp, const uint8_t *block_hash) {
+  TxxHandle *h = static_cast<TxxHandle *>(hp);
+  std::unique_ptr<ViewBlock> b(new ViewBlock);
+  for (const TxSpan &tx : h->txs) {
+    for (size_t vout = 0; vout < tx.outs.size(); ++vout) {
+      const uint8_t *script = nullptr;
+      uint32_t slen = 0;
+      out_script(tx.outs[vout], &script, &slen);
+      b->add(tx.txid, uint32_t(vout), tx.outs[vout].value, script, slen);
+    }
+  }
+  return static_cast<OutputView *>(vp)->install(block_hash, std::move(b));
+}
+
+// The same from rows (a block parsed elsewhere): `keys36` n x (txid ++
+// vout_le32), `ends[i]` where row i's script ends in `scripts`.
+long txx_view_publish_rows(void *vp, const uint8_t *block_hash, long n,
+                           const uint8_t *keys36, const int64_t *amounts,
+                           const int64_t *ends, const uint8_t *scripts) {
+  std::unique_ptr<ViewBlock> b(new ViewBlock);
+  for (long i = 0; i < n; ++i) {
+    uint32_t vout;
+    memcpy(&vout, keys36 + i * 36 + 32, 4);
+    int64_t lo = i ? ends[i - 1] : 0;
+    b->add(keys36 + i * 36, vout, amounts[i], scripts + lo,
+           size_t(ends[i] - lo));
+  }
+  return static_cast<OutputView *>(vp)->install(block_hash, std::move(b));
+}
+
+// The block is connected, or let go.  Returns the rows that left, 0 for a
+// block that has none here.
+long txx_view_forget(void *vp, const uint8_t *block_hash) {
+  OutputView *v = static_cast<OutputView *>(vp);
+  std::string hash(reinterpret_cast<const char *>(block_hash), 32);
+  std::lock_guard<std::mutex> g(v->mu);
+  return v->forget(hash);
+}
+
+// n outpoints (36 bytes each) in one hold: hit[i] 1 where the view has row
+// i, then amounts[i] and its script at scripts[ends[i-1] .. ends[i]) (a
+// miss is an empty span).  Returns the hits, or -(bytes needed) when `cap`
+// bytes do not hold the scripts (nothing of `scripts` is then to be read).
+long txx_view_lookup(void *vp, const uint8_t *keys36, long n, uint8_t *hit,
+                     int64_t *amounts, int64_t *ends, uint8_t *scripts,
+                     long cap) {
+  OutputView *v = static_cast<OutputView *>(vp);
+  std::lock_guard<std::mutex> g(v->mu);
+  long pos = 0, hits = 0;
+  for (long i = 0; i < n; ++i) {
+    OutpointKey key;
+    memcpy(key.b, keys36 + i * 36, 36);
+    auto f = v->index.find(key);
+    hit[i] = f != v->index.end();
+    if (hit[i]) {
+      const ViewBlock *b = f->second.block;
+      uint32_t row = f->second.row;
+      int64_t lo = row ? b->ends[row - 1] : 0, len = b->ends[row] - lo;
+      if (pos + len <= cap) memcpy(scripts + pos, b->scripts.data() + lo, len);
+      amounts[i] = b->amounts[row];
+      pos += len;
+      ++hits;
+    }
+    ends[i] = pos;
+  }
+  return pos > cap ? -pos : hits;
 }
 
 // All parsed txids, row-major (n_txs x 32) — block connect and mempool

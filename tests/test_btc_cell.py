@@ -373,9 +373,11 @@ ROOM = 1.5
 
 
 def test_the_backlog_cells_are_six():
+    # and PR 44's seventh, appended (tests/test_chain_cell.py holds it to
+    # the same rule)
     assert sorted(BACKLOG) == [
-        "bch-32mb.blocks", "bch-32mb.single", "bch-node.ibd",
-        "bch-utxo.ibd-spend", "bch-wan.ibd-faults", CELL]
+        "bch-32mb.blocks", "bch-32mb.single", "bch-chain.ibd-recent",
+        "bch-node.ibd", "bch-utxo.ibd-spend", "bch-wan.ibd-faults", CELL]
     for wl in BENCH["workloads"]:
         traffic = harness.load_json(harness.ROOT, "chipbench", "traffic",
                                     wl["traffic"] + ".json")

@@ -408,9 +408,23 @@ class _World:
     get_peers = lambda self: self.peers
 
     def planner(self, cfg, cap=64) -> BlockFetcher:
-        return BlockFetcher(
+        f = BlockFetcher(
             cfg, self.net, self, self, self, lambda: self.pressed,
             pending=lambda: self.pending, pending_cap=cap)
+        _PLANNERS.append(f)
+        return f
+
+
+_PLANNERS: list = []  # made outside a node: nobody exits them
+
+
+@pytest.fixture(autouse=True)
+def close_head_waits():
+    """A planner driven by hand may leave its ``ibd.head_wait`` span open;
+    an open span of one test is in the next one's ``trace._open``."""
+    yield
+    while _PLANNERS:
+        _PLANNERS.pop()._set_head_wait(False)
 
 
 def _asked(peer) -> list:

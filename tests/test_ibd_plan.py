@@ -26,6 +26,7 @@ from tests.test_ibd_faults import (
     _Peer,
     _World,
     all_online,
+    close_head_waits,  # noqa: F401 — the autouse fixture, for _World's planners
     connect_to,
     peers_of,
 )

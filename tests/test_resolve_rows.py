@@ -177,7 +177,7 @@ def a_node(region, bch, subset, sources: str, embedder) -> SimpleNamespace:
 
 
 def node_of(mempool, utxo, prevout_lookup) -> SimpleNamespace:
-    node = SimpleNamespace(mempool=mempool, utxo=utxo,
+    node = SimpleNamespace(mempool=mempool, utxo=utxo, _inflight=None,
                            cfg=SimpleNamespace(prevout_lookup=prevout_lookup))
     node._prevout_sources = lambda: Node._prevout_sources(node)
     node._prevout_oracle = lambda: Node._prevout_oracle(node)
@@ -257,7 +257,7 @@ def test_the_python_paths_oracle_is_built_from_the_same_sources(sources,
     got = node._prevout_oracle()
     if sources == "none":
         assert got is None and ref is None
-        assert node._prevout_sources() == (None, None, None)
+        assert node._prevout_sources() == (None, None, None, None)
         return
     wanted = wanted_outpoints(region, bch, subset)
     assert [got(*o) for o in wanted] == [ref(*o) for o in wanted]

@@ -264,6 +264,55 @@ async def test_restart_resumes_from_persisted_chain_and_utxo(tmp_path):
 
 
 @pytest.mark.asyncio
+async def test_restart_with_blocks_in_flight_resumes_at_the_watermark(tmp_path):
+    """The in-flight output view is memory only (ISSUE 44).  A node is
+    stopped with three blocks parsed, published to the view and held in
+    verification; the node reopened over the same store starts at the
+    watermark with the view empty, the blocks above the watermark are
+    fetched again, and every verdict of theirs — spends of each other's
+    outputs among them — equals the plain reference's."""
+    from chipbench import reference_chain
+    from tests.chain_cell import a_node, chain
+
+    ch = chain()
+    path = str(tmp_path / "node.log")
+    store = LogKV(path)
+    async with a_node(ch, store=store) as d:
+        for h in (1, 2, 3):
+            d.give(ch.block(h))
+        await poll_until(lambda: d.node.utxo.height == 3, what="connects")
+        d.hold = asyncio.Event()  # never set: stopped mid-verification
+        for h in (4, 5, 6):
+            d.give(ch.block(h))
+        await poll_until(lambda: d.node._inflight.blocks == 3,
+                         what="three blocks in the view")
+        assert len(d.node._inflight) > 0 and d.node.utxo.height == 3
+    store.close()
+
+    store2 = LogKV(path)
+    missing0 = metrics.get("node.resolve_missing")
+    async with a_node(ch, store=store2, port=17945) as d:
+        node = d.node
+        assert node.utxo.height == 3 and node.utxo.block_hash == ch.hashes[2]
+        assert len(node._inflight) == 0 and node._inflight.blocks == 0
+        skipped0 = metrics.get("node.block_replay_skipped")
+        for h in (3, 6, 5, 4):  # 3 is under the watermark: not verified again
+            d.give(ch.block(h))
+        txids = [t for ids in ch.txids[3:6] for t in ids]
+        await d.verdicts_of(txids)
+        await poll_until(lambda: node.utxo.height == 6, what="connects")
+        assert metrics.get("node.block_replay_skipped") == skipped0 + 1
+        assert not set(ch.txids[2]) & set(d.verdicts)
+        ref = dict(reference_chain.check_job(
+            {"raw": [ch.raw[t] for t in txids], "p2pk": ch.table(txids)}))
+        for t in txids:
+            assert tuple(d.verdicts[t].verdicts) == ref[t] == ch.expect[t]
+        assert metrics.get("node.resolve_missing") == missing0
+        assert d.clear_view()
+    store2.close()
+
+
+@pytest.mark.asyncio
 async def test_out_of_order_block_parks_until_predecessor(tmp_path):
     """Review pin: applying height N+2 over a watermark of N would strand
     N+1's delta below the watermark forever.  An early arrival PARKS

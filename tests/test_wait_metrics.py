@@ -172,11 +172,13 @@ def test_the_two_connect_metrics_read_the_utxo_connect_span():
         entry = by_name[name]
         # the cells that connect blocks: PR 27 appended the tip cell,
         # PR 31 its two beside their siblings, PR 36 the IBD from a network,
-        # PR 42 the BTC node's IBD
+        # PR 42 the BTC node's IBD, PR 44 the IBD of a chain that spends
+        # its own outputs
         assert entry["workloads"] == ["bch-node.ibd", "bch-32mb.blocks",
                                       "bch-tip.tip", "bch-utxo.ibd-spend",
                                       "bch-32mb.single", "bch-wan.ibd-faults",
-                                      "btc-node.ibd-taproot"]
+                                      "btc-node.ibd-taproot",
+                                      "bch-chain.ibd-recent"]
         assert entry["layer"] == "UTXO connect / store"
         assert entry["moves"] == "host_cpu_ms_per_ksig"
         assert (entry["unit"], entry["better"]) == ("ms/block", "lower")
@@ -280,7 +282,8 @@ def test_commit_ms_per_ktx_reads_the_commit_span_in_every_cell(cell):
                                                 "sigs_per_s")
     assert (entry["unit"], entry["better"]) == ("ms/ktx", "lower")
     ibd_cells = ["bch-node.ibd", "bch-utxo.ibd-spend", "bch-wan.ibd-faults",
-                 "btc-node.ibd-taproot"]  # PR 42's, appended in its turn
+                 "btc-node.ibd-taproot",  # PR 42's, appended in its turn
+                 "bch-chain.ibd-recent"]  # and PR 44's
     assert by_name["commit.ms_per_block"]["workloads"] == ibd_cells
     ctx = harness.Ctx(workload={"name": cell}, bench=BENCH, config={},
                       traffic={}, seed=0, seconds=4.0, trace=False,

@@ -247,7 +247,7 @@ def test_spans_open_at_the_captures_edges_are_in_it(tmp_path):
     time.sleep(0.05)  # after the capture: not in the event
     late.__exit__(None, None, None)
     assert metrics.get("span.unit-open-after.seconds") - wall0 >= 0.08
-    assert not trace._open
+    assert not trace._open, sorted(s._name for s in trace._open)
     data = ProfileData.from_file(_xplanes(tmp_path)[0])
     ms = {}
     for plane in data.planes:

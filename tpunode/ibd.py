@@ -56,7 +56,13 @@ Shape (deliberately the mempool fetcher's, tpunode/mempool.py):
   pipeline but never outrun it into the shed path;
 * a delivered-but-stuck head batch (its blocks shed, or lost to an engine
   failure) is re-fetched after ``refetch_after`` seconds — the watermark
-  can stall but never wedge.
+  can stall but never wedge;
+* the lead is the in-flight output view's bound (ISSUE 44): a block's
+  outputs are a prevout source from its parse to its connect, and a block
+  that spends an output of a block beneath it that has not come waits for
+  it (``node.resolve_gate``).  Such a block is no ingest pressure and no
+  work in hand: the plan is not deferred for it, and ``ibd.head_wait`` is
+  open when every block the node holds waits so.
 
 Telemetry: ``ibd.*`` metrics/events and the ``ibd.head_wait`` /
 ``ibd.stall`` spans (OBSERVABILITY.md).  Engine-side, the node submits
@@ -119,11 +125,17 @@ class IbdConfig:
     # on the wire or in verification is held under
     # Node.MAX_VERIFY_PENDING (64 messages) besides, so healthy syncs
     # never shed.  A ceiling: the scheduled lead runs between
-    # max_lead - batch_blocks + 1 and max_lead, stepping by a batch
+    # max_lead - batch_blocks + 1 and max_lead, stepping by a batch.
+    # It is also the bound of the node's in-flight output view (ISSUE
+    # 44): the outputs of every block parsed and not yet connected are
+    # held in memory as a prevout source for the blocks above them
     max_lead: int = 48
     # a delivered head batch whose blocks still have not connected after
     # this long is re-fetched (heals shed/failed ingest; in a healthy sync
-    # this never fires)
+    # this never fires).  A block that spends an output of a block
+    # beneath it that has not come waits for it (``Node._resolve_gate``)
+    # for twice this long: a head that never comes is asked for again
+    # before the wait ends
     refetch_after: float = 30.0
     # planner cadence (stalls and timeouts are detected on ticks;
     # deliveries and chain events wake it immediately)

@@ -43,7 +43,7 @@ from .timeseries import Timeline
 from .slo import DEFAULT_SLOS, SloDef, SloEvaluator
 from .mempool import Mempool, MempoolConfig
 from .metrics import metrics, percentiles
-from .trace import span
+from .trace import open_annotation, record_span, span
 from .tracectx import (
     activate as _activate_trace,
     clear_active as _clear_active_trace,
@@ -78,7 +78,10 @@ from .store import KVStore, Namespaced
 from .receipts import ReceiptLog
 from .serve import ServeServer, TenantConfig
 from .ibd import BlockFetcher, IbdConfig
-from .utxo import UNDO_DEPTH_DEFAULT, UTXO_NAMESPACE, UtxoStore
+from .utxo import (
+    UNDO_DEPTH_DEFAULT, UTXO_NAMESPACE, InflightOutputs,
+    NativeInflightOutputs, UtxoStore,
+)
 from .wire import (
     InvType,
     MsgAddr,
@@ -109,17 +112,22 @@ _native_extract_state: Optional[bool] = None
 
 
 def _parse_region(raw: bytes, n_txs: int, want_delta: bool,
-                  want_keys: bool = False):
+                  want_keys: bool = False, publish=None):
     """Worker-thread job: the ONE native parse of a message's tx region,
     and with it a block's UTXO delta (``utxo_ops``: 2-3 ms per 8,000 txs
     while the region is open, against a second parse of the block at
     connect time) and, where relay verdicts may answer for its txs
-    (ISSUE 27), each tx's hash over its full wire bytes.
+    (ISSUE 27), each tx's hash over its full wire bytes.  ``publish``
+    (a block of a node with a UTXO set, ISSUE 44) is given the open
+    region, here in the worker: the block's outputs are in the in-flight
+    view before the loop hears that the parse is done.
     -> (region, delta or None, wire hashes or None)."""
     from .txextract import ParsedTxRegion
 
     region = ParsedTxRegion(raw, n_txs)
     try:
+        if publish is not None:
+            publish(region)
         return (
             region,
             region.utxo_ops() if want_delta else None,
@@ -468,6 +476,21 @@ class Node:
         # is delivered again meanwhile — a refetch racing a slow peer, a
         # peer that sends a block twice — is dropped, not verified again
         self._blocks_taken: set[bytes] = set()
+        # outputs of blocks parsed and not yet connected, as a prevout
+        # source (ISSUE 44); a node with a UTXO set has it.  Memory only:
+        # a restart resumes at the watermark with the view empty
+        self._inflight: Optional[InflightOutputs] = None
+        if self.utxo is not None:
+            self._inflight = (
+                NativeInflightOutputs() if _native_extract_available()
+                else InflightOutputs()
+            )
+        # the order of resolves (:meth:`_resolve_gate`): blocks in flight
+        # whose own outputs and every predecessor's are in the view, and
+        # the resolves held back, by the block they wait for
+        self._gate_passed: set[bytes] = set()
+        self._gate_waiters: dict[bytes, list[asyncio.Future]] = {}
+        self._gate_held = 0  # blocks whose resolve waits
         self.mempool: Optional[Mempool] = (
             Mempool(
                 cfg.mempool,
@@ -921,7 +944,7 @@ class Node:
                 else {"enabled": False}
             ),
             "utxo": (
-                self.utxo.stats()
+                {**self.utxo.stats(), "inflight": self._inflight.stats()}
                 if self.utxo is not None
                 else {"enabled": False}
             ),
@@ -1072,25 +1095,31 @@ class Node:
         return "block"
 
     def _prevout_sources(self):
-        """``(mempool, utxo, embedder's prevout_lookup)``: the prevout
-        sources in their precedence, None where there is none to ask —
-        the mempool's unconfirmed outputs (a child spending an in-mempool
-        parent extracts with full prevout data; an empty mempool misses
-        every lookup and is left out), then the persistent UTXO store's
-        confirmed outputs (ISSUE 9), then ``cfg.prevout_lookup``."""
+        """``(mempool, in-flight view, utxo, embedder's prevout_lookup)``:
+        the prevout sources in their precedence, None where there is none
+        to ask — the mempool's unconfirmed outputs (a child spending an
+        in-mempool parent extracts with full prevout data; an empty
+        mempool misses every lookup and is left out), then the outputs of
+        blocks parsed and not yet connected (ISSUE 44; left out while no
+        block is in flight), then the persistent UTXO store's confirmed
+        outputs (ISSUE 9), then ``cfg.prevout_lookup``."""
         mempool = self.mempool
         if mempool is not None and not mempool.size():
             mempool = None
-        return mempool, self.utxo, self.cfg.prevout_lookup
+        inflight = self._inflight
+        if inflight is not None and not inflight.blocks:
+            inflight = None
+        return mempool, inflight, self.utxo, self.cfg.prevout_lookup
 
     def _prevout_oracle(self):
         """The prevout lookup the Python verify path consults: one call a
         row through :meth:`_prevout_sources` in order, the first answer
         that is not None wins.  None when nothing can answer."""
-        mempool, utxo, oracle = self._prevout_sources()
+        mempool, inflight, utxo, oracle = self._prevout_sources()
         sources = [
             lookup for lookup in (
                 mempool.lookup_prevout if mempool is not None else None,
+                inflight.lookup if inflight is not None else None,
                 utxo.lookup if utxo is not None else None,
                 oracle,
             ) if lookup is not None
@@ -1109,6 +1138,162 @@ class Node:
 
         return combined
 
+    # -- outputs of blocks in flight (ISSUE 44) -------------------------------
+
+    def _inflight_done(self, block_hash: bytes) -> None:
+        """The block is through — connected (its outputs were retired by
+        the connect), or let go without one: nothing of it stays in the
+        view, and a resolve that needs it waits for its re-delivery."""
+        if self._inflight is not None:
+            self._gate_passed.discard(block_hash)
+            # let go before it was published: who waits for it looks again
+            for fut in self._gate_waiters.pop(block_hash, ()):
+                self._gate_wake(fut, True)
+            if self._inflight.drop(block_hash):
+                # outputs left the view that no store holds: what was
+                # known of the blocks above them is known no more
+                self._gate_passed.clear()
+
+    def _gate_timeout(self) -> float:
+        """How long a block's resolve waits for a predecessor: by then
+        the planner has asked again for a head that is stuck
+        (``IbdConfig.refetch_after``), and that request has had as long
+        again to be answered."""
+        return 2 * (self.cfg.ibd or IbdConfig()).refetch_after
+
+    def _gate_missing(self, block) -> Optional[bytes]:
+        """The nearest block beneath ``block`` and above the UTXO
+        watermark whose outputs are not in the in-flight view; None when
+        there is none: every block between is published (parsed, its
+        outputs in the view) or connected (at or under the watermark; a
+        fresh set connects from height 1, so the genesis block counts;
+        a block at such a height on another branch does not: a reorg
+        beneath the watermark waits for the new branch's blocks), or the
+        walk reached a block that is no header of this chain, and nothing
+        orders the two.
+        What the walk learned is kept (``_gate_passed``): the next block
+        up looks at its predecessor alone."""
+        assert self._inflight is not None and self.utxo is not None
+        walked = []
+        prev = block.header.prev
+        while prev not in self._gate_passed:
+            beneath = self._inflight.prev_of(prev)
+            if beneath is None:  # not published
+                bn = self.chain.get_block(prev)
+                if bn is not None and bn.height and not self._covered(bn):
+                    return prev
+                break
+            walked.append(prev)
+            prev = beneath
+        self._gate_passed.update(walked)
+        return None
+
+    async def _resolve_gate(self, block, resolve=None):
+        """The order of resolves: a block's prevouts are read when every
+        block between the UTXO watermark and itself has published its
+        outputs to the in-flight view — parsed, not verified, not
+        connected: verification stays as overlapped as it was.  Blocks
+        are ordered by their header's place in the chain, whoever asked
+        for them (the planner, or a peer that pushes).
+
+        A block beneath that is here and not yet parsed is waited for: it
+        publishes within its parse.  One that has not come is waited for
+        only by a block that needs it: ``resolve(final=...)`` reads the
+        block's rows (the native path), and a block all of whose rows
+        have an answer goes on — an outpoint's value is fixed by its
+        txid, so whichever source answers says what the view would have
+        said, and a node whose callback answers every row, or a block
+        that spends nothing of the blocks it is ahead of, waits for
+        nobody.  A block with a row that no source answers yet waits for
+        the nearest block that is missing, is woken when that block
+        reaches this gate itself (or is let go), and is read again.
+        Without ``resolve`` (the Python path reads a row at a time,
+        inside its extraction) every block that is early waits.
+        -> what ``resolve`` returned, else None.
+
+        While it waits a block is no ingest pressure (as a parked block
+        is none: the planner must be free to ask again for the block it
+        waits for) and the engine has nothing of it (``ibd.head_wait``
+        sees the node as empty when every block it holds is here).  A
+        block that does not come ends the wait at :meth:`_gate_timeout`
+        (``node.resolve_gate_expired``; at once where
+        ``MAX_UTXO_PENDING`` blocks wait already): the block then
+        resolves as it would have before, and what no source answers is
+        counted ``node.resolve_missing``.  One ``node.resolve_gate`` span
+        a block, as long as the block waited (0 for most)."""
+        block_hash = block.header.hash
+        # published: the resolves that wait for this block look again
+        for fut in self._gate_waiters.pop(block_hash, ()):
+            self._gate_wake(fut, True)
+        rows = None
+        waited = 0.0
+        deadline = None
+        loop = asyncio.get_running_loop()
+        while True:
+            missing = self._gate_missing(block)
+            if missing is None:
+                self._gate_passed.add(block_hash)
+                break
+            if resolve is not None and missing not in self._blocks_taken:
+                rows = resolve(final=False)
+                if rows is not None:
+                    break  # nothing of the blocks it is ahead of is needed
+            began = loop.time()
+            if deadline is None:
+                deadline = began + (
+                    self._gate_timeout()
+                    if self._gate_held < self.MAX_UTXO_PENDING else 0.0
+                )
+            came = await self._gate_wait(missing, deadline)
+            waited += loop.time() - began
+            if not came:
+                metrics.inc("node.resolve_gate_expired")
+                events.emit(
+                    "node.resolve_gate_expired",
+                    block=block_hash[::-1].hex(),
+                    waited_for=missing[::-1].hex(),
+                )
+                # the blocks above it do not wait their own time over
+                self._gate_passed.add(block_hash)
+                break
+        record_span("node.resolve_gate", waited)
+        if rows is None and resolve is not None:
+            rows = resolve()
+        return rows
+
+    async def _gate_wait(self, missing: bytes, deadline: float) -> bool:
+        """Wait for block ``missing`` to reach the gate.  -> did it (or
+        was it let go: its re-delivery is the next to wait for); False at
+        ``deadline``."""
+        loop = asyncio.get_running_loop()
+        fut = loop.create_future()
+        self._gate_waiters.setdefault(missing, []).append(fut)
+        timer = loop.call_at(deadline, self._gate_wake, fut, False)
+        note = open_annotation("node.resolve_gate")
+        self._gate_held += 1
+        self._verify_pending -= 1
+        came = False
+        try:
+            came = await fut
+        finally:
+            self._verify_pending += 1
+            self._gate_held -= 1
+            if note is not None:
+                note.__exit__(None, None, None)
+            timer.cancel()
+            if not came:  # expired or cancelled: still on the list
+                waiters = self._gate_waiters.get(missing, [])
+                if fut in waiters:
+                    waiters.remove(fut)
+                if not waiters:
+                    self._gate_waiters.pop(missing, None)
+        return came
+
+    @staticmethod
+    def _gate_wake(fut: asyncio.Future, came: bool) -> None:
+        if not fut.done():
+            fut.set_result(came)
+
     # -- persistent UTXO block connect (ISSUE 9) ----------------------------
 
     def _persisted_height(self, block) -> Optional[int]:
@@ -1121,16 +1306,24 @@ class Node:
         if self.utxo is None:
             return None
         bn = self.chain.get_block(block.header.hash)
-        if bn is None or bn.height > self.utxo.height:
+        if bn is None or not self._covered(bn):
             return None
+        return bn.height
+
+    def _covered(self, bn) -> bool:
+        """Is the block of header node ``bn`` under the UTXO watermark,
+        on the watermark's branch?"""
+        assert self.utxo is not None
+        if bn.height > self.utxo.height:
+            return False
         if self.utxo.block_hash is not None:
             wm = self.chain.get_block(self.utxo.block_hash)
             if wm is None:
-                return None  # watermark block unknown here: re-verify
+                return False  # watermark block unknown here: re-verify
             anc = self.chain.get_ancestor(bn.height, wm)
             if anc is None or anc.hash != bn.hash:
-                return None  # different branch: not covered
-        return bn.height
+                return False  # different branch: not covered
+        return True
 
     def _block_taken(self, block_hash: bytes) -> bool:
         """Is this block in verification, or verified and parked?"""
@@ -1161,6 +1354,7 @@ class Node:
             # the chain has not accepted cannot be assigned a height
             metrics.inc("utxo.no_header")
             self._blocks_taken.discard(block.header.hash)
+            self._inflight_done(block.header.hash)
             return
         assert self.utxo is not None
         moved = False
@@ -1172,6 +1366,7 @@ class Node:
             parked = self._utxo_pending.get(bn.height)
             if parked is None or parked[0] is not block:
                 self._blocks_taken.discard(bn.hash)
+                self._inflight_done(bn.hash)
         if moved and self.ibd is not None:
             # the watermark may have moved: the planner retires finished
             # batches and schedules further ahead
@@ -1217,6 +1412,7 @@ class Node:
                     await self._utxo_apply_one(self.utxo.height + 1, *nxt)
                 finally:
                     self._blocks_taken.discard(nxt[0].header.hash)
+                    self._inflight_done(nxt[0].header.hash)
         return True
 
     # Bound on parked out-of-order block connects (blocks are held alive
@@ -1258,6 +1454,7 @@ class Node:
                 # (the fetch planner replans against the new best chain)
                 for parked, _ in self._utxo_pending.values():
                     self._blocks_taken.discard(parked.header.hash)
+                    self._inflight_done(parked.header.hash)
                 self._utxo_pending.clear()
                 bn = self.chain.get_block(block.header.hash)
                 expected = max(self.utxo.height + 1, 1)
@@ -1320,25 +1517,31 @@ class Node:
         (tests/test_utxo.py, tests/test_utxo_delta.py)."""
         assert self.utxo is not None
         utxo = self.utxo
+        inflight = self._inflight
         block_hash = block.header.hash
         raw = getattr(block, "raw_txs", None)
         native = delta is not None or (
             raw is not None and self._utxo_native()
         )
 
+        def apply():
+            if not native:
+                return utxo.apply_block(height, block_hash, list(block.txs))
+            ops = delta
+            if ops is None:
+                from .txextract import ParsedTxRegion
+
+                with ParsedTxRegion(raw, block.tx_count) as region:
+                    ops = region.utxo_ops()
+            return utxo.apply_ops_blob(height, block_hash, *ops)
+
         def connect():
             with span("utxo.connect", cpu=True):
-                if not native:
-                    return utxo.apply_block(
-                        height, block_hash, list(block.txs)
-                    )
-                ops = delta
-                if ops is None:
-                    from .txextract import ParsedTxRegion
-
-                    with ParsedTxRegion(raw, block.tx_count) as region:
-                        ops = region.utxo_ops()
-                return utxo.apply_ops_blob(height, block_hash, *ops)
+                applied = apply()
+            if applied and inflight is not None:
+                # the set answers for the block's outputs from here on:
+                # the view's copy goes, in this thread
+                inflight.retire(block_hash)
 
         if native:
             await self._run_extract(connect)
@@ -1543,7 +1746,8 @@ class Node:
         for peer, n in counts.items():
             self.cfg.pub.publish(VerifyShed(peer, n, pending))
 
-    def _resolve_ext_rows(self, region, bch: bool, subset=None):
+    def _resolve_ext_rows(self, region, bch: bool, subset=None,
+                          final: bool = True):
         """External-oracle rows for a parsed region: per-input amounts and
         scriptPubKeys, aligned with the region's flat input order —
         ``(amounts, -1 unknown; scripts, None unknown)``, two lists, or
@@ -1551,11 +1755,16 @@ class Node:
         wants gate marks are looked up.  ``subset`` (ascending tx
         indices): the rows of those txs alone, in that order — what
         ``extract_subset`` takes.  Shared by block and mempool ingest.
+        ``final=False`` (a block read ahead of one beneath it,
+        :meth:`_resolve_gate`): None, and nothing counted, where a row
+        has no answer yet — the block is read again in its turn.
 
         :meth:`_prevout_sources` answer in their precedence, a source at
         a time over the rows still unanswered, and the first answer that
-        is not None wins: the mempool's unconfirmed outputs and the UTXO
-        set in one batch read each, then the embedder's
+        is not None wins: the mempool's unconfirmed outputs, the outputs
+        of blocks in flight (a block's own among them: they are published
+        before its resolve) and the UTXO set in one batch read each, then
+        the embedder's
         ``cfg.prevout_lookup``, called with ``(bytes, int)`` once for
         every row left, in ascending row order.  Every column of the
         native scan is converted once a call and the rest is plain Python
@@ -1566,13 +1775,13 @@ class Node:
         under its caller's handler.  ONE hold of the loop: no ``await``
         between the first read and the last, and nothing kept from one
         call to the next."""
-        mempool, utxo, oracle = self._prevout_sources()
+        mempool, inflight, utxo, oracle = self._prevout_sources()
         if mempool is None and utxo is None and oracle is None:
             return None, None  # block ingest then skips the whole scan
         with span("node.resolve"):
             txids, outpoints, vouts, wants = region.scan_outpoints(bch, subset)
             todo = wants.nonzero()[0].tolist()  # wanted, unanswered yet
-            metrics.inc("node.resolve_rows", len(todo))
+            counts = [("node.resolve_rows", len(todo), None)]
             txids = _rows_of(txids)
             vouts = vouts.tolist()
             amounts = [-1] * len(vouts)
@@ -1601,16 +1810,38 @@ class Node:
                 ))
             if utxo is not None:
                 keys = _rows_of(outpoints)
-                todo = absorb(todo, utxo.lookup_many([keys[i] for i in todo]))
+                ask = [keys[i] for i in todo]
+                if inflight is not None and todo:
+                    answers = inflight.lookup_many(ask)
+                    hits = len(answers) - answers.count(None)
+                    counts += (
+                        ("node.resolve_inflight_rows", len(todo), None),
+                        ("node.resolve_inflight_hits", hits, None),
+                    )
+                    if hits:  # else every row goes on as it came
+                        todo = absorb(todo, answers)
+                        ask = [keys[i] for i in todo]
+                todo = absorb(todo, utxo.lookup_many(ask))
             if oracle is not None:
                 metrics.inc("node.resolve_oracle_calls", len(todo))
                 todo = absorb(todo, map(
                     oracle, [txids[i] for i in todo], [vouts[i] for i in todo]
                 ))
             if todo:
-                # rows no source answered: the extractor marks such an
-                # input unsupported and nothing verifies it
-                metrics.inc("node.resolve_missing", len(todo))
+                # the native scan marks an in-block spend as wanted like
+                # any other input, and the extractor's in-block map
+                # answers it before it looks at these rows: an outpoint
+                # of one of the region's own txs is no missing row
+                own = set(_hash_rows(region.txids()))
+                todo = [i for i in todo if txids[i] not in own]
+            if todo:
+                if not final:
+                    return None
+                # rows no source answered, the block itself included: the
+                # extractor marks such an input unsupported and nothing
+                # verifies it
+                counts.append(("node.resolve_missing", len(todo), None))
+            metrics.inc_batch(counts)
             return amounts, scripts
 
     def _submit_verify_tx(self, peer, tx) -> None:
@@ -2085,6 +2316,9 @@ class Node:
                     relay=relay,
                 )
 
+        # a block of a node with a UTXO set: a prevout source for the
+        # blocks after it, and in their order
+        gated = block is not None and self._inflight is not None
         block_txids: Optional[list[bytes]] = None
         subset = None  # a block's tx indices still to verify; None = all
         region: Optional[ParsedTxRegion] = None
@@ -2122,6 +2356,12 @@ class Node:
                         # looks up nothing
                         block is not None and self.mempool is not None
                         and self.mempool.finished() > 0,
+                        # its outputs go into the in-flight view there, in
+                        # the worker (ISSUE 44)
+                        functools.partial(
+                            self._inflight.publish_region,
+                            block.header.hash, block.header.prev,
+                        ) if gated else None,
                     )
                 except asyncio.CancelledError:
                     raise
@@ -2142,9 +2382,18 @@ class Node:
                 # shadows whatever the oracle would have said).  One hold
                 # for the whole message, before any job: every shard's
                 # rows are read from the sources at the same moment.
-                ext, ext_scripts = self._resolve_ext_rows(
-                    region, bch, subset
+                resolve = functools.partial(
+                    self._resolve_ext_rows, region, bch, subset
                 )
+                if gated:
+                    # later blocks may spend this one's outputs, and this
+                    # one those of the blocks beneath it: a row that has
+                    # no answer is read in the chain's order
+                    ext, ext_scripts = await self._resolve_gate(
+                        block, resolve
+                    )
+                else:
+                    ext, ext_scripts = resolve()
                 if block_txids is not None:
                     # block connect: evict confirmed txs from the mempool —
                     # the whole block's, answered from relay or not.  The
@@ -2244,6 +2493,7 @@ class Node:
             if block is not None and not connecting:
                 # not on its way to the UTXO set: a re-delivery verifies
                 self._blocks_taken.discard(block.header.hash)
+                self._inflight_done(block.header.hash)
             if region is not None and not submitted:
                 region.close()
             # cancelled (or crashed) mid-way: queued jobs never run, and a
@@ -2423,12 +2673,23 @@ class Node:
         # (amount + script) digests need (VERDICT r2 item 5 / r4 item 3).
         # Misses fall through to cfg.prevout_lookup.
         block_outs = intra_block_prevouts(txs) if len(txs) > 1 else {}
-        oracle = self._prevout_oracle()
         per_tx: list[tuple[Tx, ExtractStats, list, Optional[asyncio.Task]]] = []
         clean = True  # no extract/engine error verdicts published
         connecting = False
         try:
             with span("node.extract"):
+                if block is not None and self._inflight is not None:
+                    # as the native path: this block's outputs are a
+                    # prevout source for the blocks after it, and its own
+                    # prevouts are read in the chain's order (ISSUE 44)
+                    try:
+                        self._inflight.publish_txs(
+                            block.header.hash, block.header.prev, txs
+                        )
+                    except Exception:
+                        pass  # a malformed tx: the walk below reports it
+                    await self._resolve_gate(block)
+                oracle = self._prevout_oracle()
                 for tx in txs:
                     try:
                         # everything touching tx attributes goes inside the
@@ -2545,6 +2806,7 @@ class Node:
         finally:
             if block is not None and not connecting:
                 self._blocks_taken.discard(block.header.hash)
+                self._inflight_done(block.header.hash)
             self._verify_pending -= 1
             for _, _, _, task in per_tx:
                 if task is not None and not task.done():

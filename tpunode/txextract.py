@@ -181,6 +181,36 @@ def load_txextract_lib() -> ctypes.CDLL:
             ctypes.c_void_p, ctypes.c_uint8, ctypes.c_long, u8,
             ctypes.POINTER(ctypes.c_long), ctypes.POINTER(ctypes.c_long),
         ]
+        # the in-flight output view (tpunode/utxo.py, ISSUE 44)
+        lib.txx_view_new.restype = ctypes.c_void_p
+        lib.txx_view_new.argtypes = []
+        lib.txx_view_free.restype = None
+        lib.txx_view_free.argtypes = [ctypes.c_void_p]
+        lib.txx_view_size.restype = ctypes.c_long
+        lib.txx_view_size.argtypes = [ctypes.c_void_p]
+        lib.txx_view_publish_h.restype = ctypes.c_long
+        lib.txx_view_publish_h.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_char_p,
+        ]
+        lib.txx_view_publish_rows.restype = ctypes.c_long
+        lib.txx_view_publish_rows.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_long,
+            ctypes.c_char_p, i64, i64, ctypes.c_char_p,
+        ]
+        lib.txx_view_forget.restype = ctypes.c_long
+        lib.txx_view_forget.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
+        # the event loop's call: made with the interpreter lock held
+        # (PyDLL).  A call that gives it up hands it to one of the six
+        # threads that wait for it, and the loop queues for its turn to
+        # get it back: 170 us a 128-row batch on the chip's host against
+        # the 8 us the call takes (PERF.md §6, PR 44)
+        lookup = ctypes.PyDLL(_LIB_PATH).txx_view_lookup
+        lookup.restype = ctypes.c_long
+        lookup.argtypes = [  # addresses: no check a call
+            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_long, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_long,
+        ]
+        lib.txx_view_lookup_held = lookup
         lib.txx_txids_h.restype = ctypes.c_long
         lib.txx_txids_h.argtypes = [ctypes.c_void_p, u8]
         lib._ext_amounts_t = i64  # kept for callers building arrays
@@ -704,6 +734,14 @@ class ParsedTxRegion:
         if n < 0:
             raise ValueError(f"txx_utxo_ops_h failed ({n})")
         return buf[:n].tobytes(), int(created.value), int(spent.value)
+
+    def publish_outputs(self, view, block_hash: bytes) -> int:
+        """Every output of the region into the native in-flight output
+        view ``view`` (``txx_view_new``'s pointer) as block
+        ``block_hash``'s, in one call that holds no interpreter lock.
+        -> the rows added."""
+        assert self._h, "region closed"
+        return self._lib.txx_view_publish_h(view, self._h, block_hash)
 
     def txids(self) -> np.ndarray:
         """All parsed txids as an ``(n_txs, 32)`` uint8 array — no Python

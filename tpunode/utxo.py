@@ -48,6 +48,16 @@ chain.  No undo record (there is no block to disconnect), the watermark
 in the last batch; a crash mid-load leaves a marker and no watermark, and
 the next load starts over (ROBUSTNESS.md).
 
+Between a block's parse and its connect its outputs are in neither the
+mempool nor this set, while later blocks of a real chain spend them: most
+outputs are spent within blocks of being made (Bitcoin Core's coin cache
+marks such a coin ``FRESH``; ``ConnectBlock`` reads inputs from a view that
+already holds every earlier block's outputs).  :class:`InflightOutputs`
+is that view for the blocks a node has parsed and not yet connected
+(ISSUE 44): memory only, filled from the block's parse, emptied by the
+connect — after a restart the node resumes at the watermark and the
+blocks above it publish their outputs again as they are re-fetched.
+
 Schema (within the namespaced view): ``b"o" + txid + vout_le32`` ->
 ``amount_le64 + scriptPubKey``; ``b"!wm"`` -> ``height_le64 + block_hash``;
 ``b"U" + height_le64`` -> undo record; ``b"!ld"`` -> a snapshot load has
@@ -59,6 +69,10 @@ from __future__ import annotations
 import struct
 from typing import Iterable, Optional, Sequence
 
+import numpy as np
+
+from . import threadsan
+
 from .events import events
 from .metrics import metrics
 from .store import (
@@ -68,7 +82,8 @@ from .store import (
 from .trace import span
 
 __all__ = [
-    "UtxoStore", "UTXO_NAMESPACE", "UNDO_DEPTH_DEFAULT", "snapshot_batch",
+    "UtxoStore", "InflightOutputs", "NativeInflightOutputs",
+    "UTXO_NAMESPACE", "UNDO_DEPTH_DEFAULT", "snapshot_batch",
 ]
 
 #: The namespace the node mounts the UTXO set under on its main store.
@@ -542,3 +557,213 @@ class UtxoStore:
             "spent": metrics.get("utxo.spent"),
             "disconnected": metrics.get("utxo.disconnected"),
         }
+
+
+class InflightOutputs:
+    """Outpoint -> ``(amount, scriptPubKey)`` for every output created by
+    a block that is parsed and not yet connected (ISSUE 44): the prevout
+    source between the mempool and the UTXO set.
+
+    A block's outputs are *published* by the job that parses it, in its
+    worker thread, *retired* by the connect once the store holds them,
+    and *dropped* on every path that lets the block go without one.  An
+    outpoint's value is fixed by its txid, so the view never holds a
+    wrong answer, only one that is there or not: the connect makes the
+    store's copy visible before it retires the view's, and a reader that
+    asks the view first and the store second finds the output in one of
+    them.  The fetch planner's ``max_lead`` (and the node's
+    ``MAX_VERIFY_PENDING``) bound how many blocks are here at once.
+
+    This class keeps the rows in a dict, a Python statement an output:
+    what a node without the native library has (it parses its blocks in
+    Python too).  Where the library loads the node keeps a
+    :class:`NativeInflightOutputs`."""
+
+    def __init__(self):
+        self._lock = threadsan.lock("utxo.inflight")
+        self._prev: dict[bytes, bytes] = {}  # block -> the block beneath
+        self._index: dict[bytes, tuple[int, bytes]] = {}
+        self._keys: dict[bytes, list[bytes]] = {}  # block -> its outpoints
+        # two blocks in flight made the same outpoint (the same tx on two
+        # branches): forgetting one must leave the other's rows indexed
+        self._overlap = False
+
+    def __len__(self) -> int:
+        return len(self._index)
+
+    @property
+    def blocks(self) -> int:
+        return len(self._prev)
+
+    def prev_of(self, block_hash: bytes) -> Optional[bytes]:
+        """The block beneath a block whose outputs are here; None for a
+        block that has none here."""
+        return self._prev.get(block_hash)
+
+    # -- writers (worker threads) ---------------------------------------------
+
+    def publish_txs(
+        self, block_hash: bytes, prev: bytes, txs: Sequence
+    ) -> None:
+        """A block's outputs from its parsed txs; ``prev`` the hash of the
+        block beneath it.  A block delivered again: the newer parse's."""
+        rows = {
+            tx.txid + _U32.pack(vout): (out.value, out.script)
+            for tx in txs
+            for vout, out in enumerate(tx.outputs)
+        }
+        with self._lock:
+            self._forget(block_hash)
+            self._prev[block_hash] = prev
+            self._install(block_hash, rows)
+        metrics.inc("node.inflight_outputs_added", len(rows))
+
+    def retire(self, block_hash: bytes) -> int:
+        """The block is connected: the store answers for its outputs."""
+        with self._lock:
+            n = self._forget(block_hash)
+        if n:
+            metrics.inc("node.inflight_outputs_retired", n)
+        return n
+
+    def drop(self, block_hash: bytes) -> int:
+        """The block left without a connect (its verification failed, it
+        was parked past the bound, a reorg unwound beneath it, its connect
+        failed).  Nothing to do where it was retired or never published
+        (the loop asks for every block that is through: no lock then)."""
+        if block_hash not in self._prev:
+            return 0
+        with self._lock:
+            n = self._forget(block_hash)
+        if n:
+            metrics.inc("node.inflight_outputs_dropped", n)
+        return n
+
+    def _install(self, block_hash: bytes, rows: dict) -> None:
+        before = len(self._index)
+        self._index.update(rows)
+        if len(self._index) - before != len(rows):
+            self._overlap = True
+        self._keys[block_hash] = list(rows)
+
+    def _forget(self, block_hash: bytes) -> int:
+        if self._prev.pop(block_hash, None) is None:
+            return 0
+        keys = self._keys.pop(block_hash)
+        gone = {key: self._index.pop(key, None) for key in keys}
+        if self._overlap:
+            # the rows another block made too are that block's again
+            left = sum(map(len, self._keys.values()))
+            for other in self._keys.values():
+                self._index.update(
+                    (key, gone[key]) for key in other if key in gone
+                )
+            self._overlap = len(self._index) != left
+        return len(keys)
+
+    def stats(self) -> dict:
+        return {
+            "blocks": self.blocks,
+            "outputs": len(self),
+            "added": metrics.get("node.inflight_outputs_added"),
+            "retired": metrics.get("node.inflight_outputs_retired"),
+            "dropped": metrics.get("node.inflight_outputs_dropped"),
+        }
+
+    # -- readers (the loop) -----------------------------------------------------
+
+    def lookup(self, txid: bytes, vout: int) -> Optional[tuple[int, bytes]]:
+        """The prevout-oracle callable, as ``UtxoStore.lookup``."""
+        return self._index.get(txid + _U32.pack(vout))
+
+    def lookup_many(
+        self, outpoints: Sequence[bytes]
+    ) -> list[Optional[tuple[int, bytes]]]:
+        """:meth:`lookup` for every outpoint (its 36 wire bytes), in
+        order."""
+        return list(map(self._index.get, outpoints))
+
+
+class NativeInflightOutputs(InflightOutputs):
+    """:class:`InflightOutputs` with its rows in the native library
+    (``txx_view_*``, native/txextract): a block is published straight
+    from its parse handle in one call that holds no interpreter lock —
+    nothing here costs a Python statement an output, at 66k outputs a
+    32 MB block — and a batch of lookups is one call."""
+
+    def __init__(self):
+        from .txextract import load_txextract_lib
+
+        super().__init__()
+        self._lib = load_txextract_lib()
+        self._view = self._lib.txx_view_new()
+        # the loop's lookups write here: one reader, nothing allocated a call
+        self._rows(256)
+        self._scripts = np.empty(1 << 16, np.uint8)
+
+    def _rows(self, n: int) -> None:
+        self._hit = np.empty(n, np.uint8)
+        self._amounts = np.empty(n, np.int64)
+        self._ends = np.empty(n, np.int64)
+        self._to = tuple(
+            a.ctypes.data for a in (self._hit, self._amounts, self._ends)
+        )
+
+    def __del__(self):
+        view, self._view = getattr(self, "_view", None), None
+        if view:
+            self._lib.txx_view_free(view)
+
+    def __len__(self) -> int:
+        return self._lib.txx_view_size(self._view)
+
+    def publish_region(self, block_hash: bytes, prev: bytes, region) -> None:
+        """A block's outputs from its open parse (``ParsedTxRegion``)."""
+        # the library has a lock of its own: nothing of this class's is
+        # held across a call that takes milliseconds for a large block
+        n = region.publish_outputs(self._view, block_hash)
+        self._prev[block_hash] = prev
+        metrics.inc("node.inflight_outputs_added", n)
+
+    def _install(self, block_hash: bytes, rows: dict) -> None:
+        scripts = [script for _, script in rows.values()]
+        self._lib.txx_view_publish_rows(
+            self._view, block_hash, len(rows), b"".join(rows),
+            np.array([amount for amount, _ in rows.values()], np.int64),
+            np.cumsum([len(s) for s in scripts], dtype=np.int64),
+            b"".join(scripts),
+        )
+
+    def _forget(self, block_hash: bytes) -> int:
+        self._prev.pop(block_hash, None)
+        return self._lib.txx_view_forget(self._view, block_hash)
+
+    def lookup(self, txid: bytes, vout: int) -> Optional[tuple[int, bytes]]:
+        return self.lookup_many([txid + _U32.pack(vout)])[0]
+
+    def lookup_many(
+        self, outpoints: Sequence[bytes]
+    ) -> list[Optional[tuple[int, bytes]]]:
+        n = len(outpoints)
+        if len(self._hit) < n:
+            self._rows(2 * n)
+        keys = b"".join(outpoints)
+        while True:
+            hits = self._lib.txx_view_lookup_held(
+                self._view, keys, n, *self._to,
+                self._scripts.ctypes.data, len(self._scripts),
+            )
+            if hits >= 0:
+                break
+            self._scripts = np.empty(-2 * hits, np.uint8)
+        if not hits:
+            return [None] * n
+        ends = self._ends[:n].tolist()
+        scripts = self._scripts[: ends[-1]].tobytes()
+        return [
+            (amount, scripts[lo:hi]) if hit else None
+            for hit, amount, lo, hi in zip(
+                self._hit[:n].tolist(), self._amounts[:n].tolist(),
+                [0] + ends, ends,
+            )
+        ]
