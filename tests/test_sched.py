@@ -323,6 +323,31 @@ async def test_ibd_between_the_shapes_is_cut_at_the_small_one(pending):
     assert _cut_counts() == {'sched.lanes_cut_full{priority="ibd"}': 1}
 
 
+@pytest.mark.asyncio
+async def test_an_ibd_batch_is_a_full_lane_and_a_remainder_that_lingers():
+    """The IBD cells' turn (PERF.md §5, PR 45): 24 blocks of 176 items
+    are one lane of 4,096 and one of 128 that waits its class's whole
+    linger after the LAST block's enqueue, whatever comes or does not."""
+    metrics.reset()
+    p = LanePacker(small=SMALL, max_wait=WAIT)
+    for i in range(24):  # the burst leaves the extract pool inside 24 ms
+        p.push(_sub(176, "ibd", enqueued=10.0 + i * 0.001))
+        d = p.decide(BIG, 10.0 + i * 0.001)
+        assert d.cut == ("full" if i == 23 else None)
+    assert p.cut(BIG, now=10.023).total == SMALL
+    assert p.pending() == 24 * 176 - SMALL == 128
+    d = p.decide(BIG, 10.023)
+    assert d.cut is None and d.wait == pytest.approx(IBD)
+    assert p.decide(BIG, 10.023 + IBD - 1e-4).cut is None
+    assert p.decide(BIG, 10.023 + IBD)[:3] == ("deadline", "ibd", SMALL)
+    lane = p.cut(BIG, now=10.023 + IBD)
+    assert lane.total == 128 and lane.target == SMALL and p.pending() == 0
+    assert _cut_counts() == {
+        'sched.lanes_cut_full{priority="ibd"}': 1,
+        'sched.lanes_cut_deadline{priority="ibd"}': 1,
+    }
+
+
 @pytest.mark.parametrize("cls,pending,want", [
     ("ibd", BIG, ("full", BIG)),
     ("ibd", BIG + 10, ("full", BIG)),
