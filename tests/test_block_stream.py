@@ -293,3 +293,233 @@ def test_how_many_jobs_a_block_is_cut_into(n, workers, jobs):
         MIN_SHARD_TXS=node_mod.Node.MIN_SHARD_TXS,
         STREAM_SHARD_TXS=node_mod.Node.STREAM_SHARD_TXS)
     assert node_mod.Node._n_extract_jobs(node, n) == jobs
+
+
+# ---- the jobs leave from inside the prevout walk (ISSUE 46) ----------------
+
+STREAM = ("node.stream_blocks", "node.stream_jobs", "node.stream_jobs_in_walk",
+          "span.node.prefix.count", "span.node.resolve.count")
+
+
+def counters() -> dict:
+    return {k: metrics.get(k) for k in STREAM}
+
+
+def moved(before: dict) -> dict:
+    return {k: int(metrics.get(k) - v) for k, v in before.items()}
+
+
+def watch_the_walk(monkeypatch, d) -> list:
+    """-> a log of ``("walk",)`` where a prevout walk makes its first read
+    — a callback is put on the loop's queue there — and
+    ``("job", did that callback run, node.resolve entries closed so far)``
+    for every extract job given to the pool."""
+    log: list = []
+    loop = asyncio.get_running_loop()
+    ran: list = []
+    sources, submit = d.node._prevout_sources, d.node._extract_pool.submit
+
+    def first_read():
+        ran.clear()
+        loop.call_soon(ran.append, True)
+        log.append(("walk",))
+        return sources()
+
+    def submitted(fn, *args, **kw):
+        if fn is node_mod._extract_counted:
+            log.append(("job", bool(ran),
+                        metrics.get("span.node.resolve.count")))
+        return submit(fn, *args, **kw)
+
+    monkeypatch.setattr(d.node, "_prevout_sources", first_read)
+    monkeypatch.setattr(d.node._extract_pool, "submit", submitted)
+    return log
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("utxo", [False, True], ids=["plain", "gated"])
+async def test_every_job_is_in_the_pool_before_the_walks_hold_ends(
+        utxo, monkeypatch):
+    """ONE hold: no callback of the loop runs between the walk's first read
+    and the last job's submission, and ``node.resolve`` is still open at
+    each; the verdicts are the unsharded ones."""
+    blk, oracle, expect, order = a_block(46)
+    cut_into(monkeypatch, 4)
+    async with asyncio.timeout(120):
+        async with a_node(oracle=oracle, utxo=utxo, port=17946) as d:
+            log = watch_the_walk(monkeypatch, d)
+            c0 = counters()
+            got, subs, _ = await d.block(blk)
+            assert moved(c0) == {
+                "node.stream_blocks": 1, "node.stream_jobs": 4,
+                "node.stream_jobs_in_walk": 3, "span.node.prefix.count": 1,
+                "span.node.resolve.count": 1}
+            assert metrics.get("span.node.prefix.seconds") > 0
+    assert log[0] == ("walk",) and len(log) == 5
+    open_at = c0["span.node.resolve.count"]
+    assert log[1:] == [("job", False, open_at)] * 4
+    assert len(subs) == 4 and [v.txid for v in got] == order
+    for v in got[1:]:
+        assert v.error is None and tuple(v.verdicts) == expect[v.txid]
+
+
+@pytest.mark.asyncio
+async def test_a_block_read_ahead_of_one_beneath_it_submits_after_the_read(
+        monkeypatch):
+    """The gate's ``resolve(final=False)`` hands nothing on; where it has
+    every answer the jobs go right after it, all at once."""
+    first, _, _, _ = a_block(47)
+    job = gen.gen_job(gen.jobs_for(MIX, 48, N_TXS, N_TXS)[0])
+    oracle = gen.Oracle()
+    oracle.p2pk.update(job["p2pk"])
+    blk = block_of(job["raw"], height=2, prev=first.header.hash)
+    expect = dict(zip(job["txids"], job["expect"]))
+    cut_into(monkeypatch, 4)
+    finals: list = []
+    walk = node_mod.Node._resolve_ext_rows
+
+    def recorded(self, *a, final=True, **kw):
+        finals.append(final)
+        return walk(self, *a, final=final, **kw)
+
+    monkeypatch.setattr(node_mod.Node, "_resolve_ext_rows", recorded)
+    async with asyncio.timeout(120):
+        async with a_node(oracle=oracle, utxo=True, port=17947) as d:
+            d.node.chain.headers(d.peer, [first.header, blk.header])
+            await poll_until(
+                lambda: d.node.chain.get_block(blk.header.hash) is not None,
+                what="header import")
+            log = watch_the_walk(monkeypatch, d)
+            c0 = counters()
+            got, subs, _ = await d.block(blk)  # its parent has not come
+            assert moved(c0) == {
+                "node.stream_blocks": 1, "node.stream_jobs": 4,
+                "node.stream_jobs_in_walk": 0, "span.node.prefix.count": 1,
+                "span.node.resolve.count": 1}
+    assert finals == [False]  # one read, ahead of its turn, and it sufficed
+    # the four jobs went after node.resolve closed, and still in its hold
+    assert log == [("walk",)] + [
+        ("job", False, c0["span.node.resolve.count"] + 1)] * 4
+    assert len(subs) == 4 and len(got) == N_TXS + 1
+    for v in got[1:]:
+        assert v.error is None and tuple(v.verdicts) == expect[v.txid]
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("jobs_out", [0, 2])
+async def test_a_callback_that_raises_mid_walk_ends_the_message_with_no_verdict(
+        jobs_out, monkeypatch, gate):
+    """The embedder's callback raises with ``jobs_out`` of the block's four
+    jobs in the pool: the block's task ends as it always did on such an
+    error — crashed and counted, no verdict, no connect, nothing taken —
+    and the jobs that were out are cancelled or run out, the last of them
+    closing the region, once."""
+    blk, oracle, expect, order = a_block(49)
+    cut_into(monkeypatch, 4)  # runs of 23 txs
+    with ParsedTxRegion(blk.raw_txs, blk.tx_count) as region:
+        wants = region.scan_outpoints(True)[3]
+        answered = int(wants[: int(region.input_offsets()[23 * jobs_out])].sum())
+    calls = 0
+
+    def failing(txid, vout):
+        nonlocal calls
+        calls += 1
+        if calls > answered + 1:  # in the next shard's first rows
+            raise LookupError("embedder down")
+        return oracle(txid, vout)
+
+    jobs = gate(hold_from=0)
+    crashes0 = metrics.get("node.verify_task_crashes")
+    async with asyncio.timeout(120):
+        async with a_node(oracle=failing, utxo=True, port=17948) as d:
+            d.node.chain.headers(d.peer, [blk.header])
+            await poll_until(
+                lambda: d.node.chain.get_block(blk.header.hash) is not None,
+                what="header import")
+            futures: list = []
+            submit = d.node._extract_pool.submit
+
+            def kept(fn, *args, **kw):
+                futures.append((fn, submit(fn, *args, **kw)))
+                return futures[-1][1]
+
+            monkeypatch.setattr(d.node._extract_pool, "submit", kept)
+            c0 = counters()
+            offer(d, blk)
+            await poll_until(
+                lambda: metrics.get("node.verify_task_crashes") > crashes0,
+                what="the block's task ends")
+            assert d.node._verify_pending == 0
+            assert not d.node._block_taken(blk.header.hash)
+            assert len(d.node._inflight) == 0
+            out = [f for fn, f in futures if fn is node_mod._extract_counted]
+            assert len(out) == jobs_out
+            assert moved(c0)["node.stream_jobs"] == jobs_out
+            if jobs_out:
+                await poll_until(lambda: 0 in jobs.started, what="job 0 runs")
+                assert jobs.closes == 0  # under a live extract: not closed
+            jobs.release.set()
+            await poll_until(lambda: jobs.closes == 1, what="region closed")
+            assert all(f.done() for f in out)  # run out, or cancelled queued
+            assert await settled(d, 0) == []  # dropped: no verdict at all
+            assert d.node.utxo.height == -1
+            assert jobs.closes == 1
+            assert not [t for t in d.node._verify_tasks.children
+                        if t.get_name() in ("verify-txs", "verify-shard-commit")]
+    assert metrics.get("node.verify_task_crashes") - crashes0 == 1
+
+
+@pytest.mark.asyncio
+async def test_a_cut_that_fails_leaves_one_error_verdict_a_tx(
+        monkeypatch, gate):
+    """The cut is made between the parse and the walk (the layout, the
+    row offsets): where it raises, the block ends as where its parse
+    raises — one error verdict a tx, the region closed once, no connect."""
+    blk, oracle, _, order = a_block(51)
+    cut_into(monkeypatch, 4)
+    jobs = gate(hold_from=10 ** 6)
+
+    def no_layout(self):
+        raise MemoryError("no layout")
+
+    monkeypatch.setattr(ParsedTxRegion, "input_offsets", no_layout)
+    async with asyncio.timeout(120):
+        async with a_node(oracle=oracle, utxo=True, port=17950) as d:
+            d.node.chain.headers(d.peer, [blk.header])
+            await poll_until(
+                lambda: d.node.chain.get_block(blk.header.hash) is not None,
+                what="header import")
+            c0 = counters()
+            offer(d, blk)
+            got = await settled(d, 91)
+            await poll_until(lambda: d.node._verify_pending == 0,
+                             what="the block's task ends")
+            assert jobs.closes == 1 and not jobs.started
+            assert moved(c0)["node.stream_jobs"] == 0
+            assert d.node.utxo.height == -1
+            assert not d.node._block_taken(blk.header.hash)
+    assert sorted(v.txid for v in got) == sorted(order)  # one a tx
+    assert all(v.error == "extract: no layout" and not v.valid for v in got)
+
+
+@pytest.mark.asyncio
+async def test_a_64_tx_block_is_one_job_after_the_walk_and_counts_nothing(
+        monkeypatch):
+    job = gen.gen_job(gen.jobs_for(MIX, 50, 63, 63)[0])
+    oracle = gen.Oracle()
+    oracle.p2pk.update(job["p2pk"])
+    blk = block_of(job["raw"])
+    async with asyncio.timeout(120):
+        async with a_node(oracle=oracle, utxo=True, port=17949) as d:
+            assert d.node._n_extract_jobs(64) == 1
+            log = watch_the_walk(monkeypatch, d)
+            c0 = counters()
+            got, subs, _ = await d.block(blk)
+            assert moved(c0) == {
+                "node.stream_blocks": 0, "node.stream_jobs": 0,
+                "node.stream_jobs_in_walk": 0, "span.node.prefix.count": 0,
+                "span.node.resolve.count": 1}
+    # the walk, closed, and then the one job: in the same hold
+    assert log == [("walk",), ("job", False, c0["span.node.resolve.count"] + 1)]
+    assert len(subs) == 1 and len(got) == 64
+    assert all(v.error is None for v in got)

@@ -241,7 +241,12 @@ async def test_blocks_out_of_height_order_verify_as_in_order(path, monkeypatch):
             d.give(ch.block(h))
             await asyncio.sleep(0.002)
         await d.verdicts_of(txids)
-        await poll_until(lambda: d.node.utxo.height == n, what="connects")
+        # the connect's thread retires a block's outputs after the height
+        # is up: wait for the view too before its counters are read
+        await poll_until(
+            lambda: d.node.utxo.height == n
+            and delta(before)["node.inflight_outputs_retired"] >= n * (2 * PER + 1),
+            what="connects")
         got = delta(before)
         for t in txids:
             v = d.verdicts[t]
@@ -304,7 +309,10 @@ async def test_blocks_held_at_the_gate_are_no_pressure_and_no_work_in_hand():
         assert node.utxo.height == 0
         d.give(ch.block(1))
         await d.verdicts_of(txids)
-        await poll_until(lambda: node.utxo.height == n, what="connects")
+        # (the connect's thread retires a block's outputs after the height
+        # is up: the view empties a moment later)
+        await poll_until(lambda: node.utxo.height == n and d.clear_view(),
+                         what="connects")
         got = delta(before)
         assert all(tuple(d.verdicts[t].verdicts) == ch.expect[t] for t in txids)
         assert got["node.resolve_missing"] == 0
@@ -400,7 +408,8 @@ async def test_a_dropped_block_leaves_the_view_and_comes_back():
             d.give(ch.block(h))
         txids = [t for ids in ch.txids[2:5] for t in ids]
         await d.verdicts_of(txids)
-        await poll_until(lambda: d.node.utxo.height == 5, what="connects")
+        await poll_until(lambda: d.node.utxo.height == 5 and d.clear_view(),
+                         what="connects")
         for t in txids:
             assert tuple(d.verdicts[t].verdicts) == ch.expect[t]
         got = delta(before)

@@ -11,6 +11,8 @@ from __future__ import annotations
 
 import random
 
+import numpy as np
+
 import pytest
 
 from benchmarks.txgen import gen_signed_txs
@@ -637,8 +639,13 @@ async def test_node_publishes_the_reference_walks_verdicts(monkeypatch):
     for port, (name, walk) in enumerate(walks.items(), 17930):
         forms = []
 
-        def recorded(self, region, bch, subset=None, _walk=walk, _forms=forms):
-            out = _walk(self, region, bch, subset)
+        def recorded(self, region, bch, subset=None, _walk=walk, _forms=forms,
+                     **kw):
+            # the walk as it stood knows no shards (ISSUE 46): the block's
+            # jobs then all go after it, as they did
+            if _walk is reference_walk:
+                kw.pop("shards", None)
+            out = _walk(self, region, bch, subset, **kw)
             _forms.append(type(out[0]).__name__)
             return out
 
@@ -662,3 +669,309 @@ async def test_node_publishes_the_reference_walks_verdicts(monkeypatch):
     for txid, valid, verdicts, _, error in relay + block[1:]:
         assert error is None and verdicts == tuple(expect[txid])
         assert valid == all(expect[txid])
+
+
+# ---------------------------------------------------------------------------
+# a block's extract jobs leave from inside the walk (ISSUE 46): each shard's
+# job is in the pool the moment that shard's rows are answered, and what the
+# jobs extract is what one job after the whole walk extracted
+
+
+def _handed_on_shapes() -> dict:
+    import numpy as np
+
+    from benchmarks.txgen import gen_mixed_txs
+    from chipbench import wirefmt as w
+    from tests.test_resolve_rows import _txs
+
+    return {  # name -> (raw txs, bch, subset)
+        "bch": lambda: ([w.coinbase(1)] + _txs(149, 46), True, None),
+        "bch-subset": lambda: (
+            [w.coinbase(2)] + _txs(149, 47), True,
+            np.array([0] + list(range(2, 150, 3)), np.int32)),
+        "segwit": lambda: (
+            [tx.serialize() for tx in gen_mixed_txs(40, seed=0x46)], False,
+            None),
+    }
+
+
+_NEW_COUNTERS = ("node.stream_blocks", "node.stream_jobs",
+                 "node.stream_jobs_in_walk", "span.node.prefix.count")
+
+
+def _region_counting_closes(raws):
+    from tpunode.txextract import ParsedTxRegion
+
+    class Counted(ParsedTxRegion):
+        closes = 0
+
+        def close(self):
+            type(self).closes += bool(self._h)
+            super().close()
+
+    return Counted(b"".join(raws), len(raws)), Counted
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("sources", ["embedder", "utxo", "utxo+embedder"])
+@pytest.mark.parametrize("shape", ["bch", "bch-subset", "segwit"])
+async def test_jobs_handed_on_inside_the_walk_extract_the_serial_items(
+        shape, sources):
+    """Items, per-tx rows, the walk's two lists and the embedder's calls
+    (once a row, ascending) equal the serial order's: the whole walk, then
+    one job."""
+    import time
+    from concurrent.futures import ThreadPoolExecutor
+
+    import numpy as np
+
+    from tests.test_resolve_rows import ANSWERS, Embedder, a_node
+    from tpunode.metrics import metrics
+    from tpunode.node import Node, _ExtractJobs
+
+    raws, bch, subset = _handed_on_shapes()[shape]()
+    region, counted = _region_counting_closes(raws)
+    n_jobs = 4
+    serial_emb, emb = Embedder(ANSWERS["pair"]), Embedder(ANSWERS["pair"])
+    # the serial order: every row, then one job over all of them
+    ext, scr = Node._resolve_ext_rows(
+        a_node(region, bch, subset, sources, serial_emb), region, bch, subset)
+    if subset is None:
+        serial = region.extract(bch, True, ext, scr)
+    else:
+        region.build_intra()
+        serial = region.extract_subset(subset, bch, True, ext, scr)
+    before = {k: metrics.get(k) for k in _NEW_COUNTERS}
+    with ThreadPoolExecutor(max_workers=2) as pool:
+        region.build_intra()  # the parse job's part
+        jobs = _ExtractJobs(pool, region, bch, subset, n_jobs,
+                            time.perf_counter())
+        assert len(jobs.ranges) == n_jobs
+        got = Node._resolve_ext_rows(
+            a_node(region, bch, subset, sources, emb), region, bch, subset,
+            shards=jobs)
+        # every job went from inside the walk: nothing is left to submit
+        assert len(jobs.cfuts) == n_jobs
+        jobs.submit_rest(*got)
+        jobs.release()
+        assert len(jobs.cfuts) == len(jobs.jobs) == n_jobs
+        shards = [f.result(timeout=60) for f in jobs.cfuts]
+    assert got == (ext, scr)
+    assert emb.calls == serial_emb.calls
+    delta = {k: metrics.get(k) - v for k, v in before.items()}
+    assert delta == {
+        "node.stream_blocks": 1, "node.stream_jobs": n_jobs,
+        # with a callback the last shard's job alone leaves with no row
+        # left to ask; without one every shard's rows are answered at once
+        "node.stream_jobs_in_walk": n_jobs - 1 if "embedder" in sources else 0,
+        "span.node.prefix.count": 1,
+    }
+    merged = _merge_shards(shards)
+    assert merged.count == serial.count > 0
+    for name in _SHARD_ROWS:
+        assert np.array_equal(getattr(merged, name), getattr(serial, name)), name
+    assert np.array_equal(serial.item_tx, np.concatenate(
+        [s.item_tx + lo for s, (lo, _) in zip(shards, jobs.ranges)]))
+    # the last job out closed the region, once
+    assert counted.closes == 1 and not region._h
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("n_jobs", [1, 4])
+async def test_a_messages_jobs_and_items_are_freed_without_a_collection(n_jobs):
+    """A job's future keeps its done-callbacks: the one that lets go of
+    the region must not lead back to the jobs (a cycle would keep every
+    message's items until a collection, and make the collections longer:
+    ``gc.pause_share`` rose by a tenth to a quarter in every cell on a
+    first form)."""
+    import asyncio
+    import gc
+    import time
+    import weakref
+    from concurrent.futures import ThreadPoolExecutor
+
+    from tpunode.node import _ExtractJobs
+
+    raws, bch, _ = _handed_on_shapes()["bch"]()
+    gc.collect()
+    gc.disable()
+    try:
+        with ThreadPoolExecutor(max_workers=2) as pool:
+            region = txextract.ParsedTxRegion(b"".join(raws), len(raws))
+            region.build_intra()
+            jobs = _ExtractJobs(pool, region, bch, None, n_jobs)
+            jobs.submit_rest(None, None)
+            jobs.release()
+            assert all(i.n_txs for i in await asyncio.gather(*jobs.jobs))
+            gone = [weakref.ref(jobs)] + [weakref.ref(f) for f in jobs.cfuts]
+            del jobs
+            end = time.monotonic() + 10  # a worker lets its work item go
+            while any(r() is not None for r in gone) and time.monotonic() < end:
+                await asyncio.sleep(0.01)
+            assert [r() for r in gone] == [None] * (n_jobs + 1)
+            assert not region._h
+    finally:
+        gc.enable()
+
+
+def test_several_jobs_want_the_parse_jobs_intra_map():
+    """The map several jobs share is built off the loop, by the parse job:
+    a cut into more than one job over a region without it is refused, not
+    mended on the loop; one job builds its own."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from tpunode.node import _ExtractJobs
+
+    raws, bch, _ = _handed_on_shapes()["bch"]()
+    with ThreadPoolExecutor(max_workers=1) as pool:
+        with txextract.ParsedTxRegion(b"".join(raws), len(raws)) as region:
+            assert not region.intra_built
+            with pytest.raises(AssertionError, match="no intra map"):
+                _ExtractJobs(pool, region, bch, None, 4)
+            assert len(_ExtractJobs(pool, region, bch, None, 1).ranges) == 1
+            region.build_intra()
+            assert region.intra_built
+            assert len(_ExtractJobs(pool, region, bch, None, 4).ranges) == 4
+
+
+@pytest.mark.parametrize("callback", [True, False], ids=["callback", "no-callback"])
+def test_a_shard_is_handed_on_after_its_last_row_and_before_the_next_shards_first(
+        callback):
+    """The order of the walk's last leg: the callback's rows of shard k,
+    shard k's submission with its slice of the two lists, the rows of
+    shard k + 1.  Without a callback: the batch reads, then every shard."""
+    from types import SimpleNamespace
+
+    from tests.test_resolve_rows import (
+        ANSWERS, Embedder, a_node, wanted_outpoints)
+    from tpunode.node import Node
+
+    raws, bch, subset = _handed_on_shapes()["bch"]()
+    log: list = []
+
+    class Logged(Embedder):
+        def __call__(self, txid, vout):
+            log.append(("row", (txid, vout)))
+            return super().__call__(txid, vout)
+
+    with txextract.ParsedTxRegion(b"".join(raws), len(raws)) as region:
+        off = region.input_offsets()
+        cut = [0, 40, 41, 100, len(raws)]  # a shard of one tx among them
+        shards = SimpleNamespace(
+            rows=[(int(off[lo]), int(off[hi])) for lo, hi in zip(cut, cut[1:])],
+            submit=lambda k, amounts, scripts, in_walk=False: log.append(
+                ("job", k, list(amounts), list(scripts), in_walk)))
+        node = a_node(region, bch, subset, "utxo+embedder" if callback else "utxo",
+                      Logged(ANSWERS["pair"]))
+        amounts, scripts = Node._resolve_ext_rows(node, region, bch, shards=shards)
+        txids, _, vouts, wants = region.scan_outpoints(bch)
+        row_of = {(txids[i].tobytes(), int(vouts[i])): i
+                  for i in np.flatnonzero(wants).tolist()}
+        assert len(row_of) == len(wanted_outpoints(region, bch))
+    jobs = [e for e in log if e[0] == "job"]
+    assert [e[1] for e in jobs] == [0, 1, 2, 3]
+    for (_, k, a, s, in_walk), (fl, fh) in zip(jobs, shards.rows):
+        # a copy of the slice as the finished lists have it
+        assert a == amounts[fl:fh] and s == scripts[fl:fh]
+        assert in_walk == (callback and k < 3)
+    asked = [row_of[e[1]] for e in log if e[0] == "row"]
+    assert asked == sorted(set(asked))  # once a row, ascending
+    assert bool(asked) == callback
+    # between two submissions: exactly the rows of the later shard
+    k = -1
+    for e in log:
+        if e[0] == "job":
+            k = e[1]
+        else:
+            fl, fh = shards.rows[k + 1]
+            assert fl <= row_of[e[1]] < fh
+
+
+@pytest.mark.parametrize("answered", [True, False])
+def test_a_read_that_is_not_final_hands_nothing_on(answered):
+    """``final=False`` (a block read ahead of one beneath it): rows where
+    every row has its answer, None where one has not — and no job
+    submitted either way: the read may be made again."""
+    from types import SimpleNamespace
+
+    from tests.test_resolve_rows import ANSWERS, Embedder, a_node
+    from tpunode.node import Node
+
+    raws, bch, subset = _handed_on_shapes()["bch"]()
+    out: list = []
+    with txextract.ParsedTxRegion(b"".join(raws), len(raws)) as region:
+        off = region.input_offsets()
+        shards = SimpleNamespace(
+            rows=[(0, int(off[75])), (int(off[75]), int(off[-1]))],
+            submit=lambda *a, **kw: out.append(a))
+        emb = Embedder(ANSWERS["pair" if answered else "none"])
+        node = a_node(region, bch, subset, "utxo+embedder", emb)
+        got = Node._resolve_ext_rows(node, region, bch, final=False,
+                                     shards=shards)
+        assert (got is not None) == answered
+        assert out == [] and emb.calls
+        # the final read of the same rows hands both shards on
+        final = Node._resolve_ext_rows(node, region, bch, shards=shards)
+        assert [a[0] for a in out] == [0, 1]
+        if answered:
+            assert final == got
+
+
+def test_a_pool_that_takes_no_more_jobs_is_remembered_and_the_region_closed_once():
+    from concurrent.futures import ThreadPoolExecutor
+
+    from tpunode.node import _ExtractJobs
+
+    raws, bch, _ = _handed_on_shapes()["bch"]()
+    region, counted = _region_counting_closes(raws)
+    region.build_intra()
+    pool = ThreadPoolExecutor(max_workers=1)
+    pool.shutdown()
+    jobs = _ExtractJobs(pool, region, bch, None, 3)
+    jobs.submit_rest(None, None)
+    assert isinstance(jobs.refused, RuntimeError) and jobs.cfuts == []
+    assert counted.closes == 0  # the submitter still holds it
+    jobs.release()
+    jobs.release()
+    assert counted.closes == 1
+
+
+@pytest.mark.asyncio
+async def test_the_region_closes_once_and_under_no_live_job_whoever_lets_go_last():
+    """The submitter's hold and the jobs' done-callbacks meet on one
+    count from several threads: more workers than cores, the interpreter
+    switching every 10 µs, the submitter letting go before, between and
+    after the jobs' ends — each region is closed exactly once, and no job
+    finds it closed."""
+    import sys
+    import time
+    from concurrent.futures import ThreadPoolExecutor, wait
+
+    from tpunode.node import _ExtractJobs
+
+    raws, bch, _ = _handed_on_shapes()["segwit"]()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    deadline = time.monotonic() + 20
+    try:
+        with ThreadPoolExecutor(max_workers=32) as pool:
+            for round_ in range(60):
+                region, counted = _region_counting_closes(raws)
+                region.build_intra()
+                jobs = _ExtractJobs(pool, region, bch, None, 8)
+                for k in range(round_ % 9):  # some before the release,
+                    jobs.submit(k, None, None)
+                if round_ % 2:
+                    jobs.submit_rest(None, None)  # ... or all of them
+                cfuts = list(jobs.cfuts)
+                jobs.release()
+                done, pending = wait(cfuts, timeout=max(0.1, deadline - time.monotonic()))
+                assert not pending
+                for f in done:
+                    assert f.result().n_txs > 0  # "region closed" would raise
+                # a job's waiters hear of its end before its callbacks run
+                while region._h and time.monotonic() < deadline:
+                    time.sleep(0.001)
+                assert counted.closes == 1 and not region._h
+    finally:
+        sys.setswitchinterval(interval)

@@ -15,6 +15,7 @@ a network.
 from __future__ import annotations
 
 import asyncio
+import bisect
 import contextlib
 import contextvars
 import functools
@@ -112,7 +113,7 @@ _native_extract_state: Optional[bool] = None
 
 
 def _parse_region(raw: bytes, n_txs: int, want_delta: bool,
-                  want_keys: bool = False, publish=None):
+                  want_keys: bool = False, publish=None, n_jobs_of=None):
     """Worker-thread job: the ONE native parse of a message's tx region,
     and with it a block's UTXO delta (``utxo_ops``: 2-3 ms per 8,000 txs
     while the region is open, against a second parse of the block at
@@ -120,7 +121,11 @@ def _parse_region(raw: bytes, n_txs: int, want_delta: bool,
     (ISSUE 27), each tx's hash over its full wire bytes.  ``publish``
     (a block of a node with a UTXO set, ISSUE 44) is given the open
     region, here in the worker: the block's outputs are in the in-flight
-    view before the loop hears that the parse is done.
+    view before the loop hears that the parse is done.  ``n_jobs_of`` (a
+    block: :meth:`Node._n_extract_jobs`): where the region's txs will be
+    cut into more than one extract job, the intra-block prevout map those
+    jobs share and the tx layout the cut is made from are built here too
+    (ISSUE 46): nothing but this job stands before the first of them.
     -> (region, delta or None, wire hashes or None)."""
     from .txextract import ParsedTxRegion
 
@@ -128,6 +133,9 @@ def _parse_region(raw: bytes, n_txs: int, want_delta: bool,
     try:
         if publish is not None:
             publish(region)
+        if n_jobs_of is not None and n_jobs_of(region.n_txs) > 1:
+            region.build_intra()
+            region.tx_layout()
         return (
             region,
             region.utxo_ops() if want_delta else None,
@@ -167,6 +175,143 @@ def _extract_counted(job, **kw):
     items = job(**kw)
     _count_extracted(items)
     return items
+
+
+class _RegionHolds:
+    """The holds on a shared region handle, counted: whoever lets go last
+    closes it, exactly once.  An object of its own, because a job's
+    concurrent future keeps its done-callbacks for good: through this one
+    it reaches the region and nothing else — no way back to the list of
+    futures, so no reference cycle, and a message's items are freed when
+    the last reference to them goes, not at a collection."""
+
+    def __init__(self, region):
+        self._region = region
+        self._n = 1  # the submitter's own
+        self._lock = threadsan.lock("node.region_refcount")
+
+    def take(self) -> None:
+        with self._lock:
+            self._n += 1
+
+    def let_go(self, _f=None) -> None:
+        with self._lock:
+            self._n -= 1
+            last = self._n == 0
+        if last:
+            self._region.close()
+
+
+class _ExtractJobs:
+    """A parsed region — or ``subset``, the txs of a block that no relay
+    verdict answered (ISSUE 27) — cut into ``n_jobs`` contiguous runs of
+    equal size, one extract job each, which go to the worker pool a shard
+    at a time, in tx order (ISSUE 46): from inside the prevout walk's one
+    hold the moment a shard's rows have their answers
+    (:meth:`Node._resolve_ext_rows` calls :meth:`submit`), and after it
+    whatever the walk did not hand on (:meth:`submit_rest`).  ``ranges``:
+    the runs' ``(lo, hi)`` tx positions; ``rows``: their ``(lo, hi)`` in
+    the walk's two lists (the whole region's rows, or the subset's;
+    ``(None, None)`` where one job takes every row); ``cfuts`` / ``jobs``:
+    the jobs submitted so far as the pool and as the loop know them.
+
+    Several jobs share the intra-block prevout map, which is complete
+    before the first of them is submitted: the parse job built it
+    (:func:`_parse_region`; one job builds its own).  Each job's oracle
+    rows are a copy of its slice of the rows the walk gave.
+
+    Close ownership is collective: the region is freed when the submitter
+    has let go of it (:meth:`release`) and every job submitted by then is
+    out of the pool (finished, or cancelled before it ran) — exactly
+    once, never under a live extract.  The callbacks watch the CONCURRENT
+    futures, the only signal that cannot fire while a worker thread still
+    holds the handle (the same use-after-free discipline as
+    ``_run_extract_owned``)."""
+
+    def __init__(self, pool: ThreadPoolExecutor, region, bch: bool, subset,
+                 n_jobs: int, back: Optional[float] = None):
+        n = region.n_txs if subset is None else len(subset)
+        size = -(-n // n_jobs)
+        self.ranges = [(lo, min(lo + size, n)) for lo in range(0, n, size)]
+        self._intra = region.n_txs > 1
+        if len(self.ranges) > 1:
+            # the parse job's part (off the loop): never built here
+            assert not self._intra or region.intra_built, "no intra map"
+            if subset is None:
+                off = region.input_offsets()
+            else:
+                off = np.zeros(n + 1, np.int64)
+                np.cumsum(region.tx_layout()[0][subset], out=off[1:])
+            self.rows = [(int(off[lo]), int(off[hi])) for lo, hi in self.ranges]
+        else:
+            self.rows = [(None, None)]  # the one job takes every row
+        self.cfuts: list = []
+        self.jobs: list[asyncio.Future] = []
+        self.refused: Optional[RuntimeError] = None  # by the pool
+        self._pool, self._region, self._bch, self._subset = (
+            pool, region, bch, subset)
+        # a block in more than one job is counted, and its prefix timed
+        # from ``back``: when its parse result was back on the loop
+        self._back = back if len(self.ranges) > 1 else None
+        self._holds = _RegionHolds(region)  # one the submitter's own
+
+    def submit(self, k: int, amounts, scripts, in_walk: bool = False) -> None:
+        """Shard ``k``'s job into the pool with its rows of the walk's two
+        lists (copies; None: no oracle rows at all).  ``in_walk``: rows of
+        a later shard have still to be answered.  On the loop, in tx
+        order, no ``await``.  A pool that takes no more jobs (shut down)
+        is remembered in ``refused``, and nothing is submitted after."""
+        if self.refused is not None:
+            return
+        assert k == len(self.cfuts), "shards go to the pool in tx order"
+        lo, hi = self.ranges[k]
+        job = (
+            functools.partial(self._region.extract_range, lo, hi)
+            if self._subset is None
+            else functools.partial(
+                self._region.extract_subset, self._subset[lo:hi]
+            )
+        )
+        self._holds.take()
+        try:
+            cfut = self._pool.submit(
+                _extract_counted, job, bch=self._bch,
+                intra_amounts=self._intra, ext_amounts=amounts,
+                ext_scripts=scripts,
+            )
+        except RuntimeError as e:
+            self.refused = e
+            self._holds.let_go()
+            return
+        cfut.add_done_callback(self._holds.let_go)
+        self.cfuts.append(cfut)
+        self.jobs.append(asyncio.wrap_future(cfut))
+        if self._back is not None:
+            counts = [("node.stream_jobs", 1, None)]
+            if in_walk:
+                counts.append(("node.stream_jobs_in_walk", 1, None))
+            if k == 0:
+                counts.append(("node.stream_blocks", 1, None))
+                record_span("node.prefix", _time.perf_counter() - self._back)
+            metrics.inc_batch(counts)
+
+    def submit_rest(self, ext, ext_scripts) -> None:
+        """Every shard that is not in the pool yet, with its slice of the
+        walk's finished lists."""
+        for k in range(len(self.cfuts), len(self.ranges)):
+            fl, fh = self.rows[k]
+            self.submit(
+                k,
+                ext[fl:fh] if ext is not None else None,
+                ext_scripts[fl:fh] if ext_scripts is not None else None,
+            )
+
+    def release(self) -> None:
+        """The submitter submits no more (idempotent): the region is the
+        jobs' alone, and closed here if none is left in the pool."""
+        if self._pool is not None:
+            self._pool = None
+            self._holds.let_go()
 
 
 def _hash_rows(rows) -> "list[bytes]":
@@ -1747,7 +1892,7 @@ class Node:
             self.cfg.pub.publish(VerifyShed(peer, n, pending))
 
     def _resolve_ext_rows(self, region, bch: bool, subset=None,
-                          final: bool = True):
+                          final: bool = True, shards=None):
         """External-oracle rows for a parsed region: per-input amounts and
         scriptPubKeys, aligned with the region's flat input order —
         ``(amounts, -1 unknown; scripts, None unknown)``, two lists, or
@@ -1772,9 +1917,24 @@ class Node:
         give the GIL up in the middle of the hold).  The rows go on as
         lists: the extract converts them in its worker, and what it
         cannot convert (a mempool output is a peer's u64) it refuses
-        under its caller's handler.  ONE hold of the loop: no ``await``
-        between the first read and the last, and nothing kept from one
-        call to the next."""
+        under its caller's handler.
+
+        ``shards`` (a block cut into more than one extract job:
+        :class:`_ExtractJobs`, ISSUE 46): the sources the program owns
+        are read over ALL wanted rows first, as ever — a block's inputs
+        see the mempool, the view and the set in one state — and only the
+        callback, the one source asked a row at a time, runs shard by
+        shard, still in ascending row order; after a shard's last row
+        ``shards.submit`` puts that shard's job into the pool with a copy
+        of its slice of the two lists, so the first jobs run beside the
+        rest of the walk.  With no callback every shard's rows are
+        complete after the batch reads and all jobs go at once, before
+        ``node.resolve`` closes.  Only a final read hands on: one that
+        may be made again (``final=False``) submits nothing.
+
+        ONE hold of the loop: no ``await`` between the first read and the
+        last — nor the last submission —, and nothing kept from one call
+        to the next."""
         mempool, inflight, utxo, oracle = self._prevout_sources()
         if mempool is None and utxo is None and oracle is None:
             return None, None  # block ingest then skips the whole scan
@@ -1822,11 +1982,34 @@ class Node:
                         todo = absorb(todo, answers)
                         ask = [keys[i] for i in todo]
                 todo = absorb(todo, utxo.lookup_many(ask))
+
+            def call_back(rows: list) -> list:
+                """The embedder's callback, once a row; -> the rows it
+                left unanswered (with no callback, all of them)."""
+                if oracle is None:
+                    return rows
+                return absorb(rows, map(
+                    oracle, [txids[i] for i in rows], [vouts[i] for i in rows]
+                ))
+
             if oracle is not None:
                 metrics.inc("node.resolve_oracle_calls", len(todo))
-                todo = absorb(todo, map(
-                    oracle, [txids[i] for i in todo], [vouts[i] for i in todo]
-                ))
+            if shards is None or not final:
+                todo = call_back(todo)
+            else:
+                # a shard's rows are final once the callback has answered
+                # them (a row that stays unanswered is missing for good):
+                # its job goes now, and runs beside the later shards' rows
+                # (with no callback none is left to ask: all jobs go)
+                asked, todo, at = todo, [], 0
+                for k, (fl, fh) in enumerate(shards.rows):
+                    end = bisect.bisect_left(asked, fh, at)
+                    todo += call_back(asked[at:end])
+                    at = end
+                    shards.submit(
+                        k, amounts[fl:fh], scripts[fl:fh],
+                        in_walk=oracle is not None and at < len(asked),
+                    )
             if todo:
                 # the native scan marks an in-block spend as wanted like
                 # any other input, and the extractor's in-block map
@@ -2279,7 +2462,15 @@ class Node:
         and each shard is a chain of its own — extract job →
         ``verify_raw`` → ``node.commit`` — that goes to the engine the
         moment ITS job is out of the pool (ISSUE 32): a big block's first
-        lanes run while its later shards are still being extracted.  One
+        lanes run while its later shards are still being extracted.  And
+        its first jobs run while its later shards' prevouts are still
+        being read (ISSUE 46): the cut is made when the parse is back
+        (which built the map the jobs share), and the walk's final read
+        puts each shard's job into the pool from inside its hold, as soon
+        as that shard's rows are answered (:class:`_ExtractJobs`,
+        :meth:`_resolve_ext_rows`) — nothing but the parse stands before a
+        block's first job.  A message of one job goes as it did: the walk,
+        then the job.  One
         behavioral difference to the Python path: an extract error fails
         every tx of the message that has no verdict yet (the failed job's
         and those of the jobs not handed on; the Python path can fail
@@ -2324,10 +2515,9 @@ class Node:
         region: Optional[ParsedTxRegion] = None
         delta = None  # the block's (ops blob, created, spent), if wanted
         connecting = False  # handed to the UTXO connect, which lets go
-        submitted = False  # once its jobs are in the pool, the last of
-        # them out closes the region (_close_when_done)
-        cfuts: list = []  # a shard's extract job in the pool, in tx order,
-        jobs: list[asyncio.Future] = []  # and the same as the loop awaits it
+        # the message's cut and its extract jobs in the pool, in tx order;
+        # once it is made the region is theirs to close
+        shards: Optional[_ExtractJobs] = None
         commits: list[asyncio.Task] = []  # the shards handed on
         failed = None  # the first job that raised
         # a shard's chain is the message's child in its trace, beside
@@ -2362,29 +2552,56 @@ class Node:
                             self._inflight.publish_region,
                             block.header.hash, block.header.prev,
                         ) if gated else None,
+                        # and the intra-block map, where the block will
+                        # be cut (ISSUE 46)
+                        self._n_extract_jobs if block is not None else None,
                     )
                 except asyncio.CancelledError:
                     raise
                 except Exception as e:
                     _publish_extract_error(e)
                     return
+                back = _time.perf_counter()
                 if block is not None and self.mempool is not None:
                     block_txids = _hash_rows(region.txids())
                     if wire is not None:
                         subset = self._reuse_relay_verdicts(
                             peer, block_txids, _hash_rows(wire)
                         )
+                # Contiguous tx ranges (ISSUE 11) — with relay verdicts
+                # read, runs of the txs still to verify (ISSUE 27) —, cut
+                # now, before the walk; the intra-block prevout map is
+                # built ONCE on the shared handle (read-only for the
+                # jobs), so sharded extraction is bit-identical to serial
+                # (pinned by tests/test_txextract.py).
+                n_todo = region.n_txs if subset is None else len(subset)
+                if n_todo:  # else every tx was answered from relay
+                    assert self._extract_pool is not None  # with the engine
+                    try:
+                        shards = _ExtractJobs(
+                            self._extract_pool, region, bch, subset,
+                            self._n_extract_jobs(n_todo)
+                            if block is not None else 1,
+                            back if block is not None else None,
+                        )
+                    except Exception as e:
+                        _publish_extract_error(e)
+                        return
                 # Out-of-block prevout rows via the embedder's oracle,
                 # flattened per input in parse order.  The native side
                 # consults its intra-block map FIRST, so resolving every
                 # wants-marked input here matches the Python path's
                 # block_outs -> prevout_lookup precedence (an in-block hit
                 # shadows whatever the oracle would have said).  One hold
-                # for the whole message, before any job: every shard's
-                # rows are read from the sources at the same moment.
+                # for the whole message: every shard's rows are read from
+                # the sources the program owns at the same moment, and
+                # where it is cut into more than one job each shard's job
+                # leaves from inside that hold.
                 resolve = functools.partial(
                     self._resolve_ext_rows, region, bch, subset
                 )
+                if shards is not None and len(shards.ranges) > 1:
+                    resolve = functools.partial(resolve, shards=shards)
                 if gated:
                     # later blocks may spend this one's outputs, and this
                     # one those of the blocks beneath it: a row that has
@@ -2398,8 +2615,10 @@ class Node:
                     # block connect: evict confirmed txs from the mempool —
                     # the whole block's, answered from relay or not.  The
                     # txids come from the native parse — no Python parse —
-                    # and leave before the first job does, so before any
-                    # verdict of the engine's.
+                    # and leave before the first await behind the walk, so
+                    # before any verdict of the engine's (a job in the
+                    # pool reads the region and its rows, never the
+                    # mempool).
                     assert self.mempool is not None
                     self.mempool.confirmed(block_txids)
                 # every shard is its own engine submission (the lane
@@ -2421,28 +2640,15 @@ class Node:
                         )
                     except Exception:
                         aff = None
-                # Contiguous tx ranges (ISSUE 11) — with relay verdicts
-                # read, runs of the txs still to verify (ISSUE 27) — go to
-                # the pool together; the intra-block prevout map is built
-                # ONCE on the shared handle (read-only for the jobs), so
-                # sharded extraction is bit-identical to serial (pinned
-                # by tests/test_txextract.py).
-                n_todo = region.n_txs if subset is None else len(subset)
-                ranges: list = []
-                try:
-                    if n_todo:  # else every tx was answered from relay
-                        submitted = True
-                        cfuts, ranges = await self._submit_extract_jobs(
-                            region, bch, ext, ext_scripts, subset,
-                            self._n_extract_jobs(n_todo)
-                            if block is not None else 1,
-                        )
-                        jobs = [asyncio.wrap_future(f) for f in cfuts]
-                except asyncio.CancelledError:
-                    raise
-                except Exception as e:
-                    _publish_extract_error(e)
-                    return
+                if shards is not None:
+                    # what the walk did not hand on: a message of one job,
+                    # a read that was not final, no source to ask
+                    shards.submit_rest(ext, ext_scripts)
+                    shards.release()
+                    if shards.refused is not None:
+                        _publish_extract_error(shards.refused)
+                        return
+                jobs = shards.jobs if shards is not None else []
                 # Hand the shards on in tx order, each when its job (and
                 # every job before it) is out: the pool runs them in that
                 # order, and a block's verdicts reach the bus in runs of
@@ -2462,7 +2668,7 @@ class Node:
                         failed = e
                         for later in jobs[k + 1:]:
                             later.cancel()
-                        _publish_extract_error(e, ranges[k:])
+                        _publish_extract_error(e, shards.ranges[k:])
                         break
                     metrics.inc("node.verify_txs", items.n_txs)
                     metrics.inc(
@@ -2471,7 +2677,7 @@ class Node:
                     if block is not None:
                         # does the hand-off run ahead of the extract?
                         metrics.inc("node.stream_items", items.count)
-                        if not all(f.done() for f in cfuts[k + 1:]):
+                        if not all(f.done() for f in shards.cfuts[k + 1:]):
                             metrics.inc(
                                 "node.stream_early_items", items.count
                             )
@@ -2494,11 +2700,16 @@ class Node:
                 # not on its way to the UTXO set: a re-delivery verifies
                 self._blocks_taken.discard(block.header.hash)
                 self._inflight_done(block.header.hash)
-            if region is not None and not submitted:
+            # cancelled (or crashed) mid-way — the embedder's callback
+            # raised in the middle of the walk, say, with jobs out —:
+            # queued jobs never run, running ones run out and are dropped,
+            # the last of them closes the region, and a shard whose
+            # verdicts are not out publishes none
+            if shards is not None:
+                shards.release()
+            elif region is not None:
                 region.close()
-            # cancelled (or crashed) mid-way: queued jobs never run, and a
-            # shard whose verdicts are not out publishes none
-            for work in jobs + commits:
+            for work in (shards.jobs if shards is not None else []) + commits:
                 work.cancel()
             if tracked:
                 self._verify_pending -= 1
@@ -2583,83 +2794,6 @@ class Node:
             min(workers, n // self.MIN_SHARD_TXS),
             -(-n // self.STREAM_SHARD_TXS),
         )
-
-    async def _submit_extract_jobs(self, region, bch: bool, ext,
-                                   ext_scripts, subset, n_jobs: int):
-        """Cut a parsed region — or ``subset``, the txs of a block that
-        no relay verdict answered (ISSUE 27) — into ``n_jobs`` contiguous
-        runs of equal size and submit one extract job each to the worker
-        pool: ``-> (the jobs' concurrent futures, their (lo, hi) runs)``,
-        in tx order.  Several jobs share the intra-block prevout map,
-        built once (off-loop) before they go (one job builds its own);
-        each job's oracle rows are its slice of the rows
-        ``_resolve_ext_rows`` gave (the whole region's, or the subset's).
-        Close ownership is collective: the region is freed when the LAST
-        submitted job finishes (or every queued job is cancelled before
-        running) — never under a live extract."""
-        n = region.n_txs if subset is None else len(subset)
-        intra = region.n_txs > 1
-        size = -(-n // n_jobs)
-        ranges = [(lo, min(lo + size, n)) for lo in range(0, n, size)]
-        if len(ranges) > 1:
-            if intra:
-                await self._run_extract(region.build_intra)
-            if subset is None:
-                off = region.input_offsets()
-            else:
-                off = np.zeros(n + 1, np.int64)
-                np.cumsum(region.tx_layout()[0][subset], out=off[1:])
-            rows = [(int(off[lo]), int(off[hi])) for lo, hi in ranges]
-        else:
-            rows = [(None, None)]  # the one job takes every row
-        assert self._extract_pool is not None  # built with the engine
-        cfuts: list = []
-        try:
-            for (lo, hi), (fl, fh) in zip(ranges, rows):
-                job = (
-                    functools.partial(region.extract_range, lo, hi)
-                    if subset is None
-                    else functools.partial(
-                        region.extract_subset, subset[lo:hi]
-                    )
-                )
-                cfuts.append(self._extract_pool.submit(
-                    _extract_counted,
-                    job,
-                    bch=bch,
-                    intra_amounts=intra,
-                    ext_amounts=ext[fl:fh] if ext is not None else None,
-                    ext_scripts=(
-                        ext_scripts[fl:fh]
-                        if ext_scripts is not None else None
-                    ),
-                ))
-        finally:
-            self._close_when_done(region, cfuts)
-        return cfuts, ranges
-
-    @staticmethod
-    def _close_when_done(region, cfuts: list) -> None:
-        """Free a shared region handle once every submitted job is out of
-        the pool (finished OR cancelled-before-running).  The callbacks
-        watch the CONCURRENT futures — the only signal that cannot fire
-        while a worker thread still holds the handle (the same
-        use-after-free discipline as `_run_extract_owned`)."""
-        if not cfuts:
-            region.close()
-            return
-        state = {"remaining": len(cfuts)}
-        lock = threadsan.lock("node.region_refcount")
-
-        def _done(_f):
-            with lock:
-                state["remaining"] -= 1
-                last = state["remaining"] == 0
-            if last:
-                region.close()
-
-        for f in cfuts:
-            f.add_done_callback(_done)
 
     async def _verify_txs(self, peer, txs: list[Tx], block=None) -> None:
         """Verify every tx of one message.  All txs' signatures are submitted

@@ -454,6 +454,7 @@ class ParsedTxRegion:
         self.capacity = int(self._lib.txx_parsed_capacity(self._h))
         self.n_inputs = int(self._lib.txx_parsed_inputs(self._h))
         self._layout: Optional[tuple] = None
+        self.intra_built = False  # build_intra() has run on this handle
 
     def close(self) -> None:
         if self._h:
@@ -509,6 +510,7 @@ class ParsedTxRegion:
         extract on worker threads and only the pre-built map is
         read-only."""
         assert self._h, "region closed"
+        self.intra_built = True
         return int(self._lib.txx_build_intra_h(self._h))
 
     def tx_layout(self) -> tuple[np.ndarray, np.ndarray]:
