@@ -665,9 +665,9 @@ def _worker_pipeline() -> None:
         from tpunode.verify.engine import VerifyConfig
         from tpunode.wire import LazyTx, MsgTx
 
-        import tpunode.node as node_mod
+        from tpunode import txextract
 
-        if not node_mod._native_extract_available():
+        if not txextract.have_native_extract():
             print(json.dumps(
                 {"ok": False, "error": "native extractor unavailable"}
             ))
@@ -1636,13 +1636,11 @@ def _worker_ibd() -> None:
     Node syncs a fakenet chain through the REAL fetch planner
     (NodeConfig.ibd) — no embedder pushes anywhere — measured three ways:
 
-    * ``ingest``: verify engine ON (cpu-native rung), native sharded
-      extraction + C++ UTXO connect vs the serial all-Python baseline
-      (python extract path, python block-connect) on identical traffic —
-      e2e blocks/s and sigs/s with the speedup;
-    * ``connect``: verify engine OFF — the pure block-ingest path (wire →
-      parse → UTXO connect) native vs Python, the block-connect hot path
-      in isolation;
+    * ``ingest_native``: verify engine ON (cpu-native rung), sharded
+      extraction + delta-blob UTXO connect — e2e blocks/s and sigs/s;
+    * ``connect_native``: verify engine OFF — the pure block-ingest path
+      (wire → parse → UTXO connect), the block-connect hot path in
+      isolation;
     * ``kill9``: a child process killed mid-sync over a LogKV store, then
       restarted — proving the restart resumes from the watermark with
       ZERO re-verified (and zero re-fetched) blocks.
@@ -1675,9 +1673,9 @@ def _worker_ibd() -> None:
         from tpunode.store import LogKV
         from tpunode.verify.engine import VerifyConfig
 
-        import tpunode.node as node_mod
+        from tpunode import txextract
 
-        if not node_mod._native_extract_available():
+        if not txextract.have_native_extract():
             print(json.dumps(
                 {"ok": False, "error": "native extractor unavailable"}
             ))
@@ -1697,82 +1695,68 @@ def _worker_ibd() -> None:
             len(tx.inputs) for b in all_blocks for tx in b.txs[1:]
         )
 
-        async def sync_once(verify: bool, native: bool, store_dir: str,
-                            blocks=None):
+        async def sync_once(verify: bool, store_dir: str, blocks=None):
             blocks = all_blocks if blocks is None else blocks
             count = len(blocks)
             """One full planner-driven sync over a fresh LogKV store."""
             from tests.fakenet import dummy_peer_connect, poll_until
 
-            os.environ["TPUNODE_UTXO_NATIVE"] = "1" if native else "0"
-            saved = node_mod._native_extract_state
-            if not native:
-                # serial all-Python baseline: force the python extract
-                # path too (the pre-native block ingest)
-                node_mod._native_extract_state = False
-            try:
-                store = LogKV(os.path.join(store_dir, "kv.log"))
-                pub = Publisher(name="bench-ibd", maxsize=None)
-                cfg = NodeConfig(
-                    net=net, store=store, pub=pub,
-                    peers=["[::1]:18555"], discover=False,
-                    connect=lambda sa: dummy_peer_connect(net, blocks),
-                    verify=(
-                        VerifyConfig(backend="cpu", max_wait=0.005)
-                        if verify else None
-                    ),
-                    prevout_lookup=synth_prevout if verify else None,
-                    utxo=True,
-                    ibd=IbdConfig(batch_blocks=16, tick_interval=0.05),
-                    extract_workers=(
-                        0 if native else 1  # 0 = auto (min(4, cpu))
-                    ),
-                )
-                verdicts = 0
-                t0 = time.perf_counter()
-                async with pub.subscription() as events:
-                    async with Node(cfg) as node:
-                        async def watch():
-                            nonlocal verdicts
-                            while True:
-                                ev = await events.receive()
-                                if isinstance(ev, TxVerdict):
-                                    verdicts += 1
-                        task = asyncio.ensure_future(watch())  # asyncsan: disable=raw-spawn (bench observer, cancelled below)
-                        try:
+            store = LogKV(os.path.join(store_dir, "kv.log"))
+            pub = Publisher(name="bench-ibd", maxsize=None)
+            cfg = NodeConfig(
+                net=net, store=store, pub=pub,
+                peers=["[::1]:18555"], discover=False,
+                connect=lambda sa: dummy_peer_connect(net, blocks),
+                verify=(
+                    VerifyConfig(backend="cpu", max_wait=0.005)
+                    if verify else None
+                ),
+                prevout_lookup=synth_prevout if verify else None,
+                utxo=True,
+                ibd=IbdConfig(batch_blocks=16, tick_interval=0.05),
+            )
+            verdicts = 0
+            t0 = time.perf_counter()
+            async with pub.subscription() as events:
+                async with Node(cfg) as node:
+                    async def watch():
+                        nonlocal verdicts
+                        while True:
+                            ev = await events.receive()
+                            if isinstance(ev, TxVerdict):
+                                verdicts += 1
+                    task = asyncio.ensure_future(watch())  # asyncsan: disable=raw-spawn (bench observer, cancelled below)
+                    try:
+                        await poll_until(
+                            lambda: node.utxo.height == count,
+                            timeout=600, what="ibd sync",
+                        )
+                        if verify:
+                            total = count * (txs_per_block + 1)
                             await poll_until(
-                                lambda: node.utxo.height == count,
-                                timeout=600, what="ibd sync",
+                                lambda: verdicts >= total,
+                                timeout=120, what="all verdicts",
                             )
-                            if verify:
-                                total = count * (txs_per_block + 1)
-                                await poll_until(
-                                    lambda: verdicts >= total,
-                                    timeout=120, what="all verdicts",
-                                )
-                        finally:
-                            task.cancel()
-                        dt = time.perf_counter() - t0
-                        fetched = node.ibd.stats()["fetched_blocks"]
-                store.close()
-                sigs = sum(
-                    len(tx.inputs) for b in blocks for tx in b.txs[1:]
-                )
-                return {
-                    "wall_s": round(dt, 3),
-                    "blocks_per_s": round(count / dt, 1),
-                    "txs_per_s": round(
-                        count * (txs_per_block + 1) / dt, 1
-                    ),
-                    "sigs_per_s": round(sigs / dt, 1) if verify else None,
-                    "verdicts": verdicts,
-                    "fetched_blocks": fetched,
-                }
-            finally:
-                node_mod._native_extract_state = saved
-                os.environ.pop("TPUNODE_UTXO_NATIVE", None)
+                    finally:
+                        task.cancel()
+                    dt = time.perf_counter() - t0
+                    fetched = node.ibd.stats()["fetched_blocks"]
+            store.close()
+            sigs = sum(
+                len(tx.inputs) for b in blocks for tx in b.txs[1:]
+            )
+            return {
+                "wall_s": round(dt, 3),
+                "blocks_per_s": round(count / dt, 1),
+                "txs_per_s": round(
+                    count * (txs_per_block + 1) / dt, 1
+                ),
+                "sigs_per_s": round(sigs / dt, 1) if verify else None,
+                "verdicts": verdicts,
+                "fetched_blocks": fetched,
+            }
 
-        async def run_ab() -> dict:
+        async def run_legs() -> dict:
             out: dict = {"ok": True, "proxy": "cpu-native",
                          "blocks": n_blocks, "txs_per_block": txs_per_block,
                          "inputs_per_tx": inputs_per_tx, "sigs": n_sigs}
@@ -1785,29 +1769,25 @@ def _worker_ibd() -> None:
             _progress("warmup sync (untimed, full size)...")
             d = tempfile.mkdtemp(prefix="ibd_warmup_")
             try:
-                await sync_once(True, True, d)
+                await sync_once(True, d)
             finally:
                 shutil.rmtree(d, ignore_errors=True)
             legs = (
-                # the ingest A/B runs twice per side, best kept: host-load
-                # drift on a shared box swings a single pass ±30% (the
-                # PERF r6 round-robin lesson, applied cheaply)
-                ("ingest_native", True, True, 2,
+                # the ingest leg runs twice, best kept: host-load drift
+                # on a shared box swings a single pass ±30% (the PERF r6
+                # round-robin lesson, applied cheaply)
+                ("ingest_native", True, 2,
                  "verify on, sharded native extract + C++ connect"),
-                ("ingest_python", True, False, 2,
-                 "verify on, serial python extract + python connect"),
-                ("connect_native", False, True, 1,
+                ("connect_native", False, 1,
                  "no verify: wire -> C++ one-pass UTXO connect"),
-                ("connect_python", False, False, 1,
-                 "no verify: wire -> python parse + connect"),
             )
-            for key, verify, native, reps, note in legs:
+            for key, verify, reps, note in legs:
                 _progress(f"{key}: {note}...")
                 best = None
                 for _ in range(reps):
                     d = tempfile.mkdtemp(prefix=f"ibd_{key}_")
                     try:
-                        leg = await sync_once(verify, native, d)
+                        leg = await sync_once(verify, d)
                     finally:
                         shutil.rmtree(d, ignore_errors=True)
                     if best is None or leg["wall_s"] < best["wall_s"]:
@@ -1815,20 +1795,9 @@ def _worker_ibd() -> None:
                 best["note"] = note
                 best["runs"] = reps
                 out[key] = best
-            out["ingest_speedup"] = round(
-                out["ingest_native"]["blocks_per_s"]
-                / out["ingest_python"]["blocks_per_s"], 3,
-            )
-            out["connect_speedup"] = round(
-                out["connect_native"]["blocks_per_s"]
-                / out["connect_python"]["blocks_per_s"], 3,
-            )
-            # the acceptance ratio: block-ingest e2e, native vs the
-            # serial Python-connect baseline in the same run
-            out["speedup"] = out["ingest_speedup"]
             return out
 
-        section = asyncio.run(run_ab())
+        section = asyncio.run(run_legs())
 
         # -- kill -9 leg ----------------------------------------------------
         _progress(f"kill -9 leg: {kill_blocks}-block child sync...")

@@ -94,7 +94,8 @@ def gen_signed_txs(
     (to keep verifiers honest).  ``segwit_every`` > 0 makes every Nth tx a
     P2WPKH spend (BIP143 digest) of the PREVIOUS tx's output 0, so packed
     into one block the prevout amount is resolvable intra-block — the
-    channel node._verify_txs wires into extract_sig_items."""
+    channel block ingest resolves before any oracle
+    (``txverify.intra_block_prevouts``; the native extractor's in-block map)."""
     rng = random.Random(seed)
     priv = rng.getrandbits(256) % CURVE_N or 1
     pub = point_mul(priv, GENERATOR)

@@ -6,11 +6,11 @@ the in-flight output view (``tpunode.utxo.InflightOutputs``), the set.
 
 (a) program = plain reference = construction, signature by signature, both
 extractors; (b) the generator's shares, ages, amounts and scripts; (c)
-blocks out of height order through ``Node``, both paths; (d) a dropped and
-re-delivered block and a reorg beneath blocks in flight leave nothing in
-the view; (e) the cell's rehearsal through ``chipbench``; (f) the backlog
-rule for the seven backlog cells.  The restart case stands beside
-``tests/test_utxo.py``'s restart pin.
+blocks out of height order through ``Node``, held to construction and to
+the Python reference; (d) a dropped and re-delivered block and a reorg
+beneath blocks in flight leave nothing in the view; (e) the cell's
+rehearsal through ``chipbench``; (f) the backlog rule for the seven backlog
+cells.  The restart case stands beside ``tests/test_utxo.py``'s restart pin.
 """
 
 from __future__ import annotations
@@ -27,9 +27,12 @@ from chipbench import wirefmt as w
 from chipbench.reference import _multisig, _pushes
 from chipbench.tests.rehearse import rehearse
 from tests.chain_cell import (
-    BENCH, CELL, CONFIG, SHORT, TRAFFIC, a_node, chain, moved,
+    BENCH, CELL, CONFIG, GENESIS, SHORT, TRAFFIC, a_node, chain, moved,
 )
 from tests.fakenet import poll_until
+from tests.fixtures import (
+    reference_set, reference_verdicts, tuples, utxo_records,
+)
 from tpunode import node as node_mod
 from tpunode.ibd import IbdConfig
 from tpunode.metrics import metrics
@@ -225,13 +228,14 @@ def _shuffled(n: int, seed: int, reach: int = 7) -> list:
 
 
 @pytest.mark.asyncio
-@pytest.mark.parametrize("path", ["native", "python"])
-async def test_blocks_out_of_height_order_verify_as_in_order(path, monkeypatch):
-    n = 28 if path == "native" else 12
+@pytest.mark.parametrize("path", ["native", "reference"])
+async def test_blocks_out_of_height_order_verify_as_in_order(path):
+    """``native``: 28 blocks against construction.  ``reference``: 8, and
+    the node's verdicts and its set held to the Python reference's
+    (``tests/fixtures.py``), which is told every outpoint's truth as the
+    raw blocks have it."""
+    n = 28 if path == "native" else 8
     ch = chain()
-    if path == "python":
-        monkeypatch.setattr(node_mod, "_native_extract_available",
-                            lambda: False)
     txids = [t for ids in ch.txids[:n] for t in ids]
     order = _shuffled(n, 3)
     assert order != sorted(order)
@@ -258,8 +262,14 @@ async def test_blocks_out_of_height_order_verify_as_in_order(path, monkeypatch):
         assert got["node.inflight_outputs_added"] == n * (2 * PER + 1)
         assert got["node.inflight_outputs_retired"] == n * (2 * PER + 1)
         assert got["node.inflight_outputs_dropped"] == 0
-        if path == "native":  # the Python path reads a row at a time
-            assert got["node.resolve_inflight_hits"] > 0
+        assert got["node.resolve_inflight_hits"] > 0
+        if path == "reference":
+            blocks = [ch.block(h) for h in range(1, n + 1)]
+            ref = [row for blk in blocks for row in reference_verdicts(
+                list(blk.txs), ch.prevout, bch=True)]
+            assert tuples(d.verdicts[row[0]] for row in ref) == ref
+            assert utxo_records(d.node) == reference_set(
+                blocks, [ch.snapshot_blob()], GENESIS)
         assert d.clear_view()
 
 

@@ -13,13 +13,12 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
-import os
 
 import pytest
 
 from benchmarks.txgen import gen_chain, synth_prevout
 from tests.fakenet import dummy_peer_connect, poll_until
-from tests.fixtures import all_blocks
+from tests.fixtures import all_blocks, reference_set, utxo_records
 from tpunode import (
     BCH_REGTEST,
     IbdConfig,
@@ -202,45 +201,43 @@ async def test_restart_resumes_from_watermark_zero_refetch(tmp_path):
 async def test_sharded_block_extraction_matches_serial():
     """BLOCK regions shard across the worker pool (ISSUE 11): big blocks
     through extract_workers=4 produce the same verdicts and a
-    bit-identical UTXO store as the serial worker (which also runs the
-    pure-Python UTXO connect as cross-check)."""
+    bit-identical UTXO store as the serial worker, whose store is
+    cross-checked against the pure-Python connect's
+    (``UtxoStore.apply_block``, ``tests/fixtures.py``)."""
     blocks = gen_chain(
         NET, 2, 150, seed=0x1BD2, cache="ibd_t_2x150.bin", mix=True
     )
 
-    async def run(workers: int, native_utxo: bool):
-        os.environ["TPUNODE_UTXO_NATIVE"] = "1" if native_utxo else "0"
-        try:
-            verdicts = {}
-            async with ibd_node(
-                MemoryKV(), blocks, verify=True, extract_workers=workers,
-            ) as (node, events):
-                async def watch():
-                    while True:
-                        ev = await events.receive()
-                        if isinstance(ev, TxVerdict):
-                            verdicts[ev.txid] = (ev.valid, ev.verdicts)
+    async def run(workers: int):
+        verdicts = {}
+        async with ibd_node(
+            MemoryKV(), blocks, verify=True, extract_workers=workers,
+        ) as (node, events):
+            async def watch():
+                while True:
+                    ev = await events.receive()
+                    if isinstance(ev, TxVerdict):
+                        verdicts[ev.txid] = (ev.valid, ev.verdicts)
 
-                task = asyncio.ensure_future(watch())  # asyncsan: disable=raw-spawn (test observer, cancelled below)
-                try:
-                    await poll_until(
-                        lambda: node.utxo.height == 2, timeout=60,
-                        what=f"ibd workers={workers}",
-                    )
-                    await poll_until(
-                        lambda: len(verdicts) >= 2 * 151, timeout=30,
-                        what="verdicts",
-                    )
-                finally:
-                    task.cancel()
-                return verdicts, node.utxo.snapshot()
-        finally:
-            os.environ.pop("TPUNODE_UTXO_NATIVE", None)
+            task = asyncio.ensure_future(watch())  # asyncsan: disable=raw-spawn (test observer, cancelled below)
+            try:
+                await poll_until(
+                    lambda: node.utxo.height == 2, timeout=60,
+                    what=f"ibd workers={workers}",
+                )
+                await poll_until(
+                    lambda: len(verdicts) >= 2 * 151, timeout=30,
+                    what="verdicts",
+                )
+            finally:
+                task.cancel()
+            return verdicts, utxo_records(node)
 
-    v_serial, s_serial = await run(1, native_utxo=False)
-    v_shard, s_shard = await run(4, native_utxo=True)
+    v_serial, s_serial = await run(1)
+    v_shard, s_shard = await run(4)
     assert v_serial == v_shard  # bit-identical verdicts
-    assert s_serial == s_shard  # native connect == python connect
+    assert s_serial == s_shard  # sharded connect == serial connect
+    assert s_serial == reference_set(blocks)  # == python connect
 
 
 @pytest.mark.asyncio

@@ -23,6 +23,7 @@ from tpunode import (
     PeerConnected,
     Publisher,
     get_blocks,
+    txextract,
 )
 from tpunode.peermgr import to_host_service
 from tpunode.store import LogKV, MemoryKV
@@ -384,26 +385,29 @@ async def test_mempool_segwit_uses_embedder_prevout_lookup():
 
 
 @pytest.mark.asyncio
-async def test_block_ingest_native_path_matches_python():
-    """The native-extract fast path (wire-round-tripped messages carry raw
-    bytes) must produce the same TxVerdict stream as the Python path, and
-    must actually be taken when raw bytes are present."""
+async def test_hand_built_block_and_its_wire_round_trip_verdict_alike():
+    """A block built in-process (``raw_txs is None``) and its wire round
+    trip (raw bytes carried) give the same TxVerdict stream through the
+    one ingest path — the Python reference's —, each in one
+    ``_verify_txs_native`` call."""
     import tpunode.node as node_mod
     from benchmarks.txgen import gen_signed_txs
+    from tests.fixtures import reference_verdicts, tuples
     from tpunode import TxVerdict
     from tpunode.peer import PeerMessage
     from tpunode.util import Reader
     from tpunode.verify.engine import VerifyConfig
     from tpunode.wire import Block, BlockHeader, MsgBlock, MsgTx, Tx
 
-    if not node_mod._native_extract_available():
+    if not txextract.have_native_extract():
         pytest.skip("native extractor unavailable")
 
     txs = gen_signed_txs(
         6, inputs_per_tx=2, seed=0x7A77, invalid_every=3, segwit_every=5
     )
     hdr = BlockHeader(1, b"\x00" * 32, b"\x00" * 32, 0, 0x207FFFFF, 0)
-    built = Block(hdr, tuple(txs))  # raw_txs=None: python path
+    built = Block(hdr, tuple(txs))
+    assert built.raw_txs is None  # serialised at the node's door
     rt = Block.deserialize(Reader(built.serialize()))  # raw_txs set
     assert rt.raw_txs is not None
 
@@ -439,30 +443,21 @@ async def test_block_ingest_native_path_matches_python():
 
     node_mod.Node._verify_txs_native = counting
     try:
-        native = await run(MsgBlock(rt))
-        assert native_calls == 1, "wire-round-tripped block must go native"
-        python = await run(MsgBlock(built))
-        assert native_calls == 1, "constructed block must take the python path"
+        wire = await run(MsgBlock(rt))
+        assert native_calls == 1
+        hand = await run(MsgBlock(built))
+        assert native_calls == 2, "a constructed block goes the same way"
     finally:
         node_mod.Node._verify_txs_native = orig
 
-    assert set(native) == set(python)
-    invalid_seen = False
-    for txid, nv in native.items():
-        pv = python[txid]
-        assert (nv.valid, nv.verdicts, nv.error) == (pv.valid, pv.verdicts, pv.error)
-        assert (
-            nv.stats.total_inputs, nv.stats.extracted,
-            nv.stats.coinbase, nv.stats.unsupported,
-        ) == (
-            pv.stats.total_inputs, pv.stats.extracted,
-            pv.stats.coinbase, pv.stats.unsupported,
-        )
-        invalid_seen |= not nv.valid
-    assert invalid_seen, "fixture must exercise invalid signatures"
+    ref = reference_verdicts(txs, None, bch=True)
+    assert tuples(wire[t.txid] for t in txs) == ref
+    assert tuples(hand[t.txid] for t in txs) == ref
+    assert any(not row[1] for row in ref), (
+        "fixture must exercise invalid signatures")
 
-    # mempool path: a wire-round-tripped tx rides the native batch
-    # accumulator (round 4), not the per-message python path
+    # mempool path: a wire-round-tripped tx rides the batch accumulator
+    # (round 4), one drain for the message
     one = Tx.deserialize(Reader(txs[0].serialize()))
     assert one.raw is not None
     drain_calls = 0
@@ -509,11 +504,121 @@ async def run_single(tx):
 
 
 @pytest.mark.asyncio
+@pytest.mark.parametrize("asks_for", ["verify", "utxo"])
+async def test_a_node_that_ingests_does_not_start_without_the_extractor(
+        asks_for, monkeypatch):
+    """The requirement is stated once, at construction: a ``Node`` whose
+    config asks for verification or a UTXO set raises an error that names
+    the library and how to build it when ``libtxextract`` does not load;
+    a header-only node starts, syncs and never asks for it."""
+    from tpunode.verify.engine import VerifyConfig
+
+    def no_library():
+        raise OSError("libtxextract.so: cannot open shared object file")
+
+    monkeypatch.setattr(txextract, "load_txextract_lib", no_library)
+    pub = Publisher(name="node-events")
+    cfg = NodeConfig(
+        net=NET, store=MemoryKV(), pub=pub, peers=["[::1]:17486"],
+        connect=lambda sa: dummy_peer_connect(NET, all_blocks()),
+        verify=(VerifyConfig(backend="oracle") if asks_for == "verify"
+                else None),
+        utxo=asks_for == "utxo",
+    )
+    with pytest.raises(RuntimeError) as err:
+        Node(cfg)
+    assert "libtxextract" in str(err.value)
+    assert "make -C native" in str(err.value)
+    assert isinstance(err.value.__cause__, OSError)
+    async with make_test_node() as (node, events):
+        async with asyncio.timeout(10):
+            await wait_for_peer(events)
+        assert node.verify_engine is None and node.utxo is None
+
+
+@pytest.mark.asyncio
+async def test_objects_built_without_wire_bytes_enter_as_their_wire_forms(
+        monkeypatch):
+    """A relayed ``Tx`` with ``raw=None`` and a ``Block`` with
+    ``raw_txs=None`` (tests, an embedder's own injection) are serialised
+    at the node's door and go the way a peer's bytes go: the tx through
+    the accumulator and one extract shard, the block through exactly one
+    ``_verify_txs_native`` call, both over the bytes of their wire forms,
+    and they publish their wire forms' verdicts."""
+    import tpunode.node as node_mod
+    from benchmarks.txgen import gen_signed_txs
+    from tests.fakenet import poll_until
+    from tests.fixtures import tuples
+    from tpunode import TxVerdict
+    from tpunode.peer import PeerMessage
+    from tpunode.util import Reader
+    from tpunode.verify.engine import VerifyConfig
+    from tpunode.wire import Block, BlockHeader, LazyBlock, MsgBlock, MsgTx
+
+    if not txextract.have_native_extract():
+        pytest.skip("native extractor unavailable")
+    txs = gen_signed_txs(5, inputs_per_tx=2, seed=0x47, invalid_every=2)
+    one = txs[0]
+    hdr = BlockHeader(1, b"\x00" * 32, b"\x00" * 32, 0, 0x207FFFFF, 0)
+    built = Block(hdr, tuple(txs[1:]))
+    assert one.raw is None and built.raw_txs is None
+    region = b"".join(t.serialize() for t in built.txs)
+    wire_tx = MsgTx.deserialize_payload(Reader(one.serialize())).tx
+    wire_block = LazyBlock(hdr, built.tx_count, region)
+
+    shards, regions = [], []
+    extract_shard = node_mod.Node._extract_shard
+    verify_native = node_mod.Node._verify_txs_native
+
+    def spy_shard(self, shard, bch):
+        shards.append([raw for _, _, raw, _ in shard])
+        return extract_shard(self, shard, bch)
+
+    def spy_native(self, peer, raw, n_txs, **kw):
+        regions.append((raw, n_txs))
+        return verify_native(self, peer, raw, n_txs, **kw)
+
+    monkeypatch.setattr(node_mod.Node, "_extract_shard", spy_shard)
+    monkeypatch.setattr(node_mod.Node, "_verify_txs_native", spy_native)
+    pub = Publisher(name="node-events")
+    cfg = NodeConfig(
+        net=NET, store=MemoryKV(), pub=pub, peers=["[::1]:17486"],
+        connect=lambda sa: dummy_peer_connect(NET, all_blocks()),
+        verify=VerifyConfig(backend="cpu", max_wait=0.0),
+    )
+    async with pub.subscription() as events:
+        async with Node(cfg) as node:
+            async with asyncio.timeout(20):
+                peer = await wait_for_peer(events)
+
+                async def verdicts_of(msg, n: int) -> list:
+                    node._peer_pub.publish(PeerMessage(peer, msg))
+                    got = []
+                    while len(got) < n:
+                        ev = await events.receive()
+                        if isinstance(ev, TxVerdict):
+                            got.append(ev)
+                    return tuples(got)
+
+                hand_tx = await verdicts_of(MsgTx(one), 1)
+                assert shards == [[one.serialize()]] and not regions
+                assert hand_tx == await verdicts_of(MsgTx(wire_tx), 1)
+                hand_block = await verdicts_of(MsgBlock(built), 4)
+                assert regions == [(region, 4)]
+                # (a block still on its way out is a duplicate to drop)
+                await poll_until(lambda: not node._blocks_taken,
+                                 what="the block is through")
+                assert hand_block == await verdicts_of(MsgBlock(wire_block), 4)
+                assert regions == [(region, 4)] * 2
+    assert [row[0] for row in hand_tx + hand_block] == [t.txid for t in txs]
+    assert not all(row[1] for row in hand_block)  # an invalid one among them
+
+
+@pytest.mark.asyncio
 async def test_native_block_ingest_never_parses_txs_in_python():
     """The lazy-block native path (LazyBlock + scan_prevouts) must produce
     TxVerdicts for a block without a single Python Tx.deserialize call —
     the round-4 fix for the IBD ingest bottleneck (VERDICT r3 item 2)."""
-    import tpunode.node as node_mod
     import tpunode.wire as wire_mod
     from benchmarks.txgen import gen_mixed_txs, synth_amount
     from tpunode import TxVerdict
@@ -521,7 +626,7 @@ async def test_native_block_ingest_never_parses_txs_in_python():
     from tpunode.verify.engine import VerifyConfig
     from tpunode.wire import Block, BlockHeader, MsgBlock
 
-    if not node_mod._native_extract_available():
+    if not txextract.have_native_extract():
         pytest.skip("native extractor unavailable")
 
     txs = gen_mixed_txs(10, seed=0xDEF)
@@ -618,7 +723,6 @@ async def test_tx_accumulator_isolates_malformed_tx():
     """The mempool accumulator batches many tx messages into one native
     extract; a malformed tx must fail only itself (its peer dies, its
     verdict is an error) while the rest of the batch still verdicts."""
-    import tpunode.node as node_mod
     from benchmarks.txgen import gen_mixed_txs, synth_amount
     from tpunode import TxVerdict
     from tpunode.peer import PeerDisconnected, PeerMessage
@@ -626,7 +730,7 @@ async def test_tx_accumulator_isolates_malformed_tx():
     from tpunode.verify.engine import VerifyConfig
     from tpunode.wire import LazyTx, MsgTx
 
-    if not node_mod._native_extract_available():
+    if not txextract.have_native_extract():
         pytest.skip("native extractor unavailable")
 
     txs = gen_mixed_txs(8, seed=0xBAD)
@@ -755,7 +859,7 @@ async def test_verify_shed_rate_limited_and_lossless_counts(monkeypatch):
     from tpunode.verify.engine import VerifyConfig
     from tpunode.wire import MsgTx
 
-    if not node_mod._native_extract_available():
+    if not txextract.have_native_extract():
         pytest.skip("native extractor unavailable")
     monkeypatch.setattr(node_mod.Node, "MAX_TX_ACCUM", 4)
 

@@ -5,7 +5,7 @@ batch read each, then the embedder's ``prevout_lookup`` row by row — and
 hands the rows on as the two lists the native extract converts.
 
 One parametrised family holds it to a kept copy of the walk the node made
-before (``_prevout_oracle``'s ``combined`` chain, one call a row): amounts
+before (the sources chained, one call a row through each): amounts
 and scripts row for row, and the embedder's recorded calls — arguments,
 their types, order, count.  Beside it: who shadows whom, an exception of
 the embedder's, a source that changes between two calls (no memo), the span
@@ -27,7 +27,7 @@ from chipbench import gen
 from chipbench import wirefmt as w
 from tpunode.mempool import Mempool, MempoolConfig, TxState, _Entry
 from tpunode.metrics import metrics
-from tpunode.node import Node, _prevout_info
+from tpunode.node import Node
 from tpunode.params import BCH_REGTEST
 from tpunode.store import MemoryKV, Namespaced
 from tpunode.utxo import UTXO_NAMESPACE, UtxoStore
@@ -44,16 +44,19 @@ with open(os.path.join(REPO, "chipbench", "traffic", "blocks.json")) as _f:
 # ---- the walk as the node made it before this change ------------------------
 
 
-def reference_oracle(node):
-    """``Node._prevout_oracle`` as it stood (and stands, for the Python
-    path): the sources in precedence, one call a row through each."""
-    sources = []
-    if node.mempool is not None and node.mempool.size():
-        sources.append(node.mempool.lookup_prevout)
-    if node.utxo is not None:
-        sources.append(node.utxo.lookup)
-    if node.cfg.prevout_lookup is not None:
-        sources.append(node.cfg.prevout_lookup)
+def prevout_info(res) -> tuple:
+    """A ``prevout_lookup`` result — a plain satoshi amount (the
+    pre-taproot form), an ``(amount, scriptPubKey)`` tuple, or None — as
+    ``(amount, script)``."""
+    if res is None:
+        return None, None
+    if isinstance(res, tuple):
+        return res[0], res[1]
+    return res, None
+
+
+def chained(sources: list):
+    """One lookup over ``sources``: the first answer that is not None."""
     if not sources:
         return None
 
@@ -65,6 +68,19 @@ def reference_oracle(node):
         return None
 
     return combined
+
+
+def reference_oracle(node):
+    """The prevout oracle as the node built it before the columnar walk:
+    the sources in precedence, one call a row through each."""
+    sources = []
+    if node.mempool is not None and node.mempool.size():
+        sources.append(node.mempool.lookup_prevout)
+    if node.utxo is not None:
+        sources.append(node.utxo.lookup)
+    if node.cfg.prevout_lookup is not None:
+        sources.append(node.cfg.prevout_lookup)
+    return chained(sources)
 
 
 def reference_walk(node, region, bch: bool, subset=None):
@@ -83,7 +99,7 @@ def reference_walk(node, region, bch: bool, subset=None):
     ext = [-1] * len(pv_wants)
     ext_scripts = [None] * len(pv_wants)
     for i in pv_wants.nonzero()[0]:
-        amt, script = _prevout_info(
+        amt, script = prevout_info(
             lookup(pv_txids[i].tobytes(), int(pv_vouts[i]))
         )
         if amt is not None:
@@ -180,7 +196,6 @@ def node_of(mempool, utxo, prevout_lookup) -> SimpleNamespace:
     node = SimpleNamespace(mempool=mempool, utxo=utxo, _inflight=None,
                            cfg=SimpleNamespace(prevout_lookup=prevout_lookup))
     node._prevout_sources = lambda: Node._prevout_sources(node)
-    node._prevout_oracle = lambda: Node._prevout_oracle(node)
     return node
 
 
@@ -244,26 +259,29 @@ def test_rows_and_embedder_calls_equal_the_reference_walk(shape, sources,
 
 @pytest.mark.parametrize("answer", sorted(ANSWERS))
 @pytest.mark.parametrize("sources", SOURCES)
-def test_the_python_paths_oracle_is_built_from_the_same_sources(sources,
-                                                                answer):
-    """``_prevout_oracle`` (one call a row, the Python verify path) and the
-    walk read one list, ``_prevout_sources``: the chain answers every
-    wanted outpoint as the chain did before, and asks the embedder as
-    often."""
+def test_the_walks_list_of_sources_is_the_reference_chains(sources, answer):
+    """``_prevout_sources``, the one list the walk reads: the sources it
+    names and no other, in the reference chain's precedence — asked a row
+    at a time in its order they answer every wanted outpoint as the chain
+    did before, and ask the embedder as often."""
     region, bch, subset = region_of("block-64")
     ref_emb, new_emb = Embedder(ANSWERS[answer]), Embedder(ANSWERS[answer])
     ref = reference_oracle(a_node(region, bch, subset, sources, ref_emb))
     node = a_node(region, bch, subset, sources, new_emb)
-    got = node._prevout_oracle()
+    mempool, inflight, utxo, embedder = node._prevout_sources()
+    assert inflight is None  # no block in flight
+    assert (mempool is not None) == ("mempool" in sources)
+    assert (utxo is not None) == ("utxo" in sources)
+    assert embedder is (new_emb if "embedder" in sources else None)
+    got = chained([lookup for lookup in (
+        mempool and mempool.lookup_prevout, utxo and utxo.lookup, embedder,
+    ) if lookup is not None])
     if sources == "none":
         assert got is None and ref is None
-        assert node._prevout_sources() == (None, None, None, None)
         return
     wanted = wanted_outpoints(region, bch, subset)
     assert [got(*o) for o in wanted] == [ref(*o) for o in wanted]
     assert new_emb.calls == ref_emb.calls
-    if sources == "embedder":
-        assert got is new_emb  # a single source is handed out as it is
 
 
 def test_shapes_hold_what_their_names_say():

@@ -1438,25 +1438,26 @@ async def test_streaming_pipeline_fakenet_acceptance():
     every unique tx exactly one clean verdict, per-lane priority
     ordering holds, the UTXO watermark only ever advances, zero task
     leaks."""
-    import tpunode.node as node_mod
     from benchmarks.txgen import gen_signed_txs
     from tests.fakenet import TxRelay, dummy_peer_connect, poll_until
     from tests.fixtures import all_blocks
-    from tpunode import BCH_REGTEST, ChainSynced, Node, NodeConfig, TxVerdict
+    from tpunode import (
+        BCH_REGTEST, ChainSynced, Node, NodeConfig, TxVerdict, txextract,
+    )
     from tpunode.mempool import MempoolConfig
     from tpunode.peer import PeerConnected, PeerMessage
     from tpunode.store import MemoryKV
     from tpunode.util import Reader
     from tpunode.wire import Block, BlockHeader, MsgBlock
 
-    if not node_mod._native_extract_available():
+    if not txextract.have_native_extract():
         pytest.skip("native extractor unavailable")
     net = BCH_REGTEST
     txs = gen_signed_txs(48, inputs_per_tx=1, seed=0x10AC)
     blocks = all_blocks()
-    # a SIGNED block (wire-round-tripped so it carries raw bytes and
-    # takes the native extract path): its sig items ride block-priority
-    # lanes; the coinbase-only chain blocks drive the UTXO watermark
+    # a SIGNED block (wire-round-tripped, as a peer's: it carries raw
+    # bytes): its sig items ride block-priority lanes; the coinbase-only
+    # chain blocks drive the UTXO watermark
     blk_txs = gen_signed_txs(24, inputs_per_tx=1, seed=0xB10C)
     hdr = BlockHeader(1, b"\x00" * 32, b"\x00" * 32, 0, 0x207FFFFF, 0)
     signed_block = Block.deserialize(

@@ -465,11 +465,10 @@ def test_native_extract_parity_with_schnorr():
 async def test_node_block_ingest_with_schnorr():
     import asyncio
 
-    import tpunode.node as node_mod
     from benchmarks.txgen import gen_mixed_txs, synth_amount
     from tests.fakenet import dummy_peer_connect
     from tests.fixtures import all_blocks
-    from tpunode import BCH_REGTEST, Node, NodeConfig, Publisher
+    from tpunode import BCH_REGTEST, Node, NodeConfig, Publisher, txextract
     from tpunode.node import TxVerdict
     from tpunode.peer import PeerConnected, PeerMessage
     from tpunode.store import MemoryKV
@@ -477,7 +476,7 @@ async def test_node_block_ingest_with_schnorr():
     from tpunode.verify.engine import VerifyConfig
     from tpunode.wire import Block, BlockHeader, MsgBlock
 
-    if not node_mod._native_extract_available():
+    if not txextract.have_native_extract():
         pytest.skip("native extractor unavailable")
     txs = gen_mixed_txs(10, seed=0x5C7, schnorr_every=2)
     hdr = BlockHeader(1, b"\x00" * 32, b"\x00" * 32, 0, 0x207FFFFF, 0)

@@ -625,11 +625,10 @@ async def test_node_end_to_end_taproot_mempool():
     (amount, script) oracle -> engine -> TxVerdict on the user bus."""
     import asyncio
 
-    import tpunode.node as node_mod
     from benchmarks.txgen import gen_mixed_txs, synth_prevout
     from tests.fakenet import dummy_peer_connect
     from tests.fixtures import all_blocks
-    from tpunode import PeerConnected
+    from tpunode import PeerConnected, txextract
     from tpunode.actors import Publisher
     from tpunode.node import Node, NodeConfig, TxVerdict
     from tpunode.params import BTC_REGTEST
@@ -639,7 +638,7 @@ async def test_node_end_to_end_taproot_mempool():
     from tpunode.verify.engine import VerifyConfig
     from tpunode.wire import MsgTx
 
-    if not node_mod._native_extract_available():
+    if not txextract.have_native_extract():
         pytest.skip("native extractor unavailable")
     txs = gen_mixed_txs(6, seed=0x7A12, mix=[(1.01, "p2tr")])
     msgs = [MsgTx.deserialize_payload(Reader(t.serialize())) for t in txs]
@@ -674,24 +673,25 @@ async def test_node_end_to_end_taproot_mempool():
 
 
 @pytest.mark.asyncio
-@pytest.mark.parametrize("use_native", [True, False])
+@pytest.mark.parametrize("held_to", ["native", "reference"])
 async def test_node_block_ingest_intra_block_taproot_spend(
-    use_native, monkeypatch
+    held_to, monkeypatch
 ):
     """A block where tx A creates a P2TR output and tx B key-spends it:
     the spend's (amount, script) resolve from the INTRA-BLOCK map (the
-    C++ out_script lane / the Python intra_block_prevouts dict — no
-    oracle involved), through the full node's lazy-block ingest on BTC
-    regtest.  Both ingest paths must agree."""
+    C++ out_script lane — no oracle involved), through the full node's
+    lazy-block ingest on BTC regtest.  ``reference``: the node's verdicts
+    are the Python reference's (``txverify.intra_block_prevouts`` dict,
+    ``tests/fixtures.py``), field by field."""
     import asyncio
 
     import tpunode.node as node_mod
+    from tests.fixtures import reference_verdicts, tuples
+    from tpunode import txextract
 
-    if not use_native:
-        monkeypatch.setattr(node_mod, "_native_extract_state", False)
-    elif not node_mod._native_extract_available():
+    if not txextract.have_native_extract():
         pytest.skip("native extractor unavailable")
-    # guard the "both paths" claim: count which lane actually ran
+    # the one ingest path is the lane that ran
     lane_calls = {"native": 0}
     orig_native = node_mod.Node._verify_txs_native
 
@@ -765,8 +765,10 @@ async def test_node_block_ingest_intra_block_taproot_spend(
     assert len(ev_b.verdicts) == 1 and ev_b.stats.extracted == 1
     # tx A's garbage input is unsupported, not a failure
     assert got[tx_a.txid].stats.unsupported == 1
-    # the parametrized lane is the lane that ran
-    assert (lane_calls["native"] > 0) == use_native
+    assert lane_calls["native"] == 1
+    if held_to == "reference":
+        assert tuples([got[tx_a.txid], ev_b]) == reference_verdicts(
+            [tx_a, tx_b], None, bch=False)
 
 
 def test_taproot_heavy_mix_coverage():

@@ -29,8 +29,8 @@ from tpunode.txextract import extract_raw  # noqa: E402
 
 
 def _python_reference(txs, bch=False, lookup=None):
-    """Run the Python path the way node._verify_txs does: intra-block
-    amounts first, then the embedder lookup."""
+    """The Python reference extraction in the node's precedence:
+    intra-block amounts first, then the embedder lookup."""
     block_outs = intra_block_amounts(txs) if len(txs) > 1 else {}
     all_items, all_stats = [], []
     for tx in txs:

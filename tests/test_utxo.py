@@ -203,16 +203,16 @@ async def _sync_and_connect_blocks(node, events, blocks):
 
 
 @pytest.mark.asyncio
-async def test_node_connects_blocks_and_serves_prevout_oracle(tmp_path):
+async def test_node_connects_blocks_and_serves_prevouts_from_its_set(tmp_path):
     blocks = all_blocks()
     store = LogKV(str(tmp_path / "node.log"))
     async with utxo_node(store, blocks) as (node, events):
         await _sync_and_connect_blocks(node, events, blocks)
         assert node.utxo.height == len(blocks)
         cb = blocks[2].txs[0]
-        oracle = node._prevout_oracle()
-        assert oracle is not None
-        assert oracle(cb.txid, 0) == (
+        # the set is the one prevout source this node has
+        assert node._prevout_sources() == (None, None, node.utxo, None)
+        assert node.utxo.lookup(cb.txid, 0) == (
             cb.outputs[0].value, cb.outputs[0].script,
         )
         assert node.health()["utxo_height"] == len(blocks)

@@ -342,13 +342,16 @@ def test_damaged_append_leaves_the_whole_block_or_none(
 # in the node: one parse a block, one ``utxo.connect`` span a connect
 
 @pytest.mark.asyncio
-@pytest.mark.parametrize("case", ["sharded", "small", "python-fallback"])
+@pytest.mark.parametrize("case", ["sharded", "small", "reference"])
 async def test_utxo_connect_span_once_a_block_from_a_worker(monkeypatch, case):
+    """``reference``: a node with no engine — the delta comes out of a
+    parse in the connect's worker — and every record of its UTXO namespace
+    is ``UtxoStore.apply_block``'s on a ``MemoryKV``."""
     import threading
 
     from benchmarks.txgen import gen_chain
     from tests.fakenet import poll_until
-    from tests.fixtures import all_blocks
+    from tests.fixtures import all_blocks, reference_set, utxo_records
     from tests.test_ibd import NET, ibd_node
     from tpunode import trace
 
@@ -357,8 +360,6 @@ async def test_utxo_connect_span_once_a_block_from_a_worker(monkeypatch, case):
                            mix=True)
     else:
         blocks = all_blocks()
-    monkeypatch.setenv(
-        "TPUNODE_UTXO_NATIVE", "0" if case == "python-fallback" else "1")
     seen, parses = [], []
 
     def watched(name):
@@ -382,17 +383,19 @@ async def test_utxo_connect_span_once_a_block_from_a_worker(monkeypatch, case):
     keys = ("span.utxo.connect.count", "span.utxo.connect.seconds",
             "span.utxo.connect.cpu_seconds", "utxo.applied")
     before = [metrics.get(k) for k in keys]
-    async with ibd_node(MemoryKV(), blocks, verify=True,
+    async with ibd_node(MemoryKV(), blocks, verify=case != "reference",
                         extract_workers=4) as (node, _events):
         await poll_until(lambda: node.utxo.height == len(blocks), timeout=60,
                          what=f"utxo catch-up ({case})")
+        records = utxo_records(node)
     count, seconds, cpu, applied = (
         metrics.get(k) - b for k, b in zip(keys, before))
     assert count == applied == len(blocks) == len(seen)
-    want = "apply_block" if case == "python-fallback" else "apply_ops_blob"
-    assert set(seen) == {(want, False, 1)}  # worker thread, inside the span
+    # worker thread, inside the span
+    assert set(seen) == {("apply_ops_blob", False, 1)}
     assert 0.0 <= cpu <= seconds + 0.02  # the clocks' ticks differ
     assert seconds > 0.0
-    if case != "python-fallback":
-        # the delta came out of the parse that verification made
-        assert sorted(parses) == sorted(len(b.txs) for b in blocks)
+    # one parse a block: the one verification made, or the connect's own
+    assert sorted(parses) == sorted(len(b.txs) for b in blocks)
+    if case == "reference":
+        assert records == reference_set(blocks)

@@ -8,7 +8,7 @@ last entry, a reopen, an interrupted load and the load that starts over.
 
 Node level: a node with ``utxo=True`` and ``prevout_lookup=None`` over a
 loaded snapshot gives the verdicts a node with the callback gives, and the
-Python oracle's, signature by signature, on both extraction paths; after N
+Python reference's (``tests/fixtures.py``), signature by signature; after N
 connected blocks its set is the reference's; a row no source answers is
 counted (``node.resolve_missing``), and ``utxo.lookup_hits`` and
 ``utxo.lookup_rows`` add up.
@@ -25,10 +25,12 @@ import pytest
 from chipbench import gen, reference_utxo
 from chipbench import wirefmt as w
 from tests.fakenet import poll_until
-from tests.test_verdict_reuse import (
-    NETJ, a_node, block_of, make_txs, plain_block, tuples,
+from tests.fixtures import (
+    reference_set, reference_verdicts, tuples, utxo_records,
 )
-from tpunode import node as node_mod
+from tests.test_verdict_reuse import (
+    NETJ, a_node, block_of, make_txs, plain_block,
+)
 from tpunode.events import events
 from tpunode.metrics import metrics
 from tpunode.store import LogKV, MemoryKV, Namespaced
@@ -332,53 +334,59 @@ def filler(n: int, seed: int) -> list:
     return out
 
 
-async def through(blocks, *, oracle, snapshot, python: bool, monkeypatch):
+async def through(blocks, *, oracle, snapshot):
     """The blocks through a node with ``utxo=True``: -> (verdicts, counter
-    deltas, the node's set after the last connect, its entry count)."""
-    if python:
-        monkeypatch.setattr(node_mod, "_native_extract_available", lambda: False)
-    try:
-        async with a_node(oracle=oracle, utxo=True, port=17931) as d:
-            if snapshot is not None:
-                d.node.utxo.load_snapshot(0, GENESIS, batches(snapshot, 97))
-            for blk in blocks:
-                d.node.chain.headers(d.peer, [blk.header])
-            await poll_until(lambda: d.node.chain.get_block(
-                blocks[-1].header.hash) is not None, what="header import")
-            c0 = {k: metrics.get(k) for k in COUNTERS}
-            got = []
-            for blk in blocks:
-                got += (await d.block(blk))[0]
-            await poll_until(lambda: d.node.utxo.height >= len(blocks),
-                             what="utxo connect")
-            moved = {k: int(metrics.get(k) - c0[k]) for k in COUNTERS}
-            return got, moved, d.node.utxo.snapshot(), d.node.utxo.entries
-    finally:
-        if python:
-            monkeypatch.undo()
+    deltas, the node's UTXO namespace after the last connect — every
+    record of it —, its entry count)."""
+    async with a_node(oracle=oracle, utxo=True, port=17931) as d:
+        if snapshot is not None:
+            d.node.utxo.load_snapshot(0, GENESIS, batches(snapshot, 97))
+        for blk in blocks:
+            d.node.chain.headers(d.peer, [blk.header])
+        await poll_until(lambda: d.node.chain.get_block(
+            blocks[-1].header.hash) is not None, what="header import")
+        c0 = {k: metrics.get(k) for k in COUNTERS}
+        got = []
+        for blk in blocks:
+            got += (await d.block(blk))[0]
+        await poll_until(lambda: d.node.utxo.height >= len(blocks),
+                         what="utxo connect")
+        moved = {k: int(metrics.get(k) - c0[k]) for k in COUNTERS}
+        return got, moved, utxo_records(d.node), d.node.utxo.entries
+
+
+def by_reference(blocks, prevouts) -> list:
+    """The blocks' verdict rows by the Python reference, a block at a time."""
+    return [row for blk in blocks
+            for row in reference_verdicts(list(blk.txs), prevouts, bch=True)]
 
 
 @pytest.mark.asyncio
-@pytest.mark.parametrize("path", ["native", "python"])
-async def test_a_node_without_a_callback_answers_from_its_snapshot(
-        path, monkeypatch):
+@pytest.mark.parametrize("path", ["native", "reference"])
+async def test_a_node_without_a_callback_answers_from_its_snapshot(path):
     """Verdicts identical, signature by signature, to a node with the
-    callback (and an empty set) and to the generator's expectation; no
-    callback is asked, no row goes unanswered, and the set afterwards is the
-    reference's: spent absent, created present and equal, filler untouched,
-    the count equal."""
+    callback (and an empty set) — or to the Python reference's, and every
+    record of the UTXO namespace to ``UtxoStore.apply_block``'s — and to
+    the generator's expectation; no callback is asked, no row goes
+    unanswered, and the set afterwards is the plain reference's: spent
+    absent, created present and equal, filler untouched, the count equal."""
     blocks, oracle, expect, spendable = chain_of(3, 21, seed=0x31)
     snapshot = spendable + filler(400, seed=0x32)
-    python = path == "python"
     async with asyncio.timeout(120):
-        own, moved, snap, n = await through(
-            blocks, oracle=None, snapshot=snapshot, python=python,
-            monkeypatch=monkeypatch)
-        called, moved_cb, _, _ = await through(
-            blocks, oracle=oracle, snapshot=None, python=python,
-            monkeypatch=monkeypatch)
+        own, moved, records, n = await through(
+            blocks, oracle=None, snapshot=snapshot)
+        if path == "reference":
+            assert tuples(own) == by_reference(blocks, oracle)
+            assert records == reference_set(
+                blocks, batches(snapshot, 97), GENESIS)
+        else:
+            called, moved_cb, _, _ = await through(
+                blocks, oracle=oracle, snapshot=None)
+            assert tuples(own) == tuples(called)
+            # the other node's set answered nothing and its callback everything
+            assert moved_cb["utxo.lookup_hits"] == 0
+            assert moved_cb["node.resolve_oracle_calls"] == len(spendable)
     assert len(own) == sum(b.tx_count for b in blocks)
-    assert tuples(own) == tuples(called)
     for v in own:
         if v.txid in expect:
             assert tuple(v.verdicts) == expect[v.txid] and v.error is None
@@ -386,13 +394,10 @@ async def test_a_node_without_a_callback_answers_from_its_snapshot(
     assert any(not all(expect[v.txid]) for v in own if v.txid in expect)
     assert moved["node.resolve_oracle_calls"] == 0
     assert moved["node.resolve_missing"] == 0
-    if not python:  # the batch read is the native walk's
-        assert moved["utxo.lookup_rows"] == moved["utxo.lookup_hits"] == len(
-            spendable) == moved["node.resolve_rows"]
-        # the other node's set answered nothing and its callback everything
-        assert moved_cb["utxo.lookup_hits"] == 0
-        assert moved_cb["node.resolve_oracle_calls"] == len(spendable)
+    assert moved["utxo.lookup_rows"] == moved["utxo.lookup_hits"] == len(
+        spendable) == moved["node.resolve_rows"]
     # conservation against the plain reference
+    snap = {k: v for k, v in records.items() if k[:1] == b"o"}
     ref = reference(snapshot)
     for blk in blocks:
         ref.apply_block(blk.header.serialize() + w.varint(blk.tx_count)
@@ -404,25 +409,28 @@ async def test_a_node_without_a_callback_answers_from_its_snapshot(
 
 
 @pytest.mark.asyncio
-@pytest.mark.parametrize("path", ["native", "python"])
-async def test_a_row_no_source_answers_is_counted(path, monkeypatch):
+@pytest.mark.parametrize("path", ["native", "reference"])
+async def test_a_row_no_source_answers_is_counted(path):
     """A snapshot short of three spendable entries and no callback: the
     three rows are counted, the inputs go unverified (``unsupported``), and
-    the set's misses show in hits against rows."""
+    the set's misses show in hits against rows; ``reference``: the Python
+    reference, asked of a table short of the same three, says the same of
+    every tx."""
     blocks, oracle, expect, spendable = chain_of(1, 16, seed=0x33)
     # the bare-P2PK rows' keys are in their prevout scripts: keep those
     short = [e for e in spendable if len(e[3]) == 25][:3]
     snapshot = [e for e in spendable if e not in short]
-    python = path == "python"
     async with asyncio.timeout(120):
         got, moved, _, _ = await through(
-            blocks, oracle=None, snapshot=snapshot, python=python,
-            monkeypatch=monkeypatch)
+            blocks, oracle=None, snapshot=snapshot)
+    if path == "reference":
+        table = {(t, v): (a, s) for t, v, a, s in snapshot}
+        assert tuples(got) == by_reference(
+            blocks, lambda txid, vout: table.get((txid, vout)))
     assert moved["node.resolve_missing"] == 3
     assert moved["node.resolve_oracle_calls"] == 0
-    if not python:
-        assert moved["utxo.lookup_rows"] - moved["utxo.lookup_hits"] == 3
-        assert moved["utxo.lookup_rows"] == len(spendable)
+    assert moved["utxo.lookup_rows"] - moved["utxo.lookup_hits"] == 3
+    assert moved["utxo.lookup_rows"] == len(spendable)
     assert sum(v.stats.unsupported for v in got) == 3
     assert sum(len(v.verdicts) for v in got) < sum(
         len(e) for e in expect.values())

@@ -7,7 +7,8 @@ only the rest — the analogue of Bitcoin Core's signature cache, which
 One parametrised family.  Every case relays transactions to a node (or does
 not), hands it a block, and holds the block's verdicts — tx by tx, signature
 by signature — to (i) a mempool-less node's on the same block, (ii) the
-Python path's, and to the generator's by-construction expectation; the rules
+Python reference's (``tests/fixtures.py``), and to the generator's
+by-construction expectation; the rules
 that keep a reused verdict exact each have their case.
 """
 
@@ -24,15 +25,15 @@ import pytest
 from chipbench import gen
 from chipbench import wirefmt as w
 from tests.fakenet import dummy_peer_connect, poll_until
+from tests.fixtures import reference_verdicts, tuples
 from tpunode import BCH_REGTEST, BTC_REGTEST, Node, NodeConfig, Publisher, TxVerdict
-from tpunode import node as node_mod
 from tpunode.mempool import MempoolConfig, TxState
 from tpunode.metrics import metrics
 from tpunode.peer import PeerConnected, PeerMessage
 from tpunode.store import MemoryKV
 from tpunode.util import Reader
 from tpunode.verify.engine import VerifyConfig
-from tpunode.wire import BlockHeader, LazyBlock, MsgBlock, MsgTx
+from tpunode.wire import Block, BlockHeader, LazyBlock, MsgBlock, MsgTx
 
 txextract = pytest.importorskip("tpunode.txextract")
 if not txextract.have_native_extract():
@@ -157,34 +158,22 @@ async def a_node(*, mempool: MempoolConfig | None = None, utxo: bool = False,
                     await task
 
 
-def tuples(verdicts: list) -> list:
-    return [(v.txid, v.valid, tuple(v.verdicts), v.stats, v.error)
-            for v in verdicts]
-
-
-async def plain_block(blk: LazyBlock, oracle, *, python: bool = False,
-                      utxo: bool = False, headers: list = (),
-                      monkeypatch=None, net=BCH_REGTEST) -> tuple:
-    """The same block through a node with no mempool (nothing to reuse), on
-    the native path or the Python one: the verdicts to hold a reuse to."""
-    if python:
-        monkeypatch.setattr(node_mod, "_native_extract_available", lambda: False)
-    try:
-        async with a_node(oracle=oracle, utxo=utxo, port=17902, net=net) as d:
-            for h in headers:
-                d.node.chain.headers(d.peer, [h])
-                await poll_until(
-                    lambda: d.node.chain.get_block(h.hash) is not None,
-                    what="header import")
-            got, subs, counters = await d.block(blk)
-            if utxo:
-                await poll_until(lambda: d.node.utxo.height >= 1,
-                                 what="utxo connect")
-            snap = d.node.utxo.snapshot() if utxo else None
-            return got, subs, counters, snap
-    finally:
-        if python:
-            monkeypatch.undo()
+async def plain_block(blk: LazyBlock, oracle, *, utxo: bool = False,
+                      headers: list = (), net=BCH_REGTEST) -> tuple:
+    """The same block through a node with no mempool (nothing to reuse):
+    the verdicts to hold a reuse to."""
+    async with a_node(oracle=oracle, utxo=utxo, port=17902, net=net) as d:
+        for h in headers:
+            d.node.chain.headers(d.peer, [h])
+            await poll_until(
+                lambda: d.node.chain.get_block(h.hash) is not None,
+                what="header import")
+        got, subs, counters = await d.block(blk)
+        if utxo:
+            await poll_until(lambda: d.node.utxo.height >= 1,
+                             what="utxo connect")
+        snap = d.node.utxo.snapshot() if utxo else None
+        return got, subs, counters, snap
 
 
 def mixed_case(n_known: int = 36, n_unseen: int = 7, seed: int = 27):
@@ -202,7 +191,7 @@ def mixed_case(n_known: int = 36, n_unseen: int = 7, seed: int = 27):
 CASES = [
     "mixed", "invalid-tuple", "pending", "degraded", "evicted", "witness",
     "twice", "mempool-off", "mempool-empty", "block-verdicts-not-stored",
-    "utxo", "python-path-stores-no-block-verdict", "all-known",
+    "utxo", "hand-built-block", "all-known",
 ]
 
 
@@ -220,7 +209,8 @@ def _engine_txids(subs: list) -> set:
 async def _case_mixed(monkeypatch):
     """Valid, invalid, multisig and Schnorr txs relayed, then a block of
     them plus unseen ones: equal to a mempool-less node's verdicts, to the
-    Python path's and to construction; the engine sees the unseen txs only."""
+    Python reference's and to construction; the engine sees the unseen txs
+    only."""
     known, unseen, oracle, body, expect = mixed_case()
     assert any(not all(e) for e in known["expect"])
     assert any(not all(e) for e in unseen["expect"])
@@ -254,9 +244,7 @@ async def _case_mixed(monkeypatch):
     assert tuples(got) == tuples(plain)
     assert plain_counters["node.reuse_lookups"] == 0
     assert sum(s[1] for s in plain_subs) == gen.totals(MIX, len(body))["items"]
-    py, _, _, _ = await plain_block(blk, oracle, python=True,
-                                    monkeypatch=monkeypatch)
-    assert tuples(got) == tuples(py)
+    assert tuples(got) == reference_verdicts(list(blk.txs), oracle, bch=True)
 
 
 async def _case_invalid_tuple(monkeypatch):
@@ -505,19 +493,23 @@ async def _case_utxo(monkeypatch):
     assert snap == plain_snap and len(snap) > 29
 
 
-async def _case_python_path_stores_no_block_verdict(monkeypatch):
-    """The Python path stays the reference: no reuse, and its block
-    verdicts are not written to the store either."""
+async def _case_hand_built_block(monkeypatch):
+    """A ``wire.Block`` built in-process (``raw_txs is None``) is its wire
+    form at the node's door: its known txs are answered from relay under
+    the hash of the bytes it serialises to, the engine sees the rest, the
+    verdicts are the Python reference's, and no block verdict is stored."""
     known, unseen, oracle, body, expect = mixed_case(10, 2, seed=0xAA)
-    blk = block_of(body)
+    lazy = block_of(body)
+    blk = Block(lazy.header, lazy.txs)
+    assert blk.raw_txs is None
     async with a_node(mempool=MempoolConfig(tick_interval=0.05),
                       oracle=oracle) as d:
         await d.relay(known["raw"])
-        monkeypatch.setattr(node_mod, "_native_extract_available",
-                            lambda: False)
         got, subs, counters = await d.block(blk)
-        monkeypatch.undo()
-        assert all(v == 0 for v in counters.values())
+        assert counters["node.reuse_hits"] == 10
+        assert counters["node.reuse_lookups"] == blk.tx_count == 13
+        assert _engine_txids(subs) - {got[0].txid} == set(unseen["txids"])
+        assert tuples(got) == reference_verdicts(list(blk.txs), oracle, bch=True)
         assert [tuple(v.verdicts) for v in got[1:]] == [
             expect[v.txid] for v in got[1:]]
         await poll_until(lambda: d.node.mempool.state(

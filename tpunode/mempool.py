@@ -31,7 +31,7 @@ txs are stolen straight from the roofline (PERF.md).  This actor owns:
   (verify-what's-extractable — the pre-mempool behavior) so the
   embedder still gets a verdict; size pressure never loses one.
 * **Confirmation eviction** — block connect (txids from the block
-  ingest path, C++-computed on the native path) flips entries to
+  ingest path, C++-computed) flips entries to
   CONFIRMED, drops their payloads, and re-checks waiting orphans.
 * **Relay verdicts for the block path** (ISSUE 27) — a finished entry
   keeps the ``ExtractStats`` of its relay-time extraction beside its

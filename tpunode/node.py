@@ -33,7 +33,6 @@ from .actors import (
     LinkedTasks,
     Publisher,
     Supervisor,
-    spawn_supervised,
     task_registry,
 )
 from .blackbox import FlightRecorder, FlightRecorderConfig
@@ -54,13 +53,7 @@ from .tracectx import (
     tracer,
 )
 from .watchdog import Watchdog, WatchdogConfig
-from .txverify import (
-    ExtractStats,
-    combine_verdicts,
-    extract_sig_items,
-    intra_block_prevouts,
-    wants_amount,
-)
+from .txverify import ExtractStats
 from .verify.engine import VerifyConfig, VerifyEngine
 from .verify.sched import affinity_key
 from .params import NODE_NETWORK, Network
@@ -80,8 +73,7 @@ from .receipts import ReceiptLog
 from .serve import ServeServer, TenantConfig
 from .ibd import BlockFetcher, IbdConfig
 from .utxo import (
-    UNDO_DEPTH_DEFAULT, UTXO_NAMESPACE, InflightOutputs,
-    NativeInflightOutputs, UtxoStore,
+    UNDO_DEPTH_DEFAULT, UTXO_NAMESPACE, NativeInflightOutputs, UtxoStore,
 )
 from .wire import (
     InvType,
@@ -107,9 +99,6 @@ __all__ = [
 
 
 log = logging.getLogger("tpunode.node")
-
-
-_native_extract_state: Optional[bool] = None
 
 
 def _parse_region(raw: bytes, n_txs: int, want_delta: bool,
@@ -320,39 +309,21 @@ def _hash_rows(rows) -> "list[bytes]":
     return [blob[i : i + 32] for i in range(0, len(blob), 32)]
 
 
-def _native_extract_available() -> bool:
-    """Does the native extractor load on this box?  Cached; the first call
-    may run `make` (one attempt per process, like the other native libs)."""
-    global _native_extract_state
-    if _native_extract_state is None:
-        try:
-            from .txextract import have_native_extract
-
-            _native_extract_state = have_native_extract()
-        except Exception:
-            _native_extract_state = False
-        if not _native_extract_state:
-            log.info("[Node] native tx extractor unavailable; python path")
-    return _native_extract_state
-
-
-_NULL_TXID = b"\x00" * 32  # a coinbase input's outpoint
-
-
 def _rows_of(table: "np.ndarray") -> "list[bytes]":
     """A C-contiguous ``(n, width)`` uint8 table as ``n`` ``bytes``, in one
     conversion (no slice a row)."""
     return table.reshape(-1).view(f"V{table.shape[1]}").tolist()
 
 
-def _prevout_info(res) -> "tuple[Optional[int], Optional[bytes]]":
-    """Normalize a ``prevout_lookup`` result: plain satoshi amount (the
-    pre-taproot form), an ``(amount, scriptPubKey)`` tuple, or None."""
-    if res is None:
-        return None, None
-    if isinstance(res, tuple):
-        return res[0], res[1]
-    return res, None
+def _tx_region(block) -> bytes:
+    """A block's tx region as wire bytes: what its message carried, or —
+    an object built in-process without them (``Block.raw_txs is None``:
+    tests, an embedder's own injection) — its txs serialised at this
+    door, so that it goes the way a peer's block goes."""
+    raw = block.raw_txs
+    if raw is None:
+        raw = b"".join(tx.serialize() for tx in block.txs)
+    return raw
 
 
 @dataclass(frozen=True)
@@ -539,6 +510,21 @@ class Node:
 
     def __init__(self, cfg: NodeConfig):
         self.cfg = cfg
+        if cfg.verify is not None or cfg.utxo:
+            # the one ingest path takes wire bytes through the native
+            # extractor (built on first use): a node that verifies or
+            # keeps a set does not start without it.  A header-only node
+            # (the reference's haskoin-node) needs nothing of it.
+            from .txextract import load_txextract_lib
+
+            try:
+                load_txextract_lib()
+            except Exception as e:
+                raise RuntimeError(
+                    "tpunode: NodeConfig.verify / NodeConfig.utxo need the "
+                    "native extractor libtxextract, which did not build or "
+                    f"load (run `make -C native`): {e}"
+                ) from e
         # Internal glue buses are unbounded: their only subscribers are the
         # linked router loops (always draining; death tears the node down),
         # and dropping a control message (headers, version) would corrupt
@@ -624,12 +610,9 @@ class Node:
         # outputs of blocks parsed and not yet connected, as a prevout
         # source (ISSUE 44); a node with a UTXO set has it.  Memory only:
         # a restart resumes at the watermark with the view empty
-        self._inflight: Optional[InflightOutputs] = None
-        if self.utxo is not None:
-            self._inflight = (
-                NativeInflightOutputs() if _native_extract_available()
-                else InflightOutputs()
-            )
+        self._inflight: Optional[NativeInflightOutputs] = (
+            NativeInflightOutputs() if self.utxo is not None else None
+        )
         # the order of resolves (:meth:`_resolve_gate`): blocks in flight
         # whose own outputs and every predecessor's are in the view, and
         # the resolves held back, by the block they wait for
@@ -1256,33 +1239,6 @@ class Node:
             inflight = None
         return mempool, inflight, self.utxo, self.cfg.prevout_lookup
 
-    def _prevout_oracle(self):
-        """The prevout lookup the Python verify path consults: one call a
-        row through :meth:`_prevout_sources` in order, the first answer
-        that is not None wins.  None when nothing can answer."""
-        mempool, inflight, utxo, oracle = self._prevout_sources()
-        sources = [
-            lookup for lookup in (
-                mempool.lookup_prevout if mempool is not None else None,
-                inflight.lookup if inflight is not None else None,
-                utxo.lookup if utxo is not None else None,
-                oracle,
-            ) if lookup is not None
-        ]
-        if not sources:
-            return None
-        if len(sources) == 1:
-            return sources[0]
-
-        def combined(txid: bytes, vout: int):
-            for lookup in sources:
-                res = lookup(txid, vout)
-                if res is not None:
-                    return res
-            return None
-
-        return combined
-
     # -- outputs of blocks in flight (ISSUE 44) -------------------------------
 
     def _inflight_done(self, block_hash: bytes) -> None:
@@ -1333,7 +1289,7 @@ class Node:
         self._gate_passed.update(walked)
         return None
 
-    async def _resolve_gate(self, block, resolve=None):
+    async def _resolve_gate(self, block, resolve):
         """The order of resolves: a block's prevouts are read when every
         block between the UTXO watermark and itself has published its
         outputs to the in-flight view — parsed, not verified, not
@@ -1344,7 +1300,7 @@ class Node:
         A block beneath that is here and not yet parsed is waited for: it
         publishes within its parse.  One that has not come is waited for
         only by a block that needs it: ``resolve(final=...)`` reads the
-        block's rows (the native path), and a block all of whose rows
+        block's rows, and a block all of whose rows
         have an answer goes on — an outpoint's value is fixed by its
         txid, so whichever source answers says what the view would have
         said, and a node whose callback answers every row, or a block
@@ -1352,9 +1308,7 @@ class Node:
         nobody.  A block with a row that no source answers yet waits for
         the nearest block that is missing, is woken when that block
         reaches this gate itself (or is let go), and is read again.
-        Without ``resolve`` (the Python path reads a row at a time,
-        inside its extraction) every block that is early waits.
-        -> what ``resolve`` returned, else None.
+        -> what ``resolve`` returned.
 
         While it waits a block is no ingest pressure (as a parked block
         is none: the planner must be free to ask again for the block it
@@ -1379,7 +1333,7 @@ class Node:
             if missing is None:
                 self._gate_passed.add(block_hash)
                 break
-            if resolve is not None and missing not in self._blocks_taken:
+            if missing not in self._blocks_taken:
                 rows = resolve(final=False)
                 if rows is not None:
                     break  # nothing of the blocks it is ahead of is needed
@@ -1402,7 +1356,7 @@ class Node:
                 self._gate_passed.add(block_hash)
                 break
         record_span("node.resolve_gate", waited)
-        if rows is None and resolve is not None:
+        if rows is None:
             rows = resolve()
         return rows
 
@@ -1649,57 +1603,37 @@ class Node:
     async def _utxo_connect_off_loop(
         self, height: int, block, delta=None
     ) -> None:
-        """The physical connect, in a worker thread under the
-        ``utxo.connect`` span (one a block, ``cpu=True``).  Native fast
-        path: the block's delta blob — made by the one parse its
-        verification ran (``delta``), else by a parse here (a block that
-        came another way: no engine, Python verify path) — goes through
-        ``UtxoStore.apply_ops_blob`` into the log and the index with no
-        Python object per operation (ISSUE 11, ISSUE 26).  The Python
-        ``apply_block`` path stays the reference and the fallback
-        (``TPUNODE_UTXO_NATIVE=0``, eager blocks without raw bytes, no
-        native toolchain); both produce bit-identical stores
-        (tests/test_utxo.py, tests/test_utxo_delta.py)."""
+        """The physical connect, in a worker of the extraction pool under
+        the ``utxo.connect`` span (one a block, ``cpu=True``).  The
+        block's delta blob — made by the one parse its verification ran
+        (``delta``), else by a parse here (a node with no engine) — goes
+        through ``UtxoStore.apply_ops_blob`` into the log and the index
+        with no Python object per operation (ISSUE 11, ISSUE 26).
+        ``UtxoStore.apply_block`` is the reference it is held to: both
+        produce bit-identical stores (tests/test_utxo.py,
+        tests/test_utxo_delta.py)."""
         assert self.utxo is not None
         utxo = self.utxo
         inflight = self._inflight
         block_hash = block.header.hash
-        raw = getattr(block, "raw_txs", None)
-        native = delta is not None or (
-            raw is not None and self._utxo_native()
-        )
-
-        def apply():
-            if not native:
-                return utxo.apply_block(height, block_hash, list(block.txs))
-            ops = delta
-            if ops is None:
-                from .txextract import ParsedTxRegion
-
-                with ParsedTxRegion(raw, block.tx_count) as region:
-                    ops = region.utxo_ops()
-            return utxo.apply_ops_blob(height, block_hash, *ops)
 
         def connect():
             with span("utxo.connect", cpu=True):
-                applied = apply()
+                ops = delta
+                if ops is None:
+                    from .txextract import ParsedTxRegion
+
+                    with ParsedTxRegion(
+                        _tx_region(block), block.tx_count
+                    ) as region:
+                        ops = region.utxo_ops()
+                applied = utxo.apply_ops_blob(height, block_hash, *ops)
             if applied and inflight is not None:
                 # the set answers for the block's outputs from here on:
                 # the view's copy goes, in this thread
                 inflight.retire(block_hash)
 
-        if native:
-            await self._run_extract(connect)
-        else:
-            await asyncio.to_thread(connect)
-
-    @staticmethod
-    def _utxo_native() -> bool:
-        """Does block connect take the extractor's delta blob?"""
-        return (
-            _native_extract_available()
-            and os.environ.get("TPUNODE_UTXO_NATIVE", "1") != "0"
-        )
+        await self._run_extract(connect)
 
     async def _utxo_unwind_reorg(self, block) -> bool:
         """Disconnect tip blocks (per-block UNDO records, ISSUE 11) until
@@ -1820,10 +1754,10 @@ class Node:
                 elif self.verify_engine is not None and isinstance(msg, MsgTx):
                     self._submit_verify_tx(p, msg.tx)
                 elif self.verify_engine is not None and isinstance(msg, MsgBlock):
-                    # the block stays lazy (wire.LazyBlock): the native path
-                    # never parses its txs in Python.  Confirmation
-                    # eviction rides the ingest path (txids are computed
-                    # there, natively when possible).
+                    # the block stays lazy (wire.LazyBlock): ingest never
+                    # parses its txs in Python.  Confirmation eviction
+                    # rides the ingest path (txids are computed there,
+                    # natively).
                     if self.ibd is not None:
                         self.ibd.block_arrived(p, msg.block.header.hash)
                     self._submit_verify(p, block=msg.block)
@@ -1954,12 +1888,12 @@ class Node:
                 for i, res in zip(rows, answers):
                     if res is None:
                         left.append(i)
-                    elif isinstance(res, tuple):  # as _prevout_info reads it
+                    elif isinstance(res, tuple):  # (amount, scriptPubKey)
                         if res[0] is not None:
                             amounts[i] = res[0]
                         if res[1]:
                             scripts[i] = res[1]
-                    else:
+                    else:  # the pre-taproot form: the amount alone
                         amounts[i] = res
                 return left
 
@@ -2032,13 +1966,13 @@ class Node:
         accumulator and make sure a drain task is running.  Coalescing many
         single-tx messages into one native extract + one engine batch is
         what lifts the firehose off the per-message task/thread overhead
-        that bounded round 3 at ~820 sigs/s (VERDICT r3 item 5).  Falls
-        back to the per-message Python path when raw bytes or the native
-        extractor are unavailable."""
+        that bounded round 3 at ~820 sigs/s (VERDICT r3 item 5).  A tx
+        built in-process without wire bytes (``Tx.raw is None``: tests, an
+        embedder's own injection) is serialised here, once, and goes the
+        same way."""
         raw = tx.raw
-        if raw is None or not _native_extract_available():
-            self._submit_verify(peer, txs=[tx], raw=raw)
-            return
+        if raw is None:
+            raw = tx.serialize()
         if len(self._tx_accum) >= self.MAX_TX_ACCUM:
             metrics.inc("node.verify_dropped")
             self._publish_shed(peer, 1)
@@ -2273,8 +2207,8 @@ class Node:
             pairs = []
             for shard, items in zip(shards, extracted):
                 if items is None:
-                    # isolate the offender: each tx goes through the
-                    # single-tx native path on its own (error verdicts +
+                    # isolate the offender: each tx goes through
+                    # _verify_txs_native on its own (error verdicts +
                     # peer kill there; finishes each tx's trace too)
                     for peer, tx, raw, act in shard:
                         with _activate_trace(act):
@@ -2370,24 +2304,15 @@ class Node:
             if act is not None:
                 tracer.finish(act[0])
 
-    def _submit_verify(
-        self,
-        peer,
-        txs: Optional[list[Tx]] = None,
-        raw: Optional[bytes] = None,
-        block=None,
-    ) -> None:
-        """Fan inbound transactions into the batch verify engine without
-        blocking the event-routing loop; one TxVerdict per tx lands on the
-        user bus when its batch completes (or fails: ``error`` set).
-
-        Tx messages pass ``txs`` (+ ``raw`` wire bytes); block messages
-        pass ``block`` (a wire.LazyBlock), whose tx region is handed to
-        the native extractor without ever parsing txs in Python.  When the
-        native extractor builds on this box, extraction runs in C++
-        straight from wire bytes (~13x the Python path; PERF.md) — the
-        Python path remains the reference and the fallback."""
-        if block is not None and self._persisted_height(block) is not None:
+    def _submit_verify(self, peer, block) -> None:
+        """Fan a block message's transactions into the batch verify engine
+        without blocking the event-routing loop; one TxVerdict per tx
+        lands on the user bus when its batch completes (or fails:
+        ``error`` set).  The block's tx region goes to the native
+        extractor as wire bytes (a ``wire.LazyBlock``'s own; see
+        :func:`_tx_region`) without ever parsing txs in Python; relayed
+        txs come in through :meth:`_submit_verify_tx`."""
+        if self._persisted_height(block) is not None:
             # restart replay (ISSUE 9): this block is at or below the
             # persistent UTXO watermark — it was fully verified AND its
             # UTXO delta durably applied before a crash/restart, so
@@ -2396,50 +2321,24 @@ class Node:
             metrics.inc("node.block_replay_skipped")
             _discard_active_trace()
             return
-        if block is not None and self._block_taken(block.header.hash):
+        if self._block_taken(block.header.hash):
             # above the watermark, and already here: its verdicts are
             # out or on their way, exactly once
             metrics.inc("node.block_duplicate_skipped")
             _discard_active_trace()
             return
-        n_txs = block.tx_count if block is not None else len(txs)
+        n_txs = block.tx_count
         if self._verify_pending >= self.MAX_VERIFY_PENDING:
             metrics.inc("node.verify_dropped", n_txs)
             self._publish_shed(peer, n_txs)
-            if txs is not None:  # block txs are never mempool-admitted
-                self._mempool_shed(txs)
             _discard_active_trace()  # shed: pipeline ends here, unretained
             return
         self._verify_pending += 1
-        if block is not None:
-            self._blocks_taken.add(block.header.hash)
-            raw = block.raw_txs
-        if raw is not None and _native_extract_available():
-            coro = self._verify_txs_native(peer, raw, n_txs, block=block, txs=txs)
-        else:
-            if txs is None:
-                try:
-                    txs = list(block.txs)  # python fallback parses lazily
-                except Exception as e:
-                    # Malformed lazy tx region: the eager decode used to
-                    # surface this as a DecodeError in the peer loop (and
-                    # kill the peer); with lazy blocks it surfaces here —
-                    # report it and kill the peer, never crash the router.
-                    self._verify_pending -= 1
-                    self._blocks_taken.discard(block.header.hash)
-                    self._verify_failure("block-decode", e)
-                    self._publish_verdict(
-                        TxVerdict(peer, b"", False, (), ExtractStats(),
-                                  error=f"block decode: {e}")
-                    )
-                    peer.kill(CannotDecodePayload(f"block: {e}"))
-                    _finish_active_trace()  # verdict published: trace ends
-                    return
-            if block is not None and self.mempool is not None:
-                # python-path block connect: txs parsed above anyway
-                self.mempool.confirmed([tx.txid for tx in txs])
-            coro = self._verify_txs(peer, txs, block=block)
-        self._verify_tasks.add_child(coro, name="verify-txs")
+        self._blocks_taken.add(block.header.hash)
+        self._verify_tasks.add_child(
+            self._verify_txs_native(peer, _tx_region(block), n_txs, block=block),
+            name="verify-txs",
+        )
 
     async def _verify_txs_native(
         self,
@@ -2450,13 +2349,14 @@ class Node:
         txs: Optional[list[Tx]] = None,
         tracked: bool = True,  # False: caller owns _verify_pending
     ) -> None:
-        """Native-extract fast path of :meth:`_verify_txs`: parse + sighash +
-        DER + pubkey decode run in C++ over the original wire bytes
-        (tpunode/txextract.py), and the packed item arrays go to the engine
-        with no per-item Python objects — for a block, not even Tx objects
-        (prevouts for the amount oracle come from ``scan_outpoints``, C++
-        too).  Bit-identical verdicts to the Python path
-        (tests/test_txextract.py).
+        """Verify every tx of one message — a block, or one relayed tx the
+        drain isolates: parse + sighash + DER + pubkey decode run in C++
+        over the original wire bytes (tpunode/txextract.py), and the
+        packed item arrays go to the engine with no per-item Python
+        objects — for a block, not even Tx objects (prevouts for the
+        amount oracle come from ``scan_outpoints``, C++ too).
+        Bit-identical verdicts to the Python reference,
+        ``txverify.extract_sig_items`` (tests/test_txextract.py).
 
         A message's txs are cut into shards (:meth:`_n_extract_jobs`),
         and each shard is a chain of its own — extract job →
@@ -2470,11 +2370,9 @@ class Node:
         as that shard's rows are answered (:class:`_ExtractJobs`,
         :meth:`_resolve_ext_rows`) — nothing but the parse stands before a
         block's first job.  A message of one job goes as it did: the walk,
-        then the job.  One
-        behavioral difference to the Python path: an extract error fails
-        every tx of the message that has no verdict yet (the failed job's
-        and those of the jobs not handed on; the Python path can fail
-        per tx)."""
+        then the job.  An extract error fails every tx of the message
+        that has no verdict yet: the failed job's and those of the jobs
+        not handed on."""
         assert self.verify_engine is not None
         from .txextract import ParsedTxRegion
 
@@ -2538,8 +2436,7 @@ class Node:
                     # and goes with this frame if they never are.
                     region, delta, wire = await self._run_extract(
                         _parse_region, raw, n_txs,
-                        block is not None and self.utxo is not None
-                        and self._utxo_native(),
+                        block is not None and self.utxo is not None,
                         # a block's txs may have relay verdicts only where
                         # a mempool holds one: a node without (or with an
                         # empty one: IBD, big-block replay) hashes and
@@ -2590,7 +2487,7 @@ class Node:
                 # Out-of-block prevout rows via the embedder's oracle,
                 # flattened per input in parse order.  The native side
                 # consults its intra-block map FIRST, so resolving every
-                # wants-marked input here matches the Python path's
+                # wants-marked input here keeps the reference's
                 # block_outs -> prevout_lookup precedence (an in-block hit
                 # shadows whatever the oracle would have said).  One hold
                 # for the whole message: every shard's rows are read from
@@ -2794,159 +2691,6 @@ class Node:
             min(workers, n // self.MIN_SHARD_TXS),
             -(-n // self.STREAM_SHARD_TXS),
         )
-
-    async def _verify_txs(self, peer, txs: list[Tx], block=None) -> None:
-        """Verify every tx of one message.  All txs' signatures are submitted
-        to the engine CONCURRENTLY so a whole block coalesces into full
-        device batches (awaiting per tx would degrade a 150k-sig block into
-        sequential tiny batches).  ``block``: the originating block, UTXO-
-        connected only after every verdict published without an error."""
-        assert self.verify_engine is not None
-        # Intra-block prevouts: a block message carries the funding tx for
-        # every in-block spend — exactly what BIP143 (amount) and BIP341
-        # (amount + script) digests need (VERDICT r2 item 5 / r4 item 3).
-        # Misses fall through to cfg.prevout_lookup.
-        block_outs = intra_block_prevouts(txs) if len(txs) > 1 else {}
-        per_tx: list[tuple[Tx, ExtractStats, list, Optional[asyncio.Task]]] = []
-        clean = True  # no extract/engine error verdicts published
-        connecting = False
-        try:
-            with span("node.extract"):
-                if block is not None and self._inflight is not None:
-                    # as the native path: this block's outputs are a
-                    # prevout source for the blocks after it, and its own
-                    # prevouts are read in the chain's order (ISSUE 44)
-                    try:
-                        self._inflight.publish_txs(
-                            block.header.hash, block.header.prev, txs
-                        )
-                    except Exception:
-                        pass  # a malformed tx: the walk below reports it
-                    await self._resolve_gate(block)
-                oracle = self._prevout_oracle()
-                for tx in txs:
-                    try:
-                        # everything touching tx attributes goes inside the
-                        # guard: a malformed LazyTx (wire.LazyTx) raises on
-                        # first attribute access, which must become an error
-                        # verdict + peer kill, never a dead ingest task
-                        amounts: dict[int, int] = {}
-                        scripts: dict[int, bytes] = {}
-                        for idx, txin in enumerate(tx.inputs):
-                            key = (txin.prevout.txid, txin.prevout.index)
-                            # Precedence mirrors the native resolve(): the
-                            # intra-block map is consulted for EVERY input (a
-                            # dict hit is free, and classification must see
-                            # in-block P2TR scripts identically on both
-                            # paths); the external oracle only for inputs the
-                            # tx-level witness gate marks (review r5 parity
-                            # finding).
-                            hit = block_outs.get(key)
-                            if hit is not None:
-                                amt, script = hit
-                            elif oracle is not None and (
-                                wants_amount(tx, idx, self.cfg.net.bch)
-                            ):
-                                res = oracle(*key)
-                                # as the native walk counts (a coinbase's
-                                # null outpoint is no row of its)
-                                if res is None and key[0] != _NULL_TXID:
-                                    metrics.inc("node.resolve_missing")
-                                amt, script = _prevout_info(res)
-                            else:
-                                amt = script = None
-                            if amt is not None:
-                                amounts[idx] = amt
-                            if script is not None:
-                                scripts[idx] = script
-                        items, stats = extract_sig_items(
-                            tx,
-                            prevout_amounts=amounts or None,
-                            bch=self.cfg.net.bch,
-                            prevout_scripts=scripts or None,
-                        )
-                    except Exception as e:
-                        clean = False
-                        self._verify_failure("extract", e)
-                        try:
-                            txid = tx.txid
-                        except Exception:
-                            txid = b""  # unparseable lazy tx: aggregate
-                            peer.kill(CannotDecodePayload(f"tx: {e}"))
-                        self._publish_verdict(
-                            TxVerdict(peer, txid, False, (), ExtractStats(),
-                                      error=f"extract: {e}"),
-                            relay=block is None,
-                        )
-                        continue
-                    metrics.inc("node.verify_txs")
-                    metrics.inc("node.verify_inputs", stats.total_inputs)
-                    metrics.inc("extract.unsupported_inputs", stats.unsupported)
-                    task = None
-                    if items:
-                        task = spawn_supervised(
-                            self.verify_engine.verify(
-                                [i.verify_item for i in items],
-                                priority=(
-                                    self._block_priority()
-                                    if block is not None
-                                    else "mempool"
-                                ),
-                            ),
-                            name="verify-sigbatch",
-                            owner=self._verify_tasks,
-                        )
-                    per_tx.append((tx, stats, items, task))
-            # Awaiting the engine happens OUTSIDE any commit span — the
-            # wait is carried by the engine's own spans (sched.linger /
-            # sched.slot_wait, then verify.lane with its dispatch and
-            # delivery), and folding it into node.commit would make that
-            # histogram mean something different on this path than on
-            # the native one.
-            for tx, stats, items, task in per_tx:
-                if task is None:
-                    self._publish_verdict(
-                        TxVerdict(peer, tx.txid, True, (), stats),
-                        relay=block is None,
-                    )
-                    continue
-                try:
-                    verdicts = await task
-                except asyncio.CancelledError:
-                    raise
-                except Exception as e:
-                    clean = False
-                    self._verify_failure("engine", e)
-                    self._publish_verdict(
-                        TxVerdict(peer, tx.txid, False, (), stats,
-                                  error=f"engine: {e}"),
-                        relay=block is None,
-                    )
-                    continue
-                # candidate verdicts -> per-signature (consensus walk)
-                with span("node.commit"):
-                    per_sig = tuple(combine_verdicts(items, verdicts))
-                    self._publish_verdict(
-                        TxVerdict(peer, tx.txid, all(per_sig), per_sig,
-                                  stats),
-                        relay=block is None,
-                    )
-            if block is not None and clean:
-                # persistent UTXO connect only AFTER every verdict landed
-                # cleanly (mirrors the native path): the watermark means
-                # "verified AND applied" — an error-verdict block stays
-                # unpersisted so its re-delivery re-verifies
-                connecting = self._connect_block_utxo(block)
-        finally:
-            if block is not None and not connecting:
-                self._blocks_taken.discard(block.header.hash)
-                self._inflight_done(block.header.hash)
-            self._verify_pending -= 1
-            for _, _, _, task in per_tx:
-                if task is not None and not task.done():
-                    task.cancel()
-            # the message's pipeline trace (if any) ends with its verdicts
-            _finish_active_trace()
 
 
 class _TCPConnection:

@@ -778,7 +778,8 @@ def extract_raw(
     builds the in-block prevout->amount map (block ingest); ``ext_amounts``
     supplies per-input amounts flattened across txs in parse order, ``-1``
     or ``None`` entries meaning unknown — consulted after the intra map,
-    mirroring node._verify_txs's block_outs -> prevout_lookup precedence.
+    keeping the reference's block_outs -> prevout_lookup precedence
+    (``txverify.intra_block_prevouts``, then the embedder's oracle).
 
     One-shot convenience over :class:`ParsedTxRegion` (use that directly
     to combine prevout listing + extraction over a single parse).

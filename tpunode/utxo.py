@@ -31,11 +31,12 @@ going loudly stale; ``utxo.reorg_stale`` remains the fallback for reorgs
 deeper than the retained undo depth.  Disconnect followed by re-connect
 round-trips the UTXO set bit-identically (pinned by tests/test_utxo.py).
 
-Block connect has two producers: :meth:`apply_block` parses wire ``Tx``
-objects in Python (the reference path), and :meth:`apply_ops_blob`
+Block connect has one producer in the node: :meth:`apply_ops_blob`
 consumes the C++ extractor's one-pass delta blob
-(``ParsedTxRegion.utxo_ops``) so the Python per-tx parse leaves block
-ingest entirely (node._apply_block_utxo, ISSUE 11).  The blob is never
+(``ParsedTxRegion.utxo_ops``), so no Python per-tx parse is in block
+ingest (node._apply_block_utxo, ISSUE 11).  :meth:`apply_block`, which
+parses wire ``Tx`` objects in Python, is the reference the tests hold it
+to, bit for bit.  The blob is never
 unpacked into per-operation tuples: ``store.write_delta`` hands it to the
 store, which frames it natively into the records it appends and gives
 back only the keys and pre-spend values the undo record is made of
@@ -575,9 +576,8 @@ class InflightOutputs:
     ``MAX_VERIFY_PENDING``) bound how many blocks are here at once.
 
     This class keeps the rows in a dict, a Python statement an output:
-    what a node without the native library has (it parses its blocks in
-    Python too).  Where the library loads the node keeps a
-    :class:`NativeInflightOutputs`."""
+    the reference, and the base, of the :class:`NativeInflightOutputs` a
+    node keeps (tests/test_chain_cell.py holds the one to the other)."""
 
     def __init__(self):
         self._lock = threadsan.lock("utxo.inflight")
