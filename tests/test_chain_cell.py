@@ -673,7 +673,7 @@ def test_the_backlog_cells_are_seven():
     assert BACKLOG == [
         "bch-node.ibd", "bch-32mb.blocks", "bch-utxo.ibd-spend",
         "bch-32mb.single", "bch-wan.ibd-faults", "btc-node.ibd-taproot", CELL]
-    assert len(BENCH["workloads"]) == 10 and len(BENCH["configs"]) == 7
+    assert len(BENCH["workloads"]) == 11 and len(BENCH["configs"]) == 8
 
 
 @pytest.mark.parametrize("cell", BACKLOG)

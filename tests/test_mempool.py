@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+from types import SimpleNamespace
 
 import pytest
 
@@ -68,13 +69,39 @@ def signed_txs(n: int, seed: int, **kw) -> list:
 
 
 @contextlib.asynccontextmanager
-async def mempool_actor(cfg: MempoolConfig = None, **kw):
-    """A running Mempool actor with a counting submit hook."""
+async def mempool_actor(cfg: MempoolConfig = None, prevout_lookup=None, **kw):
+    """A running Mempool actor with a counting submit hook that plays the
+    part of the node's relay drain (``Node._extract_shard``, a shard of
+    one, none of its threads): the node's own walk over this mempool and
+    ``prevout_lookup``, its question to the mempool (``parks``) and its
+    probe extract; a tx the extractor would leave an input out of goes
+    back by ``orphaned`` and is not counted as submitted."""
+    from tpunode.node import _WalkLeft
+    from tpunode.txextract import ParsedTxRegion
+
     submitted: list = []
-    mp = Mempool(
+    node = SimpleNamespace(utxo=None, _inflight=None,
+                           cfg=SimpleNamespace(prevout_lookup=prevout_lookup))
+    node._prevout_sources = lambda: Node._prevout_sources(node)
+
+    def submit(peer, tx) -> None:
+        rec = (peer, tx, tx.serialize(), None)
+        region, left = ParsedTxRegion(rec[2], 1), _WalkLeft()
+        amounts, scripts = Node._resolve_ext_rows(node, region, NET.bch,
+                                                  left=left)
+        maybe = left.rows and Node._may_wait(
+            node, region.input_offsets().tolist(), [rec], left)
+        if not maybe:
+            region.close()
+        elif Node._extract_kept_and_close(region, [0], NET.bch, amounts,
+                                          scripts)[0]:
+            return node.mempool.orphaned(peer, tx, maybe[0])
+        submitted.append((peer, tx))
+
+    node.mempool = mp = Mempool(
         cfg if cfg is not None else MempoolConfig(tick_interval=0.02),
         net=NET,
-        submit=lambda peer, tx: submitted.append((peer, tx)),
+        submit=submit,
         **kw,
     )
     async with mp:
@@ -677,3 +704,135 @@ async def test_node_stats_and_health_carry_mempool():
     async with Node(cfg) as node:
         assert node.stats()["mempool"] == {"enabled": False}
         assert node.mempool is None
+
+
+# --- the orphan pool on BCH: a FORKID P2PKH child (ISSUE 48) ------------------
+#
+# The six orphan tests above pin the witness gate, with transactions that
+# cannot exist on the network they run on.  Their twins: a P2PKH spend signed
+# under SIGHASH_ALL|FORKID of a P2PKH output, which is what a BCH node is
+# relayed — and which, until ISSUE 48, no gate held.
+
+
+def _forkid_pair(seed: int):
+    from tests.unconf_cell import Maker
+
+    mk = Maker(seed)
+    funding, spender = mk.chain("p2pkh", 2)
+    return mk, funding, spender
+
+
+async def _bch_parked_then_resolved_by_parent():
+    mk, funding, spender = _forkid_pair(0x0F0)
+    p = StubPeer("a")
+    snap = metrics.snapshot()
+    # the callback holds the funding outpoints and nothing unconfirmed
+    async with mempool_actor(prevout_lookup=mk.callback) as (mp, submitted):
+        mp.tx_pushed(p, spender.lazy)  # child first: prevout unknown
+        await poll_until(lambda: mp.orphan_count() == 1, what="orphan parked")
+        assert not submitted
+        assert mp.state(spender.txid) == TxState.ORPHAN
+        mp.tx_pushed(p, funding.lazy)  # parent arrives: child re-admits
+        await poll_until(lambda: len(submitted) == 2, what="both submitted")
+        assert [t.txid for _, t in submitted] == [funding.txid, spender.txid]
+        assert mp.orphan_count() == 0
+        assert mp.lookup_prevout(funding.txid, 0) == funding.outs[0]
+    now = metrics.snapshot()
+    for name in ("mempool.orphan_resolved", "span.mempool.orphan_wait.count",
+                 'mempool.orphan_resolved_by{how="push"}'):
+        assert now.get(name, 0) - snap.get(name, 0) == 1, name
+
+
+async def _bch_ttl_expiry_admits_degraded():
+    mk, _, spender = _forkid_pair(0x77A)
+    async with mempool_actor(
+        MempoolConfig(orphan_ttl=0.05, tick_interval=0.02),
+        prevout_lookup=mk.callback,
+    ) as (mp, submitted):
+        mp.tx_pushed(StubPeer("a"), spender.lazy)
+        await poll_until(lambda: mp.orphan_count() == 1, what="orphan parked")
+        await poll_until(lambda: len(submitted) == 1, what="degraded admit")
+        assert mp.orphan_count() == 0
+        assert mp.state(spender.txid) == TxState.PENDING
+        # and the submit path's walk is told not to hand it back
+        assert mp.parks(spender.lazy, [(b"\x07" * 32, 0)]) == frozenset()
+
+
+async def _bch_size_bound_admits_oldest_degraded():
+    pairs = [_forkid_pair(0xC0 + i) for i in range(3)]
+    spenders = [p[2] for p in pairs]
+
+    def callback(txid, vout):
+        return next((hit for mk, _, _ in pairs
+                     if (hit := mk.callback(txid, vout))), None)
+
+    async with mempool_actor(
+        MempoolConfig(max_orphans=2, orphan_ttl=600, tick_interval=0),
+        prevout_lookup=callback,
+    ) as (mp, submitted):
+        for s in spenders:
+            mp.tx_pushed(StubPeer("a"), s.lazy)
+        await poll_until(lambda: mp.orphan_count() == 2, what="bounded pool")
+        assert [tx.txid for _, tx in submitted] == [spenders[0].txid]
+        assert mp.state(spenders[0].txid) == TxState.PENDING
+        assert {mp.state(s.txid) for s in spenders[1:]} == {TxState.ORPHAN}
+
+
+async def _bch_external_oracle_prevents_orphaning():
+    mk, _, spender = _forkid_pair(0x0AC)
+    async with mempool_actor(prevout_lookup=mk.prevout) as (mp, submitted):
+        mp.tx_pushed(StubPeer("a"), spender.lazy)
+        await poll_until(lambda: len(submitted) == 1, what="direct admit")
+        assert mp.orphan_count() == 0
+
+
+async def _bch_confirmed_unblocks_waiting_orphans():
+    mk, funding, spender = _forkid_pair(0x0FF)
+    oracle_on = []  # flipped on when the "block" with the parent connects
+    async with mempool_actor(
+        prevout_lookup=lambda t, v: (mk.prevout if oracle_on
+                                     else mk.callback)(t, v)
+    ) as (mp, submitted):
+        mp.tx_pushed(StubPeer("a"), spender.lazy)
+        await poll_until(lambda: mp.orphan_count() == 1, what="orphan parked")
+        oracle_on.append(True)
+        snap = metrics.snapshot()
+        mp.confirmed([funding.txid])
+        await poll_until(lambda: len(submitted) == 1, what="child admitted")
+        assert mp.state(funding.txid) == TxState.CONFIRMED
+        key = 'mempool.orphan_resolved_by{how="block"}'
+        assert metrics.snapshot().get(key, 0) - snap.get(key, 0) == 1
+
+
+async def _bch_admitted_after_parent_arrives_fakenet():
+    """Through Node: the park is the outcome of the drain's walk, the
+    callback answers the funding outpoints alone, and the child's verdict
+    is whole — until ISSUE 48 it was ``valid=True, verdicts=()``."""
+    mk, funding, spender = _forkid_pair(0x0A11)
+    relays = {17671: TxRelay([funding.lazy], announce=False, mode="serve",
+                             push=[spender.lazy])}
+    async with relay_node(relays, prevout_lookup=mk.callback) as (node, events):
+        async with asyncio.timeout(20):
+            seen = []
+            while len(seen) < 2:
+                ev = await events.receive()
+                if isinstance(ev, TxVerdict):
+                    seen.append(ev)
+            assert [v.txid for v in seen] == [funding.txid, spender.txid]
+            assert all(v.valid and v.stats.unsupported == 0 for v in seen)
+            assert seen[1].stats.extracted == 2 and len(seen[1].verdicts) == 2
+            assert node.mempool.orphan_count() == 0
+            assert node.mempool.stats()["orphan_resolved"] == 1
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("case", [
+    _bch_parked_then_resolved_by_parent,
+    _bch_ttl_expiry_admits_degraded,
+    _bch_size_bound_admits_oldest_degraded,
+    _bch_external_oracle_prevents_orphaning,
+    _bch_confirmed_unblocks_waiting_orphans,
+    _bch_admitted_after_parent_arrives_fakenet,
+], ids=lambda f: f.__name__[5:])
+async def test_orphan_pool_on_a_forkid_network(case):
+    await case()

@@ -173,12 +173,13 @@ def test_the_two_connect_metrics_read_the_utxo_connect_span():
         # the cells that connect blocks: PR 27 appended the tip cell,
         # PR 31 its two beside their siblings, PR 36 the IBD from a network,
         # PR 42 the BTC node's IBD, PR 44 the IBD of a chain that spends
-        # its own outputs
+        # its own outputs, PR 48 the tip under unconfirmed chains
         assert entry["workloads"] == ["bch-node.ibd", "bch-32mb.blocks",
                                       "bch-tip.tip", "bch-utxo.ibd-spend",
                                       "bch-32mb.single", "bch-wan.ibd-faults",
                                       "btc-node.ibd-taproot",
-                                      "bch-chain.ibd-recent"]
+                                      "bch-chain.ibd-recent",
+                                      "bch-unconf.tip-unconf"]
         assert entry["layer"] == "UTXO connect / store"
         assert entry["moves"] == "host_cpu_ms_per_ksig"
         assert (entry["unit"], entry["better"]) == ("ms/block", "lower")

@@ -20,16 +20,23 @@ txs are stolen straight from the roofline (PERF.md).  This actor owns:
   verified exactly once, and a re-push or re-announcement of a
   known-invalid tx costs zero verify work and feeds a per-peer
   misbehavior count.
-* **Orphan pool** — a tx whose witness-bearing inputs spend unknown
-  prevouts (not in the mempool, not resolvable via the embedder's
-  ``NodeConfig.prevout_lookup`` oracle) would verify degraded
+* **Orphan pool** — a tx with an input whose prevout data its digest
+  takes (a witness, a bare-P2PK spend, any input signed under
+  SIGHASH_FORKID) and no source answers would verify degraded
   (unsupported inputs), so it parks in a size- and age-bounded orphan
   set and re-enters admission when its parent arrives (push, fetch or
-  block).  Parked orphans' missing parents join the want-list — the
-  relaying peer likely has them.  An orphan leaving the pool
-  unresolved — aged out or size-evicted — is admitted anyway
-  (verify-what's-extractable — the pre-mempool behavior) so the
-  embedder still gets a verdict; size pressure never loses one.
+  block).  The park is the OUTCOME of the submit path's prevout walk
+  (ISSUE 48): every tx is admitted, the node's drain asks its sources —
+  this mempool, the outputs of blocks in flight, the UTXO set, the
+  embedder's callback — once a row, and a tx the extractor would leave
+  an input out of comes back through :meth:`Mempool.orphaned` instead
+  of going to the engine; nothing is asked twice, and this actor holds
+  no rule of its own about which inputs need a prevout.  Parked
+  orphans' missing parents join the want-list — the relaying peer
+  likely has them.  An orphan leaving the pool unresolved — aged out or
+  size-evicted — is admitted anyway (verify-what's-extractable — the
+  pre-mempool behavior) so the embedder still gets a verdict; size
+  pressure never loses one.
 * **Confirmation eviction** — block connect (txids from the block
   ingest path, C++-computed) flips entries to
   CONFIRMED, drops their payloads, and re-checks waiting orphans.
@@ -68,9 +75,8 @@ from .metrics import metrics
 from .params import Network
 from .peer import CannotDecodePayload, Peer, get_txs
 from .seenlru import SeenLru
-from .trace import span
+from .trace import record_span, span
 from .tracectx import discard_active as _discard_active_trace
-from .txverify import needs_prevout
 from .util import double_sha256, hash_to_hex
 
 __all__ = ["MempoolConfig", "Mempool", "TxState"]
@@ -119,7 +125,7 @@ class _Entry:
     """One seen txid: state + (while useful) the tx and its outputs."""
 
     __slots__ = ("txid", "wtxid", "state", "tx", "outputs", "origin",
-                 "missing", "added", "verdicts", "stats")
+                 "missing", "added", "verdicts", "stats", "degraded")
 
     def __init__(self, txid: bytes, wtxid: bytes, state: str, tx=None,
                  outputs=None, origin: str = "?"):
@@ -138,6 +144,9 @@ class _Entry:
         # VALID or INVALID by a relay verdict: what makes it readable by
         # the block path (relay_verdicts)
         self.stats = None
+        # PENDING: admitted though a parent is missing (an orphan that left
+        # the pool unresolved): the walk does not hand it back
+        self.degraded = False
 
 
 class _Want:
@@ -175,6 +184,13 @@ class _Verdict:
     verdicts: tuple
     error: Optional[str]
     stats: object = None  # the TxVerdict's ExtractStats
+
+
+@dataclass(frozen=True)
+class _Orphaned:
+    peer: object
+    tx: object
+    parents: frozenset
 
 
 @dataclass(frozen=True)
@@ -228,8 +244,9 @@ class Mempool:
     """The mempool actor handle + query API.
 
     ``submit(peer, tx)`` is the verify-ingest hook (node.py's
-    ``_submit_verify_tx``); ``prevout_lookup`` is the embedder's UTXO
-    oracle (NodeConfig.prevout_lookup); ``pressure()`` true defers fetch
+    ``_submit_verify_tx``), which hands a tx it cannot verify whole back
+    through :meth:`orphaned` (the node's drain: its walk asks every
+    source it has, once); ``pressure()`` true defers fetch
     scheduling (ingest backpressure); ``pressure_key(txid)`` true defers
     fetching just THAT txid (ISSUE 19 host-affine backpressure: one
     slow verify host parks only its own keys, the rest keep fetching).
@@ -241,7 +258,6 @@ class Mempool:
         cfg: MempoolConfig,
         net: Network,
         submit: Callable[[object, object], None],
-        prevout_lookup: Optional[Callable] = None,
         pressure: Optional[Callable[[], bool]] = None,
         pressure_key: Optional[Callable[[bytes], bool]] = None,
         on_failure=None,
@@ -249,7 +265,6 @@ class Mempool:
         self.cfg = cfg
         self.net = net
         self._submit = submit
-        self._oracle = prevout_lookup
         self._pressure = pressure
         self._pressure_key = pressure_key
         self.mailbox: Mailbox = Mailbox(name="mempool")
@@ -268,6 +283,10 @@ class Mempool:
         self._waiting: dict[bytes, set[bytes]] = {}  # parent -> orphans
         self._want: "OrderedDict[bytes, _Want]" = OrderedDict()
         self._inflight: dict[Peer, int] = {}
+        # txids this node sent a getdata for that has not failed, and
+        # when: a tx admitted while it is here came by fetch (the RPC's
+        # end and the tx's own message reach this mailbox in either order)
+        self._asked: dict[bytes, float] = {}
         self._sched_queued = False  # a _Sched marker is in the mailbox
         self._size = 0  # PENDING + VALID entries
         self._finished = 0  # entries with a relay verdict's stats
@@ -304,6 +323,8 @@ class Mempool:
                 self._on_invs(msg.peer, msg.txids)
             elif isinstance(msg, _Verdict):
                 self._on_verdict(msg)
+            elif isinstance(msg, _Orphaned):
+                self._on_orphaned(msg)
             elif isinstance(msg, _Confirmed):
                 self._on_confirmed(msg.txids)
             elif isinstance(msg, _ConfirmedBlock):
@@ -343,6 +364,36 @@ class Mempool:
         self.mailbox.send(
             _Verdict(txid, valid, tuple(verdicts), error, stats)
         )
+
+    def parks(self, tx, outpoints) -> "frozenset[bytes]":
+        """The submit path's walk found no source for the ``outpoints``
+        (``(txid, vout)`` pairs) that inputs of ``tx`` spend (ISSUE 48).
+        -> the parents (txids) worth waiting for; where there are any and
+        the extractor would leave such an input out, the caller hands the
+        tx back (:meth:`orphaned`).  Empty: verify it as it stands — it
+        was admitted degraded (an orphan that left the pool unresolved),
+        it is no pending entry of this mempool, or no such parent can
+        bring the output: it is in a connected block (the chain answers
+        for its outputs: spent, or never there), or it is here with its
+        outputs and has none of that number.  A read of loop-owned state:
+        call it from the loop."""
+        e = self._seen.get(tx.txid)
+        if e is None or e.state != TxState.PENDING or e.degraded:
+            return frozenset()
+        wait = set()
+        for txid, _vout in outpoints:
+            p = self._seen.get(txid)
+            # here with outputs, the walk would have found one in range
+            if p is None or (p.state != TxState.CONFIRMED
+                             and p.outputs is None):
+                wait.add(txid)
+        return frozenset(wait)
+
+    def orphaned(self, peer, tx, parents: "frozenset[bytes]") -> None:
+        """``tx``, admitted and submitted, waits for ``parents``
+        (:meth:`parks`): the caller publishes no verdict for it, its
+        admission is undone and it is parked until they come."""
+        self.mailbox.send(_Orphaned(peer, tx, parents))
 
     def confirmed(self, txids: "list[bytes]") -> None:
         """Block connect: these txids are now in a block."""
@@ -483,8 +534,10 @@ class Mempool:
     def _admit(self, peer, tx, re_entry: bool = False,
                force: bool = False, resolve: bool = True) -> bool:
         """Run one tx through admission.  Returns True iff it was
-        submitted to the verify pipeline (False: dedup hit, parked as
-        orphan, or rejected as malformed)."""
+        submitted to the verify pipeline (False: dedup hit or rejected as
+        malformed).  ``re_entry``: an orphan leaving the pool, which
+        ``mempool.admitted`` counted when it first came; ``force``: it
+        leaves with a parent missing, and is not handed back for it."""
         origin = _label(peer)
         raw = getattr(tx, "raw", None)
         if raw is not None and not re_entry:
@@ -519,27 +572,24 @@ class Mempool:
             return False
         if wtxid != txid:
             self._seen.alias(wtxid, txid)
-        if not force:
-            missing = self._missing_parents(tx)
-            if missing:
-                self._park_orphan(peer, tx, txid, wtxid, missing,
-                                  re_entry=re_entry)
-                return False
         outputs = tuple(
             (tx.outputs[i].value, tx.outputs[i].script) for i in range(n_out)
         )
         entry = _Entry(txid, wtxid, TxState.PENDING, tx=tx,
                        outputs=outputs, origin=origin)
+        entry.degraded = force
         self._insert_seen(entry)
         self._size += 1
-        self._admitted += 1
-        metrics.inc("mempool.admitted")
+        if not re_entry:
+            self._admitted += 1
+            metrics.inc("mempool.admitted")
         metrics.set_gauge("mempool.size", self._size)
+        asked = self._asked.pop(txid, None) is not None
         self._drop_want(txid)
         self._submit(peer, tx)
         if resolve:
             # a newly admitted tx may be the parent an orphan waits for
-            self._resolve_waiting(txid)
+            self._resolve_waiting(txid, "fetch" if asked else "push")
         return True
 
     def _dedup_hit(self, peer, txid: bytes) -> None:
@@ -552,30 +602,6 @@ class Mempool:
                 # a verdict served from cache: zero verify work, and the
                 # peer relaying a known-invalid tx is counted against it
                 self._misbehave(peer, "relayed-known-invalid")
-
-    def _missing_parents(self, tx) -> "set[bytes]":
-        """Parent txids whose absence would degrade this tx's
-        verification: only inputs whose digest/classification actually
-        consumes prevout data gate admission (txverify.needs_prevout) —
-        a legacy input with an unknown prevout verifies fine and must
-        not orphan the tx."""
-        missing: set[bytes] = set()
-        for idx, txin in enumerate(tx.inputs):
-            if not needs_prevout(tx, idx):
-                continue
-            prev = txin.prevout
-            e = self._seen.get(prev.txid)
-            if e is not None:
-                if e.outputs is not None and prev.index < len(e.outputs):
-                    continue
-                if e.state == TxState.CONFIRMED:
-                    continue  # in the chain: the embedder's oracle owns it
-            if self._oracle is not None and (
-                self._oracle(prev.txid, prev.index) is not None
-            ):
-                continue
-            missing.add(prev.txid)
-        return missing
 
     def _insert_seen(self, entry: _Entry) -> None:
         # eviction policy (PENDING rotation, 2x ceiling) lives in the
@@ -604,7 +630,7 @@ class Mempool:
     # -- orphan pool --------------------------------------------------------
 
     def _park_orphan(self, peer, tx, txid: bytes, wtxid: bytes,
-                     missing: "set[bytes]", re_entry: bool = False) -> None:
+                     missing: "set[bytes]") -> None:
         entry = _Entry(txid, wtxid, TxState.ORPHAN, tx=tx,
                        origin=_label(peer))
         entry.missing = missing
@@ -616,8 +642,7 @@ class Mempool:
             # put the parent on the want-list sourced from that peer
             if isinstance(peer, Peer):
                 self._want_tx(parent, peer)
-        if not re_entry:
-            metrics.inc("mempool.orphaned")
+        metrics.inc("mempool.orphaned")
         metrics.set_gauge("mempool.orphans", len(self._orphans))
         events.emit("mempool.orphan", txid=hash_to_hex(txid),
                     missing=len(missing), peer=entry.origin)
@@ -636,6 +661,31 @@ class Mempool:
                         force=True)
         self._schedule_soon()
 
+    def _on_orphaned(self, m: _Orphaned) -> None:
+        """The walk's hand-back (:meth:`orphaned`): undo the admission and
+        park the tx — unless every parent it lacked came while the message
+        waited here, in which case it goes straight back to the walk."""
+        txid = m.tx.txid
+        e = self._seen.get(txid)
+        if e is None or e.state != TxState.PENDING or e.tx is None:
+            return  # confirmed or evicted meanwhile
+        missing = {p for p in m.parents if not self._answers(p)}
+        if not missing:
+            self._submit(m.peer, m.tx)
+            return
+        self._seen.pop(txid, None)
+        self._forget(txid, e)
+        if e.wtxid != txid:
+            self._seen.alias(e.wtxid, txid)
+        self._park_orphan(m.peer, m.tx, txid, e.wtxid, missing)
+
+    def _answers(self, parent: bytes) -> bool:
+        """Is ``parent`` here with its outputs, or in a connected block?"""
+        e = self._seen.get(parent)
+        return e is not None and (
+            e.outputs is not None or e.state == TxState.CONFIRMED
+        )
+
     def _unpark(self, txid: bytes, e: _Entry, pop: bool = True) -> None:
         """Remove orphan bookkeeping (the seen entry is the caller's)."""
         if pop:
@@ -648,11 +698,12 @@ class Mempool:
                     del self._waiting[parent]
         metrics.set_gauge("mempool.orphans", len(self._orphans))
 
-    def _resolve_waiting(self, parent: bytes) -> None:
-        """A parent arrived (admitted or confirmed): re-run admission for
-        the orphans that were waiting on it.  Iterative worklist — a
-        deep orphan chain resolving parent-by-parent must not recurse
-        ``max_orphans`` frames deep."""
+    def _resolve_waiting(self, parent: bytes, how: str) -> None:
+        """A parent arrived (admitted by ``push`` or by ``fetch``, or
+        confirmed in a ``block``): re-run admission for the orphans that
+        were waiting on it.  Iterative worklist — a deep orphan chain
+        resolving parent-by-parent must not recurse ``max_orphans`` frames
+        deep."""
         queue = [parent]
         while queue:
             parent = queue.pop()
@@ -668,12 +719,17 @@ class Mempool:
                     continue  # still waiting on other parents
                 self._unpark(child_txid, e)
                 self._seen.pop(child_txid, None)
-                # re-admission re-checks every prevout: other parents
-                # may have been evicted meanwhile -> it re-parks
+                # the walk reads every prevout again: where another
+                # parent was evicted meanwhile it hands the tx back
                 if self._admit(_Origin(e.origin), e.tx, re_entry=True,
                                resolve=False):
                     self._orphan_resolved += 1
-                    metrics.inc("mempool.orphan_resolved")
+                    record_span("mempool.orphan_wait",
+                                time.monotonic() - e.added)
+                    metrics.inc_batch((
+                        ("mempool.orphan_resolved", 1, None),
+                        ("mempool.orphan_resolved_by", 1, {"how": how}),
+                    ))
                     events.emit(
                         "mempool.orphan_resolved",
                         txid=hash_to_hex(child_txid),
@@ -760,7 +816,7 @@ class Mempool:
         # are now the embedder oracle's/chain's responsibility) — seen
         # or not: an orphan can wait on a parent that was never relayed
         for txid in txids:
-            self._resolve_waiting(txid)
+            self._resolve_waiting(txid, "block")
 
     def _on_confirmed_block(self, block) -> None:
         try:
@@ -842,6 +898,7 @@ class Mempool:
                     batch = batches.setdefault(p, [])
                 batch.append(txid)
                 w.inflight = p
+                self._asked[txid] = time.monotonic()
                 break
         if deferred_txs:
             metrics.inc("mempool.fetch_deferred_txs", deferred_txs)
@@ -888,6 +945,7 @@ class Mempool:
                 # push path owns admission from here
                 del self._want[txid]
                 continue
+            self._asked.pop(txid, None)  # what comes now, comes by itself
             w.attempts += 1
             w.tried.add(peer)
             w.announcers = [p for p in w.announcers if p is not peer]
@@ -937,6 +995,10 @@ class Mempool:
                 expired += 1
         if expired:
             metrics.inc("mempool.want_expired", expired)
+        # asked for and never admitted (a duplicate by then, notfound)
+        for txid in [t for t, at in self._asked.items()
+                     if now - at > self.cfg.fetch_timeout]:
+            del self._asked[txid]
 
     def _misbehave(self, peer, why: str) -> None:
         metrics.inc("mempool.misbehavior")

@@ -303,6 +303,38 @@ class _ExtractJobs:
             self._holds.let_go()
 
 
+class _WalkLeft:
+    """What a relay shard's prevout walk leaves with its caller
+    (:meth:`Node._resolve_ext_rows`, ``left=``): the rows no source
+    answered, the outpoint ``(txid, vout)`` each spends, and the walk's
+    counts."""
+
+    __slots__ = ("rows", "spends", "tally")
+
+    def __init__(self):
+        self.rows: list = []
+        self.spends: list = []
+        self.tally: list = []
+
+
+def _count_walk(tally: list, but: "frozenset[int]" = frozenset()) -> None:
+    """A walk's counters, one registry update: every entry of ``tally``
+    is ``(counter, rows asked, rows left unanswered)`` and counts the rows
+    between them.  ``but``: rows the walk left that a tx now waits for in
+    the orphan pool — in no count yet, neither asked nor missing: they are
+    counted by the walk that answers them, when the tx comes again.  (A
+    row that was answered counts in every walk that read it, here as in
+    the sources' own counters: the shares of ``node.resolve_rows`` sum
+    to 100.)"""
+    counts = []
+    for name, asked, rest in tally:
+        n = len(asked) - len(rest)
+        if but:
+            n -= sum(i in but for i in asked) - sum(i in but for i in rest)
+        counts.append((name, n, None))
+    metrics.inc_batch(counts)
+
+
 def _hash_rows(rows) -> "list[bytes]":
     """An ``(n, 32)`` uint8 array of hashes as a list of ``bytes``."""
     blob = rows.tobytes()
@@ -624,7 +656,6 @@ class Node:
                 cfg.mempool,
                 net=cfg.net,
                 submit=self._mempool_submit,
-                prevout_lookup=cfg.prevout_lookup,
                 pressure=self._ingest_pressure,
                 pressure_key=self._ingest_pressure_key,
                 on_failure=self._component_failed,
@@ -1826,7 +1857,7 @@ class Node:
             self.cfg.pub.publish(VerifyShed(peer, n, pending))
 
     def _resolve_ext_rows(self, region, bch: bool, subset=None,
-                          final: bool = True, shards=None):
+                          final: bool = True, shards=None, left=None):
         """External-oracle rows for a parsed region: per-input amounts and
         scriptPubKeys, aligned with the region's flat input order —
         ``(amounts, -1 unknown; scripts, None unknown)``, two lists, or
@@ -1866,6 +1897,13 @@ class Node:
         ``node.resolve`` closes.  Only a final read hands on: one that
         may be made again (``final=False``) submits nothing.
 
+        ``left`` (a relay drain's shard, :class:`_WalkLeft`): takes the
+        rows no source answered, each with the outpoint it spends, and
+        the walk's counts, which the caller commits once it knows which
+        txs wait for a parent instead of going to the extractor
+        (ISSUE 48): the rows they wait for are counted when they come
+        again (:func:`_count_walk`).
+
         ONE hold of the loop: no ``await`` between the first read and the
         last — nor the last submission —, and nothing kept from one call
         to the next."""
@@ -1875,7 +1913,9 @@ class Node:
         with span("node.resolve"):
             txids, outpoints, vouts, wants = region.scan_outpoints(bch, subset)
             todo = wants.nonzero()[0].tolist()  # wanted, unanswered yet
-            counts = [("node.resolve_rows", len(todo), None)]
+            # what is counted: (counter, the rows a source was asked, the
+            # rows it left)
+            tally = [("node.resolve_rows", todo, ())]
             txids = _rows_of(txids)
             vouts = vouts.tolist()
             amounts = [-1] * len(vouts)
@@ -1899,22 +1939,25 @@ class Node:
 
             # the sources the program owns: one batch read each
             if mempool is not None:
+                asked = todo
                 todo = absorb(todo, mempool.lookup_prevouts(
                     [txids[i] for i in todo], [vouts[i] for i in todo]
                 ))
+                tally.append(("node.resolve_mempool_hits", asked, todo))
             if utxo is not None:
                 keys = _rows_of(outpoints)
                 ask = [keys[i] for i in todo]
                 if inflight is not None and todo:
                     answers = inflight.lookup_many(ask)
-                    hits = len(answers) - answers.count(None)
-                    counts += (
-                        ("node.resolve_inflight_rows", len(todo), None),
-                        ("node.resolve_inflight_hits", hits, None),
-                    )
-                    if hits:  # else every row goes on as it came
+                    asked = todo
+                    if answers.count(None) < len(answers):
+                        # else every row goes on as it came
                         todo = absorb(todo, answers)
                         ask = [keys[i] for i in todo]
+                    tally += (
+                        ("node.resolve_inflight_rows", asked, ()),
+                        ("node.resolve_inflight_hits", asked, todo),
+                    )
                 todo = absorb(todo, utxo.lookup_many(ask))
 
             def call_back(rows: list) -> list:
@@ -1927,7 +1970,7 @@ class Node:
                 ))
 
             if oracle is not None:
-                metrics.inc("node.resolve_oracle_calls", len(todo))
+                tally.append(("node.resolve_oracle_calls", todo, ()))
             if shards is None or not final:
                 todo = call_back(todo)
             else:
@@ -1957,8 +2000,13 @@ class Node:
                 # rows no source answered, the block itself included: the
                 # extractor marks such an input unsupported and nothing
                 # verifies it
-                counts.append(("node.resolve_missing", len(todo), None))
-            metrics.inc_batch(counts)
+                tally.append(("node.resolve_missing", todo, ()))
+            if left is None:
+                _count_walk(tally)
+            else:
+                left.tally = tally
+                left.rows = todo
+                left.spends = [(txids[i], vouts[i]) for i in todo]
             return amounts, scripts
 
     def _submit_verify_tx(self, peer, tx) -> None:
@@ -2092,9 +2140,49 @@ class Node:
         finally:
             region.close()
 
-    async def _run_extract_owned(self, region, _pool=None, **kw):
+    @staticmethod
+    def _extract_kept_and_close(region, maybe: list, bch: bool, amounts,
+                                scripts):
+        """A relay shard's extract where the walk left rows unanswered
+        (ISSUE 48): of the txs ``maybe`` (ascending indices: those with
+        such a row that the mempool would park) the ones the extractor
+        leaves an input out of wait for their parents, the rest of the
+        shard is extracted.  The extractor itself says which — a probe
+        over ``maybe`` alone, uncounted —, so the gate cannot drift from
+        it: a spend signed without SIGHASH_FORKID needs no amount and
+        goes on.  -> ``(the waiting txs' indices, the others' items or
+        None where none is left)``; closes the region, as
+        :meth:`_extract_and_close` does."""
+        try:
+            off = region.input_offsets().tolist()
+
+            def rows_of(txs: list) -> dict:
+                return dict(
+                    bch=bch, intra_amounts=False,
+                    ext_amounts=[a for t in txs
+                                 for a in amounts[off[t]:off[t + 1]]],
+                    ext_scripts=[s for t in txs
+                                 for s in scripts[off[t]:off[t + 1]]],
+                )
+
+            probe = region.extract_subset(maybe, **rows_of(maybe))
+            waits = [t for t, n in zip(maybe, probe.tx_unsupported.tolist())
+                     if n]
+            gone = set(waits)
+            keep = [t for t in range(region.n_txs) if t not in gone]
+            items = None
+            if keep:
+                items = _extract_counted(
+                    region.extract_subset, tx_indices=keep, **rows_of(keep)
+                )
+            return waits, items
+        finally:
+            region.close()
+
+    async def _run_extract_owned(self, region, _pool=None, _job=None, **kw):
         """Submit the extract with close-ownership attached: the worker
-        thread closes the region when the job RUNS (`_extract_and_close`);
+        thread closes the region when the job RUNS (`_extract_and_close`,
+        or ``_job`` in its place);
         a job cancelled while still QUEUED (node teardown, pool
         `cancel_futures`) never runs, so the done-callback closes it.
 
@@ -2108,7 +2196,7 @@ class Node:
         pool = _pool if _pool is not None else self._extract_pool
         assert pool is not None  # built with the engine
         cfut = pool.submit(
-            self._extract_and_close, region, **kw
+            _job or self._extract_and_close, region, **kw
         )
         cfut.add_done_callback(
             lambda f: region.close() if f.cancelled() else None
@@ -2118,8 +2206,19 @@ class Node:
     async def _extract_shard(self, shard: list, bch: bool):
         """One C++ extract over a contiguous run of accumulated txs
         (``intra_amounts`` off — mempool txs are independent, exactly
-        like the old per-message path).  Returns RawSigItems, or None on
-        failure (the caller isolates the offender per tx)."""
+        like the old per-message path).  -> ``(the shard's records that
+        were extracted, their RawSigItems)``; the items are None on
+        failure (the caller isolates the offender per tx).
+
+        A tx that cannot be verified whole yet goes back to the mempool
+        (ISSUE 48): where the walk leaves a wanted row unanswered, the
+        mempool would wait for that row's parent (``Mempool.parks``) and
+        the extractor would leave the input out
+        (:meth:`_extract_kept_and_close`), the tx is handed back
+        (``Mempool.orphaned``), parked as an orphan, and has no part in
+        the shard's verdicts; it comes again when its
+        parent is here.  The walk is the gate: no row is asked twice, and
+        a shard whose rows are all answered pays nothing for it."""
         from .txextract import ParsedTxRegion
 
         concat = b"".join(r for _, _, r, _ in shard)
@@ -2134,29 +2233,67 @@ class Node:
                 pool = None
         region = None
         submitted = False
+        left = _WalkLeft() if self.mempool is not None else None
+        maybe: dict = {}  # tx index -> the parents it would wait for
+        unsettled: frozenset = frozenset()  # rows that a tx waits for
         try:
             region = await self._run_extract(
                 ParsedTxRegion, concat, len(shard), _pool=pool
             )
             # oracle lookups stay on the loop thread (they read
             # mempool/utxo state owned by it)
-            ext, ext_scripts = self._resolve_ext_rows(region, bch)
+            ext, ext_scripts = self._resolve_ext_rows(region, bch, left=left)
+            if left is not None and left.rows:  # hardly ever
+                off = region.input_offsets().tolist()
+                maybe = self._may_wait(off, shard, left)
             submitted = True  # from here the job owns close
-            return await self._run_extract_owned(
-                region,
-                _pool=pool,
-                bch=bch,
-                intra_amounts=False,
-                ext_amounts=ext,
-                ext_scripts=ext_scripts,
+            if not maybe:
+                return shard, await self._run_extract_owned(
+                    region,
+                    _pool=pool,
+                    bch=bch,
+                    intra_amounts=False,
+                    ext_amounts=ext,
+                    ext_scripts=ext_scripts,
+                )
+            waits, items = await self._run_extract_owned(
+                region, _pool=pool, _job=self._extract_kept_and_close,
+                maybe=sorted(maybe), bch=bch, amounts=ext,
+                scripts=ext_scripts,
             )
+            gone = set(waits)
+            unsettled = frozenset(
+                i for i in left.rows if bisect.bisect_right(off, i) - 1 in gone
+            )
+            for t in waits:
+                self.mempool.orphaned(shard[t][0], shard[t][1], maybe[t])
+            return [r for t, r in enumerate(shard) if t not in gone], items
         except asyncio.CancelledError:
             raise
-        except Exception:
-            return None
+        except Exception as e:
+            log.debug("[Node] relay shard extract failed: %s", e)
+            return shard, None
         finally:
             if region is not None and not submitted:
                 region.close()
+            if left is not None and left.tally:
+                # the rows a tx waits for are counted when it comes again
+                _count_walk(left.tally, unsettled)
+
+    def _may_wait(self, off: list, shard: list, left) -> dict:
+        """tx index -> the parents the mempool would have it wait for,
+        over the txs of a relay shard with a row the walk left
+        unanswered (``off``: each tx's first row)."""
+        spends: dict = {}
+        for row, outpoint in zip(left.rows, left.spends):
+            spends.setdefault(bisect.bisect_right(off, row) - 1,
+                              []).append(outpoint)
+        waits = {}
+        for t, outpoints in spends.items():
+            parents = self.mempool.parks(shard[t][1], outpoints)
+            if parents:
+                waits[t] = parents
+        return waits
 
     async def _ring_acquire(self) -> None:
         await self._extract_ring.acquire()
@@ -2176,7 +2313,8 @@ class Node:
         verdict publication through a bounded ring so extraction of
         batch K+1 overlaps verification of K.  A malformed tx poisons
         only itself: on shard extract failure each of its txs retries
-        individually (:meth:`_verify_txs_native`), so one hostile peer
+        as a shard of one (and the one that fails again gets its error
+        verdict from :meth:`_verify_txs_native`), so one hostile peer
         cannot fail other peers' verdicts."""
         bch = self.cfg.net.bch
         # The drain task inherited the FIRST accumulated message's trace
@@ -2202,14 +2340,37 @@ class Node:
                     extracted = await asyncio.gather(
                         *(self._extract_shard(s, bch) for s in shards)
                     )
+                    failed = [rec for shard, items in extracted
+                              if items is None for rec in shard]
+                    if failed:
+                        # isolate the offender: each tx of a failed shard
+                        # is a shard of its own, so what holds for a tx
+                        # in company (the walk's hand-back) holds for it
+                        # alone
+                        extracted = [
+                            e for e in extracted if e[1] is not None
+                        ] + await asyncio.gather(
+                            *(self._extract_shard([rec], bch)
+                              for rec in failed)
+                        )
             finally:
                 self._end_tx_spans(recs)
+            if sum(len(shard) for shard, _ in extracted) < len(batch):
+                # txs that went back to the mempool to wait for a parent
+                # (:meth:`_extract_shard`): this pass of their pipeline
+                # ends here, unretained
+                kept = {id(r) for shard, _ in extracted for r in shard}
+                for rec in batch:
+                    if id(rec) not in kept and rec[3] is not None:
+                        tracer.discard(rec[3][0])
             pairs = []
-            for shard, items in zip(shards, extracted):
+            for shard, items in extracted:
+                if not shard:
+                    continue  # every tx of it waits for a parent
                 if items is None:
-                    # isolate the offender: each tx goes through
-                    # _verify_txs_native on its own (error verdicts +
-                    # peer kill there; finishes each tx's trace too)
+                    # the offender, alone: _verify_txs_native publishes
+                    # its error verdict (and kills the peer of a tx that
+                    # cannot be parsed; finishes its trace too)
                     for peer, tx, raw, act in shard:
                         with _activate_trace(act):
                             await self._verify_txs_native(

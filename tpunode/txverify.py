@@ -60,7 +60,6 @@ __all__ = [
     "intra_block_amounts",
     "intra_block_prevouts",
     "wants_amount",
-    "needs_prevout",
     "is_p2tr",
     "is_p2pk",
     "is_single_key_tapscript",
@@ -93,23 +92,6 @@ def wants_amount(tx: Tx, idx: int, bch: bool) -> bool:
     never use prevout data, so callers skip their (possibly expensive)
     lookups."""
     if bch or tx.has_witness:
-        return True
-    return _is_single_push_sig(tx.inputs[idx].script)
-
-
-def needs_prevout(tx: Tx, idx: int) -> bool:
-    """Would verification of this tx be DEGRADED without input ``idx``'s
-    prevout data?  The mempool's orphan gate (tpunode/mempool.py).
-
-    Stricter than :func:`wants_amount`: a witness-carrying tx digests
-    prevout amounts/scripts (BIP143 per-input; BIP341 every-input, so the
-    gate is tx-level when any witness is present), and a single-push
-    scriptSig (bare P2PK) needs the prevout script to identify the
-    template — but the blanket FORKID clause is dropped: a legacy BCH
-    spend extracts and verifies fine without the oracle (pinned by the
-    fakenet ingest tests), so an unknown legacy prevout must not park
-    the tx as an orphan."""
-    if tx.has_witness:
         return True
     return _is_single_push_sig(tx.inputs[idx].script)
 
